@@ -702,6 +702,13 @@ class AotExecutableCache:
             from jax.experimental.serialize_executable import serialize
 
             payload, in_tree, out_tree = serialize(compiled)
+            # the devices this executable was compiled for: load must
+            # hand exactly these back to deserialize_and_load, whose
+            # default is EVERY device of the backend (a one-device
+            # executable loaded as an N-device one fails at first call)
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
             blob = pickle.dumps(
                 (payload, in_tree, out_tree),
                 protocol=pickle.HIGHEST_PROTOCOL,
@@ -711,6 +718,7 @@ class AotExecutableCache:
                 "kernel": name,
                 "aot_key": key,
                 "fingerprint": self.fingerprint(),
+                "device_ids": device_ids,
                 "created_ms": int(time.time() * 1000),
                 "compile_ms": (
                     round(compile_ms, 3) if compile_ms is not None else None
@@ -756,13 +764,24 @@ class AotExecutableCache:
             self._bump("stale_fingerprint")
             self._evict(path)
             return None
+        devices = self._execution_devices(header.get("device_ids"))
+        if devices is None:
+            # compiled for devices this process does not have (or an
+            # entry from before device_ids was recorded): a counted
+            # miss, never a load onto some other device set
+            self._bump("stale_fingerprint")
+            self._evict(path)
+            return None
         try:
             from jax.experimental.serialize_executable import (
                 deserialize_and_load,
             )
 
             payload, in_tree, out_tree = pickle.loads(blob)
-            return deserialize_and_load(payload, in_tree, out_tree)
+            return deserialize_and_load(
+                payload, in_tree, out_tree,
+                backend=devices[0].client, execution_devices=devices,
+            )
         # lint: allow(broad-except) undeserializable entry -> compile
         except Exception as e:
             self._bump("load_errors")
@@ -771,6 +790,19 @@ class AotExecutableCache:
             )
             self._evict(path)
             return None
+
+    @staticmethod
+    def _execution_devices(device_ids) -> list | None:
+        """The recorded device ids resolved against this process's
+        devices, in recorded order; None when any is absent."""
+        import jax
+
+        if not device_ids:
+            return None
+        by_id = {d.id: d for d in jax.devices()}
+        if any(i not in by_id for i in device_ids):
+            return None
+        return [by_id[i] for i in device_ids]
 
     def load(self, name: str, key: str):
         """The warm path: claim a preloaded executable or deserialize

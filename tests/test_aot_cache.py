@@ -222,6 +222,49 @@ class TestAotCacheUnit:
         assert s["hits"] == 1  # the load itself succeeded...
         assert s["load_errors"] == 1  # ...but its first call rejected
 
+    @pytest.mark.parametrize("n_dev", [1, 4], ids=["device0", "mesh4"])
+    def test_loads_onto_the_devices_it_was_compiled_for(
+        self, aot_dir, n_dev
+    ):
+        """An executable compiled for device 0 (or a 4-device mesh) of
+        the 8 virtual devices is loaded onto exactly those devices and
+        CALLED — deserialize_and_load's default of every backend device
+        loads fine and only fails at the first call."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        devs = jax.devices()[:n_dev]
+        assert len(jax.devices()) > n_dev
+        sharding = NamedSharding(Mesh(np.array(devs), ("x",)), P("x"))
+        x = jax.device_put(jnp.arange(64, dtype=jnp.int32), sharding)
+        fn = jax.jit(lambda v: (v * 5 + 1).sum() + v)
+        compiled = fn.lower(x).compile()
+        assert aot_dir.store("dev-kern", f"d{n_dev}", compiled, 1.0)
+        [entry] = aot_dir._entry_paths()
+        header, _ = AotExecutableCache._read_file(entry)
+        assert header["device_ids"] == [d.id for d in devs]
+
+        loaded = aot_dir.load("dev-kern", f"d{n_dev}")
+        assert loaded is not None
+        out = loaded(x)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(fn(x)))
+        assert {d.id for d in out.sharding.device_set} == {
+            d.id for d in devs
+        }
+
+    def test_entry_for_absent_devices_is_a_counted_miss(self, aot_dir):
+        compiled = jax.jit(lambda v: v + 1).lower(
+            jnp.arange(4, dtype=jnp.int32)
+        ).compile()
+        assert aot_dir.store("gone-kern", "gk", compiled, 1.0)
+        [path] = aot_dir._entry_paths()
+        header, blob = AotExecutableCache._read_file(path)
+        header["device_ids"] = [len(jax.devices()) + 7]
+        with open(path, "wb") as f:
+            f.write(json.dumps(header).encode() + b"\n" + blob)
+        assert aot_dir.load("gone-kern", "gk") is None
+        s = aot_dir.summary()
+        assert s["misses"] == 1 and s["hits"] == 0 and s["entries"] == 0
+
     def test_disabled_cache_is_total_noop(self):
         cache = configure_aot("off")
         compiled = jax.jit(lambda v: v).lower(

@@ -3,6 +3,7 @@
 
 import asyncio
 
+from openr_tpu.kvstore.wrapper import wait_until
 from openr_tpu.messaging import ReplicateQueue
 from openr_tpu.runtime import (
     Actor,
@@ -73,24 +74,32 @@ async def test_debounce_bounded_staleness_under_storm():
 async def test_debounce_postpones_like_reference():
     # Reference contract (AsyncDebounce.h:44-52): every call below max
     # backoff RESCHEDULES the pending fire with a doubled window; calls at
-    # max backoff leave it alone.
+    # max backoff leave it alone. Postponement is read off the timer
+    # handles (the loop's own schedule), not off how long a sleep took:
+    # a loaded host stretches sleeps, it does not move deadlines earlier.
+    loop = asyncio.get_running_loop()
     fired = []
-    db = AsyncDebounce(0.02, 0.08, lambda: fired.append(1))
+    db = AsyncDebounce(0.02, 0.08, lambda: fired.append(loop.time()))
+    t0 = loop.time()
     db()  # scheduled +0.02
-    await asyncio.sleep(0.015)
+    first = db._handle
+    assert t0 + 0.02 <= first.when() <= loop.time() + 0.02
+    t1 = loop.time()
     db()  # rescheduled +0.04 from now — the original +0.02 must NOT fire
-    await asyncio.sleep(0.015)  # t=0.03 > first deadline
-    assert fired == []  # postponed
-    await asyncio.sleep(0.04)
-    assert fired == [1]
+    assert first.cancelled()
+    assert t1 + 0.04 <= db._handle.when() <= loop.time() + 0.04
+    deadline = db._handle.when()
+    await wait_until(lambda: fired)
+    assert len(fired) == 1 and fired[0] >= deadline  # postponed, fired once
     # cancel resets backoff: next call starts again at min
     db()
     db.cancel()
     await asyncio.sleep(0.1)
-    assert fired == [1]
+    assert len(fired) == 1
+    t2 = loop.time()
     db()
-    await asyncio.sleep(0.03)
-    assert fired == [1, 1]
+    assert t2 + 0.02 <= db._handle.when() <= loop.time() + 0.02
+    await wait_until(lambda: len(fired) == 2)
 
 
 def test_exponential_backoff():

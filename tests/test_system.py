@@ -60,20 +60,22 @@ class TestFourNodeMesh:
                 nodes[n].advertise_prefix(loopback(i))
 
             def converged():
+                # converged = every loopback reached over its direct
+                # single-hop link; a table that is complete while one
+                # adjacency is still forming (two-hop ECMP) is not yet it
                 for i, n in enumerate(names):
-                    expect = {loopback(j) for j in range(4) if j != i}
-                    if set(nodes[n].fib_routes) != expect:
+                    fib = nodes[n].fib_routes
+                    if set(fib) != {loopback(j) for j in range(4) if j != i}:
                         return False
+                    for j, m in enumerate(names):
+                        if i != j and {
+                            nh.neighbor_node_name
+                            for nh in fib[loopback(j)].nexthops
+                        } != {m}:
+                            return False
                 return True
 
             await wait_until(converged, timeout_s=CONVERGENCE_S)
-            # direct single-hop next hops in a full mesh
-            for i, n in enumerate(names):
-                for j, m in enumerate(names):
-                    if i == j:
-                        continue
-                    entry = nodes[n].fib_routes[loopback(j)]
-                    assert {nh.neighbor_node_name for nh in entry.nexthops} == {m}
         finally:
             await stop_all(nodes)
 
