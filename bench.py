@@ -891,11 +891,11 @@ def bench_boot_aot() -> dict:
     import asyncio
     import os
     import shutil
-    import tempfile
 
     from openr_tpu.config import DecisionConfig
     from openr_tpu.kvstore.wrapper import wait_until
     from openr_tpu.ops.xla_cache import (
+        cache_root,
         clear_all_jit_caches,
         configure_aot,
         retrace,
@@ -904,10 +904,14 @@ def bench_boot_aot() -> dict:
     from openr_tpu.runtime.openr_wrapper import OpenrWrapper
     from openr_tpu.spark import MockIoMesh
 
-    cache_dir = os.environ.get("OPENR_TPU_AOT_BENCH_DIR") or tempfile.mkdtemp(
-        prefix="openr-aot-bench-"
+    # a fixed path: the directory is part of the cache key
+    cache_dir = os.environ.get("OPENR_TPU_AOT_BENCH_DIR") or os.path.join(
+        cache_root(), "aot_bench"
     )
     cleanup = "OPENR_TPU_AOT_BENCH_DIR" not in os.environ
+    if cleanup:
+        # run A must compile cold: drop what a killed run left behind
+        shutil.rmtree(cache_dir, ignore_errors=True)
     aot = configure_aot(cache_dir)
 
     async def _one_boot() -> dict:

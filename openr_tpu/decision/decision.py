@@ -134,53 +134,39 @@ class PendingUpdates:
         self.replay_snapshot = None
 
 
+# TpuSpfSolver-only constructor arguments the Decision actor fills in
+# from DecisionConfig; the CPU oracle takes none of them
+_DEVICE_SOLVER_KWARGS = (
+    "xla_cache_dir", "enable_numerical_sentinels", "fuse_n_cap",
+    "incremental_spf", "incremental_cone_frac",
+    "multichip_n_cap_threshold", "multichip_batch", "spf_kernel",
+    "transfer_guard", "streaming_pipeline", "aot_cache_dir",
+    "aot_speculate",
+)
+
+
 def make_solver(
     node_name: str, backend: str, small_graph_nodes: int = 0, **kwargs
 ):
-    """The solver-backend hook (role of the plugin boundary). "auto"
-    prefers the device but routes graphs below small_graph_nodes to the
-    CPU oracle (a device launch + result pull has a fixed cost that
-    dwarfs small solves)."""
+    """The solver-backend hook (role of the plugin boundary). "tpu" and
+    "auto" are both the device solver; "auto" additionally routes
+    graphs below small_graph_nodes to the CPU oracle (a device launch +
+    result pull has a fixed cost that dwarfs small solves). A device
+    backend that cannot initialize raises HERE, at construction —
+    never a silent CPU oracle under a device label."""
     if backend == "cpu":
-        kwargs.pop("xla_cache_dir", None)
-        kwargs.pop("enable_numerical_sentinels", None)
-        kwargs.pop("fuse_n_cap", None)
-        kwargs.pop("incremental_spf", None)
-        kwargs.pop("incremental_cone_frac", None)
-        kwargs.pop("multichip_n_cap_threshold", None)
-        kwargs.pop("multichip_batch", None)
-        kwargs.pop("spf_kernel", None)
-        kwargs.pop("transfer_guard", None)
-        kwargs.pop("streaming_pipeline", None)
-        kwargs.pop("aot_cache_dir", None)
-        kwargs.pop("aot_speculate", None)
+        for k in _DEVICE_SOLVER_KWARGS:
+            kwargs.pop(k, None)
         return SpfSolver(node_name, **kwargs)
     if backend in ("tpu", "auto"):
-        try:
-            from openr_tpu.decision.tpu_solver import TpuSpfSolver
+        import jax
 
-            if backend == "auto":
-                kwargs.setdefault("small_graph_nodes", small_graph_nodes)
-            return TpuSpfSolver(node_name, **kwargs)
-        except Exception:
-            if backend == "tpu":
-                raise
-            counters.increment("decision.solver.backend_fallbacks")
-            log.warning("tpu solver unavailable; falling back to cpu")
-            kwargs.pop("xla_cache_dir", None)
-            kwargs.pop("small_graph_nodes", None)
-            kwargs.pop("enable_numerical_sentinels", None)
-            kwargs.pop("fuse_n_cap", None)
-            kwargs.pop("incremental_spf", None)
-            kwargs.pop("incremental_cone_frac", None)
-            kwargs.pop("multichip_n_cap_threshold", None)
-            kwargs.pop("multichip_batch", None)
-            kwargs.pop("spf_kernel", None)
-            kwargs.pop("transfer_guard", None)
-            kwargs.pop("streaming_pipeline", None)
-            kwargs.pop("aot_cache_dir", None)
-            kwargs.pop("aot_speculate", None)
-            return SpfSolver(node_name, **kwargs)
+        from openr_tpu.decision.tpu_solver import TpuSpfSolver
+
+        jax.devices()  # backend init failure surfaces now, with its message
+        if backend == "auto":
+            kwargs.setdefault("small_graph_nodes", small_graph_nodes)
+        return TpuSpfSolver(node_name, **kwargs)
     raise ValueError(f"unknown solver backend {backend!r}")
 
 

@@ -90,6 +90,19 @@ def _build_policy_manager(oc):
     )
 
 
+def device_identity() -> dict:
+    """Which device the solver will run on, as jax reports it. Raises
+    what jax raises when the backend cannot initialize."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "devices": len(devs),
+    }
+
+
 async def run_daemon(args) -> None:
     # boot lifecycle (runtime/lifecycle.py): t0 is taken BEFORE config
     # load and backdated into begin() once the node name is known, so
@@ -124,14 +137,9 @@ async def run_daemon(args) -> None:
         with boot_tracer.phase(
             "device_init", node=node_name, backend=backend
         ) as ph:
-            try:
-                import jax
-
-                ph["platform"] = jax.default_backend()
-                ph["devices"] = jax.device_count()
-            # lint: allow(broad-except) cpu fallback boots without jax
-            except Exception as e:
-                ph["error"] = str(e)
+            # a device backend that cannot initialize fails the boot:
+            # booting on would put the CPU under a device label
+            ph.update(device_identity())
         with boot_tracer.phase("jit_cache_attach", node=node_name) as ph:
             from openr_tpu.ops.xla_cache import enable_compilation_cache
 

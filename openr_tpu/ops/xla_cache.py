@@ -8,9 +8,15 @@ compilation cache so a restarting daemon (or a second bench run) loads
 them from disk instead of recompiling.
 
 Resolution order for the cache directory:
-  1. explicit `cache_dir` argument (daemon --xla-cache-dir / config)
-  2. $OPENR_TPU_XLA_CACHE (set to "0"/"off" to disable)
-  3. ~/.cache/openr_tpu/xla
+  1. $JAX_COMPILATION_CACHE_DIR — jax reads it itself; where it is set
+     this module sets no directory in code, so whoever runs the program
+     decides where compiled code is kept
+  2. explicit `cache_dir` argument (daemon --xla-cache-dir / config)
+  3. $OPENR_TPU_XLA_CACHE
+  4. <checkout>/.jax_cache — a FIXED path (the path is part of jax's
+     cache key, so a directory that moves never hits)
+"0"/"off" in 2 or 3 disables. The AOT tier's "auto" home and the AOT
+bench directory sit under the same root (`cache_root()`).
 
 Safe to call any number of times; only the first call wins (jax reads
 the setting at first compile).
@@ -89,6 +95,21 @@ def _hook_cache_monitoring() -> bool:
     return True
 
 
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def cache_root() -> str:
+    """The one directory compiled code is kept under:
+    $JAX_COMPILATION_CACHE_DIR where set, else `.jax_cache` inside the
+    checkout (git-ignored)."""
+    return os.environ.get(ENV_JAX_CACHE_DIR) or os.path.join(
+        os.path.dirname(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        ),
+        ".jax_cache",
+    )
+
+
 def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
     """Point jax at a persistent on-disk compilation cache; returns the
     directory in use, or None when disabled. Idempotent."""
@@ -100,15 +121,14 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
     if d.lower() in _DISABLE:
         _applied = ""
         return None
-    if not d:
-        d = os.path.join(
-            os.path.expanduser("~"), ".cache", "openr_tpu", "xla"
-        )
+    from_env = os.environ.get(ENV_JAX_CACHE_DIR, "")
+    d = from_env or d or cache_root()
     try:
         os.makedirs(d, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", d)
+        if not from_env:
+            jax.config.update("jax_compilation_cache_dir", d)
         # the daemon's kernels are worth caching even when XLA compiles
         # them quickly — a restart replays dozens of them
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
@@ -503,8 +523,7 @@ class KernelLedger:
 
     def record(
         self, name: str, compile_ms: float | None, cost: dict,
-        aot: bool = True, loaded: bool = False,
-        load_ms: float | None = None,
+        loaded: bool = False, load_ms: float | None = None,
     ) -> None:
         """`loaded` marks an executable installed from the persistent
         AOT cache (deserialize, no compile): compile_ms stays None and
@@ -517,7 +536,6 @@ class KernelLedger:
                 "compile_ms": (
                     round(compile_ms, 3) if compile_ms is not None else None
                 ),
-                "aot": aot,
                 "aot_loaded": loaded,
                 "load_ms": (
                     round(load_ms, 3) if load_ms is not None else None
@@ -974,7 +992,7 @@ def configure_aot(
 
     `spec` resolution: None/"" consults $OPENR_TPU_AOT_CACHE (empty =
     stays disabled — the cache is opt-in, unlike the jax compilation
-    cache); "auto" resolves ~/.cache/openr_tpu/aot; "off"/"0" disables;
+    cache); "auto" resolves <cache_root()>/aot; "off"/"0" disables;
     anything else is the directory. Repointing drops unclaimed
     preloads; an identical repoint is a cheap no-op."""
     global aot
@@ -983,9 +1001,7 @@ def configure_aot(
     if d.lower() in _AOT_DISABLE or not d:
         d = ""
     elif d.lower() in _AOT_AUTO:
-        d = os.path.join(
-            os.path.expanduser("~"), ".cache", "openr_tpu", "aot"
-        )
+        d = os.path.join(cache_root(), "aot")
     if d != aot.dir or (keep is not None and keep != aot.keep):
         aot = AotExecutableCache(d, keep if keep is not None else aot.keep)
     return aot
@@ -1082,9 +1098,10 @@ def instrument_jit(name: str, jitted, aot_key: str | None = None):
     the ledger, and every later invocation hits the compiled executable
     directly. Callers must keep argument shapes/dtypes fixed per
     instrumented instance — true for the solver's shape-keyed pipeline
-    factories, whose lru key IS the shape class. Where AOT fails (e.g.
-    a backend quirk) the wrapper degrades to the plain jitted fn and
-    the ledger says so.
+    factories, whose lru key IS the shape class. A compile the
+    backend's compiler refuses raises into the caller with the
+    compiler's message: the plain jitted fn would only hit the same
+    refusal later, under a less telling name.
 
     With `aot_key` (the canonical repr of EVERY factory argument — the
     kernel name alone under-keys: it omits r_cap/kr_cap/budget and the
@@ -1132,26 +1149,20 @@ def instrument_jit(name: str, jitted, aot_key: str | None = None):
                 return fn, True
         return _compile(args, kwargs), False
 
-    def _ensure(args, kwargs):
+    def _ensure(args, kwargs) -> bool:
+        """Install once; True when this call did the install."""
         with lock:
-            fn = state["fn"]
-            if fn is not None:
-                return fn
-            try:
-                fn, loaded = _install(args, kwargs)
-                state["verify_loaded"] = loaded
-            # lint: allow(broad-except) degrades to plain jit, ledgered
-            except Exception as e:
-                log.debug("AOT compile failed for %s (%s)", name, e)
-                fn = jitted
-                ledger.record(name, None, {}, aot=False)
+            if state["fn"] is not None:
+                return False
+            fn, loaded = _install(args, kwargs)
+            state["verify_loaded"] = loaded
             state["fn"] = fn
-            return fn
+            return True
 
     def wrapper(*args, **kwargs):
+        if state["fn"] is None:
+            _ensure(args, kwargs)
         fn = state["fn"]
-        if fn is None:
-            fn = _ensure(args, kwargs)
         ledger.bump_calls(name)
         if state["verify_loaded"]:
             # first call on a cache-loaded executable: a TypeError here
@@ -1167,13 +1178,7 @@ def instrument_jit(name: str, jitted, aot_key: str | None = None):
                     "(%s); recompiling", name, e,
                 )
                 with lock:
-                    try:
-                        fn = _compile(args, kwargs)
-                    # lint: allow(broad-except) degrade to plain jit
-                    except Exception:
-                        fn = jitted
-                        ledger.record(name, None, {}, aot=False)
-                    state["fn"] = fn
+                    fn = state["fn"] = _compile(args, kwargs)
                 return fn(*args, **kwargs)
         return fn(*args, **kwargs)
 
@@ -1181,15 +1186,7 @@ def instrument_jit(name: str, jitted, aot_key: str | None = None):
         """Install (AOT-load or compile + persist) WITHOUT executing;
         `args` may be jax.ShapeDtypeStructs. Returns True when this
         call did the install. The speculative baker's entry point."""
-        if state["fn"] is not None:
-            return False
-        with lock:
-            if state["fn"] is not None:
-                return False
-            fn, loaded = _install(args, kwargs)
-            state["verify_loaded"] = loaded
-            state["fn"] = fn
-        return True
+        return state["fn"] is None and _ensure(args, kwargs)
 
     wrapper.prime = prime
     wrapper.kernel_name = name

@@ -288,15 +288,95 @@ class TestAotCacheUnit:
             # disable words beat the env var
             assert configure_aot("off").enabled is False
             assert configure_aot("0").enabled is False
-            # auto resolves the home cache dir
+            # auto sits under the one cache root, wherever that is
+            monkeypatch.setenv(
+                "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "root")
+            )
             auto = configure_aot("auto")
-            assert auto.dir.endswith(os.path.join("openr_tpu", "aot"))
+            assert auto.dir == str(tmp_path / "root" / "aot")
             # keep re-point preserves the knob
             keep = configure_aot(str(tmp_path / "kd"), keep=7)
             assert keep.keep == 7
             assert get_aot() is keep
         finally:
             configure_aot("off")
+
+
+# -- where compiled code is kept -------------------------------------------
+
+
+@pytest.fixture
+def fresh_xla_cache_state():
+    """enable_compilation_cache is first-call-wins and mutates jax's
+    config; isolate both (the conftest keeps the cache off)."""
+    import openr_tpu.ops.xla_cache as xc
+
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    old_applied = xc._applied
+    old_cfg = {k: getattr(jax.config, k) for k in keys}
+    xc._applied = None
+    yield xc
+    xc._applied = old_applied
+    for k, v in old_cfg.items():
+        jax.config.update(k, v)
+
+
+@pytest.mark.parametrize(
+    "jax_env, arg, ours_env, expect",
+    [
+        # placed from outside: jax's own variable beats everything and
+        # the program sets no directory in code
+        ("{tmp}/outside", "{tmp}/arg", "{tmp}/ours", "{tmp}/outside"),
+        ("", "{tmp}/arg", "{tmp}/ours", "{tmp}/arg"),
+        ("", None, "{tmp}/ours", "{tmp}/ours"),
+        # nothing set: one fixed path inside the checkout
+        ("", None, "", "{tmp}/checkout/.jax_cache"),
+        ("{tmp}/outside", None, "off", None),
+    ],
+    ids=["jax-env-wins", "explicit", "ours-env", "in-checkout", "off"],
+)
+def test_compile_cache_placement(
+    fresh_xla_cache_state, tmp_path, monkeypatch, jax_env, arg, ours_env,
+    expect,
+):
+    xc = fresh_xla_cache_state
+
+    def sub(v):
+        return v.format(tmp=tmp_path) if v else v
+
+    monkeypatch.setenv("OPENR_TPU_XLA_CACHE", sub(ours_env))
+    if jax_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", sub(jax_env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(
+            xc, "cache_root",
+            lambda: str(tmp_path / "checkout" / ".jax_cache"),
+        )
+    before = jax.config.jax_compilation_cache_dir
+    got = xc.enable_compilation_cache(sub(arg))
+    assert got == sub(expect)
+    if jax_env or expect is None:
+        assert jax.config.jax_compilation_cache_dir == before
+    else:
+        assert jax.config.jax_compilation_cache_dir == sub(expect)
+    if expect is not None:
+        assert os.path.isdir(sub(expect))
+
+
+def test_cache_root_is_fixed_inside_the_checkout(monkeypatch):
+    import openr_tpu.ops.xla_cache as xc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert xc.cache_root() == os.path.join(repo, ".jax_cache")
+    assert xc.cache_root() == xc.cache_root()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert xc.cache_root() == "/some/dir"
 
 
 # -- speculative baker -----------------------------------------------------

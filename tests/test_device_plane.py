@@ -153,7 +153,7 @@ def test_instrument_jit_records_cost_and_calls():
     fn(x)
     entry = ledger.snapshot()["ledgertest"]
     assert entry["calls"] == 2
-    assert entry["aot"] is True
+    assert entry["aot_loaded"] is False
     assert entry["compile_ms"] >= 0.0
     assert entry["flops"] > 0  # cost_analysis saw the adds/muls
 
