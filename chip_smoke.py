@@ -440,13 +440,15 @@ async def run_served(args, platform: str) -> None:
 
 
 def run_multichip(args, platform: str) -> None:
-    import tempfile
+    import os
+    import shutil
 
     import jax
 
     from openr_tpu.decision.tpu_solver import TpuSpfSolver
     from openr_tpu.models import topologies
     from openr_tpu.ops.xla_cache import (
+        cache_root,
         clear_all_jit_caches,
         configure_aot,
         retrace,
@@ -471,63 +473,73 @@ def run_multichip(args, platform: str) -> None:
         routes = dict(db.unicast_routes)
         return solver, routes, round(time.perf_counter() - t0, 3)
 
-    with tempfile.TemporaryDirectory(prefix="chip_smoke_aot_") as aot_dir:
-        try:
-            mc, mc_routes, mc_s = solve(
-                aot_dir, multichip_n_cap_threshold=threshold
-            )
-            mesh_info = mc.last_timing.get("multichip")
-            check(bool(mesh_info), "the multichip tier did not engage")
-            resident = list(mc._device_arrays(mc=True))
-            check(resident, "no multichip-resident array")
-            spans = {
-                d.id for arr in resident for d in arr.sharding.device_set
-            }
+    # a fixed path under the one cache root, emptied so that the first
+    # solve must compile and serialize
+    aot_dir = os.path.join(cache_root(), "aot_smoke")
+    shutil.rmtree(aot_dir, ignore_errors=True)
+    try:
+        mc, mc_routes, mc_s = solve(
+            aot_dir, multichip_n_cap_threshold=threshold
+        )
+        mesh_info = mc.last_timing.get("multichip")
+        check(bool(mesh_info), "the multichip tier did not engage")
+        resident = list(mc._device_arrays(mc=True))
+        check(resident, "no multichip-resident array")
+        spans = {
+            d.id for arr in resident for d in arr.sharding.device_set
+        }
+        check(
+            len(spans) == 4
+            and all(len(arr.sharding.device_set) == 4 for arr in resident),
+            f"resident planes span devices {sorted(spans)}, not four each",
+        )
+        partitioned = sum(
+            not arr.sharding.is_fully_replicated for arr in resident
+        )
+        check(partitioned > 0, "every resident plane is fully replicated")
+        in_use = [
+            (d.memory_stats() or {}).get("bytes_in_use") for d in devices
+        ]
+        if platform == "tpu":
             check(
-                len(spans) == 4,
-                f"resident planes span devices {sorted(spans)}, not four",
+                all(in_use) and all(b > 0 for b in in_use),
+                f"bytes_in_use per device: {in_use}",
             )
-            in_use = [
-                (d.memory_stats() or {}).get("bytes_in_use") for d in devices
-            ]
-            if platform == "tpu":
-                check(
-                    all(in_use) and all(b > 0 for b in in_use),
-                    f"bytes_in_use per device: {in_use}",
-                )
-            emit(
-                phase="multichip", mesh=mesh_info, solve_s=mc_s,
-                routes=len(mc_routes), resident_arrays=len(resident),
-                devices_spanned=sorted(spans), bytes_in_use=in_use,
-                platform=platform,
-            )
+        emit(
+            phase="multichip", mesh=mesh_info, solve_s=mc_s,
+            routes=len(mc_routes), resident_arrays=len(resident),
+            partitioned_arrays=partitioned,
+            devices_spanned=sorted(spans), bytes_in_use=in_use,
+            platform=platform,
+        )
 
-            # AOT round trip of the mesh executable: a second solver
-            # with every in-memory executable dropped must install the
-            # serialized one onto the same four devices and agree
-            cache = configure_aot(aot_dir)
-            stored = cache.summary()["writes"]
-            check(stored > 0, "the mesh executable was not serialized")
-            clear_all_jit_caches()
-            jax.clear_caches()
-            retrace.reset()
-            cache.reset_stats()
-            _, warm_routes, warm_s = solve(
-                aot_dir, multichip_n_cap_threshold=threshold
-            )
-            s = cache.summary()
-            check(
-                s["hits"] > 0 and s["load_errors"] == 0,
-                f"AOT cache did not round-trip the mesh executable: {s}",
-            )
-            compare_tables(warm_routes, mc_routes, "multichip aot reload")
-            emit(
-                phase="multichip_aot", stored=stored, hits=s["hits"],
-                misses=s["misses"], load_errors=s["load_errors"],
-                solve_s=warm_s, platform=platform,
-            )
-        finally:
-            configure_aot("off")
+        # AOT round trip of the mesh executable: a second solver
+        # with every in-memory executable dropped must install the
+        # serialized one onto the same four devices and agree
+        cache = configure_aot(aot_dir)
+        stored = cache.summary()["writes"]
+        check(stored > 0, "the mesh executable was not serialized")
+        clear_all_jit_caches()
+        jax.clear_caches()
+        retrace.reset()
+        cache.reset_stats()
+        _, warm_routes, warm_s = solve(
+            aot_dir, multichip_n_cap_threshold=threshold
+        )
+        s = cache.summary()
+        check(
+            s["hits"] > 0 and s["load_errors"] == 0,
+            f"AOT cache did not round-trip the mesh executable: {s}",
+        )
+        compare_tables(warm_routes, mc_routes, "multichip aot reload")
+        emit(
+            phase="multichip_aot", stored=stored, hits=s["hits"],
+            misses=s["misses"], load_errors=s["load_errors"],
+            solve_s=warm_s, platform=platform,
+        )
+    finally:
+        configure_aot("off")
+        shutil.rmtree(aot_dir, ignore_errors=True)
 
     one, one_routes, one_s = solve("off", multichip_n_cap_threshold=0)
     check(not one.last_timing.get("multichip"), "one-chip solve went multichip")

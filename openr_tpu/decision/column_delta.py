@@ -1,6 +1,6 @@
 """Packed column deltas: the zero-copy RIB -> FIB spine.
 
-BENCH_r05 put the cold 100k bottleneck at host materialization: the
+A run before PR 1 put the cold 100k bottleneck at host materialization: the
 solver's packed device output was immediately re-expressed as ~100k
 `RibUnicastEntry` objects so the diff, the Fib actor, and the platform
 agent could each walk them one at a time. This module keeps that state

@@ -1,6 +1,6 @@
 """Columnar, lazily-materialized RIB.
 
-BENCH_r05 showed a cold 100k-prefix rebuild spends 70% of its wall time
+A profile before PR 1 showed a cold 100k-prefix rebuild spends 70% of its wall time
 constructing `RibUnicastEntry` Python objects in `_build_entries` — for
 routes most consumers never look at individually. This module keeps the
 solver's packed device outputs (metric, selected-announcer words,

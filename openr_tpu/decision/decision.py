@@ -209,8 +209,7 @@ class Decision(Actor):
         if config.enable_lfa:
             skw.setdefault("enable_lfa", True)
         if backend != "cpu":
-            # "" -> default resolution (env var, then ~/.cache); "off"
-            # disables (ops/xla_cache.py)
+            # "" -> default resolution (ops/xla_cache.py); "off" disables
             skw.setdefault("xla_cache_dir", config.xla_cache_dir or None)
             skw.setdefault(
                 "enable_numerical_sentinels",

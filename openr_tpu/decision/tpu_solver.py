@@ -839,10 +839,9 @@ def _stream_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     plane set, not two. `sbudget` (a STREAM_BUDGETS bucket) and
     `dirty_cap` are both capacity-signature ints, so budget churn
     buckets inside the "stream" namespace and can never evict the
-    full-solve or incr executables. Donation is gated off on CPU
-    (XLA cannot honor it there and jax warns) and whenever a transfer
-    guard is armed (the guarded-retry path would replay consumed
-    buffers)."""
+    full-solve or incr executables. Donation is gated off whenever a
+    transfer guard is armed (the guarded-retry path would replay
+    consumed buffers)."""
     import jax
 
     kw = {"donate_argnums": (9, 10, 11, 12, 13, 14)} if donate else {}
@@ -1094,23 +1093,21 @@ def _next_shape_key(shape_key: tuple) -> tuple:
 
 
 @bounded_jit_cache()
-def _scatter_jit(donate: bool = False):
+def _scatter_jit():
     import jax
 
     def scatter(arr, idx, vals):
         shape = arr.shape
         return arr.ravel().at[idx].set(vals).reshape(shape)
 
-    if donate:
-        # the resident array's buffer is reused in place — a delta sync
-        # never doubles the plan mirror's HBM footprint. Gated off on
-        # CPU, where XLA cannot honor the donation and jax warns.
-        return jax.jit(scatter, donate_argnums=(0,))
-    return jax.jit(scatter)
+    # the resident array's buffer is reused in place — a delta sync
+    # never doubles the plan mirror's HBM footprint (every backend of
+    # the installed jax honors the donation, the CPU's included)
+    return jax.jit(scatter, donate_argnums=(0,))
 
 
 @bounded_jit_cache(namespace="multichip")
-def _mc_scatter_jit(sharding, donate: bool = False):
+def _mc_scatter_jit(sharding):
     """Delta scatter that PRESERVES the resident array's NamedSharding:
     pinning out_shardings keeps the multichip tier's weight shards in
     place, so GSPMD routes each update to the owning device and churn
@@ -1121,8 +1118,7 @@ def _mc_scatter_jit(sharding, donate: bool = False):
         shape = arr.shape
         return arr.ravel().at[idx].set(vals).reshape(shape)
 
-    kw = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(scatter, out_shardings=sharding, **kw)
+    return jax.jit(scatter, out_shardings=sharding, donate_argnums=(0,))
 
 
 def _pack_matrix(matrix: PrefixMatrix, node_over: np.ndarray) -> tuple:
@@ -1579,9 +1575,6 @@ class TpuSpfSolver:
         # host->device transfer accounting for the current solve; read
         # into last_timing by collect_route_db (bench bytes_uploaded)
         self._bytes_uploaded = 0
-        # buffer donation for delta scatters (resolved lazily from the
-        # backend: CPU cannot honor donation and warns)
-        self._donate: Optional[bool] = None
         self.last_device_stats: dict = {}
         # wall-time breakdown of the last fast-path solve (bench.py)
         self.last_timing: dict = {}
@@ -2259,15 +2252,6 @@ class TpuSpfSolver:
 
     # -- device state sync -------------------------------------------------
 
-    def _donation_on(self) -> bool:
-        """Donate resident buffers into delta scatters (in-place HBM
-        update). CPU cannot honor donation and warns, so gate there."""
-        if self._donate is None:
-            import jax
-
-            self._donate = jax.default_backend() != "cpu"
-        return self._donate
-
     def _mc_mesh_for(self, n_cap: int):
         """The ('batch','graph') mesh the multichip tier solves this
         capacity class on, or None when the tier stays off: threshold
@@ -2319,15 +2303,13 @@ class TpuSpfSolver:
         tier) the result is pinned to the resident NamedSharding — a
         per-shard update, not a gather-to-one-device round trip."""
         self._bytes_uploaded += idx.nbytes + vals.nbytes
-        donate = self._donation_on()
-        if donate:
-            # the donated input may be referenced by the last-exec probe
-            # tuples; those handles die with the donation
-            self._last_exec = None
-            self._last_exec_incr = None
+        # the donated input may be referenced by the last-exec probe
+        # tuples; those handles die with the donation
+        self._last_exec = None
+        self._last_exec_incr = None
         if sharding is not None:
-            return _mc_scatter_jit(sharding, donate)(d_arr, idx, vals)
-        return _scatter_jit(donate)(d_arr, idx, vals)
+            return _mc_scatter_jit(sharding)(d_arr, idx, vals)
+        return _scatter_jit()(d_arr, idx, vals)
 
     def _diff_scatter(self, d_arr, old_np, new_np, extra_idx=None,
                       sharding=None):
@@ -2942,11 +2924,8 @@ class TpuSpfSolver:
         incr, vs = pv["incr"], pv["vs"]
         sbudget = int(vs.stream_budget) or 64
         # the guarded-retry path in _run_exec replays the call after a
-        # finding — impossible once the inputs are donated — and CPU
-        # cannot honor donation at all: gate it off for both
-        donate = (
-            self._donation_on() and self._transfer_guard_mode() is None
-        )
+        # finding — impossible once the inputs are donated
+        donate = self._transfer_guard_mode() is None
         kernel_name, run = _instrumented_stream(
             *pv["shape_key"], _DELTA_BUDGET, incr["cap"], sbudget,
             pv["lfa"], pv["block_v4"], self.enable_sentinels,

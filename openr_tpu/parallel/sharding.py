@@ -212,12 +212,7 @@ def _sharded_fabric_fn(mesh, n_cap: int, s_cap: int, r_cap: int,
 
         return jax.vmap(one_root)(roots, root_nbr, root_w)
 
-    try:
-        from jax import shard_map  # jax >= 0.6
-        _check_kw = {"check_vma": False}
-    except ImportError:  # older jax: experimental module, check_rep kwarg
-        from jax.experimental.shard_map import shard_map
-        _check_kw = {"check_rep": False}
+    from jax import shard_map
 
     jitted = jax.jit(
         shard_map(
@@ -246,7 +241,7 @@ def _sharded_fabric_fn(mesh, n_cap: int, s_cap: int, r_cap: int,
                 P("batch", None),    # ok
                 P("batch"),
             ),
-            **_check_kw,
+            check_vma=False,
         )
     )
     mesh_tag = f"{mesh.shape['batch']}x{mesh.shape['graph']}"
@@ -305,16 +300,6 @@ def plan_shardings(mesh, n_cap: int, r_cap: int, d_cap: int) -> dict:
         # (see make_mc_sssp) — each device owns whole lanes instead
         "dist": sh(P("batch", None), d_cap % b == 0),
     }
-
-
-def _shard_map():
-    """(shard_map callable, check-disable kwarg) across jax versions."""
-    try:
-        from jax import shard_map  # jax >= 0.6
-        return shard_map, {"check_vma": False}
-    except ImportError:  # older jax: experimental module, check_rep kwarg
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}
 
 
 def make_mc_sssp(mesh, s_cap: int, has_res: bool, n_cap: int,
@@ -421,7 +406,8 @@ def make_mc_sssp(mesh, s_cap: int, has_res: bool, n_cap: int,
             )
         return dist, trips[None], rounds[None]
 
-    shard_map, check_kw = _shard_map()
+    from jax import shard_map
+
     return shard_map(
         local_fn,
         mesh=mesh,
@@ -434,7 +420,7 @@ def make_mc_sssp(mesh, s_cap: int, has_res: bool, n_cap: int,
             P("batch"),          # root_w
         ),
         out_specs=(P("batch", None), P("batch"), P("batch")),
-        **check_kw,
+        check_vma=False,
     )
 
 
@@ -690,7 +676,8 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             )
         return dist, trips[None], cone[None], fell_back[None], rounds[None]
 
-    shard_map, check_kw = _shard_map()
+    from jax import shard_map
+
     return shard_map(
         local_fn,
         mesh=mesh,
@@ -708,7 +695,7 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
         out_specs=(
             P("batch", None), P("batch"), P(), P(), P("batch"),
         ),
-        **check_kw,
+        check_vma=False,
     )
 
 

@@ -1,6 +1,6 @@
 """Persistent perf-baseline ledger (ISSUE 14).
 
-The BENCH_r01..r05 trajectory and the kernel cost ledger
+Bench JSONs and the kernel cost ledger
 (ops/xla_cache.KernelLedger) are write-only snapshots: nothing persists
 per-kernel / per-stage baselines across runs, so a perf regression is
 only caught by a human diffing bench JSONs. This module is the

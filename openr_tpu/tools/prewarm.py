@@ -288,8 +288,8 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--cache-dir", default=None,
-        help="XLA cache directory (default: ~/.cache/openr_tpu/xla / "
-        "$OPENR_TPU_XLA_CACHE)",
+        help="XLA cache directory (default: $OPENR_TPU_XLA_CACHE, then "
+        "<checkout>/.jax_cache; $JAX_COMPILATION_CACHE_DIR beats all)",
     )
     p.add_argument(
         "--lfa", action="store_true",
@@ -320,7 +320,7 @@ def main(argv=None) -> int:
         "--aot-cache-dir", default="auto",
         help="persistent AOT executable-cache directory to bake "
         "serialized executables into (default 'auto' = "
-        "~/.cache/openr_tpu/aot; 'off' disables; empty consults "
+        "<compile-cache root>/aot; 'off' disables; empty consults "
         "$OPENR_TPU_AOT_CACHE)",
     )
     p.add_argument(

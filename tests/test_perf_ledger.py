@@ -297,7 +297,7 @@ class TestPerfDiff:
             {"configs": {"mesh4": {"tpu_ms": 30.0}}, "rig_rtt_ms": 999.0}))
         assert perf_diff.main([str(base), str(same), "--json"]) == 0
         assert perf_diff.main([str(base), str(slow), "--json"]) == 1
-        # rig_rtt_ms is the tunnel's property — excluded even though it
+        # rig_rtt_ms is the machine's property — excluded even though it
         # "regressed" 25x
         flat = perf_diff._load_bench(str(slow))
         assert "rig_rtt_ms" not in flat

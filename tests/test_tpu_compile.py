@@ -1,0 +1,308 @@
+"""The solver's device programs compile for a TPU v5e that is described,
+not attached (ISSUE 23 step 4).
+
+No Pallas kernel exists here: the "kernels" are the jitted pipeline
+factories of decision/tpu_solver.py and ops/ksp2.py. A CPU solve is run
+with every factory wrapped by a recorder, which keeps each program's
+jitted callable and the shapes of its first call; each recorded program
+is then lowered with ShapeDtypeStructs placed on a described v5e:2x2
+device and compiled by the TPU compiler installed here. What that
+compiler refuses — a lowering it has no rule for, a donation it cannot
+alias, a program that does not fit 16 GB — fails here, at no chip time.
+The multichip variant is rebuilt by its own factory on a 4-device Mesh of
+the described devices (its closure binds the mesh), and its text must
+show the cross-device min.
+
+Runs at the n_cap 4096 capacity class (2-3 s per program). The real
+class, n_cap 131072, takes ~85 s per program: CHANGES.md PR 23 records
+that run, made by hand with this module's `Capture` and scenarios.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU's library, and every xdist
+worker imports every test file (on-chip-measurement guide §2).
+"""
+
+import dataclasses
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from openr_tpu.decision import tpu_solver as ts
+from openr_tpu.models import topologies
+from openr_tpu.ops import ksp2 as ksp2_ops
+from openr_tpu.ops import xla_cache
+from openr_tpu.types import PrefixForwardingAlgorithm, PrefixForwardingType
+
+SIDE = 50  # 2500 nodes -> the n_cap 4096 class
+
+
+# -- recording the programs a CPU solve builds -----------------------------
+
+
+def _aval(x):
+    return jax.ShapeDtypeStruct(
+        np.shape(x), jax.dtypes.canonicalize_dtype(np.result_type(x))
+    )
+
+
+class Capture:
+    """Wraps the solver's program factories; `programs` maps a label to
+    (jitted callable, avals of its first call), `mesh_programs` a label
+    to (factory, factory args after the mesh, avals)."""
+
+    SINGLE_CHIP_FACTORIES = (
+        (ts, "_scatter_jit"),
+        (ksp2_ops, "_base_sssp_fn"),
+        (ksp2_ops, "_masked_rows_fn"),
+        (ksp2_ops, "_masked_rows_delta_fn"),
+    )
+    MESH_FACTORIES = ((ts, "_mc_pipeline"), (ts, "_mc_incr_pipeline"))
+
+    def __init__(self):
+        self.programs: dict = {}
+        self.mesh_programs: dict = {}
+        self._undo: list = []
+
+    def __enter__(self):
+        # the factories memoize their (already wrapped) results
+        xla_cache.clear_all_jit_caches()
+        self._patch(xla_cache, "instrument_jit", self._instrument_jit)
+        for mod, name in self.SINGLE_CHIP_FACTORIES:
+            self._patch(mod, name, self._factory(name, getattr(mod, name)))
+        for mod, name in self.MESH_FACTORIES:
+            self._patch(
+                mod, name, self._factory(name, getattr(mod, name), True)
+            )
+        return self
+
+    def __exit__(self, *exc):
+        for mod, name, old in reversed(self._undo):
+            setattr(mod, name, old)
+        xla_cache.clear_all_jit_caches()
+
+    def _patch(self, mod, name, new) -> None:
+        self._undo.append((mod, name, getattr(mod, name)))
+        setattr(mod, name, new)
+
+    def _instrument_jit(self, name, jitted, aot_key=None):
+        real = self._undo[0][2]
+        run = real(name, jitted, aot_key=aot_key)
+        if isinstance(jitted, _MeshProgram):
+            return run  # recorded by its factory, with the mesh
+
+        def wrapper(*args, **kwargs):
+            self.programs.setdefault(
+                name, (jitted, jax.tree.map(_aval, args))
+            )
+            return run(*args, **kwargs)
+
+        wrapper.__dict__.update(run.__dict__)
+        return wrapper
+
+    def _factory(self, label, factory, mesh: bool = False):
+        def wrapped(*fargs):
+            jitted = factory(*fargs)
+            if mesh:
+                return _MeshProgram(self, label, factory, fargs, jitted)
+
+            def call(*args):
+                self.programs.setdefault(
+                    f"{label}{fargs}", (jitted, jax.tree.map(_aval, args))
+                )
+                return jitted(*args)
+
+            return call
+
+        return wrapped
+
+
+class _MeshProgram:
+    """A mesh-bound jitted pipeline: records its factory arguments at
+    lower() (instrument_jit's compile), so the same factory can rebuild
+    it on a mesh of described devices."""
+
+    def __init__(self, capture, label, factory, fargs, jitted):
+        self._c, self._label, self._factory = capture, label, factory
+        self._fargs, self._jitted = fargs, jitted
+
+    def lower(self, *args, **kwargs):
+        self._c.mesh_programs.setdefault(
+            self._label,
+            (self._factory, self._fargs[1:], jax.tree.map(_aval, args)),
+        )
+        return self._jitted.lower(*args, **kwargs)
+
+
+# -- scenarios: what a user's solves build, per variant --------------------
+
+
+def _grid(side: int, ksp2_every: int = 0):
+    adj_dbs, prefix_dbs = topologies.grid(side, node_labels=bool(ksp2_every))
+    if ksp2_every:
+        prefix_dbs = [
+            dataclasses.replace(db, prefix_entries=tuple(
+                dataclasses.replace(
+                    e,
+                    forwarding_type=PrefixForwardingType.SR_MPLS,
+                    forwarding_algorithm=(
+                        PrefixForwardingAlgorithm.KSP2_ED_ECMP
+                    ),
+                )
+                for e in db.prefix_entries
+            )) if i % ksp2_every == ksp2_every // 2 else db
+            for i, db in enumerate(prefix_dbs)
+        ]
+    return adj_dbs, prefix_dbs, f"node-{side // 2}-{side // 2}"
+
+
+def _bump_metric(adj_dbs, node: str, metric: int) -> list:
+    """Raise `node`'s first link (both directions) to `metric`; -> the
+    two changed adjacency databases."""
+    index = {db.this_node_name: i for i, db in enumerate(adj_dbs)}
+    other = adj_dbs[index[node]].adjacencies[0].other_node_name
+    changed = []
+    for me, peer in ((node, other), (other, node)):
+        db = adj_dbs[index[me]]
+        adj_dbs[index[me]] = db = dataclasses.replace(
+            db, adjacencies=tuple(
+                dataclasses.replace(a, metric=metric)
+                if a.other_node_name == peer else a
+                for a in db.adjacencies
+            ),
+        )
+        changed.append(db)
+    return changed
+
+
+def solve_then_churn(side: int, ksp2_every: int = 0, churn: int = 1, **kw):
+    """A cold solve, then `churn` link-metric changes each followed by a
+    warm solve — on one solver, through LinkState's own update path (the
+    solver's delta sync reads its change journal)."""
+    adj_dbs, prefix_dbs, me = _grid(side, ksp2_every)
+    states, prefix_state = topologies.build_states(adj_dbs, prefix_dbs)
+    solver = ts.TpuSpfSolver(me, **kw)
+    assert solver.build_route_db(me, states, prefix_state) is not None
+    far = f"node-{side // 2}-{side - 2}"
+    for step in range(churn):
+        for db in _bump_metric(adj_dbs, far, 3 + step):
+            states[db.area].update_adjacency_database(db)
+        assert solver.build_route_db(me, states, prefix_state) is not None
+    return solver
+
+
+def run_scenarios(side: int, ksp2_every: int, mc_threshold: int) -> Capture:
+    with Capture() as cap:
+        # Decision's default: full solve, then the incremental kernel
+        solve_then_churn(side, incremental_spf=True)
+        # streaming epoch (donates the resident planes), with LFA
+        solve_then_churn(side, streaming_pipeline=True, enable_lfa=True)
+        # KSP2: base field, masked batch, then the delta batch
+        solve_then_churn(side, ksp2_every, churn=2, incremental_spf=True)
+        # the multichip tier, full and incremental
+        solve_then_churn(
+            side, incremental_spf=True,
+            multichip_n_cap_threshold=mc_threshold,
+        )
+    return cap
+
+
+# -- compiling them for the described chip ---------------------------------
+
+
+def compile_single(one_chip, jitted, avals):
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        avals,
+    )
+    return jitted.lower(*args).compile()
+
+
+def compile_mesh(mesh, factory, fargs, avals):
+    # in_shardings are pinned by the factory: bare shapes suffice
+    return factory(mesh, *fargs).lower(*avals).compile()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies as jax_topologies
+
+    try:
+        return jax_topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    # lint: allow(broad-except) any failure to describe means skip
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cache_off():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run would warn
+    and compile again): keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def captured(topo, cache_off):
+    return run_scenarios(SIDE, ksp2_every=300, mc_threshold=1024)
+
+
+VARIANTS = {
+    # variant -> (label prefix, must the text alias a donated input?)
+    "full": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,bk1]", False),
+    "incremental": ("pipeline_incr[n=4096,", False),
+    "full_lfa": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,lfa,bk1]", False),
+    "streaming_lfa_donating": ("pipeline_stream[n=4096,", True),
+    "delta_scatter_donating": ("_scatter_jit", True),
+    "ksp2_base": ("_base_sssp_fn", False),
+    "ksp2_masked_batch": ("_masked_rows_fn", False),
+    "ksp2_delta_batch": ("_masked_rows_delta_fn", False),
+}
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_program_compiles_for_a_described_v5e(captured, one_chip, variant):
+    prefix, donating = VARIANTS[variant]
+    labels = [k for k in captured.programs if k.startswith(prefix)]
+    assert labels, (variant, sorted(captured.programs))
+    for label in labels:
+        compiled = compile_single(one_chip, *captured.programs[label])
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 16 << 30, (label, mem)
+        if donating:
+            assert "input_output_alias" in compiled.as_text(), label
+
+
+@pytest.mark.parametrize("variant", ["_mc_pipeline", "_mc_incr_pipeline"])
+def test_multichip_program_compiles_on_a_described_mesh(
+    captured, topo, variant
+):
+    from jax.sharding import Mesh
+
+    assert len(topo.devices) == 4
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "graph"))
+    factory, fargs, avals = captured.mesh_programs[variant]
+    compiled = compile_mesh(mesh, factory, fargs, avals)
+    # the 'graph' axis shards the weight state: each relaxation round
+    # ends in a cross-device min (the pmin of parallel/sharding.py)
+    text = compiled.as_text()
+    assert "all-reduce" in text and "minimum" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30

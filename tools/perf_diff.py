@@ -1,7 +1,7 @@
 """Per-headline perf verdicts between two bench JSONs, or a bench JSON
 and the perf ledger.
 
-    python -m tools.perf_diff BENCH_r05.json bench-smoke.json
+    python -m tools.perf_diff BENCH_FLAPSTORM_r01.json bench-flapstorm.json
     python -m tools.perf_diff --ledger <ledger-dir> bench-new.json
 
 Both bench output shapes (quick and full) flatten to dotted numeric
@@ -161,7 +161,7 @@ def _load_bench(path: str) -> dict[str, float]:
         doc = doc["parsed"]
     flat = flatten(doc)
     # skipped configs flatten to nothing numeric; rig_rtt_ms is the
-    # tunnel's property, not the code's — never a verdict subject
+    # machine's property, not the code's — never a verdict subject
     return {k: v for k, v in flat.items() if not k.endswith("rig_rtt_ms")}
 
 
