@@ -451,6 +451,7 @@ def run_multichip(args, platform: str) -> None:
         cache_root,
         clear_all_jit_caches,
         configure_aot,
+        ledger,
         retrace,
     )
 
@@ -466,7 +467,9 @@ def run_multichip(args, platform: str) -> None:
 
     def solve(aot_dir: str, **kw):
         configure_aot(aot_dir)
-        solver = TpuSpfSolver(me, **kw)
+        # incremental_spf as DecisionConfig defaults it: the programs a
+        # user's Decision would build (and the one-chip run has cached)
+        solver = TpuSpfSolver(me, incremental_spf=True, **kw)
         t0 = time.perf_counter()
         db = solver.build_route_db(me, states, prefix_state)
         check(db is not None, "vantage not in the LSDB")
@@ -510,6 +513,9 @@ def run_multichip(args, platform: str) -> None:
             routes=len(mc_routes), resident_arrays=len(resident),
             partitioned_arrays=partitioned,
             devices_spanned=sorted(spans), bytes_in_use=in_use,
+            compile_ms={  # before the AOT reload overwrites the entry
+                k: e["compile_ms"] for k, e in ledger.snapshot().items()
+            },
             platform=platform,
         )
 
