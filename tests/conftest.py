@@ -11,6 +11,8 @@ import asyncio
 import functools
 import os
 
+import pytest
+
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
@@ -33,3 +35,25 @@ def run_async(fn):
         return asyncio.run(asyncio.wait_for(fn(*args, **kwargs), timeout=60))
 
     return wrapper
+
+
+@pytest.fixture
+def fresh_xla_cache_state():
+    """enable_compilation_cache is first-call-wins and mutates jax's
+    config; isolate both (the conftest keeps the cache off)."""
+    import jax
+
+    import openr_tpu.ops.xla_cache as xc
+
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    old_applied = xc._applied
+    old_cfg = {k: getattr(jax.config, k) for k in keys}
+    xc._applied = None
+    yield xc
+    xc._applied = old_applied
+    for k, v in old_cfg.items():
+        jax.config.update(k, v)

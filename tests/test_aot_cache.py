@@ -305,26 +305,6 @@ class TestAotCacheUnit:
 # -- where compiled code is kept -------------------------------------------
 
 
-@pytest.fixture
-def fresh_xla_cache_state():
-    """enable_compilation_cache is first-call-wins and mutates jax's
-    config; isolate both (the conftest keeps the cache off)."""
-    import openr_tpu.ops.xla_cache as xc
-
-    keys = (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes",
-    )
-    old_applied = xc._applied
-    old_cfg = {k: getattr(jax.config, k) for k in keys}
-    xc._applied = None
-    yield xc
-    xc._applied = old_applied
-    for k, v in old_cfg.items():
-        jax.config.update(k, v)
-
-
 @pytest.mark.parametrize(
     "jax_env, arg, ours_env, expect",
     [

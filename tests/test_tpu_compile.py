@@ -29,6 +29,7 @@ import jax
 import numpy as np
 import pytest
 
+from chip_smoke import set_metric
 from openr_tpu.decision import tpu_solver as ts
 from openr_tpu.models import topologies
 from openr_tpu.ops import ksp2 as ksp2_ops
@@ -64,6 +65,7 @@ class Capture:
         self.programs: dict = {}
         self.mesh_programs: dict = {}
         self._undo: list = []
+        self._real_instrument_jit = xla_cache.instrument_jit
 
     def __enter__(self):
         # the factories memoize their (already wrapped) results
@@ -87,8 +89,7 @@ class Capture:
         setattr(mod, name, new)
 
     def _instrument_jit(self, name, jitted, aot_key=None):
-        real = self._undo[0][2]
-        run = real(name, jitted, aot_key=aot_key)
+        run = self._real_instrument_jit(name, jitted, aot_key=aot_key)
         if isinstance(jitted, _MeshProgram):
             return run  # recorded by its factory, with the mesh
 
@@ -157,25 +158,6 @@ def _grid(side: int, ksp2_every: int = 0):
     return adj_dbs, prefix_dbs, f"node-{side // 2}-{side // 2}"
 
 
-def _bump_metric(adj_dbs, node: str, metric: int) -> list:
-    """Raise `node`'s first link (both directions) to `metric`; -> the
-    two changed adjacency databases."""
-    index = {db.this_node_name: i for i, db in enumerate(adj_dbs)}
-    other = adj_dbs[index[node]].adjacencies[0].other_node_name
-    changed = []
-    for me, peer in ((node, other), (other, node)):
-        db = adj_dbs[index[me]]
-        adj_dbs[index[me]] = db = dataclasses.replace(
-            db, adjacencies=tuple(
-                dataclasses.replace(a, metric=metric)
-                if a.other_node_name == peer else a
-                for a in db.adjacencies
-            ),
-        )
-        changed.append(db)
-    return changed
-
-
 def solve_then_churn(side: int, ksp2_every: int = 0, churn: int = 1, **kw):
     """A cold solve, then `churn` link-metric changes each followed by a
     warm solve — on one solver, through LinkState's own update path (the
@@ -184,9 +166,10 @@ def solve_then_churn(side: int, ksp2_every: int = 0, churn: int = 1, **kw):
     states, prefix_state = topologies.build_states(adj_dbs, prefix_dbs)
     solver = ts.TpuSpfSolver(me, **kw)
     assert solver.build_route_db(me, states, prefix_state) is not None
-    far = f"node-{side // 2}-{side - 2}"
+    index = {db.this_node_name: i for i, db in enumerate(adj_dbs)}
+    far, farther = (f"node-{side // 2}-{side - k}" for k in (2, 1))
     for step in range(churn):
-        for db in _bump_metric(adj_dbs, far, 3 + step):
+        for db in set_metric(adj_dbs, index, far, farther, 3 + step):
             states[db.area].update_adjacency_database(db)
         assert solver.build_route_db(me, states, prefix_state) is not None
     return solver

@@ -613,38 +613,18 @@ def test_ucmp_device_fixpoint_bounded_on_zero_weight_cycle():
     assert int(rounds) == fixpoint_bound(n_cap)
 
 
-def test_prewarm_tool_bakes_cache(tmp_path, monkeypatch):
+def test_prewarm_tool_bakes_cache(
+    tmp_path, monkeypatch, fresh_xla_cache_state
+):
     """openr-tpu-prewarm compiles a capacity class into the persistent
     cache (shapes only — correctness covered by the differentials)."""
-    # --cache-dir loses to jax's own variable (ops/xla_cache.py)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    import openr_tpu.ops.xla_cache as xc
     from openr_tpu.tools.prewarm import main as prewarm_main
 
-    import jax
-
-    old = xc._applied
-    old_cfg = {
-        k: getattr(jax.config, k)
-        for k in (
-            "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes",
-        )
-    }
-    xc._applied = None  # the conftest disables the cache; isolate
-    try:
-        rc = prewarm_main(
-            ["--nodes", "16", "--cache-dir", str(tmp_path / "xla")]
-        )
-        assert rc == 0
-        assert (tmp_path / "xla").is_dir()
-    finally:
-        xc._applied = old
-        # the tool mutates jax's cache config; later tests must run
-        # with the conftest's disabled-cache state, not a deleted tmp dir
-        for k, v in old_cfg.items():
-            jax.config.update(k, v)
+    # --cache-dir loses to jax's own variable (ops/xla_cache.py)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    rc = prewarm_main(["--nodes", "16", "--cache-dir", str(tmp_path / "xla")])
+    assert rc == 0
+    assert (tmp_path / "xla").is_dir()
 
 
 # -- randomized churn soak ---------------------------------------------------
