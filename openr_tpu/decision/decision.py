@@ -109,6 +109,9 @@ class PendingUpdates:
     # keep their own boundaries
     replay_cursor: int = 0
     replay_snapshot: Optional[dict] = None
+    # time.monotonic() of the first rebuild trigger this batch raised
+    # (the decision.debounce span's start); None until one fires
+    first_trigger: Optional[float] = None
 
     def apply_link_state_change(
         self, change: LinkStateChange, node_name: str
@@ -270,6 +273,11 @@ class Decision(Actor):
         self._provenance = ProvenanceLedger()
         self._ingest_tags: dict[str, tuple] = {}
         self._solve_epoch = 0
+        # running seconds in the adjacency filter and in LinkState's
+        # update; decision.lsdb_apply.update reports each publication's
+        # share as filter_ms / link_state_ms
+        self._filter_s = 0.0
+        self._link_state_s = 0.0
         # per-epoch RIB digests (decision/rib_digest.py): the delta
         # digest of the last finish plus the rolling session chain —
         # stamped on every convergence trace and exported through the
@@ -458,45 +466,72 @@ class Decision(Actor):
             else None
         )
         damped = False
-        with tracer.span(ctx, "decision.lsdb_apply", node=self.node_name):
+        with tracer.span(
+            ctx, "decision.lsdb_apply", node=self.node_name
+        ) as apply_sp:
+            parent = apply_sp.span.span_id if apply_sp is not None else None
+            # the damper's verdict per key, as it arrives: a suppressed
+            # key is held and never decoded
+            kvs: list[tuple] = []  # (key, value, suppressed)
             for key, value in pub.key_vals.items():
                 if value.value is None:
                     continue  # ttl refresh only
-                if damper is not None and damper.record_change(area, key):
+                suppressed = damper is not None and damper.record_change(
+                    area, key
+                )
+                if suppressed:
                     damper.hold(area, key, (
                         "kv", value.version, value.originator_id,
                         value.value,
                     ))
+                    damped = True
+                kvs.append((key, value, suppressed))
+            with tracer.span(
+                ctx, "decision.lsdb_apply.decode", parent_id=parent
+            ):
+                decoded = [
+                    None if suppressed else self._decode_key(key, value.value)
+                    for key, value, suppressed in kvs
+                ]
+            filter0, link0 = self._filter_s, self._link_state_s
+            with tracer.span(
+                ctx, "decision.lsdb_apply.update", parent_id=parent
+            ) as update_sp:
+                # applied (and recorded) in arrival order, the
+                # suppressed keys in their places
+                for (key, value, suppressed), db in zip(kvs, decoded):
+                    if not suppressed:
+                        self._apply_decoded(area, db)
+                        self._note_ingest(area, key, value.originator_id)
                     if rec is not None:
                         rec.record_kv(
                             area, key, value.version, value.originator_id,
-                            value.value, recv_t, suppressed=True,
+                            value.value, recv_t, suppressed=suppressed,
                         )
-                    damped = True
-                    continue
-                self._update_key_in_lsdb(area, key, value.value)
-                self._note_ingest(area, key, value.originator_id)
-                if rec is not None:
-                    rec.record_kv(
-                        area, key, value.version, value.originator_id,
-                        value.value, recv_t,
-                    )
-            for key in pub.expired_keys:
-                # a withdrawal is a flap too (RFC 2439 counts both
-                # directions); a suppressed key's expiry is held as the
-                # latest state, not applied
-                if damper is not None and damper.record_change(area, key):
-                    damper.hold(area, key, ("expire",))
+                for key in pub.expired_keys:
+                    # a withdrawal is a flap too (RFC 2439 counts both
+                    # directions); a suppressed key's expiry is held as
+                    # the latest state, not applied
+                    if damper is not None and damper.record_change(
+                        area, key
+                    ):
+                        damper.hold(area, key, ("expire",))
+                        if rec is not None:
+                            rec.record_expired(
+                                area, key, recv_t, suppressed=True
+                            )
+                        damped = True
+                        continue
+                    self._delete_key_from_lsdb(area, key)
+                    self._note_ingest(area, key, "<expired>")
                     if rec is not None:
-                        rec.record_expired(
-                            area, key, recv_t, suppressed=True
-                        )
-                    damped = True
-                    continue
-                self._delete_key_from_lsdb(area, key)
-                self._note_ingest(area, key, "<expired>")
-                if rec is not None:
-                    rec.record_expired(area, key, recv_t)
+                        rec.record_expired(area, key, recv_t)
+                if update_sp is not None:
+                    update_sp.set(
+                        keys=len(kvs) + len(pub.expired_keys),
+                        filter_ms=(self._filter_s - filter0) * 1e3,
+                        link_state_ms=(self._link_state_s - link0) * 1e3,
+                    )
         if ctx is not None:
             if self.pending.count == before:
                 # nothing route-relevant changed; close so the trace
@@ -528,39 +563,46 @@ class Decision(Actor):
             self.pending.topo_tag = tag
 
     def _update_key_in_lsdb(self, area: str, key: str, raw: bytes) -> None:
+        self._apply_decoded(area, self._decode_key(key, raw))
+
+    def _decode_key(self, key: str, raw: bytes):
+        """The database a key's value carries, or None where it carries
+        none: an erase tombstone (KvStore unset — the withdrawal itself
+        arrives via key expiry), a key Decision does not read, a value
+        that does not parse."""
         if not raw:
-            # erase tombstone (KvStore unset): carries no database; the
-            # actual withdrawal arrives via key expiry
-            return
-        node = parse_adj_key(key)
-        if node is not None:
-            try:
-                adj_db = deserialize(raw, AdjacencyDatabase)
-            except Exception:
-                counters.increment("decision.lsdb_parse_errors")
-                log.exception("%s: bad adj db for %s", self.name, key)
-                return
-            self._update_adjacency_db(area, adj_db)
-            return
-        parsed = parse_prefix_key(key)
-        if parsed is not None:
-            try:
-                prefix_db = deserialize(raw, PrefixDatabase)
-            except Exception:
-                counters.increment("decision.lsdb_parse_errors")
-                log.exception("%s: bad prefix db for %s", self.name, key)
-                return
-            changed = self.prefix_state.update_prefix_database(prefix_db)
+            return None
+        if parse_adj_key(key) is not None:
+            kind = AdjacencyDatabase
+        elif parse_prefix_key(key) is not None:
+            kind = PrefixDatabase
+        else:
+            return None
+        try:
+            return deserialize(raw, kind)
+        except Exception:
+            counters.increment("decision.lsdb_parse_errors")
+            log.exception(
+                "%s: bad %s for %s", self.name,
+                "adj db" if kind is AdjacencyDatabase else "prefix db", key,
+            )
+            return None
+
+    def _apply_decoded(self, area: str, db) -> None:
+        if isinstance(db, AdjacencyDatabase):
+            self._update_adjacency_db(area, db)
+        elif isinstance(db, PrefixDatabase):
+            changed = self.prefix_state.update_prefix_database(db)
             self.pending.apply_prefix_changes(changed)
 
     def _update_adjacency_db(self, area: str, adj_db: AdjacencyDatabase) -> None:
         link_state = self.area_link_states.setdefault(area, LinkState(area))
-        filtered = self._filter_adj_only_used_by_other_node(adj_db)
         t0 = time.perf_counter()
+        filtered = self._filter_adj_only_used_by_other_node(adj_db)
+        t1 = time.perf_counter()
         change = link_state.update_adjacency_database(filtered)
-        counters.add_stat_value(
-            "decision.linkstate_update_ms", (time.perf_counter() - t0) * 1e3
-        )
+        self._filter_s += t1 - t0
+        self._link_state_s += time.perf_counter() - t1
         if change:
             self.pending.apply_link_state_change(change, adj_db.this_node_name)
 
@@ -610,6 +652,8 @@ class Decision(Actor):
     def _trigger_rebuild(self) -> None:
         if not self._kvstore_synced:
             return  # initialization gating
+        if self.pending.first_trigger is None:
+            self.pending.first_trigger = time.monotonic()
         if self._rebuild_debounced is not None:
             self._rebuild_debounced()
 
@@ -618,6 +662,11 @@ class Decision(Actor):
             return
         pending = self.pending
         self.pending = PendingUpdates()
+        if pending.first_trigger is not None:
+            tracer.record_span(
+                pending.trace, "decision.debounce",
+                pending.first_trigger, time.monotonic(),
+            )
         if self._solve_q is not None:
             # async dispatch: hand the snapshot to the dispatch fiber
             # and return immediately — the actor loop stays free to
@@ -1517,11 +1566,10 @@ class Decision(Actor):
             self._emit_solver_sample("DEVICE_RETRACE", evt)
 
     def _fold_solver_timing(self, ctx, spf_sp) -> None:
-        """Fold the TPU pipeline's last_timing breakdown in as timed
-        children of decision.spf: per-area sync/exec/mat stages, laid
-        back-to-back ending at the span's end (the pipeline overlaps
-        stages across areas, so per-stage wall offsets are not
-        recoverable — durations are exact, placement is indicative)."""
+        """Put the TPU pipeline's stages into the trace as children of
+        decision.spf. The solver has no trace context; it stamps each
+        stage where it runs (actor thread or materialization worker)
+        and hands the intervals back in last_timing["spans"]."""
         if ctx is None or spf_sp is None:
             return
         tm = getattr(self.solver, "last_timing", None)
@@ -1552,19 +1600,17 @@ class Decision(Actor):
             spf_sp.attributes["stream_changed_rows"] = st.get(
                 "changed_rows"
             )
-        areas = tm.get("areas") or {"": tm}
-        cursor = spf_sp.end
-        for area, stages in sorted(areas.items(), reverse=True):
-            for stage in ("mat_ms", "exec_ms", "sync_ms"):
-                d = stages.get(stage)
-                if not isinstance(d, (int, float)) or d <= 0:
-                    continue
-                name = f"tpu.{stage[:-3]}" + (f"[{area}]" if area else "")
-                tracer.record_span(
-                    ctx, name, cursor - d / 1e3, cursor,
-                    parent_id=spf_sp.span_id, area=area or None,
-                )
-                cursor -= d / 1e3
+        # a parent stands before its children in the list
+        ids: dict[tuple, int] = {}
+        for name, parent, start, end, attrs in tm.get("spans") or ():
+            area = attrs.get("area")
+            sp = tracer.record_span(
+                ctx, name, start, end,
+                parent_id=ids.get((area, parent), spf_sp.span_id),
+                **attrs,
+            )
+            if sp is not None:
+                ids[(area, name)] = sp.span_id
 
     def _stamp_boot_first_solve(self, build_ms: float) -> None:
         """Boot lifecycle: record the first full solve with its

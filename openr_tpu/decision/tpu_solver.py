@@ -449,191 +449,199 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                  root, root_nbr, root_w,
                  prev_metric, prev_s3w, prev_nhw,
                  prev_lfa_slot, prev_lfa_metric, *incr_args):
-        o = 0
-        ann_node = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        ann_flags = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        path_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        source_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        dist_adv = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        min_nh = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-        ann_valid = (ann_flags & 1).astype(bool)
-        ann_over = (ann_flags & 2).astype(bool)
-        # per-prefix v4 bit rides flag bit 2 of announcer slot 0
-        v4_blocked = (
-            (ann_flags[:, 0] & 4).astype(bool)
-            if block_v4
-            else jnp.zeros((p_cap,), bool)
-        )
+        with jax.named_scope("unpack"):
+            o = 0
+            ann_node = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            ann_flags = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            path_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            source_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            dist_adv = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            min_nh = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
+            ann_valid = (ann_flags & 1).astype(bool)
+            ann_over = (ann_flags & 2).astype(bool)
+            # per-prefix v4 bit rides flag bit 2 of announcer slot 0
+            v4_blocked = (
+                (ann_flags[:, 0] & 4).astype(bool)
+                if block_v4
+                else jnp.zeros((p_cap,), bool)
+            )
 
-        if incr:
-            (prev_dist, s_dirty_idx, s_dirty_old,
-             r_dirty_idx, r_dirty_old, cone_limit) = incr_args
-            if mesh is not None:
-                dist_d, trips_v, cone_v, fell_v, rounds_v = mc_sssp_incr(
-                    deltas, shift_w, res_rows, res_nbr, res_w, root,
-                    root_nbr, root_w, prev_dist,
-                    s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
-                    cone_limit,
-                )
-                trips = trips_v.max()
-                rounds = rounds_v.max()
-                cone, fell_back = cone_v[0], fell_v[0]
+        with jax.named_scope("seed"):
+            if incr:
+                (prev_dist, s_dirty_idx, s_dirty_old,
+                 r_dirty_idx, r_dirty_old, cone_limit) = incr_args
+                if mesh is not None:
+                    dist_d, trips_v, cone_v, fell_v, rounds_v = mc_sssp_incr(
+                        deltas, shift_w, res_rows, res_nbr, res_w, root,
+                        root_nbr, root_w, prev_dist,
+                        s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
+                        cone_limit,
+                    )
+                    trips = trips_v.max()
+                    rounds = rounds_v.max()
+                    cone, fell_back = cone_v[0], fell_v[0]
+                else:
+                    dist_d, trips, cone, fell_back, rounds = incremental_sssp(
+                        deltas, shift_w, res_rows, res_nbr, res_w, root,
+                        root_nbr, root_w, prev_dist,
+                        s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
+                        cone_limit,
+                        s_cap, has_res, n_cap, d_cap, max_trips,
+                        kernel, delta_exp,
+                    )  # [D, N]
             else:
-                dist_d, trips, cone, fell_back, rounds = incremental_sssp(
-                    deltas, shift_w, res_rows, res_nbr, res_w, root,
-                    root_nbr, root_w, prev_dist,
-                    s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
-                    cone_limit,
-                    s_cap, has_res, n_cap, d_cap, max_trips,
-                    kernel, delta_exp,
-                )  # [D, N]
-        else:
-            if mesh is not None:
-                dist_d, trips_v, rounds_v = mc_sssp(
-                    deltas, shift_w, res_rows, res_nbr, res_w, root,
-                    root_nbr, root_w,
-                )
-                trips = trips_v.max()
-                rounds = rounds_v.max()
-            else:
-                dist_d, trips, rounds = _plan_sssp(
-                    deltas, shift_w, res_rows, res_nbr, res_w, root,
-                    root_nbr, root_w,
-                    s_cap, has_res, n_cap, d_cap, max_trips,
-                    kernel, delta_exp,
-                )  # [D, N]
+                if mesh is not None:
+                    dist_d, trips_v, rounds_v = mc_sssp(
+                        deltas, shift_w, res_rows, res_nbr, res_w, root,
+                        root_nbr, root_w,
+                    )
+                    trips = trips_v.max()
+                    rounds = rounds_v.max()
+                else:
+                    dist_d, trips, rounds = _plan_sssp(
+                        deltas, shift_w, res_rows, res_nbr, res_w, root,
+                        root_nbr, root_w,
+                        s_cap, has_res, n_cap, d_cap, max_trips,
+                        kernel, delta_exp,
+                    )  # [D, N]
         if mesh is not None:
             # the resident copy stays lane-sharded (out_shardings pins
             # it); the selection tail reads a replicated copy so the
             # partitioner never touches a sharded gather axis
             dist_res = dist_d
             dist_d = jax.lax.with_sharding_constraint(dist_d, mc_rep)
-        via = root_w[:, None] + dist_d  # <= 2^30, overflow-free
-        dist = jnp.minimum(via.min(axis=0), INF_E).at[root].set(0)  # [N]
+        with jax.named_scope("select"):
+            via = root_w[:, None] + dist_d  # <= 2^30, overflow-free
+            dist = jnp.minimum(via.min(axis=0), INF_E).at[root].set(0)  # [N]
 
-        # selection (reference order; drain via flags)
-        idx = jnp.clip(ann_node, 0, n_cap - 1)
-        ann_dist = dist[idx]
-        reach = ann_valid & (ann_dist < INF_E)
-        pp = jnp.where(reach, path_pref, _NEG)
-        s = reach & (pp == pp.max(axis=1, keepdims=True))
-        sp = jnp.where(s, source_pref, _NEG)
-        s = s & (sp == sp.max(axis=1, keepdims=True))
-        da = jnp.where(s, dist_adv, INF_E)
-        s2 = s & (da == da.min(axis=1, keepdims=True))
-        nd = s2 & ~ann_over
-        s3 = jnp.where(nd.any(axis=1, keepdims=True), nd, s2)
-        igp = jnp.where(s3, ann_dist, INF_E)
-        metric = igp.min(axis=1)
-        s4 = s3 & (igp == metric[:, None])
+            # selection (reference order; drain via flags)
+            idx = jnp.clip(ann_node, 0, n_cap - 1)
+            ann_dist = dist[idx]
+            reach = ann_valid & (ann_dist < INF_E)
+            pp = jnp.where(reach, path_pref, _NEG)
+            s = reach & (pp == pp.max(axis=1, keepdims=True))
+            sp = jnp.where(s, source_pref, _NEG)
+            s = s & (sp == sp.max(axis=1, keepdims=True))
+            da = jnp.where(s, dist_adv, INF_E)
+            s2 = s & (da == da.min(axis=1, keepdims=True))
+            nd = s2 & ~ann_over
+            s3 = jnp.where(nd.any(axis=1, keepdims=True), nd, s2)
+            igp = jnp.where(s3, ann_dist, INF_E)
+            metric = igp.min(axis=1)
+            s4 = s3 & (igp == metric[:, None])
 
-        on_sp = (via == dist[None, :]).T  # [N, D]
-        nh_mask = jnp.any(s4[:, :, None] & on_sp[idx], axis=1)  # [P, D]
+        with jax.named_scope("nexthop"):
+            on_sp = (via == dist[None, :]).T  # [N, D]
+            nh_mask = jnp.any(s4[:, :, None] & on_sp[idx], axis=1)  # [P, D]
 
         if lfa:
-            # rfc5286 loop-free alternates from the SAME per-slot distance
-            # fields: slot d is a valid backup for prefix row p iff its
-            # neighbor's own distance to the selected announcer set
-            # (min over s3 of dist_d) beats detouring back through the
-            # root (dist_d[root] + route metric). Strict < guarantees no
-            # micro-loop. One [P, A, D] row-gather — the same shape the
-            # ECMP predicate's on_sp[idx] gather already pays.
-            d_root = dist_d[:, root]  # [D] neighbor -> root distance
-            ann_nd = dist_d.T[idx]  # [P, A, D]
-            nbr_pd = jnp.where(
-                s3[:, :, None], ann_nd, INF_E
-            ).min(axis=1)  # [P, D]
-            link_up = root_w < INF_E
-            ok_lfa = (
-                link_up[None, :]
-                & ~nh_mask
-                & (nbr_pd < INF_E)  # neighbor actually reaches the prefix
-                & (nbr_pd < d_root[None, :] + metric[:, None])
-            )
-            # alternate cost <= 2^29 + 2^28 < the 2^30 mask fill
-            alt = jnp.where(
-                ok_lfa, root_w[None, :] + nbr_pd, jnp.int32(1 << 30)
-            )
-            has_lfa = ok_lfa.any(axis=1)
-            # argmin returns the FIRST minimum: lowest slot breaks ties,
-            # matching the oracle's ordered-link iteration
-            lfa_slot = jnp.where(
-                has_lfa, jnp.argmin(alt, axis=1).astype(jnp.int32), -1
-            )
-            lfa_metric = jnp.where(has_lfa, alt.min(axis=1), 0)
+            with jax.named_scope("lfa"):
+                # rfc5286 loop-free alternates from the SAME per-slot distance
+                # fields: slot d is a valid backup for prefix row p iff its
+                # neighbor's own distance to the selected announcer set
+                # (min over s3 of dist_d) beats detouring back through the
+                # root (dist_d[root] + route metric). Strict < guarantees no
+                # micro-loop. One [P, A, D] row-gather — the same shape the
+                # ECMP predicate's on_sp[idx] gather already pays.
+                d_root = dist_d[:, root]  # [D] neighbor -> root distance
+                ann_nd = dist_d.T[idx]  # [P, A, D]
+                nbr_pd = jnp.where(
+                    s3[:, :, None], ann_nd, INF_E
+                ).min(axis=1)  # [P, D]
+                link_up = root_w < INF_E
+                ok_lfa = (
+                    link_up[None, :]
+                    & ~nh_mask
+                    & (nbr_pd < INF_E)  # neighbor actually reaches the prefix
+                    & (nbr_pd < d_root[None, :] + metric[:, None])
+                )
+                # alternate cost <= 2^29 + 2^28 < the 2^30 mask fill
+                alt = jnp.where(
+                    ok_lfa, root_w[None, :] + nbr_pd, jnp.int32(1 << 30)
+                )
+                has_lfa = ok_lfa.any(axis=1)
+                # argmin returns the FIRST minimum: lowest slot breaks ties,
+                # matching the oracle's ordered-link iteration
+                lfa_slot = jnp.where(
+                    has_lfa, jnp.argmin(alt, axis=1).astype(jnp.int32), -1
+                )
+                lfa_metric = jnp.where(has_lfa, alt.min(axis=1), 0)
         else:
             lfa_slot = prev_lfa_slot
             lfa_metric = prev_lfa_metric
 
-        s3w = _pack_words(s3)
-        nhw = _pack_words(nh_mask)
+        with jax.named_scope("pack"):
+            s3w = _pack_words(s3)
+            nhw = _pack_words(nh_mask)
 
-        # route-level ok computed on device: compacts the cold full
-        # pull to ok rows, and on the streaming path rides the delta
-        # payload per changed row (the host apply is then unpack-free)
-        ok = route_ok_device(
-            metric, s3, nh_mask, ann_node, min_nh, v4_blocked, root,
-        )
-        changed = column_diff(
-            metric, s3w, nhw, lfa_slot, lfa_metric,
-            prev_metric, prev_s3w, prev_nhw,
-            prev_lfa_slot, prev_lfa_metric, lfa,
-        )
-        count, delta_parts = compact_changed_rows(
-            changed, trips, metric, s3w, nhw,
-            ok if stream else None,
-            lfa_slot, lfa_metric, stream or budget, p_cap, lfa,
-        )
-        # cold-rebuild compaction: only ok rows' outputs ship (gathered
-        # to the front — pad slots past okc carry the last ok row's
-        # values and are ignored)
-        okc = ok.sum().astype(jnp.int32)
-        oidx = jnp.nonzero(ok, size=p_cap, fill_value=p_cap)[0]
-        osafe = jnp.clip(oidx, 0, p_cap - 1).astype(jnp.int32)
-        full_parts = [
-            okc[None],
-            trips[None].astype(jnp.int32),
-            oidx.astype(jnp.int32),
-            metric[osafe],
-            s3w[osafe].ravel(),
-            nhw[osafe].ravel(),
-        ]
-        if lfa:
-            # delta-side lfa columns already rode compact_changed_rows
-            full_parts += [lfa_slot[osafe], lfa_metric[osafe]]
-        if sentinels:
-            # numerical-health sentinels: two scalar reductions riding
-            # the tail of BOTH pull buffers (free — the pull happens
-            # anyway). unreachable = rows with a live announcer but no
-            # finite metric; saturated = finite metrics past 2^28,
-            # within one metric-add of the 2^29 INF_E encoding — the
-            # overflow precursor the encoding cannot represent failing.
-            unreach = (
-                (ann_valid.any(axis=1) & (metric >= INF_E))
-                .sum()
-                .astype(jnp.int32)
+            # route-level ok computed on device: compacts the cold full
+            # pull to ok rows, and on the streaming path rides the delta
+            # payload per changed row (the host apply is then unpack-free)
+            ok = route_ok_device(
+                metric, s3, nh_mask, ann_node, min_nh, v4_blocked, root,
             )
-            saturated = (
-                ((metric < INF_E) & (metric > _SENTINEL_SAT))
-                .sum()
-                .astype(jnp.int32)
+        with jax.named_scope("diff"):
+            changed = column_diff(
+                metric, s3w, nhw, lfa_slot, lfa_metric,
+                prev_metric, prev_s3w, prev_nhw,
+                prev_lfa_slot, prev_lfa_metric, lfa,
             )
-            delta_parts += [unreach[None], saturated[None]]
-            full_parts += [unreach[None], saturated[None]]
-        if incr:
-            # cone + in-kernel-fallback flag (the host parses the tail
-            # back to front: [-3]=cone, [-2]=fell_back, with the
-            # sentinels at [-5]/[-4] when enabled, rounds always at [-1])
-            tail = [cone[None], fell_back.astype(jnp.int32)[None]]
-            delta_parts += tail
-            full_parts += tail
-        # executed-relaxation work metric rides LAST unconditionally:
-        # sync rounds = trips * UNROLL; bucketed rounds = ladder passes
-        # + one handoff relaxation per bucket epoch (trips = epochs)
-        delta_parts += [rounds[None].astype(jnp.int32)]
-        full_parts += [rounds[None].astype(jnp.int32)]
-        delta_buf = jnp.concatenate(delta_parts)
-        full_buf = jnp.concatenate(full_parts)
+        with jax.named_scope("compact"):
+            count, delta_parts = compact_changed_rows(
+                changed, trips, metric, s3w, nhw,
+                ok if stream else None,
+                lfa_slot, lfa_metric, stream or budget, p_cap, lfa,
+            )
+            # cold-rebuild compaction: only ok rows' outputs ship (gathered
+            # to the front — pad slots past okc carry the last ok row's
+            # values and are ignored)
+            okc = ok.sum().astype(jnp.int32)
+            oidx = jnp.nonzero(ok, size=p_cap, fill_value=p_cap)[0]
+            osafe = jnp.clip(oidx, 0, p_cap - 1).astype(jnp.int32)
+            full_parts = [
+                okc[None],
+                trips[None].astype(jnp.int32),
+                oidx.astype(jnp.int32),
+                metric[osafe],
+                s3w[osafe].ravel(),
+                nhw[osafe].ravel(),
+            ]
+            if lfa:
+                # delta-side lfa columns already rode compact_changed_rows
+                full_parts += [lfa_slot[osafe], lfa_metric[osafe]]
+            if sentinels:
+                # numerical-health sentinels: two scalar reductions riding
+                # the tail of BOTH pull buffers (free — the pull happens
+                # anyway). unreachable = rows with a live announcer but no
+                # finite metric; saturated = finite metrics past 2^28,
+                # within one metric-add of the 2^29 INF_E encoding — the
+                # overflow precursor the encoding cannot represent failing.
+                unreach = (
+                    (ann_valid.any(axis=1) & (metric >= INF_E))
+                    .sum()
+                    .astype(jnp.int32)
+                )
+                saturated = (
+                    ((metric < INF_E) & (metric > _SENTINEL_SAT))
+                    .sum()
+                    .astype(jnp.int32)
+                )
+                delta_parts += [unreach[None], saturated[None]]
+                full_parts += [unreach[None], saturated[None]]
+            if incr:
+                # cone + in-kernel-fallback flag (the host parses the tail
+                # back to front: [-3]=cone, [-2]=fell_back, with the
+                # sentinels at [-5]/[-4] when enabled, rounds always at [-1])
+                tail = [cone[None], fell_back.astype(jnp.int32)[None]]
+                delta_parts += tail
+                full_parts += tail
+            # executed-relaxation work metric rides LAST unconditionally:
+            # sync rounds = trips * UNROLL; bucketed rounds = ladder passes
+            # + one handoff relaxation per bucket epoch (trips = epochs)
+            delta_parts += [rounds[None].astype(jnp.int32)]
+            full_parts += [rounds[None].astype(jnp.int32)]
+            delta_buf = jnp.concatenate(delta_parts)
+            full_buf = jnp.concatenate(full_parts)
         if mesh is not None:
             # pin BOTH pull buffers replicated: on small shape classes
             # GSPMD re-partitions the short concatenate and emits an
@@ -1158,7 +1166,7 @@ class _AreaDev:
         "plan", "d_deltas", "d_shift_w", "d_res_rows", "d_res_nbr",
         "d_res_w", "matrix_key", "matrix", "flags", "d_mbuf",
         "matrix_version", "pack_over", "drain_epoch", "drain_log",
-        "mc_mesh",
+        "mc_mesh", "sync_marks",
     )
 
     def __init__(self):
@@ -1194,6 +1202,10 @@ class _AreaDev:
         # single-chip placement. A tier flip forces a full re-put under
         # the new placement (_sync_area).
         self.mc_mesh = None
+        # the last _sync_area's stages for the tpu.sync.* spans:
+        # (plan start, plan end = upload start, upload end) on
+        # time.monotonic(), bytes uploaded, slots scattered
+        self.sync_marks: tuple = ()
 
 
 class _VantageState:
@@ -1244,7 +1256,7 @@ class _PendingBuild:
 
     __slots__ = (
         "route_db", "futures", "t_pipe0", "ksp2_timing",
-        "bytes_uploaded", "delegated", "dispatch_wall_ms",
+        "bytes_uploaded", "delegated",
     )
 
     def __init__(self, route_db, futures=None, t_pipe0=0.0,
@@ -1255,7 +1267,6 @@ class _PendingBuild:
         self.ksp2_timing: dict = {}
         self.bytes_uploaded = 0
         self.delegated = delegated
-        self.dispatch_wall_ms = 0.0
 
 
 _UCMP_ALGOS = (
@@ -1871,9 +1882,6 @@ class TpuSpfSolver:
         pending.ksp2_timing = self._ksp2_timing
         self._ksp2_timing = {}
         pending.bytes_uploaded = self._bytes_uploaded
-        # dispatch/collect boundary for the latency-budget ledger: how
-        # much of the pipeline wall was phase 1 (on-loop) vs phase 2
-        pending.dispatch_wall_ms = (_time.perf_counter() - t_pipe0) * 1e3
         return pending
 
     @affinity.executor_safe
@@ -1891,8 +1899,8 @@ class TpuSpfSolver:
             return route_db
         import time as _time
 
-        t_collect0 = _time.perf_counter()
         views = []
+        spans: list[tuple] = []
         stages = {"sync_ms": 0.0, "exec_ms": 0.0, "mat_ms": 0.0}
         area_timing: dict[str, dict] = {}
         incremental = False
@@ -1936,6 +1944,10 @@ class TpuSpfSolver:
             for k, v in res["timing"].items():
                 stages[k] = stages.get(k, 0.0) + v
             area_timing[area] = dict(res["timing"])
+            spans.extend(
+                (name, parent, start, end, {**attrs, "area": area})
+                for name, parent, start, end, attrs in res["spans"]
+            )
             # the shape-class kernel this area executed, for the
             # ctrl.tpu.kernels estimated-vs-achieved join
             if stats.get("kernel"):
@@ -1978,9 +1990,11 @@ class TpuSpfSolver:
             **stages,
             "pipeline_wall_ms": wall,
             "pipeline_stages_ms": sum(stages.values()),
-            "dispatch_wall_ms": pending.dispatch_wall_ms,
-            "collect_wall_ms": (_time.perf_counter() - t_collect0) * 1e3,
             "areas": area_timing,
+            # every stage's real interval on time.monotonic(), as
+            # (name, parent's name or None, start, end, attributes):
+            # Decision records them under decision.spf
+            "spans": spans,
             "bytes_uploaded": float(pending.bytes_uploaded),
             "bytes_downloaded": float(bytes_downloaded),
             "incremental": incremental,
@@ -2344,8 +2358,12 @@ class TpuSpfSolver:
         ad = self._area_dev.get(area)
         if ad is None:
             ad = self._area_dev[area] = _AreaDev()
+        import time as _time
+
         old_plan = ad.plan
+        t_plan0 = _time.monotonic()
         plan = sync_plan(link_state, old_plan)
+        t_plan1 = _time.monotonic()
         rebuilt = plan is not old_plan
         ad.plan = plan
         # multichip tier decision: placement is part of the mirror's
@@ -2378,6 +2396,9 @@ class TpuSpfSolver:
         def shp(key):
             return None if mc_sh is None else mc_sh[key]
 
+        t_up0 = _time.monotonic()
+        bytes0 = self._bytes_uploaded
+        dirty_slots = 0
         if rebuilt or ad.d_deltas is None:
             # same-capacity rebuild (index renumbering, class reshuffle
             # without a pow2 bucket change): the resident arrays stay on
@@ -2466,6 +2487,9 @@ class TpuSpfSolver:
         else:
             ((s_idx, s_val, s_old), (r_idx, r_val, r_old),
              nbr_changed) = drain_dirty(plan)
+            dirty_slots = sum(
+                len(idx) for idx in (s_idx, r_idx) if idx is not None
+            )
             if s_idx is not None:
                 ad.d_shift_w = self._scatter_counted(
                     ad.d_shift_w, s_idx, s_val, shp("shift_w")
@@ -2495,6 +2519,13 @@ class TpuSpfSolver:
                     else dict(zip(r_idx.tolist(), r_old.tolist()))
                 )
                 ad.drain_log.append((ad.drain_epoch, s_map, r_map))
+        # the host mirror diff, then the scatters / device_puts of what
+        # it found; the rest of a sync (announcer matrix, vantage
+        # state, incremental seeds) is tpu.sync's own time
+        ad.sync_marks = (
+            t_plan0, t_plan1, t_up0, _time.monotonic(),
+            self._bytes_uploaded - bytes0, dirty_slots,
+        )
 
         # announcer matrix: keyed on prefix churn + node-index stability
         mkey = (prefix_state.generation, plan.index_version)
@@ -2565,13 +2596,15 @@ class TpuSpfSolver:
         prefix_state: PrefixState,
         prefixes: list[str],
     ) -> dict:
-        """Host half of a fast-path solve (the tpu.sync span): device
-        mirror sync, out-link extraction, vantage-state (re)init. Reads
-        LSDB state, so it must run on the owning thread. Returns the
-        dispatch context consumed by _dispatch_one/_dispatch_fused."""
+        """Host half of a fast-path solve (the tpu.sync span; its
+        children tpu.sync.plan and tpu.sync.upload are _sync_area's):
+        device mirror sync, out-link extraction, vantage-state (re)init.
+        Reads LSDB state, so it must run on the owning thread. Returns
+        the dispatch context consumed by _dispatch_one/_dispatch_fused;
+        its end (t1) is where tpu.dispatch begins."""
         import time as _time
 
-        t0 = _time.perf_counter()
+        t0 = _time.monotonic()
         ad = self._sync_area(area, link_state, prefix_state, prefixes)
         plan, matrix = ad.plan, ad.matrix
         root_idx = plan.node_index[my_node_name]
@@ -2697,7 +2730,7 @@ class TpuSpfSolver:
                         "denom": denom,
                     }
 
-        t1 = _time.perf_counter()
+        t1 = _time.monotonic()
         return {
             "area": area, "ad": ad, "plan": plan, "matrix": matrix,
             "root_idx": root_idx, "root_nbr": root_nbr, "root_w": root_w,
@@ -2708,7 +2741,7 @@ class TpuSpfSolver:
             "d_cap": d_cap, "p_cap": p_cap, "a_cap": a_cap,
             "mc": mc, "incr": incr, "root_sig": root_sig,
             "dist_epoch": ad.drain_epoch,
-            "t0": t0, "t1": t1,
+            "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
         }
 
     def _lane_args(self, pv: dict) -> tuple:
@@ -3009,6 +3042,8 @@ class TpuSpfSolver:
 
         from openr_tpu.ops.stream import STREAM_BUDGETS, stream_budget
 
+        # the jitted call has just returned: the device runs from here
+        t_disp = _time.monotonic()
         plan, matrix, vs = pv["plan"], pv["matrix"], pv["vs"]
         lfa = pv["lfa"]
         sentinels = self.enable_sentinels
@@ -3058,18 +3093,22 @@ class TpuSpfSolver:
                     for _sh in new_prev[0].addressable_shards:
                         _sh.data.block_until_ready()
                         per_shard[str(getattr(_sh.device, "id", len(per_shard)))] = round(
-                            (_time.perf_counter() - t1) * 1e3, 3
+                            (_time.monotonic() - t1) * 1e3, 3
                         )
                 # lint: allow(broad-except) timing is best-effort
                 except Exception:
                     per_shard = {}
                 if per_shard:
                     mc_info["shard_ms"] = per_shard
+            # the pull below would block just the same: waiting here
+            # first tells the device's time from the copy's
+            (delta_buf if was_valid else full_buf).block_until_ready()
+            t_ready = _time.monotonic()
             if was_valid:
                 dbuf = np.asarray(delta_buf)  # ONE pull
                 count = int(dbuf[0])
                 trips = int(dbuf[1])
-            t2 = _time.perf_counter()
+            t2 = _time.monotonic()
             full_pull = count is None or count > b
             stats = {
                 "n_cap": plan.n_cap,
@@ -3085,7 +3124,7 @@ class TpuSpfSolver:
                 stats["multichip"] = mc_info
             if full_pull:
                 fbuf = np.asarray(full_buf)
-                t2 = _time.perf_counter()
+                t2 = _time.monotonic()
                 okc = int(fbuf[0])
                 trips = int(fbuf[1])
                 o = 2
@@ -3218,15 +3257,43 @@ class TpuSpfSolver:
             # here (still on the materialization worker) keeps the
             # Decision loop's first touch O(1)
             stats["ok_rows"] = int(len(crib.cols.key_rows()))
-            t3 = _time.perf_counter()
+            t3 = _time.monotonic()
+            # what the relaxation loop moved, by ops/relax.py's model:
+            # over the loop's device time it is the achieved rate
+            relax_bytes = relax_ops.relax_bytes(
+                spf_kernel, rounds, trips, plan.s_cap, d_cap, plan.n_cap,
+                *(plan.res_nbr.shape if plan.k_res > 0 else (0, 0)),
+            )
+            plan0, plan1, up0, up1, up_bytes, dirty_slots = pv["sync_marks"]
             return {
                 "view": crib.view(),
                 "stats": stats,
+                # exec_ms = tpu.dispatch + tpu.device_wait + tpu.pull
                 "timing": {
                     "sync_ms": (t1 - t0) * 1e3,
                     "exec_ms": (t2 - t1) * 1e3,
                     "mat_ms": (t3 - t2) * 1e3,
                 },
+                "spans": [
+                    ("tpu.sync", None, t0, t1, {}),
+                    ("tpu.sync.plan", "tpu.sync", plan0, plan1, {}),
+                    ("tpu.sync.upload", "tpu.sync", up0, up1, {
+                        "bytes_uploaded": up_bytes,
+                        "dirty_slots": dirty_slots,
+                    }),
+                    ("tpu.dispatch", None, t1, t_disp, {
+                        "kernel": kernel_name, "incremental": incr,
+                    }),
+                    ("tpu.device_wait", None, t_disp, t_ready, {
+                        "rounds": rounds, "relax_bytes": relax_bytes,
+                    }),
+                    ("tpu.pull", None, t_ready, t2, {
+                        "bytes_downloaded": bytes_dl,
+                        "full_pull": full_pull,
+                        "changed_rows": count,
+                    }),
+                    ("tpu.mat", None, t2, t3, {}),
+                ],
             }
 
         return prepare

@@ -267,6 +267,13 @@ class Fib(Actor):
         )
         t_prog = time.monotonic()
         prog0 = self._service_program_ms()
+        # what is handed to the service is built under .build, the
+        # awaited calls run under .write; _end_program closes whichever
+        # is open when a failure leaves early
+        parent = sp.span_id if sp is not None else None
+        stages = [tracer.start_span(
+            trace, "platform.program.build", parent_id=parent
+        )]
         # both tables are always attempted — a partial unicast failure must
         # not leave pending MPLS routes unprogrammed (ref syncRoutes covers
         # both with retry)
@@ -283,6 +290,14 @@ class Fib(Actor):
                 )
 
                 batch = build_column_batch(rs.unicast_routes)
+            unicast = (
+                list(rs.unicast_routes.values()) if batch is None else batch
+            )
+            mpls = list(rs.mpls_routes.values())
+            tracer.end_span(stages[0], routes=len(unicast) + len(mpls))
+            stages.append(tracer.start_span(
+                trace, "platform.program.write", parent_id=parent
+            ))
             if batch is not None:
                 # columnar spine: the desired table ships as packed
                 # arrays — no per-route objects between here and the
@@ -290,30 +305,32 @@ class Fib(Actor):
                 counters.increment("fib.column_syncs")
                 await self.service.sync_fib_columns(CLIENT_ID_OPENR, batch)
             else:
-                await self.service.sync_fib(
-                    CLIENT_ID_OPENR, list(rs.unicast_routes.values())
-                )
+                await self.service.sync_fib(CLIENT_ID_OPENR, unicast)
         except FibUpdateError as e:
             failed_p.update(e.failed_prefixes)
             failed_l.update(e.failed_labels)
         except Exception as e:
             log.warning("%s: syncFib failed: %s", self.name, e)
             counters.increment("fib.sync_fib_failure")
-            self._end_program(sp, t_prog, ok=False, trace=trace, prog0=prog0)
+            self._end_program(
+                sp, t_prog, ok=False, trace=trace, prog0=prog0,
+                stages=stages,
+            )
             self._park_trace(trace)
             self._schedule_retry()
             return
         try:
-            await self.service.sync_mpls_fib(
-                CLIENT_ID_OPENR, list(rs.mpls_routes.values())
-            )
+            await self.service.sync_mpls_fib(CLIENT_ID_OPENR, mpls)
         except FibUpdateError as e:
             failed_p.update(e.failed_prefixes)
             failed_l.update(e.failed_labels)
         except Exception as e:
             log.warning("%s: syncMplsFib failed: %s", self.name, e)
             counters.increment("fib.sync_fib_failure")
-            self._end_program(sp, t_prog, ok=False, trace=trace, prog0=prog0)
+            self._end_program(
+                sp, t_prog, ok=False, trace=trace, prog0=prog0,
+                stages=stages,
+            )
             self._park_trace(trace)
             # the unicast sync already ran: publish the unicast routes that
             # DID land as an INCREMENTAL delta (additive — it must not
@@ -341,7 +358,10 @@ class Fib(Actor):
         if failed_p or failed_l:
             # partial: only the failed subset stays dirty; publish ONLY what
             # actually landed (FIB-ACK must never claim unprogrammed routes)
-            self._end_program(sp, t_prog, ok=False, trace=trace, prog0=prog0)
+            self._end_program(
+                sp, t_prog, ok=False, trace=trace, prog0=prog0,
+                stages=stages,
+            )
             now = time.monotonic()
             for p in failed_p:
                 rs.dirty_prefixes[p] = now
@@ -363,7 +383,10 @@ class Fib(Actor):
             )
             self._schedule_retry()
             return
-        self._end_program(sp, t_prog, ok=True, trace=trace, prog0=prog0)
+        self._end_program(
+            sp, t_prog, ok=True, trace=trace, prog0=prog0,
+            stages=stages,
+        )
         rs.dirty_prefixes.clear()
         rs.dirty_labels.clear()
         self._retry_backoff.report_success()
@@ -381,7 +404,11 @@ class Fib(Actor):
         ok: bool,
         trace: Optional[TraceContext] = None,
         prog0: Optional[float] = None,
+        stages=(),
     ) -> None:
+        for stage in stages:
+            if stage is not None and stage.end is None:
+                tracer.end_span(stage)
         tracer.end_span(sp, ok=ok)
         counters.add_stat_value(
             "fib.program_ms", (time.monotonic() - t_prog) * 1000.0
@@ -500,6 +527,10 @@ class Fib(Actor):
         )
         t_prog = now
         prog0 = self._service_program_ms()
+        parent = sp.span_id if sp is not None else None
+        stages = [tracer.start_span(
+            ctx, "platform.program.build", parent_id=parent
+        )]
 
         add_prefixes = [
             p
@@ -521,18 +552,27 @@ class Fib(Actor):
             for l, ts in rs.dirty_labels.items()
             if ts <= now and l not in rs.mpls_routes
         ]
+        add_unicast = [rs.unicast_route_of(p) for p in add_prefixes]
+        add_mpls = [rs.mpls_routes[l] for l in add_labels]
         programmed = DecisionRouteUpdate(
             type=RouteUpdateType.INCREMENTAL,
             solve_epoch=self._pending_epoch,
         )
+        tracer.end_span(
+            stages[0],
+            routes=len(add_prefixes) + len(del_prefixes)
+            + len(add_labels) + len(del_labels),
+        )
+        stages.append(tracer.start_span(
+            ctx, "platform.program.write", parent_id=parent
+        ))
         ok = True
         try:
             # chaos seam: everything due stays dirty and retries
             maybe_fail("fib.program", span=sp)
             if add_prefixes:
                 await self.service.add_unicast_routes(
-                    CLIENT_ID_OPENR,
-                    [rs.unicast_route_of(p) for p in add_prefixes],
+                    CLIENT_ID_OPENR, add_unicast
                 )
             for p in add_prefixes:
                 rs.dirty_prefixes.pop(p, None)
@@ -576,9 +616,7 @@ class Fib(Actor):
 
         try:
             if add_labels:
-                await self.service.add_mpls_routes(
-                    CLIENT_ID_OPENR, [rs.mpls_routes[l] for l in add_labels]
-                )
+                await self.service.add_mpls_routes(CLIENT_ID_OPENR, add_mpls)
             for l in add_labels:
                 rs.dirty_labels.pop(l, None)
                 programmed.mpls_routes_to_update[l] = rs.mpls_routes[l]
@@ -610,7 +648,9 @@ class Fib(Actor):
             log.warning("%s: delete_mpls failed: %s", self.name, e)
             ok = False
 
-        self._end_program(sp, t_prog, ok=ok, trace=ctx, prog0=prog0)
+        self._end_program(
+            sp, t_prog, ok=ok, trace=ctx, prog0=prog0, stages=stages
+        )
         if not programmed.empty():
             self._publish_programmed(programmed, perf, trace=ctx)
         else:
@@ -630,6 +670,9 @@ class Fib(Actor):
         perf: Optional[PerfEvents],
         trace: Optional[TraceContext] = None,
     ) -> None:
+        # the programmed update's bookkeeping, the push of the ack and
+        # the conv-ack write; ends before the trace does
+        pub_sp = tracer.start_span(trace, "fib.publish", node=self.node_name)
         if perf is not None:
             add_perf_event(perf, self.node_name, "FIB_PROGRAMMED")
             programmed.perf_events = perf
@@ -705,6 +748,7 @@ class Fib(Actor):
         if top_comp:
             end_attrs["budget_top"] = top_comp
             end_attrs["budget_top_ms"] = round(top_ms, 3)
+        tracer.end_span(pub_sp)
         tracer.end_trace(
             trace,
             status="ok",

@@ -161,86 +161,88 @@ def incremental_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
     else:
         rwm_old = res_w
 
-    par = _parent_plane(
-        deltas, swm_old, res_rows, res_nbr, rwm_old, prev_dist,
-        s_cap, has_res, n_cap, d_cap,
-    )
-
-    # --- classify increased dirty edges + seed the affected cone ---
-    aff = jnp.zeros((d_cap, n_cap), jnp.int32)
-
-    ok_s = (s_dirty_idx >= 0) & (s_dirty_idx < s_cap * n_cap)
-    sic = jnp.clip(s_dirty_idx, 0, s_cap * n_cap - 1)
-    k_j = sic // n_cap
-    u_j = sic % n_cap
-    # compare ROOT-MASKED values: root-column edges are INF to both
-    # solves, so their churn is invisible and must not seed anything
-    new_m = swm_new.ravel()[sic]
-    old_m = jnp.where(u_j == root, INF_E, s_dirty_old)
-    inc_s = ok_s & (new_m > old_m)
-    # class-k edge u -> v with v = (u + deltas[k]) % n (roll semantics)
-    v_j = (u_j + deltas[k_j]) % n_cap
-    pv = par[:, jnp.clip(v_j, 0, n_cap - 1)]  # [D, Sd]
-    seed_s = (inc_s[None, :] & (pv == u_j[None, :])).astype(jnp.int32)
-    v_sc = jnp.where(ok_s, v_j, n_cap)
-    aff = aff.at[:, v_sc].max(seed_s, mode="drop")
-
-    if has_res:
-        kr = res_nbr.shape[1]
-        lim = res_rows.shape[0] * kr
-        ok_r = (r_dirty_idx >= 0) & (r_dirty_idx < lim)
-        ric = jnp.clip(r_dirty_idx, 0, lim - 1)
-        row_j = ric // kr
-        c_j = ric % kr
-        ru = res_nbr[row_j, c_j]  # source neighbor
-        rv = res_rows[row_j]  # destination node
-        new_mr = rwm_new[row_j, c_j]
-        old_mr = jnp.where(ru == root, INF_E, r_dirty_old)
-        inc_r = ok_r & (new_mr > old_mr) & (ru >= 0) & (rv >= 0)
-        pv_r = par[:, jnp.clip(rv, 0, n_cap - 1)]
-        seed_r = (inc_r[None, :] & (pv_r == ru[None, :])).astype(
-            jnp.int32
+    with jax.named_scope("seed.parent"):
+        par = _parent_plane(
+            deltas, swm_old, res_rows, res_nbr, rwm_old, prev_dist,
+            s_cap, has_res, n_cap, d_cap,
         )
-        rv_sc = jnp.where(ok_r & (rv >= 0), rv, n_cap)
-        aff = aff.at[:, rv_sc].max(seed_r, mode="drop")
 
-    # --- propagate aff to tree descendants (one step = one level) ---
-    nodes = jnp.arange(n_cap, dtype=jnp.int32)
+    with jax.named_scope("seed.cone"):
+        # --- classify increased dirty edges + seed the affected cone ---
+        aff = jnp.zeros((d_cap, n_cap), jnp.int32)
 
-    def aff_step(acc):
-        def cls(k, a):
-            dk = deltas[k]
-            childpar = jnp.roll(par, -dk, axis=1)  # par of v at pos u
-            is_child = childpar == nodes[None, :]
-            contrib = jnp.roll(jnp.where(is_child, a, 0), dk, axis=1)
-            return jnp.maximum(a, contrib)
+        ok_s = (s_dirty_idx >= 0) & (s_dirty_idx < s_cap * n_cap)
+        sic = jnp.clip(s_dirty_idx, 0, s_cap * n_cap - 1)
+        k_j = sic // n_cap
+        u_j = sic % n_cap
+        # compare ROOT-MASKED values: root-column edges are INF to both
+        # solves, so their churn is invisible and must not seed anything
+        new_m = swm_new.ravel()[sic]
+        old_m = jnp.where(u_j == root, INF_E, s_dirty_old)
+        inc_s = ok_s & (new_m > old_m)
+        # class-k edge u -> v with v = (u + deltas[k]) % n (roll semantics)
+        v_j = (u_j + deltas[k_j]) % n_cap
+        pv = par[:, jnp.clip(v_j, 0, n_cap - 1)]  # [D, Sd]
+        seed_s = (inc_s[None, :] & (pv == u_j[None, :])).astype(jnp.int32)
+        v_sc = jnp.where(ok_s, v_j, n_cap)
+        aff = aff.at[:, v_sc].max(seed_s, mode="drop")
 
-        acc = jax.lax.fori_loop(0, s_cap, cls, acc)
         if has_res:
-            is_child = (
-                par[:, rows_c][:, :, None] == res_nbr[None]
-            ) & (res_nbr >= 0)[None]  # [D, R, K]
-            acc_n = acc[:, nbr_c]  # [D, R, K]
-            contrib = jnp.where(is_child, acc_n, 0).max(axis=2)
-            acc = acc.at[:, rows_s].max(contrib, mode="drop")
-        return acc
+            kr = res_nbr.shape[1]
+            lim = res_rows.shape[0] * kr
+            ok_r = (r_dirty_idx >= 0) & (r_dirty_idx < lim)
+            ric = jnp.clip(r_dirty_idx, 0, lim - 1)
+            row_j = ric // kr
+            c_j = ric % kr
+            ru = res_nbr[row_j, c_j]  # source neighbor
+            rv = res_rows[row_j]  # destination node
+            new_mr = rwm_new[row_j, c_j]
+            old_mr = jnp.where(ru == root, INF_E, r_dirty_old)
+            inc_r = ok_r & (new_mr > old_mr) & (ru >= 0) & (rv >= 0)
+            pv_r = par[:, jnp.clip(rv, 0, n_cap - 1)]
+            seed_r = (inc_r[None, :] & (pv_r == ru[None, :])).astype(
+                jnp.int32
+            )
+            rv_sc = jnp.where(ok_r & (rv >= 0), rv, n_cap)
+            aff = aff.at[:, rv_sc].max(seed_r, mode="drop")
 
-    def aff_body(state):
-        acc, _, t = state
-        new = acc
-        for _ in range(_UNROLL):
-            new = aff_step(new)
-        return new, jnp.any(new != acc), t + 1
+        # --- propagate aff to tree descendants (one step = one level) ---
+        nodes = jnp.arange(n_cap, dtype=jnp.int32)
 
-    def aff_cond(state):
-        return state[1] & (state[2] < max_trips)
+        def aff_step(acc):
+            def cls(k, a):
+                dk = deltas[k]
+                childpar = jnp.roll(par, -dk, axis=1)  # par of v at pos u
+                is_child = childpar == nodes[None, :]
+                contrib = jnp.roll(jnp.where(is_child, a, 0), dk, axis=1)
+                return jnp.maximum(a, contrib)
 
-    aff, _, _ = jax.lax.while_loop(
-        aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
-    )
+            acc = jax.lax.fori_loop(0, s_cap, cls, acc)
+            if has_res:
+                is_child = (
+                    par[:, rows_c][:, :, None] == res_nbr[None]
+                ) & (res_nbr >= 0)[None]  # [D, R, K]
+                acc_n = acc[:, nbr_c]  # [D, R, K]
+                contrib = jnp.where(is_child, acc_n, 0).max(axis=2)
+                acc = acc.at[:, rows_s].max(contrib, mode="drop")
+            return acc
 
-    cone = aff.sum().astype(jnp.int32)
-    fell_back = cone > cone_limit
+        def aff_body(state):
+            acc, _, t = state
+            new = acc
+            for _ in range(_UNROLL):
+                new = aff_step(new)
+            return new, jnp.any(new != acc), t + 1
+
+        def aff_cond(state):
+            return state[1] & (state[2] < max_trips)
+
+        aff, _, _ = jax.lax.while_loop(
+            aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
+        )
+
+        cone = aff.sum().astype(jnp.int32)
+        fell_back = cone > cone_limit
 
     # --- seed: warm (re-anchored prev) or cold (full-solve dist0) ---
     valid = seeds_w < INF_E

@@ -116,6 +116,38 @@ def derive_delta_exp(deltas, shift_w) -> int:
     return min(e, 28)
 
 
+def relax_bytes(kernel: str, rounds: int, trips: int, s_cap: int,
+                d_cap: int, n_cap: int, r_cap: int, kr_cap: int) -> int:
+    """Bytes one solve's relaxation loop moves through device memory,
+    as a model from its shapes and the rounds it ran. A full relaxation
+    (``make_relax``): every shift class reads the distance plane and
+    its weight row and reads and writes the accumulator; the residual
+    gathers ``kr_cap`` neighbours (index, weight, a distance per lane)
+    for each of ``r_cap`` rows and scatter-mins one value per lane into
+    each row (row index, read, write); the closing minimum reads two
+    planes and writes one. A ladder pass of the bucketed kernel: each
+    laddered class reads and writes the plane and reads its rung's
+    weight row, then the rungs double (two rows read, one written). A
+    trip's no-change test reads two planes. The sync kernel runs
+    ``rounds`` full relaxations in ``trips`` trips; the bucketed one
+    ``trips`` epochs of one full relaxation each and ``rounds - trips``
+    ladder passes. Pad classes and pad rows count: the device runs
+    them. Over a measured loop time this gives an achieved rate to set
+    beside the device's peak; it is not a count of what the compiler's
+    fusions really move."""
+    plane = 4 * d_cap * n_cap
+    full = (
+        s_cap * (3 * plane + 4 * n_cap)
+        + r_cap * (kr_cap * (8 + 4 * d_cap) + 8 * d_cap + 4)
+        + 3 * plane
+    )
+    if kernel != "bucketed":
+        return rounds * full + trips * 2 * plane
+    s_lad = min(s_cap, LADDER_WIDTH)
+    ladder = s_lad * (2 * plane + 4 * n_cap + 12 * n_cap) + 2 * plane
+    return trips * (full + 2 * plane) + (rounds - trips) * ladder
+
+
 def make_relax(deltas, s_cap: int, w_of, residual=None, combine=None):
     """One exact synchronous relaxation step ``dist -> dist'`` over a
     shift-decomposed mirror (ops/edgeplan.py). ``dist`` is int32
@@ -140,13 +172,15 @@ def make_relax(deltas, s_cap: int, w_of, residual=None, combine=None):
                 jnp.roll(dist + w_of(k)[None, :], deltas[k], axis=1),
             )
 
-        acc = jax.lax.fori_loop(
-            0, s_cap, cls, jnp.full_like(dist, INF_E)
-        )
+        with jax.named_scope("relax.shift"):
+            acc = jax.lax.fori_loop(
+                0, s_cap, cls, jnp.full_like(dist, INF_E)
+            )
         if residual is not None:
-            rows_c, nbr_c, rw = residual
-            cand = (dist[:, nbr_c] + rw[None]).min(axis=2)
-            acc = acc.at[:, rows_c].min(cand)
+            with jax.named_scope("relax.residual"):
+                rows_c, nbr_c, rw = residual
+                cand = (dist[:, nbr_c] + rw[None]).min(axis=2)
+                acc = acc.at[:, rows_c].min(cand)
         if combine is not None:
             acc = combine(acc)
         return jnp.minimum(acc, dist)
@@ -176,9 +210,10 @@ def run_sync(relax, state0, bound: int):
     def cond(s):
         return s[1] & (s[2] < bound)
 
-    state, _, trips = jax.lax.while_loop(
-        cond, body, (state0, jnp.bool_(True), jnp.int32(0))
-    )
+    with jax.named_scope("relax"):
+        state, _, trips = jax.lax.while_loop(
+            cond, body, (state0, jnp.bool_(True), jnp.int32(0))
+        )
     return state, trips, trips * jnp.int32(UNROLL)
 
 
@@ -252,10 +287,11 @@ def run_bucketed(relax, dist0, deltas, score_w, w_of, n_cap: int,
         def lcond(st):
             return st[4] & (st[3] < j_cap)
 
-        di, _, _, j, _ = jax.lax.while_loop(
-            lcond, lbody,
-            (dist, w_base, d_base, jnp.int32(0), jnp.bool_(True)),
-        )
+        with jax.named_scope("relax.ladder"):
+            di, _, _, j, _ = jax.lax.while_loop(
+                lcond, lbody,
+                (dist, w_base, d_base, jnp.int32(0), jnp.bool_(True)),
+            )
         return di, j
 
     def ebody(st):
@@ -274,8 +310,9 @@ def run_bucketed(relax, dist0, deltas, score_w, w_of, n_cap: int,
     def econd(st):
         return st[1] & (st[2] < epoch_bound)
 
-    dist, _, epochs, rounds = jax.lax.while_loop(
-        econd, ebody,
-        (dist0, jnp.bool_(True), jnp.int32(0), jnp.int32(0)),
-    )
+    with jax.named_scope("relax"):
+        dist, _, epochs, rounds = jax.lax.while_loop(
+            econd, ebody,
+            (dist0, jnp.bool_(True), jnp.int32(0), jnp.int32(0)),
+        )
     return dist, epochs, rounds

@@ -511,129 +511,131 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             rows_c = jnp.clip(res_rows, 0, n_cap - 1)
             rows_s = jnp.where(res_rows >= 0, res_rows, n_cap)
 
-        # --- parent plane under the OLD weights (cf. ops/incremental
-        # _parent_plane): per-shard tight-edge finds over local
-        # columns, then one pmax('graph') combine ---
-        src = jnp.arange(n_cap, dtype=jnp.int32)
-        par = jnp.full((d_loc, n_cap), -1, jnp.int32)
+        with jax.named_scope("seed.parent"):
+            # --- parent plane under the OLD weights (cf. ops/incremental
+            # _parent_plane): per-shard tight-edge finds over local
+            # columns, then one pmax('graph') combine ---
+            src = jnp.arange(n_cap, dtype=jnp.int32)
+            par = jnp.full((d_loc, n_cap), -1, jnp.int32)
 
-        def pcls(k, par):
-            dk = deltas[k]
-            w_full = jax.lax.dynamic_update_slice(
-                jnp.full((n_cap,), INF_E, jnp.int32), swm_old[k],
-                (my_col0,),
-            )
-            cand = prev_dist + w_full[None, :]
-            tgt = jnp.roll(prev_dist, -dk, axis=1)
-            hit = (
-                (prev_dist < INF_E) & (w_full < INF_E)[None, :]
-                & (cand == tgt)
-            )
-            hit_v = jnp.roll(hit, dk, axis=1)
-            src_v = jnp.roll(src, dk)[None, :]
-            return jnp.where((par < 0) & hit_v, src_v, par)
+            def pcls(k, par):
+                dk = deltas[k]
+                w_full = jax.lax.dynamic_update_slice(
+                    jnp.full((n_cap,), INF_E, jnp.int32), swm_old[k],
+                    (my_col0,),
+                )
+                cand = prev_dist + w_full[None, :]
+                tgt = jnp.roll(prev_dist, -dk, axis=1)
+                hit = (
+                    (prev_dist < INF_E) & (w_full < INF_E)[None, :]
+                    & (cand == tgt)
+                )
+                hit_v = jnp.roll(hit, dk, axis=1)
+                src_v = jnp.roll(src, dk)[None, :]
+                return jnp.where((par < 0) & hit_v, src_v, par)
 
-        par = jax.lax.fori_loop(0, s_cap, pcls, par)
-        par = jax.lax.pmax(par, "graph")
-        if has_res:
-            row_valid = res_rows >= 0
-            prev_n = prev_dist[:, nbr_c]
-            cand = prev_n + rwm_old[None]
-            tgt = prev_dist[:, rows_c][:, :, None]
-            hit = (
-                (prev_n < INF_E)
-                & (rwm_old < INF_E)[None]
-                & (cand == tgt)
-                & (res_nbr >= 0)[None]
-            )
-            has = hit.any(axis=2)
-            first = jnp.argmax(hit, axis=2)
-            nbr_b = jnp.broadcast_to(res_nbr[None], hit.shape)
-            pick = jnp.take_along_axis(
-                nbr_b, first[:, :, None], axis=2
-            )[:, :, 0]
-            cur = par[:, rows_c]
-            new = jnp.where(
-                (cur < 0) & has & row_valid[None], pick, cur
-            )
-            par = par.at[:, rows_s].set(new, mode="drop")
+            par = jax.lax.fori_loop(0, s_cap, pcls, par)
+            par = jax.lax.pmax(par, "graph")
+            if has_res:
+                row_valid = res_rows >= 0
+                prev_n = prev_dist[:, nbr_c]
+                cand = prev_n + rwm_old[None]
+                tgt = prev_dist[:, rows_c][:, :, None]
+                hit = (
+                    (prev_n < INF_E)
+                    & (rwm_old < INF_E)[None]
+                    & (cand == tgt)
+                    & (res_nbr >= 0)[None]
+                )
+                has = hit.any(axis=2)
+                first = jnp.argmax(hit, axis=2)
+                nbr_b = jnp.broadcast_to(res_nbr[None], hit.shape)
+                pick = jnp.take_along_axis(
+                    nbr_b, first[:, :, None], axis=2
+                )[:, :, 0]
+                cur = par[:, rows_c]
+                new = jnp.where(
+                    (cur < 0) & has & row_valid[None], pick, cur
+                )
+                par = par.at[:, rows_s].set(new, mode="drop")
 
-        # --- classify increased dirty edges + seed the cone ---
-        aff = jnp.zeros((d_loc, n_cap), jnp.int32)
-        new_loc = jnp.where(
-            owned,
-            swm_new.ravel()[
-                jnp.clip(lflat, 0, s_cap * shard_cols - 1)
-            ],
-            INF_E,
-        )
-        new_m = jax.lax.pmin(new_loc, "graph")
-        old_m = jnp.where(u_j == root, INF_E, s_dirty_old)
-        inc_s = ok_s & (new_m > old_m)
-        v_j = (u_j + deltas[k_j]) % n_cap
-        pv = par[:, jnp.clip(v_j, 0, n_cap - 1)]
-        seed_s = (inc_s[None, :] & (pv == u_j[None, :])).astype(
-            jnp.int32
-        )
-        v_sc = jnp.where(ok_s, v_j, n_cap)
-        aff = aff.at[:, v_sc].max(seed_s, mode="drop")
-
-        if has_res:
-            kr = res_nbr.shape[1]
-            lim = res_rows.shape[0] * kr
-            ok_r = (r_dirty_idx >= 0) & (r_dirty_idx < lim)
-            ric = jnp.clip(r_dirty_idx, 0, lim - 1)
-            row_j = ric // kr
-            c_j = ric % kr
-            ru = res_nbr[row_j, c_j]
-            rv = res_rows[row_j]
-            new_mr = rwm_new[row_j, c_j]
-            old_mr = jnp.where(ru == root, INF_E, r_dirty_old)
-            inc_r = ok_r & (new_mr > old_mr) & (ru >= 0) & (rv >= 0)
-            pv_r = par[:, jnp.clip(rv, 0, n_cap - 1)]
-            seed_r = (inc_r[None, :] & (pv_r == ru[None, :])).astype(
+        with jax.named_scope("seed.cone"):
+            # --- classify increased dirty edges + seed the cone ---
+            aff = jnp.zeros((d_loc, n_cap), jnp.int32)
+            new_loc = jnp.where(
+                owned,
+                swm_new.ravel()[
+                    jnp.clip(lflat, 0, s_cap * shard_cols - 1)
+                ],
+                INF_E,
+            )
+            new_m = jax.lax.pmin(new_loc, "graph")
+            old_m = jnp.where(u_j == root, INF_E, s_dirty_old)
+            inc_s = ok_s & (new_m > old_m)
+            v_j = (u_j + deltas[k_j]) % n_cap
+            pv = par[:, jnp.clip(v_j, 0, n_cap - 1)]
+            seed_s = (inc_s[None, :] & (pv == u_j[None, :])).astype(
                 jnp.int32
             )
-            rv_sc = jnp.where(ok_r & (rv >= 0), rv, n_cap)
-            aff = aff.at[:, rv_sc].max(seed_r, mode="drop")
+            v_sc = jnp.where(ok_s, v_j, n_cap)
+            aff = aff.at[:, v_sc].max(seed_s, mode="drop")
 
-        # --- propagate aff to tree descendants (par is group-uniform
-        # and the residual is replicated, so no collectives here) ---
-        nodes = jnp.arange(n_cap, dtype=jnp.int32)
-
-        def aff_step(acc):
-            def cls(k, a):
-                dk = deltas[k]
-                childpar = jnp.roll(par, -dk, axis=1)
-                is_child = childpar == nodes[None, :]
-                contrib = jnp.roll(
-                    jnp.where(is_child, a, 0), dk, axis=1
-                )
-                return jnp.maximum(a, contrib)
-
-            acc = jax.lax.fori_loop(0, s_cap, cls, acc)
             if has_res:
-                is_child = (
-                    par[:, rows_c][:, :, None] == res_nbr[None]
-                ) & (res_nbr >= 0)[None]
-                acc_n = acc[:, nbr_c]
-                contrib = jnp.where(is_child, acc_n, 0).max(axis=2)
-                acc = acc.at[:, rows_s].max(contrib, mode="drop")
-            return acc
+                kr = res_nbr.shape[1]
+                lim = res_rows.shape[0] * kr
+                ok_r = (r_dirty_idx >= 0) & (r_dirty_idx < lim)
+                ric = jnp.clip(r_dirty_idx, 0, lim - 1)
+                row_j = ric // kr
+                c_j = ric % kr
+                ru = res_nbr[row_j, c_j]
+                rv = res_rows[row_j]
+                new_mr = rwm_new[row_j, c_j]
+                old_mr = jnp.where(ru == root, INF_E, r_dirty_old)
+                inc_r = ok_r & (new_mr > old_mr) & (ru >= 0) & (rv >= 0)
+                pv_r = par[:, jnp.clip(rv, 0, n_cap - 1)]
+                seed_r = (inc_r[None, :] & (pv_r == ru[None, :])).astype(
+                    jnp.int32
+                )
+                rv_sc = jnp.where(ok_r & (rv >= 0), rv, n_cap)
+                aff = aff.at[:, rv_sc].max(seed_r, mode="drop")
 
-        def aff_body(state):
-            acc, _, t = state
-            new = acc
-            for _ in range(_UNROLL):
-                new = aff_step(new)
-            return new, jnp.any(new != acc), t + 1
+            # --- propagate aff to tree descendants (par is group-uniform
+            # and the residual is replicated, so no collectives here) ---
+            nodes = jnp.arange(n_cap, dtype=jnp.int32)
 
-        def aff_cond(state):
-            return state[1] & (state[2] < max_trips)
+            def aff_step(acc):
+                def cls(k, a):
+                    dk = deltas[k]
+                    childpar = jnp.roll(par, -dk, axis=1)
+                    is_child = childpar == nodes[None, :]
+                    contrib = jnp.roll(
+                        jnp.where(is_child, a, 0), dk, axis=1
+                    )
+                    return jnp.maximum(a, contrib)
 
-        aff, _, _ = jax.lax.while_loop(
-            aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
-        )
+                acc = jax.lax.fori_loop(0, s_cap, cls, acc)
+                if has_res:
+                    is_child = (
+                        par[:, rows_c][:, :, None] == res_nbr[None]
+                    ) & (res_nbr >= 0)[None]
+                    acc_n = acc[:, nbr_c]
+                    contrib = jnp.where(is_child, acc_n, 0).max(axis=2)
+                    acc = acc.at[:, rows_s].max(contrib, mode="drop")
+                return acc
+
+            def aff_body(state):
+                acc, _, t = state
+                new = acc
+                for _ in range(_UNROLL):
+                    new = aff_step(new)
+                return new, jnp.any(new != acc), t + 1
+
+            def aff_cond(state):
+                return state[1] & (state[2] < max_trips)
+
+            aff, _, _ = jax.lax.while_loop(
+                aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
+            )
 
         # one global warm-vs-cold decision: sum lane-partial cones over
         # 'batch' ('graph' members already agree)

@@ -16,6 +16,10 @@ boundary; this module crosses it. Three concerns live here:
 - **profiler** — single-flight `jax.profiler.start_trace`/`stop_trace`
   with an optional auto-stop timer, served by the ctrl API so an
   operator captures a Perfetto-compatible XLA trace from a live daemon.
+  The capture is anchored to the host's monotonic clock, so the stop
+  also reduces it to device time per named scope of the solver's
+  programs and writes the convergence tracer's spans beside the
+  profiler's files, on the profiler's clock.
 
 Passive polling (the Monitor's metrics loop) must not *cause* a jax
 import in processes that never touched the device — `_jax()` only
@@ -25,6 +29,9 @@ requests (profiler start, bench) import it on purpose.
 
 from __future__ import annotations
 
+import glob
+import gzip
+import json
 import logging
 import os
 import sys
@@ -238,6 +245,25 @@ _prof_lock = threading.Lock()
 _prof_state: Optional[dict] = None
 
 
+# the annotation inside which profiler_start stamps the host's monotonic
+# clock: where it lies in the capture carries the tracer's spans
+# (time.monotonic()) onto the profiler's clock
+ANCHOR = "openr.anchor"
+# the jax.named_scope names inside the solver's device programs
+# (tpu_solver._make_pipeline, ops/relax.py, ops/incremental.py), the
+# same in every variant of the pipeline
+DEVICE_SCOPES = (
+    "unpack", "seed", "seed.parent", "seed.cone", "relax", "relax.ladder",
+    "relax.shift", "relax.residual", "select", "nexthop", "lfa", "pack",
+    "diff", "compact",
+)
+DEVICE_PROCESS = "/device:"
+OPS_THREAD = "XLA Ops"
+SPANS_FILE = "openr_spans.trace.json"
+# what a stop answers where the capture holds nothing to reduce
+_UNREDUCED = {"by_scope": None, "anchor": None, "spans_file": None}
+
+
 def profiler_start(
     out_dir: Optional[str] = None, seconds: Optional[float] = None
 ) -> dict:
@@ -255,7 +281,16 @@ def profiler_start(
             )
         out = out_dir or tempfile.mkdtemp(prefix="openr-tpu-trace-")
         os.makedirs(out, exist_ok=True)
-        jax.profiler.start_trace(out)
+        # the host side of the time line is the tracer's spans, so the
+        # interpreter's own (per-call, costly) tracer stays off
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=options)
+        # stamped inside the annotation: its start is taken on entry,
+        # so the two clocks are read microseconds apart whatever the
+        # first annotation of a process costs to set up
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            anchor_mono_ns = time.monotonic_ns()
         timer = None
         if seconds is not None and seconds > 0:
             timer = threading.Timer(seconds, _profiler_auto_stop)
@@ -264,6 +299,7 @@ def profiler_start(
         _prof_state = {
             "out_dir": out,
             "started_ts": time.time(),
+            "anchor_mono_ns": anchor_mono_ns,
             "seconds": seconds,
             "timer": timer,
         }
@@ -275,7 +311,8 @@ def profiler_start(
 def profiler_stop() -> dict:
     """Stop the active capture; returns the trace directory and how
     many files the profiler wrote there (>0 is the smoke signal that
-    the capture actually produced a trace)."""
+    the capture actually produced a trace), and what `reduce_capture`
+    read from it."""
     global _prof_state
     with _prof_lock:
         if _prof_state is None:
@@ -287,6 +324,12 @@ def profiler_stop() -> dict:
     import jax
 
     jax.profiler.stop_trace()
+    try:
+        reduced = reduce_capture(state["out_dir"], state["anchor_mono_ns"])
+    # lint: allow(broad-except) the capture itself is on disk either way
+    except Exception:
+        log.exception("profiler capture could not be reduced")
+        reduced = _UNREDUCED
     files = 0
     for _, _, names in os.walk(state["out_dir"]):
         files += len(names)
@@ -303,7 +346,163 @@ def profiler_stop() -> dict:
         "out_dir": state["out_dir"],
         "duration_s": duration,
         "files": files,
+        **reduced,
     }
+
+
+def scope_of(parts: list) -> str:
+    """The innermost of DEVICE_SCOPES on an operation's name-stack path
+    (`jit(pipeline)/seed/relax/while/body/relax.shift/.../add:`, split
+    at "/"); for an operation outside them the path's first component
+    (the jitted function), or "unscoped" where there is no path."""
+    for part in reversed(parts):
+        if part in DEVICE_SCOPES:
+            return part
+    return (parts[0] if parts else "") or "unscoped"
+
+
+def scope_ms(ops: Iterable) -> dict:
+    """ops: [path, start, duration] of one device's operations, in one
+    unit of time; -> that unit per scope (ms where the caller divides).
+    The profiler nests a loop's body inside the loop's own event, so
+    each instant goes to the innermost operation running in it: the
+    values add up to the device's busy time, and `relax` beside
+    `relax.shift` is the loop's own share (condition, closing minimum,
+    trip overhead), not the whole loop. The compiler leaves a loop
+    itself without a path: it takes what the paths of the operations
+    inside it have in common."""
+    own: dict[str, float] = {}
+    # [end, own parts or None, time not given to a child, common parts
+    # of the children]
+    open_ops: list[list] = []
+
+    def close(op) -> None:
+        parts = op[1] if op[1] is not None else (op[3] or [])
+        scope = scope_of(parts)
+        own[scope] = own.get(scope, 0.0) + max(0.0, op[2])
+        if open_ops and open_ops[-1][1] is None and parts:
+            parent = open_ops[-1]
+            if parent[3] is None:
+                parent[3] = list(parts)
+            else:
+                n = 0
+                for a, b in zip(parent[3], parts):
+                    if a != b:
+                        break
+                    n += 1
+                del parent[3][n:]
+
+    for path, start, dur in sorted(ops, key=lambda o: (o[1], -o[2])):
+        while open_ops and open_ops[-1][0] <= start:
+            close(open_ops.pop())
+        if open_ops:
+            open_ops[-1][2] -= min(dur, open_ops[-1][0] - start)
+        open_ops.append(
+            [start + dur, path.split("/") if path else None, dur, None]
+        )
+    while open_ops:
+        close(open_ops.pop())
+    return own
+
+
+def read_capture(trace_json_gz: str) -> dict:
+    """The trace-event JSON the profiler writes beside its xplane: each
+    device's operations as [scope path, start us, duration us], and
+    where the anchor annotation lies (us). The path is the operation's
+    `tf_op`: the xplane keeps it on the event's metadata, which
+    jax.profiler.ProfileData does not show, and this file needs gzip
+    and json alone. The profiler caps the file at about a million
+    events: a capture of a few seconds, not of minutes."""
+    with gzip.open(trace_json_gz, "rt") as f:
+        events = json.load(f).get("traceEvents", [])
+    processes: dict = {}
+    threads: dict = {}
+    for ev in events:
+        if ev.get("ph") != "M":
+            continue
+        if ev.get("name") == "process_name":
+            processes[ev["pid"]] = ev["args"]["name"]
+        elif ev.get("name") == "thread_name":
+            threads[(ev["pid"], ev.get("tid"))] = ev["args"]["name"]
+    ops: dict[str, list] = {}
+    anchor_us = None
+    for ev in events:
+        if ev.get("ph") != "X":
+            continue
+        process = processes.get(ev.get("pid"), "")
+        if process.startswith(DEVICE_PROCESS):
+            if threads.get((ev["pid"], ev.get("tid"))) == OPS_THREAD:
+                ops.setdefault(process, []).append([
+                    (ev.get("args") or {}).get("tf_op", ""),
+                    ev["ts"], ev.get("dur", 0.0),
+                ])
+        elif anchor_us is None and ev.get("name") == ANCHOR:
+            anchor_us = ev["ts"]
+    return {"ops": ops, "anchor_us": anchor_us}
+
+
+def reduce_capture(out_dir: str, anchor_mono_ns: int) -> dict:
+    """What profiler_stop adds to its answer: `by_scope` (device ms per
+    named scope, averaged over the devices that ran any), `anchor` (the
+    monotonic clock and the profiler's at the anchor annotation, ns) and
+    `spans_file`: the tracer's traces that closed during the capture as
+    Chrome trace events on the profiler's clock, beside the xplane."""
+    found = sorted(glob.glob(os.path.join(
+        out_dir, "plugins", "profile", "*", "*.trace.json.gz"
+    )))
+    if not found:
+        return _UNREDUCED
+    capture = read_capture(found[-1])
+    by_scope: dict[str, float] = {}
+    for ops in capture["ops"].values():
+        for scope, us in scope_ms(ops).items():
+            by_scope[scope] = by_scope.get(scope, 0.0) + us / 1e3
+    devices = max(1, len(capture["ops"]))
+    by_scope = {
+        scope: round(ms / devices, 6)
+        for scope, ms in sorted(by_scope.items(), key=lambda kv: -kv[1])
+    }
+    if capture["anchor_us"] is None:
+        return {**_UNREDUCED, "by_scope": by_scope}
+    anchor_trace_ns = capture["anchor_us"] * 1e3
+    spans_file = os.path.join(os.path.dirname(found[-1]), SPANS_FILE)
+    _write_spans(spans_file, anchor_mono_ns, anchor_trace_ns)
+    return {
+        "by_scope": by_scope,
+        "anchor": {"mono_ns": anchor_mono_ns, "trace_ns": anchor_trace_ns},
+        "spans_file": spans_file,
+    }
+
+
+def _write_spans(path: str, anchor_mono_ns: int, anchor_trace_ns: float):
+    from openr_tpu.runtime.tracing import MAX_CLOSED_TRACES, tracer
+
+    def us(mono_s: float) -> float:
+        return (mono_s * 1e9 - anchor_mono_ns + anchor_trace_ns) / 1e3
+
+    events = [{
+        "ph": "M", "pid": 1, "name": "process_name",
+        "args": {"name": "openr_tpu convergence spans"},
+    }]
+    for tr in tracer.get_traces(limit=MAX_CLOSED_TRACES):
+        root_end = tr["spans"][0]["end"]
+        if root_end is None or root_end * 1e9 < anchor_mono_ns:
+            continue  # closed before the capture began
+        for span in tr["spans"]:
+            if span["end"] is None:
+                continue
+            events.append({
+                "ph": "X", "pid": 1, "tid": tr["trace_id"],
+                "name": span["name"], "cat": tr["name"],
+                "ts": us(span["start"]),
+                "dur": (span["end"] - span["start"]) * 1e6,
+                "args": {
+                    k: v for k, v in span["attributes"].items()
+                    if isinstance(v, (str, int, float, bool)) or v is None
+                },
+            })
+    with open(path, "w") as f:
+        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
 
 
 def _profiler_auto_stop() -> None:

@@ -5,6 +5,7 @@ and KvStore-advertised fleet health. All on the virtual-CPU backend —
 the graceful-degradation path (no memory_stats) is itself under test."""
 
 import asyncio
+import os
 import time
 from types import SimpleNamespace
 
@@ -117,6 +118,13 @@ def test_profiler_round_trip_and_single_flight(tmp_path):
     jax.jit(lambda x: x * 2)(np.arange(16)).block_until_ready()
     stopped = device_stats.profiler_stop()
     assert stopped["ok"] and stopped["files"] > 0
+    # the capture is anchored and reduced: the annotation was found on
+    # the profiler's clock, the tracer's spans lie beside the xplane,
+    # and a backend with no device plane has no scope to report
+    assert stopped["anchor"]["trace_ns"] >= 0
+    assert stopped["anchor"]["mono_ns"] <= time.monotonic_ns()
+    assert os.path.exists(stopped["spans_file"])
+    assert stopped["by_scope"] == {}
     assert device_stats.profiler_status() == {"capturing": False}
     try:
         device_stats.profiler_stop()

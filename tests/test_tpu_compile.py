@@ -232,13 +232,19 @@ def one_chip(topo):
 def cache_off():
     """A compile for a described chip is written to the persistent cache
     but cannot be read back without the chip (the next run would warn
-    and compile again): keep the cache out of it."""
+    and compile again): keep the cache out of it. The program's own
+    AOT store too: where an earlier test file of this process left it
+    on, a second run in one checkout is answered from disk and lowers
+    nothing for the capture to record."""
     from jax.experimental.compilation_cache import compilation_cache
 
     old = jax.config.jax_enable_compilation_cache
+    old_aot = xla_cache.get_aot().dir
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    xla_cache.configure_aot("off")
     yield
+    xla_cache.configure_aot(old_aot or "off")
     jax.config.update("jax_enable_compilation_cache", old)
     compilation_cache.reset_cache()
 
