@@ -504,7 +504,9 @@ def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
 
     new_mapping = new.snapshot()
 
-    if len(old) == 0:
+    # emptiness, not len(): a lazy `old` answers it from its host keys
+    # and its segments' ok masks without naming a single key
+    if not old:
         segments = []
         for i, sn in enumerate(new.segments):
             rows = sn.key_rows()

@@ -258,14 +258,31 @@ def build_entries(
         counters.increment("decision.rib.entries_built", built)
 
 
+def row_index(matrix) -> dict:
+    """prefix -> matrix row, built ONCE per PrefixMatrix and kept on it
+    (`prefix_list` is never mutated; a new matrix means new cribs). The
+    key index of every generation of every crib over the matrix is this
+    dict plus the generation's own `ok` mask, so a copy-on-write epoch
+    builds no Python key structure. Every O(rows) key build (here and
+    `LazyUnicastRoutes._key_set`) counts in
+    decision.crib.key_index_builds, which must stand still across warm
+    epochs."""
+    idx = matrix._row_index
+    if idx is None:
+        idx = matrix._row_index = {
+            p: r for r, p in enumerate(matrix.prefix_list)
+        }
+        counters.increment("decision.crib.key_index_builds")
+    return idx
+
+
 class _Cols:
     """One generation of the packed columns. Treated as immutable once a
     RibView references it (ColumnarRib copies-on-write before mutating a
     referenced bundle)."""
 
     __slots__ = (
-        "met", "s3w", "nhw", "lfa_slot", "lfa_metric", "ok",
-        "_key_rows", "_row_of",
+        "met", "s3w", "nhw", "lfa_slot", "lfa_metric", "ok", "_key_rows",
     )
 
     def __init__(self):
@@ -273,7 +290,6 @@ class _Cols:
         self.lfa_slot = self.lfa_metric = None
         self.ok = None
         self._key_rows = None  # cached np.flatnonzero(ok)
-        self._row_of = None  # cached prefix -> row for ok rows
 
     def copy(self) -> "_Cols":
         c = _Cols()
@@ -339,9 +355,8 @@ class ColumnarRib:
         if any(v.cols is c for v in self._views):
             self.cols = c.copy()
         else:
-            # in-place mutation: the derived caches go stale
+            # in-place mutation: the derived cache goes stale
             c._key_rows = None
-            c._row_of = None
 
     def set_full_packed(self, rows: np.ndarray, met, s3w, nhw,
                         lfa_slot=None, lfa_metric=None) -> None:
@@ -434,7 +449,6 @@ class ColumnarRib:
             c.lfa_metric[rows] = lfa_metric
         c.ok[rows] = ok
         c._key_rows = None
-        c._row_of = None
         self.epoch += 1
         self.journal.append((self.epoch, np.asarray(rows), exact))
         if len(self.journal) > _JOURNAL_MAX:
@@ -573,16 +587,29 @@ class RibView:
     def key_rows(self) -> np.ndarray:
         return self.cols.key_rows()
 
+    def n_rows(self) -> int:
+        """Routes in this generation, without naming them."""
+        return int(np.count_nonzero(self.cols.ok))
+
     def prefixes(self) -> list[str]:
         plist = self.crib.matrix.prefix_list
         return [plist[r] for r in self.key_rows().tolist()]
 
     def _row_of(self, prefix: str):
-        c = self.cols
-        if c._row_of is None:
-            plist = self.crib.matrix.prefix_list
-            c._row_of = {plist[r]: r for r in self.key_rows().tolist()}
-        return c._row_of.get(prefix)
+        """The prefix's row iff it is a route in THIS generation: the
+        matrix's index says which row, the pinned `ok` mask whether it
+        counts (a stale view answers from its own bundle, not the
+        tip's)."""
+        matrix = self.crib.matrix
+        # the memo read inline: this runs per key of every dirty-set
+        # scan and entry-level compare (tens of thousands a plane drain)
+        idx = matrix._row_index
+        if idx is None:
+            idx = row_index(matrix)
+        r = idx.get(prefix)
+        if r is None or not self.cols.ok[r]:
+            return None
+        return r
 
     def has(self, prefix: str) -> bool:
         return self._row_of(prefix) is not None
@@ -625,7 +652,17 @@ class LazyUnicastRoutes(MutableMapping):
     static insertions neither force materialization nor break the
     journal diff — mutated keys simply join the diff's candidate set).
 
-    Iteration/len/contains are cheap (ok-mask key sets); values force.
+    What each read costs (rows = every row of every segment, host =
+    base + overrides + deleted, a handful):
+      O(1)     `k in lz`, `lz[k]`/`_lookup` of one key: the matrix's
+               prefix -> row index (`row_index`, built once per matrix)
+               and the pinned generation's `ok` bit;
+      O(host)  `bool(lz)` for any composition; `len(lz)` with at most
+               one segment: `count_nonzero(ok)` corrected by the
+               host-touched keys;
+      O(rows)  iteration, `prefixes()`, and `len(lz)` over several
+               segments (multi-area: segments may share prefixes), all
+               through the cached `_key_set`; values force a bulk build.
     Equality materializes both sides (dict == LazyUnicastRoutes works
     through the reflected __eq__)."""
 
@@ -666,6 +703,7 @@ class LazyUnicastRoutes(MutableMapping):
 
     def _key_set(self) -> dict:
         if self._keys is None:
+            counters.increment("decision.crib.key_index_builds")
             ks = dict.fromkeys(self.base)
             for seg in self.segments:
                 ks.update(dict.fromkeys(seg.prefixes()))
@@ -683,7 +721,38 @@ class LazyUnicastRoutes(MutableMapping):
     def __len__(self):
         if self._merged is not None:
             return len(self._merged)
-        return len(self._key_set())
+        segs = self.segments
+        if len(segs) > 1:
+            # segments may announce the same prefix (multi-area): no
+            # cheap exact count, name the keys
+            return len(self._key_set())
+        # |rows ∪ host| − |deleted ∩ that|, the host-touched keys
+        # resolved one by one against the segment's ok mask
+        host = set(self.base) | set(self.overrides)
+
+        def has(k):
+            return any(s.has(k) for s in segs)
+
+        n = sum(s.n_rows() for s in segs)
+        n += sum(1 for k in host if not has(k))
+        n -= sum(1 for k in self.deleted if k in host or has(k))
+        return n
+
+    def __bool__(self):
+        """Emptiness without naming a key, exact for every composition:
+        some host key survives `deleted`, or some segment holds more
+        routes than `deleted` takes from it."""
+        if self._merged is not None:
+            return bool(self._merged)
+        dl = self.deleted
+        if any(k not in dl for k in self.overrides) or any(
+            k not in dl for k in self.base
+        ):
+            return True
+        return any(
+            seg.n_rows() > sum(1 for k in dl if seg.has(k))
+            for seg in self.segments
+        )
 
     def snapshot(self) -> "LazyUnicastRoutes":
         """Detached copy sharing the column bundles: fresh RibViews pin

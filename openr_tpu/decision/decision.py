@@ -950,11 +950,20 @@ class Decision(Actor):
         self._emit_retraces(spf_sp)
 
         t_mat = time.perf_counter()
-        with tracer.span(ctx, "decision.rib_diff", node=self.node_name):
+        with tracer.span(
+            ctx, "decision.rib_diff", node=self.node_name
+        ) as diff_sp:
             if self.rib_policy is not None and self.rib_policy.is_active():
                 self.rib_policy.apply_policy(new_db.unicast_routes)
 
             update = self.route_db.calculate_update(new_db)
+            if diff_sp is not None:
+                # O(rows) key structures built so far (prefix -> row
+                # index, key sets): stands still across warm epochs
+                diff_sp.set(key_index_builds=int(
+                    counters.get_counter("decision.crib.key_index_builds")
+                    or 0
+                ))
         counters.add_stat_value(
             "decision.mat_ms", (time.perf_counter() - t_mat) * 1e3
         )

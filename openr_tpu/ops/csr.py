@@ -219,6 +219,12 @@ class PrefixMatrix:
     # under overload churn rewrites only the flags segment in place
     # instead of re-concatenating all 6*P*A words
     _mbuf: np.ndarray = None
+    # prefix -> row memo (decision/columnar_rib.row_index): prefix_list
+    # is never mutated, so the columnar RIB's key index lives as long as
+    # the matrix — every generation of every crib over it answers
+    # "which row" from this one dict and "is it a route" from its own
+    # ok mask
+    _row_index: dict = None
 
 
 def build_prefix_matrix(

@@ -131,6 +131,114 @@ def test_column_diff_snapshot_isolated_from_later_churn():
     assert dict(snap) == before
 
 
+# -- the key index stands still across warm epochs -------------------------
+
+
+def _counter(name):
+    from openr_tpu.runtime.counters import counters
+
+    return int(counters.get_counter(name) or 0)
+
+
+def test_warm_epochs_build_no_key_index_and_only_changed_entries():
+    """After the first warm epoch, further apply_rows + calculate_update
+    + RouteState.update + Fib's four dirty-set scans build no O(rows)
+    key structure (decision.crib.key_index_builds stands still) and only
+    the changed routes' entries (decision.rib.entries_built)."""
+    from openr_tpu.fib.fib import RouteState
+
+    adj_dbs, prefix_dbs = topologies.grid(7, node_labels=False)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    me = "node-3-3"
+    tpu = TpuSpfSolver(me)
+    db = tpu.build_route_db(me, states, ps)
+    rs = RouteState()
+    empty = type(db)()
+    rs.update(empty.calculate_update(db))
+    changed_epochs, builds = 0, None
+    victims = [f"node-3-{c}" for c in (0, 1, 2, 4, 5, 6)] + ["node-1-3"]
+    for step, victim in enumerate(victims):
+        # a node on the vantage's own row: routes behind it change
+        _flap(states, adj_dbs, victim, metric=3 + step)
+        crib = db.unicast_routes.segments[0].crib
+        floor = crib.journal_floor
+        new_db = tpu.build_route_db(me, states, ps)
+        assert crib.journal_floor == floor  # apply_rows, not a full reset
+        entries = _counter("decision.rib.entries_built")
+        upd = db.calculate_update(new_db)
+        assert upd.columns is not None and not upd.columns.full
+        rs.update(upd)
+        dirty = dict.fromkeys(upd.unicast_routes_to_update, 0.0)
+        dirty.update(dict.fromkeys(upd.unicast_routes_to_delete, 0.0))
+        now = 1.0
+        # Fib._program_dirty_routes' scans, as written there
+        add_prefixes = [
+            p for p, ts in dirty.items()
+            if ts <= now and p in rs.unicast_routes
+        ]
+        del_prefixes = [
+            p for p, ts in dirty.items()
+            if ts <= now and p not in rs.unicast_routes
+        ]
+        add_unicast = [rs.unicast_route_of(p) for p in add_prefixes]
+        assert all(e is not None for e in add_unicast)
+        assert sorted(del_prefixes) == sorted(upd.unicast_routes_to_delete)
+        assert len(rs.unicast_routes) == int(crib.cols.ok.sum())
+        assert (
+            _counter("decision.rib.entries_built") - entries
+            == len(add_prefixes)
+        ), step
+        if builds is None:
+            # the first warm epoch that changes a route may build the
+            # matrix's index; from here on nothing O(rows) may be built
+            if add_prefixes:
+                builds = _counter("decision.crib.key_index_builds")
+        else:
+            changed_epochs += bool(add_prefixes)
+            assert (
+                _counter("decision.crib.key_index_builds") == builds
+            ), step
+        db = new_db
+    assert changed_epochs >= 3, changed_epochs
+    assert builds >= 1  # the counter does fire: the index was built once
+
+
+def test_rehearsed_benchmark_stamps_key_index_builds_on_rib_diff(capsys):
+    """In a rehearsal of the benchmark's flap cell (benchmark/rehearsal,
+    CPU) every decision.rib_diff span carries `key_index_builds`, and
+    the value no longer moves once the warm-up is over."""
+    import os
+    import sys
+
+    from openr_tpu.runtime.tracing import tracer
+
+    bench = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark",
+    )
+    sys.path.insert(0, bench)
+    try:
+        import run
+
+        tracer.clear()
+        assert run.main([
+            "--workload", "grid12.flap", "--seed", "7", "--seconds", "2",
+            "--trace", "0", "--rehearse",
+            "--root", os.path.join(bench, "rehearsal"),
+        ]) == 0
+    finally:
+        sys.path.remove(bench)
+    assert '"correct": true' in capsys.readouterr().out.splitlines()[-1]
+    stamps = [
+        sp["attributes"].get("key_index_builds")
+        for tr in tracer.get_traces(limit=256)
+        for sp in tr["spans"] if sp["name"] == "decision.rib_diff"
+    ]
+    assert len(stamps) >= 10 and None not in stamps, stamps
+    # the window's epochs are the newest: the counter stood still
+    assert stamps[-10] == stamps[-1] >= 1, stamps
+
+
 # -- batch decode parity ---------------------------------------------------
 
 

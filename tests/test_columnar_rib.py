@@ -180,3 +180,119 @@ def test_lazy_mapping_semantics():
     # re-insert restores
     lazy[pfx] = plain[pfx]
     assert lazy == plain
+
+
+# -- the key index: one per matrix, answers from the pinned ok mask --------
+
+
+def _two_vantage_views(seed):
+    """Two live tables over one topology from two vantages: their prefix
+    sets overlap everywhere but at the vantages' own prefixes, and every
+    shared prefix has different next hops — the shape of a multi-area
+    table whose segments shadow each other."""
+    adj_dbs, prefix_dbs = topologies.random_mesh(18, seed=seed)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    dbs = [
+        TpuSpfSolver(me).build_route_db(me, states, ps)
+        for me in ("node-0", "node-1")
+    ]
+    return [db.unicast_routes.segments[0] for db in dbs]
+
+
+def _flip_ok(crib, rows, ok):
+    """One apply_rows that rewrites `rows` with their own columns and
+    the given ok bits (what a withdrawal / a re-announcement does)."""
+    c = crib.cols
+    rows = np.asarray(rows)
+    crib.apply_rows(
+        rows, c.met[rows].copy(), c.s3w[rows].copy(), c.nhw[rows].copy(),
+        None if c.lfa_slot is None else c.lfa_slot[rows].copy(),
+        None if c.lfa_metric is None else c.lfa_metric[rows].copy(),
+        ok=np.asarray(ok, bool),
+    )
+
+
+@pytest.mark.parametrize("stale", [False, True], ids=["tip", "stale"])
+@pytest.mark.parametrize("n_seg", [0, 1, 2])
+@pytest.mark.parametrize("seed", [2, 11, 29])
+def test_lazy_table_arithmetic_matches_materialized(seed, n_seg, stale):
+    """len, truth, membership and per-key lookup of a LazyUnicastRoutes
+    equal those of its materialized dict for seeded compositions of
+    base, zero to two (overlapping) segments, overrides and deleted —
+    over the tip and over a view a later apply_rows left stale."""
+    from openr_tpu.decision.columnar_rib import _lookup
+
+    rng = np.random.default_rng(seed * 7 + n_seg)
+    both = _two_vantage_views(seed)
+    views = both[:n_seg]
+    everything = dict(both[1].all_routes())
+    seg_keys = [k for v in views for k in v.prefixes()]
+    if stale and views:
+        # later churn on the crib: the table below must keep answering
+        # from the generation its views pinned
+        crib = views[0].crib
+        ok_rows = views[0].key_rows()
+        gone = rng.choice(ok_rows, size=4, replace=False)
+        _flip_ok(crib, gone, [False] * 4)
+        assert not views[0].current
+    pool = sorted(everything)
+    pick = lambda n: [pool[i] for i in rng.choice(len(pool), n, False)]
+    absent = [f"fd00:dead::{i:x}/128" for i in range(6)]
+    base = {k: everything[k] for k in pick(5)}
+    base.update({k: everything[pool[0]] for k in absent[:2]})
+    lz = LazyUnicastRoutes(base, views)
+    lz.overrides = {k: everything[pool[1]] for k in pick(3) + absent[2:4]}
+    lz.deleted = set(pick(6) + absent[3:5] + list(base)[:1])
+    # an all-but-empty table too: everything deleted
+    lz_empty = LazyUnicastRoutes(base, views)
+    lz_empty.deleted = set(base) | set(seg_keys)
+
+    for table in (lz, lz_empty):
+        probe = sorted(set(pool) | set(absent) | set(seg_keys))
+        n, truth = len(table), bool(table)
+        member = {k: k in table for k in probe}
+        found = {k: _lookup(table, k) for k in probe}
+        mat = dict(table.snapshot().materialized())
+        assert n == len(mat)
+        assert truth == bool(mat)
+        assert member == {k: k in mat for k in probe}
+        assert found == {k: mat.get(k) for k in probe}
+        assert set(table) == set(mat)  # the O(rows) dump agrees too
+    assert not lz_empty and len(lz_empty) == 0
+
+
+@pytest.mark.parametrize("seed", [4, 13])
+def test_stale_view_answers_from_its_own_generation(seed):
+    """After two apply_rows that flip ok both ways, each RibView answers
+    has/get from the bundle it pinned — the matrix's prefix -> row index
+    is shared, the ok mask is not."""
+    from openr_tpu.runtime.counters import counters
+
+    view0 = _two_vantage_views(seed)[0]
+    crib = view0.crib
+    plist = crib.matrix.prefix_list
+    before = dict(view0.all_routes())
+    r_a, r_b = (int(r) for r in view0.key_rows()[[1, 5]])
+    p_a, p_b = plist[r_a], plist[r_b]
+    assert view0.has(p_a)  # the matrix's index exists from here on
+    builds = counters.get_counter("decision.crib.key_index_builds") or 0
+    _flip_ok(crib, [r_a], [False])  # epoch 1: a leaves
+    view1 = crib.view()
+    _flip_ok(crib, [r_a, r_b], [True, False])  # epoch 2: a back, b leaves
+    tip = crib.view()
+    assert not view0.current and not view1.current and tip.current
+    assert (view0.has(p_a), view1.has(p_a), tip.has(p_a)) == (
+        True, False, True)
+    assert (view0.has(p_b), view1.has(p_b), tip.has(p_b)) == (
+        True, True, False)
+    for view in (view0, view1, tip):
+        for p, r in ((p_a, r_a), (p_b, r_b)):
+            assert view._row_of(p) == (r if view.has(p) else None)
+            got = view.get(p, bulk=False)
+            assert got == (before[p] if view.has(p) else None)
+    assert view1.n_rows() == tip.n_rows() == view0.n_rows() - 1
+    assert not view0.has("fd00:dead::1/128")
+    # three generations, one index: nothing O(rows) was built for them
+    assert (
+        counters.get_counter("decision.crib.key_index_builds") or 0
+    ) == builds
