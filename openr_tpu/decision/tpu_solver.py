@@ -1204,7 +1204,8 @@ class _AreaDev:
         self.mc_mesh = None
         # the last _sync_area's stages for the tpu.sync.* spans:
         # (plan start, plan end = upload start, upload end) on
-        # time.monotonic(), bytes uploaded, slots scattered
+        # time.monotonic(), bytes uploaded, slots scattered, the
+        # mirror's occupancy (EdgePlan.occupancy)
         self.sync_marks: tuple = ()
 
 
@@ -2522,9 +2523,12 @@ class TpuSpfSolver:
         # the host mirror diff, then the scatters / device_puts of what
         # it found; the rest of a sync (announcer matrix, vantage
         # state, incremental seeds) is tpu.sync's own time
+        mirror = plan.occupancy()
+        for key, value in mirror.items():
+            counters.set_counter(f"decision.tpu.{key}", value)
         ad.sync_marks = (
             t_plan0, t_plan1, t_up0, _time.monotonic(),
-            self._bytes_uploaded - bytes0, dirty_slots,
+            self._bytes_uploaded - bytes0, dirty_slots, mirror,
         )
 
         # announcer matrix: keyed on prefix churn + node-index stability
@@ -3257,6 +3261,20 @@ class TpuSpfSolver:
             # here (still on the materialization worker) keeps the
             # Decision loop's first touch O(1)
             stats["ok_rows"] = int(len(crib.cols.key_rows()))
+            mat_attrs = {}
+            if lfa and crib.cols.lfa_slot is not None:
+                # routes the table holds, and those of them that carry a
+                # loop-free alternate
+                lfa_routes = int(np.count_nonzero(
+                    crib.cols.lfa_slot[crib.cols.key_rows()] >= 0
+                ))
+                counters.set_counter(
+                    "decision.lfa.routes", stats["ok_rows"]
+                )
+                counters.set_counter(
+                    "decision.lfa.routes_with_backup", lfa_routes
+                )
+                mat_attrs["lfa_routes"] = stats["lfa_routes"] = lfa_routes
             t3 = _time.monotonic()
             # what the relaxation loop moved, by ops/relax.py's model:
             # over the loop's device time it is the achieved rate
@@ -3264,7 +3282,9 @@ class TpuSpfSolver:
                 spf_kernel, rounds, trips, plan.s_cap, d_cap, plan.n_cap,
                 *(plan.res_nbr.shape if plan.k_res > 0 else (0, 0)),
             )
-            plan0, plan1, up0, up1, up_bytes, dirty_slots = pv["sync_marks"]
+            (plan0, plan1, up0, up1, up_bytes, dirty_slots,
+             mirror) = pv["sync_marks"]
+            stats.update(mirror)
             return {
                 "view": crib.view(),
                 "stats": stats,
@@ -3276,7 +3296,7 @@ class TpuSpfSolver:
                 },
                 "spans": [
                     ("tpu.sync", None, t0, t1, {}),
-                    ("tpu.sync.plan", "tpu.sync", plan0, plan1, {}),
+                    ("tpu.sync.plan", "tpu.sync", plan0, plan1, mirror),
                     ("tpu.sync.upload", "tpu.sync", up0, up1, {
                         "bytes_uploaded": up_bytes,
                         "dirty_slots": dirty_slots,
@@ -3292,7 +3312,7 @@ class TpuSpfSolver:
                         "full_pull": full_pull,
                         "changed_rows": count,
                     }),
-                    ("tpu.mat", None, t2, t3, {}),
+                    ("tpu.mat", None, t2, t3, mat_attrs),
                 ],
             }
 

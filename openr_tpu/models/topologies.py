@@ -301,6 +301,197 @@ def wan(
     )
 
 
+# wan_rtt's geography: the plane the region centres are drawn on, and how
+# far from its region's centre a core, an aggregation and an access router
+# may lie
+WAN_PLANE_KM = (4500.0, 2500.0)
+WAN_TIER_RADIUS_KM = (10.0, 40.0, 80.0)
+
+
+def _bridge_side(n: int, pairs: set) -> "set | None":
+    """One side of a cut of at most one edge in the graph over range(n):
+    a component that is not the whole graph or, where it is connected,
+    the far side of a bridge. None where the graph is 2-edge-connected."""
+
+    def component(skip):
+        seen, todo = {0}, [0]
+        while todo:
+            a = todo.pop()
+            for x, y in pairs:
+                if (x, y) == skip or a not in (x, y):
+                    continue
+                b = y if a == x else x
+                if b not in seen:
+                    seen.add(b)
+                    todo.append(b)
+        return seen
+
+    for skip in [None, *sorted(pairs)]:
+        side = component(skip)
+        if len(side) < n:
+            return side
+    return None
+
+
+def wan_rtt(
+    regions: int = 50,
+    cores: int = 4,
+    aggs: int = 64,
+    access: int = 932,
+    seed: int = 7,
+    area: str = "0",
+    forwarding_algorithm: PrefixForwardingAlgorithm = PrefixForwardingAlgorithm.SP_ECMP,
+    node_labels: bool = False,
+    core_agg_ports: int = 40,
+    agg_access_ports: int = 48,
+    router_ports: int = 64,
+    positions: "dict | None" = None,
+) -> tuple[list[AdjacencyDatabase], list[PrefixDatabase]]:
+    """A WAN whose metrics are measured round-trip times (BASELINE config
+    4's size at the defaults: 50 x 1,000 routers), as `use_rtt_metric`
+    makes them: metric = LinkMonitor's get_rtt_metric(rtt_us), with
+    rtt_us = 10 us/km x 1.4 (fibre does not run straight) x the straight
+    distance + 100 us, the same both ways. Geography, not the index,
+    decides who links to whom, so degrees run from 2 (an access router)
+    to `router_ports` and nothing is index-affine.
+
+    Region centres are drawn on `WAN_PLANE_KM`; a region's routers lie
+    within `WAN_TIER_RADIUS_KM` of it (cores, aggregation, access). Links:
+    a region's cores in a full mesh; each aggregation router to its two
+    nearest cores with a port free (`core_agg_ports` a core) and to its
+    two ring neighbours by bearing; each access router to its two nearest
+    aggregation routers with a port free (`agg_access_ports` a router);
+    each region to its three nearest regions and to seeded Waxman chords
+    until the region graph has 2.5 pairs a region and no bridge; a region
+    pair is carried by two links between different core pairs, on the
+    cores with the most ports free. No router has more than `router_ports`
+    links and no pair of routers two. Names are region-major (r07-core2,
+    r07-agg31, r07-acc0415). `positions`, where
+    given, is filled with name -> (x km, y km)."""
+    import math
+
+    from openr_tpu.link_monitor.link_monitor import get_rtt_metric
+
+    rng = random.Random(seed)
+    wr = max(2, len(str(regions - 1)))
+    widths = [
+        max(least, len(str(n - 1)))
+        for least, n in zip((1, 2, 4), (cores, aggs, access))
+    ]
+    tiers = ("core", "agg", "acc")
+
+    def name(g: int, tier: int, i: int) -> str:
+        return f"r{g:0{wr}d}-{tiers[tier]}{i:0{widths[tier]}d}"
+
+    def near(centre, radius):
+        # uniform over the disc
+        r, phi = radius * math.sqrt(rng.random()), rng.uniform(0, 2 * math.pi)
+        return centre[0] + r * math.cos(phi), centre[1] + r * math.sin(phi)
+
+    centres = [
+        (rng.uniform(0, WAN_PLANE_KM[0]), rng.uniform(0, WAN_PLANE_KM[1]))
+        for _ in range(regions)
+    ]
+    pos: dict[str, tuple] = {}
+    for g, centre in enumerate(centres):
+        for tier, count in enumerate((cores, aggs, access)):
+            for i in range(count):
+                pos[name(g, tier, i)] = near(centre, WAN_TIER_RADIUS_KM[tier])
+    if positions is not None:
+        positions.update(pos)
+
+    links: dict[tuple, int] = {}  # (a, b) sorted -> metric
+    ports = dict.fromkeys(pos, 0)
+
+    def km(a: str, b: str) -> float:
+        return math.dist(pos[a], pos[b])
+
+    def link(a: str, b: str) -> None:
+        key = (a, b) if a < b else (b, a)
+        if a == b or key in links:
+            raise ValueError(f"wan_rtt: a second link {a} - {b}")
+        links[key] = get_rtt_metric(int(10 * 1.4 * km(a, b) + 100))
+        for x in key:
+            ports[x] += 1
+            if ports[x] > router_ports:
+                raise ValueError(f"wan_rtt: {x} has no port left")
+
+    def nearest_free(a: str, candidates: list, used: dict, cap: int, k: int):
+        free = [c for c in candidates if used[c] < cap]
+        if len(free) < k:
+            raise ValueError(f"wan_rtt: {a} finds fewer than {k} free ports")
+        return sorted(free, key=lambda c: (km(a, c), c))[:k]
+
+    for g, centre in enumerate(centres):
+        core = [name(g, 0, i) for i in range(cores)]
+        agg = [name(g, 1, i) for i in range(aggs)]
+        for i, a in enumerate(core):
+            for b in core[i + 1:]:
+                link(a, b)
+        down = dict.fromkeys(core, 0)
+        for a in agg:
+            for c in nearest_free(a, core, down, core_agg_ports,
+                                  min(2, cores)):
+                down[c] += 1
+                link(a, c)
+        ring = sorted(agg, key=lambda a: (math.atan2(
+            pos[a][1] - centre[1], pos[a][0] - centre[0]), a))
+        if len(ring) > 2:
+            for a, b in zip(ring, ring[1:] + ring[:1]):
+                link(a, b)
+        elif len(ring) == 2:
+            link(*ring)
+        down = dict.fromkeys(agg, 0)
+        for i in range(access):
+            a = name(g, 2, i)
+            for c in nearest_free(a, agg, down, agg_access_ports,
+                                  min(2, aggs)):
+                down[c] += 1
+                link(a, c)
+
+    # the region graph: three nearest, then Waxman chords, then no bridge
+    def rkm(a: int, b: int) -> float:
+        return math.dist(centres[a], centres[b])
+
+    every = regions * (regions - 1) // 2
+    target = min(regions * 5 // 2, every)
+    pairs: set[tuple] = set()
+    for a in range(regions):
+        others = sorted((b for b in range(regions) if b != a),
+                        key=lambda b: (rkm(a, b), b))
+        pairs.update((min(a, b), max(a, b)) for b in others[:3])
+    scale = 0.25 * math.hypot(*WAN_PLANE_KM)
+    while len(pairs) < target:
+        a, b = rng.randrange(regions), rng.randrange(regions)
+        if a != b and rng.random() < math.exp(-rkm(a, b) / scale):
+            pairs.add((min(a, b), max(a, b)))
+    while regions > 2 and len(pairs) < every:
+        side = _bridge_side(regions, pairs)
+        if side is None:
+            break
+        pairs.add(min(
+            ((min(a, b), max(a, b)) for a in side
+             for b in range(regions) if b not in side),
+            key=lambda p: (p in pairs, rkm(*p), p),
+        ))
+    for a, b in sorted(pairs):
+        taken: set = set()
+        for _ in range(min(2, cores)):
+            ends = [
+                min((c for c in (name(g, 0, i) for i in range(cores))
+                     if c not in taken), key=lambda c: (ports[c], c))
+                for g in (a, b)
+            ]
+            taken.update(ends)
+            link(*ends)
+
+    nodes: dict[str, list[Adjacency]] = {n: [] for n in sorted(pos)}
+    for (a, b), metric in sorted(links.items()):
+        nodes[a].append(_adj(a, b, metric=metric))
+        nodes[b].append(_adj(b, a, metric=metric))
+    return _mk_dbs(nodes, area, forwarding_algorithm, node_labels)
+
+
 def random_mesh(
     n: int,
     avg_degree: int = 4,

@@ -115,6 +115,10 @@ class EdgePlan:
     _res_row_of: dict = field(default_factory=dict)  # v_idx -> row
     _res_fill: Optional[np.ndarray] = None  # int32 [r_cap] cols used per row
     _res_nrows: int = 0
+    # directed edges that own a slot of each kind (a down link keeps its
+    # slot): what the mirror's occupancy gauges report
+    shift_edges: int = 0
+    res_edges: int = 0
     # delta-update state
     synced_generation: int = -1
     needs_rebuild: bool = False
@@ -139,6 +143,21 @@ class EdgePlan:
     # (kernel, delta_exp) jit-cache class. 0 = no usable shift classes:
     # the solver's eligibility ladder falls back to the sync kernel.
     delta_exp: int = 0
+
+    def occupancy(self) -> dict:
+        """Where the graph's directed edges live in the mirror, and the
+        residual's padded shape: the `decision.tpu.*` gauges, the
+        `tpu.sync.plan` span's attributes and `last_device_stats`. The
+        relaxation gathers r_cap x k_cap residual slots a round whatever
+        `residual_edges` of them hold an edge."""
+        r_cap, k_cap = self.res_nbr.shape if self.k_res > 0 else (0, 0)
+        return {
+            "residual_edges": self.res_edges,
+            "shift_edges": self.shift_edges,
+            "residual_r_cap": r_cap,
+            "residual_k_cap": k_cap,
+            "delta_exp": self.delta_exp,
+        }
 
     # -- host-side out-edge view (per-vantage, cheap) ----------------------
 
@@ -385,6 +404,8 @@ def build_plan(
         _res_row_of=row_of,
         _res_fill=fill,
         _res_nrows=n_rows,
+        shift_edges=e2 - len(res_idx),
+        res_edges=len(res_idx),
         synced_generation=link_state.generation,
         index_version=index_version,
         delta_exp=delta_exp,
@@ -446,6 +467,7 @@ def _add_link(plan: EdgePlan, link: Link) -> None:
                 if d == 0:
                     break
                 plan._shift_occ[k, u] = True
+                plan.shift_edges += 1
                 plan.edge_loc.setdefault(link, [None, None])[idx] = (
                     "s", k, u,
                 )
@@ -473,6 +495,7 @@ def _add_link(plan: EdgePlan, link: Link) -> None:
         if w == 0:
             plan.has_zero_w = True
         plan.k_res = max(plan.k_res, col + 1)
+        plan.res_edges += 1
         plan.edge_loc.setdefault(link, [None, None])[idx] = ("r", row, col)
         # a fresh slot's pre-write value is the INF pad
         plan.dirty_res.append((row, col, w, int(INF32E)))
