@@ -14,8 +14,16 @@ the mirror decomposes the directed edge set into
      hierarchical fabrics mostly (pods/planes are index-affine under
      natural-sorted node numbering); arbitrary graphs partially.
   2. **residual ELL**: leftover edges in padded in-neighbor lists,
-     relaxed with the (slow but correct) gather path. The decomposer
-     keeps this small by construction.
+     relaxed with the (slow but correct) gather path. The gather costs
+     by the padded slot, so the ELL is **row-split**: its width comes
+     from the graph's residual in-degrees (`_residual_width`), and a
+     destination with more in-edges than the width spans several rows,
+     each naming it in `res_rows`. Every consumer combines a node's
+     rows as it combines its edges (a scatter-min of candidates, a
+     scatter-max of marks), so the split changes no result; it keeps
+     a fabric's 288 spine switches of in-degree 173 from padding the
+     8,304 rack switches' rows of 8 to 256 columns (fabric10k:
+     32,768 x 8 slots instead of 16,384 x 256 for 232,512 edges).
 
 Effective weights fold every vantage-INDEPENDENT usability rule on the
 host: link down, source-node transit drain (overload). The root-as-
@@ -77,6 +85,41 @@ def _next_pow2(n: int, floor: int = 1) -> int:
     return c
 
 
+# What one residual row costs a pass beyond its slots (its scatter-min
+# into the plane), in units of one gathered slot. Measured on a TPU v5
+# lite by `tools/residual_width.py` (PR 30's chip calls 1 and 2; the
+# table is in PERF.md section 6): a gathered slot costs 2.5-2.7 ns at
+# every width from 256 to 2 and in either layout ([R, K] or [K, R]); a
+# row costs 9.6 ns at fabric10k (8 lanes into 16,384 nodes: 3.9 slots by
+# `relax.residual`'s share of `by_scope` at widths 16, 8, 4 and 2, 3.8 by
+# the bare loop over all eight widths) and 15.5 ns at wan50k (4 lanes
+# into 65,536 nodes: 6.9 and 6.3 slots). One constant between the two:
+# the width it picks, 8 at both, is the fastest measured at both (0.97
+# and 2.19 ms a pass; fabric10k picks 8 whatever a row costs, wan50k for
+# any cost above 4, and 2 below).
+_ROW_COST = 5.0
+
+
+def _residual_width(degrees: np.ndarray) -> int:
+    """The residual ELL's width for a graph whose destinations have
+    these residual in-degrees: the power of two from 2 to the widest
+    degree's that makes a pass cheapest by `r_cap(K) * (K + _ROW_COST)`,
+    r_cap(K) the pow2 pad of sum(ceil(deg / K)) rows. Ties go to the
+    wider K (fewer rows), so a graph whose widest destination is
+    already narrow keeps one row a destination."""
+    if not len(degrees):
+        return 2
+    best_k = k = _next_pow2(int(degrees.max()), 2)
+    best = None
+    while k >= 2:
+        rows = int((-(-degrees // k)).sum())
+        cost = _next_pow2(rows, 8) * (k + _ROW_COST)
+        if best is None or cost < best:
+            best_k, best = k, cost
+        k //= 2
+    return best_k
+
+
 @dataclass
 class EdgePlan:
     """Host arrays + bookkeeping; ships to device as-is."""
@@ -86,11 +129,14 @@ class EdgePlan:
     s_cap: int  # shift-class slots (padded; unused classes have delta 0, all-INF weights)
     deltas: np.ndarray  # int32 [s_cap]
     shift_w: np.ndarray  # int32 [s_cap, n_cap]; w of edge v -> v+deltas[k]
-    # residual ELL is ROW-COMPACT: only destination nodes with irregular
-    # in-edges occupy a row (hierarchical fabrics have few such nodes), so
-    # the slow gather scales with real residual edges, not n_cap
-    k_res: int  # real max residual in-degree (0 = no residual path)
-    res_rows: np.ndarray  # int32 [r_cap]; destination node of each row, -1 pad
+    # residual ELL is ROW-COMPACT and ROW-SPLIT: only destination nodes
+    # with irregular in-edges occupy rows (hierarchical fabrics have few
+    # such nodes), and one with more in-edges than k_cap occupies
+    # ceil(deg / k_cap) of them — consecutive after a build, wherever a
+    # row was free after an _add_link — so the slow gather scales with
+    # real residual edges, not with n_cap x the widest in-degree
+    k_res: int  # widest fill of any row, <= k_cap (0 = no residual path)
+    res_rows: np.ndarray  # int32 [r_cap]; destination node of each row, -1 pad; a node may repeat
     res_nbr: np.ndarray  # int32 [r_cap, k_cap]; source node, -1 pad
     res_w: np.ndarray  # int32 [r_cap, k_cap]
     node_overloaded: np.ndarray  # bool [n_cap]
@@ -112,7 +158,7 @@ class EdgePlan:
     _loc_b: Optional[np.ndarray] = None  # int32: u | col
     # occupancy (a slot with INF weight may still be owned by a down link)
     _shift_occ: Optional[np.ndarray] = None  # bool [s_cap, n_cap]
-    _res_row_of: dict = field(default_factory=dict)  # v_idx -> row
+    _res_row_of: dict = field(default_factory=dict)  # v_idx -> its LAST row (where the next edge goes)
     _res_fill: Optional[np.ndarray] = None  # int32 [r_cap] cols used per row
     _res_nrows: int = 0
     # directed edges that own a slot of each kind (a down link keeps its
@@ -149,13 +195,17 @@ class EdgePlan:
         residual's padded shape: the `decision.tpu.*` gauges, the
         `tpu.sync.plan` span's attributes and `last_device_stats`. The
         relaxation gathers r_cap x k_cap residual slots a round whatever
-        `residual_edges` of them hold an edge."""
+        `residual_edges` of them hold an edge; `residual_rows` of the
+        r_cap rows are in use, `residual_split_rows` of those beyond
+        their destination's first."""
         r_cap, k_cap = self.res_nbr.shape if self.k_res > 0 else (0, 0)
         return {
             "residual_edges": self.res_edges,
             "shift_edges": self.shift_edges,
             "residual_r_cap": r_cap,
             "residual_k_cap": k_cap,
+            "residual_rows": self._res_nrows,
+            "residual_split_rows": self._res_nrows - len(self._res_row_of),
             "delta_exp": self.delta_exp,
         }
 
@@ -237,7 +287,9 @@ def build_plan(
     prev: Optional[EdgePlan] = None,
 ) -> EdgePlan:
     """Full build: natural-order the nodes, histogram index deltas, keep
-    the top classes, spill the rest to the residual ELL.
+    the top classes, spill the rest to the residual ELL at the width
+    `_residual_width` picks from their in-degrees (sticky through `prev`,
+    as the row cap is, so churn never changes the compile class).
 
     Fully vectorized over directed-edge arrays — the only Python-level
     per-link work is one sort key, one index lookup per endpoint and one
@@ -327,40 +379,48 @@ def build_plan(
     else:
         res_idx = np.arange(e2)
 
-    # residual ELL: group leftover edges by destination (row-compact)
+    # residual ELL: group leftover edges by destination, then split each
+    # destination over ceil(deg / k_cap) consecutive rows (row-split)
     rv = dst[res_idx]
     order2 = np.argsort(rv, kind="stable")  # edge order within a group
     res_sorted = res_idx[order2]
     sv = rv[order2]
     uniq_v, first_v = np.unique(sv, return_index=True)
-    n_rows = len(uniq_v)
     group_counts = np.diff(np.r_[first_v, len(sv)]).astype(np.int32)
-    k_res = int(group_counts.max()) if n_rows else 0
-    k_cap = _next_pow2(max(k_res, 1), 2)
+    # width and row cap are sticky: churn never changes the compile class
+    sticky = prev is not None and prev.k_res > 0
+    k_cap = (
+        prev.res_nbr.shape[1] if sticky else _residual_width(group_counts)
+    )
+    rows_of = -(-group_counts // k_cap)  # rows each destination spans
+    n_rows = int(rows_of.sum())
+    k_res = min(int(group_counts.max()), k_cap) if n_rows else 0
     r_cap = _next_pow2(max(n_rows, 1), 8)
-    if prev is not None and prev.k_res:
-        k_cap = max(k_cap, prev.res_nbr.shape[1])
+    if sticky:
         r_cap = max(r_cap, prev.res_rows.shape[0])
     res_rows = np.full(r_cap, -1, np.int32)
     res_nbr = np.full((r_cap, k_cap), -1, np.int32)
     res_w = np.full((r_cap, k_cap), INF32E, np.int32)
     fill = np.zeros(r_cap, np.int32)
+    row_of = {}
     if n_rows:
-        res_rows[:n_rows] = uniq_v
-        rows_per_edge = np.repeat(
-            np.arange(n_rows, dtype=np.int32), group_counts
-        )
-        cols_per_edge = (
+        res_rows[:n_rows] = np.repeat(uniq_v, rows_of)
+        last_row = np.cumsum(rows_of, dtype=np.int32) - 1
+        first_row = last_row - rows_of + 1
+        nth = (  # an edge's rank among its destination's residual edges
             np.arange(len(sv), dtype=np.int32)
             - np.repeat(first_v.astype(np.int32), group_counts)
         )
+        rows_per_edge = np.repeat(first_row, group_counts) + nth // k_cap
+        cols_per_edge = nth % k_cap
         res_nbr[rows_per_edge, cols_per_edge] = src[res_sorted]
         res_w[rows_per_edge, cols_per_edge] = w[res_sorted]
-        fill[:n_rows] = group_counts
+        fill[:n_rows] = k_cap
+        fill[last_row] = group_counts - (rows_of - 1) * k_cap
         loc_kind[res_sorted] = 1
         loc_a[res_sorted] = rows_per_edge
         loc_b[res_sorted] = cols_per_edge
-    row_of = {int(v): r for r, v in enumerate(uniq_v)}
+        row_of = dict(zip(uniq_v.tolist(), last_row.tolist()))
 
     index_version = 0
     if prev is not None:
@@ -477,7 +537,9 @@ def _add_link(plan: EdgePlan, link: Link) -> None:
         if placed:
             continue
         row = plan._res_row_of.get(v)
-        if row is None:
+        if row is None or plan._res_fill[row] >= plan.res_nbr.shape[1]:
+            # v's first residual edge, or its last row is full: open
+            # the next free row for it
             if plan._res_nrows >= plan.res_rows.shape[0]:
                 plan.needs_rebuild = True
                 return
@@ -486,9 +548,6 @@ def _add_link(plan: EdgePlan, link: Link) -> None:
             plan._res_row_of[v] = row
             plan.res_rows[row] = v
         col = int(plan._res_fill[row])
-        if col >= plan.res_nbr.shape[1]:
-            plan.needs_rebuild = True
-            return
         plan._res_fill[row] = col + 1
         plan.res_nbr[row, col] = u
         plan.res_w[row, col] = w
@@ -505,7 +564,8 @@ def _add_link(plan: EdgePlan, link: Link) -> None:
 
 def _remove_link(plan: EdgePlan, link: Link) -> None:
     """Tombstone: weight INF, slot stays owned (a re-added link reuses
-    it); residual slots are NOT compacted."""
+    it); residual slots are NOT compacted, nor a split destination's
+    rows merged."""
     for src_name in (link.n1, link.n2):
         _set_edge_w(plan, link, src_name, int(INF32E))
 

@@ -121,7 +121,11 @@ def _parent_plane(deltas, swm_old, res_rows, res_nbr, rwm_old,
         )[:, :, 0]  # [D, R]
         cur = par[:, rows_c]
         new = jnp.where((cur < 0) & has & row_valid[None], pick, cur)
-        par = par.at[:, rows_s].set(new, mode="drop")
+        # max, not set: a destination may span several rows, and one
+        # that found no tight parent (-1) must not erase another's.
+        # Either row's find is a tight-edge parent; a shift-class
+        # parent (cur >= 0) is the same in all of them and is kept
+        par = par.at[:, rows_s].max(new, mode="drop")
     return par
 
 

@@ -208,6 +208,9 @@ def _make_te(n_links, n_srcs, n_dem, es_cap, er_cap, n_cap, s_cap,
                     acc, _ = jax.lax.scan(cls, d, (deltas, swf))
                     if has_res:
                         nd = d[nbr_c]  # [rows, K]
+                        # softmin within a row, hard min across rows
+                        # (and against the shift classes): a node that
+                        # spans several rows takes the best of them
                         cand = -tau * jax.nn.logsumexp(
                             -(nd + rwf) / tau, axis=1
                         )

@@ -557,7 +557,9 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
                 new = jnp.where(
                     (cur < 0) & has & row_valid[None], pick, cur
                 )
-                par = par.at[:, rows_s].set(new, mode="drop")
+                # max: a destination's rows each report (see
+                # ops/incremental._parent_plane)
+                par = par.at[:, rows_s].max(new, mode="drop")
 
         with jax.named_scope("seed.cone"):
             # --- classify increased dirty edges + seed the cone ---

@@ -18,6 +18,7 @@ the decision.solver.incr.* counter split.
 """
 
 import numpy as np
+import pytest
 
 from openr_tpu.decision.spf_solver import SpfSolver
 from openr_tpu.decision.tpu_solver import TpuSpfSolver
@@ -323,3 +324,83 @@ def test_incremental_solve_exact_on_link_down_up():
     churn.link_up(u, v, su, sv)
     st = solve("up")
     assert st.get("incremental") is True, st
+
+
+# -- a destination spread over several residual rows (ops/edgeplan.py) ------
+
+
+def _hub_graph():
+    """node-00 (the vantage) reaches the hub node-09 through node-01 at
+    1 + 1 and through node-02..05 at 10 + 10; node-10 and node-11 hang
+    behind the hub. Every edge is residual (no index offset has eight
+    edges), and at width 2 the hub's in-edges fill three rows: [01, 02],
+    [03, 04], [05, 10]. In node-01's lane the one tight parent of the hub
+    sits in its FIRST row and the later rows hold none."""
+    from openr_tpu.models.topologies import _adj, _mk_dbs
+    from openr_tpu.types import PrefixForwardingAlgorithm
+
+    links = [("node-00", "node-01", 1), ("node-01", "node-09", 1),
+             ("node-09", "node-10", 1), ("node-10", "node-11", 1)]
+    for far in ("node-02", "node-03", "node-04", "node-05"):
+        links += [("node-00", far, 10), (far, "node-09", 10)]
+    nodes: dict = {}
+    for a, b, metric in links:
+        nodes.setdefault(a, []).append(_adj(a, b, metric))
+        nodes.setdefault(b, []).append(_adj(b, a, metric))
+    nodes = dict(sorted(nodes.items()))
+    adj_dbs, prefix_dbs = _mk_dbs(
+        nodes, "0", PrefixForwardingAlgorithm.SP_ECMP, False
+    )
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    return adj_dbs, states, ps
+
+
+@pytest.mark.parametrize("tier", ["single", "multichip"])
+def test_increase_behind_a_first_row_parent_invalidates_the_cone(
+    tier, monkeypatch
+):
+    """The parent plane combines a split destination's rows by max. With
+    a plain set the hub's last row, which finds no tight parent, writes -1
+    over the first row's find, the increased edge seeds no cone, and the
+    hub and what hangs behind it keep their old, too short distances
+    (checked by hand against `.set` in both tiers: the hub at metric 2 where 20 is right)."""
+    from openr_tpu.ops import edgeplan
+
+    monkeypatch.setattr(edgeplan, "_residual_width", lambda degrees: 2)
+    me = "node-00"
+    adj_dbs, states, ps = _hub_graph()
+    churn = _Churn(adj_dbs, states)
+    kw = {} if tier == "single" else dict(
+        multichip_n_cap_threshold=4, multichip_batch=4
+    )
+    cpu = SpfSolver(me)
+    incr = TpuSpfSolver(me, incremental_spf=True, spf_kernel="sync", **kw)
+
+    def solve(ctx):
+        assert_rib_equal(
+            cpu.build_route_db(me, states, ps),
+            incr.build_route_db(me, states, ps), ctx,
+        )
+        return incr.last_device_stats
+
+    solve("cold")
+    plan = incr._area_dev["0"].plan
+    hub, via = plan.node_index["node-09"], plan.node_index["node-01"]
+    rows = np.flatnonzero(plan.res_rows == hub)
+    assert plan.shift_edges == 0 and len(rows) == 3
+    assert plan.res_nbr[rows[0], 0] == via
+    assert via not in plan.res_nbr[rows[1:]]
+    if tier == "multichip":
+        assert incr.last_timing.get("multichip")
+
+    churn.set_metric("node-01", "node-09", 50)
+    st = solve("increase on the edge whose parent sits in the first row")
+    assert st.get("incremental") and not st.get("fell_back"), st
+    # the hub and the two behind it, in node-01's lane at the least
+    assert st.get("cone") >= 3, st
+    churn.set_metric("node-01", "node-09", 4)
+    st = solve("decrease")
+    assert st.get("incremental"), st
+    churn.set_metric("node-01", "node-09", 1)
+    st = solve("restore")
+    assert st.get("incremental"), st

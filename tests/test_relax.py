@@ -15,6 +15,7 @@ from openr_tpu.decision.spf_solver import SpfSolver
 from openr_tpu.decision.tpu_solver import TpuSpfSolver
 from openr_tpu.models import topologies
 from openr_tpu.ops import relax as relax_ops
+from tests.test_edgeplan import SPLIT_GRAPHS, _rows_of
 from tests.test_incremental_spf import _Churn
 from tests.test_tpu_solver import assert_rib_equal
 
@@ -248,6 +249,68 @@ def test_incremental_path_churn_parity():
         solve(f"round{i + 1}: {u}<->{v}={m}")
     # metric-only churn away from the vantage must take the warm lane
     assert engaged >= 3, engaged
+
+
+# -- a residual split over several rows a destination ------------------------
+
+# tests/test_edgeplan.py's graphs whose residual is narrower than its
+# widest destination whatever a row costs, and a vantage in each
+SPLIT_VANTAGE = {"fabric": "pod000-rsw00", "wan": "r01-acc0000"}
+
+
+SPLIT_PATHS = {
+    "full": dict(incremental_spf=False),
+    "incr": dict(incremental_spf=True),
+    # rows sharded over 'graph': a destination's rows may lie on two
+    # shards, whose candidates the plane's pmin combines
+    "multichip": dict(multichip_n_cap_threshold=4, multichip_batch=4),
+    "multichip-incr": dict(
+        incremental_spf=True, multichip_n_cap_threshold=4, multichip_batch=4
+    ),
+}
+
+
+@pytest.mark.parametrize("path", sorted(SPLIT_PATHS))
+@pytest.mark.parametrize("graph", sorted(SPLIT_VANTAGE))
+def test_split_mirror_parity(graph, path):
+    """An increase, a decrease and a restore on an edge into a
+    destination that spans several residual rows: sync and bucketed, on
+    the full and on the incremental path of either tier, against the CPU
+    oracle and each other."""
+    me = SPLIT_VANTAGE[graph]
+    adj_dbs, prefix_dbs = SPLIT_GRAPHS[graph]()
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    incremental = "incr" in path
+    solve, buck = _trio(me, states, ps, **SPLIT_PATHS[path])
+    solve("cold")
+    assert bool(buck.last_timing.get("multichip")) == ("multichip" in path)
+    plan = buck._area_dev[AREA].plan
+    assert plan.occupancy()["residual_split_rows"] > 0
+    # the destination with most rows, and the source of an edge in its
+    # first row that is not the vantage's own
+    root = plan.node_index[me]
+    v, mine = max(
+        ((v, r) for v, r in _rows_of(plan).items() if v != root),
+        key=lambda kv: len(kv[1]),
+    )
+    assert len(mine) > 1
+    u = next(int(x) for x in plan.res_nbr[mine[0]] if x not in (-1, root))
+    a, b = plan.node_names[u], plan.node_names[v]
+    churn = _Churn(adj_dbs, states, AREA)
+    base = next(
+        x.metric for x in churn.dbs[a].adjacencies if x.other_node_name == b
+    )
+    warm = 0
+    for ctx, metric in (
+        ("increase", 3 * base + 2), ("decrease", base + 1),
+        ("restore", base),
+    ):
+        churn.set_metric(a, b, metric)
+        st = solve(f"{ctx}: {a} <-> {b} = {metric}")
+        warm += bool(st.get("incremental") and not st.get("fell_back"))
+    assert buck._area_dev[AREA].plan is plan  # the delta path, no rebuild
+    if incremental:
+        assert warm >= 2, warm
 
 
 # -- multichip path ---------------------------------------------------------

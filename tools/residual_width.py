@@ -1,0 +1,251 @@
+"""The chip capture behind `ops/edgeplan._ROW_COST`: what one pass over
+the residual ELL costs at each candidate width, on the two deployments
+whose mirrors are all residual.
+
+    chiprun -- python3 tools/residual_width.py            # both
+    chiprun -- python3 tools/residual_width.py fabric10k  # one
+    chiprun -- python3 tools/residual_width.py fabric10k:16  # from width 16 down
+    JAX_PLATFORMS=cpu python3 tools/residual_width.py fabric-small  # rehearsal
+
+For every power-of-two width from 2 to the widest destination's it
+rebuilds the mirror at that width (by replacing the builder's own choice,
+`edgeplan._residual_width`, for the length of the build) and reports
+
+  `loop`   ms per pass of a jitted loop of relaxations over that mirror
+           alone (`ops/relax.make_relax`, all lanes of the vantage), by
+           the host's clock round `block_until_ready`; `kmajor` is the
+           same pass with the ELL stored [K, R], `gather` the pass with
+           no scatter into the plane;
+  `scope`  ms per pass of `relax.residual`, and per epoch of `seed.cone`,
+           `seed.parent` and the whole device program, from
+           `profiler_stop()`'s `by_scope` over incremental events of the
+           solver itself (a remote link's metric up, then back), whose
+           tables are compared with the first width's; taken at the
+           first width and at widths up to 8 (two compiles a width).
+
+The last lines fit `loop` to `a * r_cap * (K + c)` by least squares: `c`
+is `_ROW_COST`. Needs a TPU: a CPU's gather costs nothing like the chip's.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from chip_smoke import set_metric  # noqa: E402
+from openr_tpu.models import topologies  # noqa: E402
+from openr_tpu.ops import edgeplan  # noqa: E402
+from openr_tpu.ops import relax as relax_ops  # noqa: E402
+
+CONFIGS = {
+    # generator, vantage; the -small ones rehearse the script on a CPU
+    "fabric-small": (
+        lambda: topologies.fabric(
+            pods=12, planes=2, ssws_per_plane=3, rsws_per_pod=6
+        ),
+        "pod000-rsw00",
+    ),
+    "wan-small": (
+        lambda: topologies.wan_rtt(
+            regions=4, cores=2, aggs=6, access=52, seed=7
+        ),
+        "r01-acc0000",
+    ),
+    "fabric10k": (
+        lambda: topologies.fabric(
+            pods=173, planes=8, ssws_per_plane=36, rsws_per_pod=48
+        ),
+        "pod000-rsw00",
+    ),
+    "wan50k": (
+        lambda: topologies.wan_rtt(
+            regions=50, cores=4, aggs=64, access=932, seed=7
+        ),
+        "r25-acc0000",
+    ),
+}
+CONFIGS_ON_CHIP = ("fabric10k", "wan50k")
+PASSES = 16
+REPS = 5
+EVENTS = 6
+# the solver's own capture compiles two pipelines a width (49 s each at
+# wan50k): taken at the width before the split and at the narrow ones
+SCOPE_MAX_WIDTH = 8
+
+
+@contextlib.contextmanager
+def _forced_width(width: int):
+    """Mirrors built inside are `width` wide, whatever the builder would
+    choose."""
+    chosen = edgeplan._residual_width
+    edgeplan._residual_width = lambda degrees: width
+    try:
+        yield
+    finally:
+        edgeplan._residual_width = chosen
+
+
+def _loop_ms(plan, root: int, d_cap: int, layout: str) -> float:
+    """ms per pass of PASSES relaxations over the plan's residual."""
+    import jax
+    import jax.numpy as jnp
+
+    n_cap = plan.n_cap
+    inf = relax_ops.INF_E
+    rows_c = jnp.clip(jnp.asarray(plan.res_rows), 0, n_cap - 1)
+    nbr = jnp.asarray(plan.res_nbr)
+    rw = jnp.where(nbr == root, inf, jnp.asarray(plan.res_w))
+    nbr_c = jnp.clip(nbr, 0, n_cap - 1)
+    deltas = jnp.asarray(plan.deltas)
+    shift_w = jnp.asarray(plan.shift_w)
+    if layout == "rk":
+        relax = relax_ops.make_relax(
+            deltas, plan.s_cap, lambda k: shift_w[k],
+            residual=(rows_c, nbr_c, rw),
+        )
+    else:
+        nbr_t, rw_t = nbr_c.T, rw.T  # [K, R]
+
+        def relax(dist):
+            cand = (dist[:, nbr_t] + rw_t[None]).min(axis=1)
+            if layout == "gather":  # the rows' minimum stands in
+                return jnp.minimum(dist, cand.min(axis=1, keepdims=True))
+            acc = jnp.full_like(dist, inf).at[:, rows_c].min(cand)
+            return jnp.minimum(acc, dist)
+
+    @jax.jit
+    def loop(dist):
+        return jax.lax.fori_loop(0, PASSES, lambda _, d: relax(d), dist)
+
+    dist0 = jnp.full((d_cap, n_cap), inf, jnp.int32)
+    dist0 = dist0.at[:, root].set(0)
+    loop(dist0).block_until_ready()
+    times = []
+    for _ in range(REPS):
+        t0 = time.monotonic()
+        loop(dist0).block_until_ready()
+        times.append((time.monotonic() - t0) * 1e3 / PASSES)
+    return float(np.median(times))
+
+
+def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
+    """by_scope over EVENTS incremental solves at this width; the table
+    after each event against `want` (the first width's)."""
+    from openr_tpu.decision.tpu_solver import TpuSpfSolver
+    from openr_tpu.runtime import device_stats
+
+    adj_dbs = list(adj_dbs)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    index = {db.this_node_name: i for i, db in enumerate(adj_dbs)}
+    # a link far from the vantage: the last node's first adjacency
+    far = adj_dbs[-1].this_node_name
+    peer = adj_dbs[-1].adjacencies[0].other_node_name
+    base = adj_dbs[-1].adjacencies[0].metric
+    solver = TpuSpfSolver(me, enable_lfa=True, incremental_spf=True)
+    tables, rounds, cones = [], [], []
+
+    def solve(step: int) -> dict:
+        """The link's metric up (even steps) or back, then a solve."""
+        if step >= 0:
+            metric = base * 3 if step % 2 == 0 else base
+            for db in set_metric(adj_dbs, index, far, peer, metric):
+                states[db.area].update_adjacency_database(db)
+        tables.append(solver.build_route_db(me, states, ps).unicast_routes)
+        return solver.last_device_stats
+
+    with _forced_width(width):
+        solve(-1)  # the full solve
+        for step in range(2):  # compile the incremental pipeline
+            solve(step)
+        device_stats.profiler_start()
+        for step in range(EVENTS):
+            stats = solve(step)
+            if not stats.get("incremental"):
+                raise SystemExit(f"event {step} did not solve warm: {stats}")
+            rounds.append(solver.last_timing.get("rounds"))
+            cones.append((stats.get("cone"), bool(stats.get("fell_back"))))
+        by_scope = device_stats.profiler_stop()["by_scope"] or {}
+    same = want is None or all(a == b for a, b in zip(tables, want))
+    passes = sum(rounds)
+    return {
+        "relax.residual_ms_per_pass": by_scope.get("relax.residual", 0) / passes,
+        "seed.cone_ms_per_event": by_scope.get("seed.cone", 0) / EVENTS,
+        "seed.parent_ms_per_event": by_scope.get("seed.parent", 0) / EVENTS,
+        "device_ms_per_event": sum(by_scope.values()) / EVENTS,
+        "rounds": rounds,
+        "cones": cones,
+        "tables_equal_first_width": same,
+    }, tables
+
+
+def capture(name: str, max_width: int = 0) -> None:
+    import jax
+
+    gen, me = CONFIGS[name]
+    adj_dbs, prefix_dbs = gen()
+    states, _ = topologies.build_states(adj_dbs, prefix_dbs)
+    link_state = states["0"]
+    # the width before the split: the widest destination's, pow2
+    plan = edgeplan.build_plan(link_state)
+    used = plan.res_rows >= 0
+    degrees = np.bincount(plan.res_rows[used], weights=plan._res_fill[used])
+    lines, want = [], None
+    width = min(
+        edgeplan._next_pow2(int(degrees.max()), 2), max_width or 1 << 30
+    )
+    while width >= 2:
+        with _forced_width(width):
+            plan = edgeplan.build_plan(link_state)
+        root = plan.node_index[me]
+        d_cap = plan.out_links(link_state, me)[0].shape[0]
+        r_cap, k_cap = plan.res_nbr.shape
+        line = {
+            "config": name, "width": k_cap, "r_cap": r_cap,
+            "rows": plan._res_nrows, "slots": r_cap * k_cap,
+            "edges": plan.res_edges, "d_cap": d_cap,
+            "device": jax.devices()[0].device_kind,
+            "loop_ms_per_pass": _loop_ms(plan, root, d_cap, "rk"),
+            "kmajor_ms_per_pass": _loop_ms(plan, root, d_cap, "kr"),
+            "gather_ms_per_pass": _loop_ms(plan, root, d_cap, "gather"),
+        }
+        if want is None or width <= SCOPE_MAX_WIDTH:
+            scope, tables = _scope_ms(
+                name, adj_dbs, prefix_dbs, me, width, want
+            )
+            want = want or tables
+            line.update(scope)
+        print(json.dumps(line), flush=True)
+        lines.append(line)
+        width //= 2
+    # loop = a * r_cap * (K + c)  ->  loop / r_cap = a * K + a * c
+    ks = np.array([ln["width"] for ln in lines], float)
+    for key in ("loop_ms_per_pass", "kmajor_ms_per_pass"):
+        per_row = np.array([ln[key] / ln["r_cap"] for ln in lines])
+        a, ac = np.polyfit(ks, per_row, 1)
+        print(json.dumps({
+            "config": name, "fit_of": key,
+            "ns_per_slot": a * 1e6, "row_cost_in_slots": ac / a,
+        }), flush=True)
+
+
+def main(argv):
+    import jax
+
+    # "fabric10k:16" starts at width 16 (a second call's way to go on)
+    specs = [(spec + ":0").split(":")[:2] for spec in argv or CONFIGS_ON_CHIP]
+    small = all(name.endswith("-small") for name, _ in specs)
+    if jax.devices()[0].platform != "tpu" and not small:
+        raise SystemExit("tools/residual_width.py measures a TPU; none here")
+    for name, max_width in specs:
+        capture(name, int(max_width))
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
