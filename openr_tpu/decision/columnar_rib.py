@@ -136,7 +136,7 @@ def pack_words_host(bits: np.ndarray) -> np.ndarray:
 def route_ok_rows(matrix, root_idx: int, rows, met, s3, nh,
                   block_v4: bool) -> np.ndarray:
     """Vectorized route-level filter (the host mirror of the device ok
-    predicate in tpu_solver._plan_pipeline). met/s3/nh are indexed
+    predicate in tpu_solver._make_pipeline). met/s3/nh are indexed
     0..len(rows); `rows` (array or slice) indexes the matrix arrays."""
     ok = s3.any(axis=1) & (met < INF_E)
     if block_v4:

@@ -61,7 +61,7 @@ from __future__ import annotations
 
 import logging
 from collections import OrderedDict
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -79,7 +79,6 @@ from openr_tpu.runtime import affinity
 from openr_tpu.runtime.counters import counters
 from openr_tpu.ops.csr import (
     INF32,
-    EllGraph,
     PrefixMatrix,
     build_prefix_matrix,
 )
@@ -171,142 +170,6 @@ def _ucmp_weight_anomalies(w) -> int:
 
 
 # ---------------------------------------------------------------------------
-# legacy single-graph kernels (driver entry / sharding / whole-fabric path)
-# ---------------------------------------------------------------------------
-
-def _sssp_kernel(in_nbr, in_w, in_up, node_over, root):
-    """dist[v] fixpoint over the padded in-neighbor mirror; int32 [N_cap]."""
-    import jax
-    import jax.numpy as jnp
-
-    n = in_nbr.shape[0]
-    dist0 = jnp.full((n,), INF, jnp.int32).at[root].set(0)
-    usable = in_up & (in_nbr >= 0) & ((in_nbr == root) | ~node_over[in_nbr])
-
-    def relax(dist):
-        nbr_dist = dist[in_nbr]
-        cand = jnp.where(
-            usable & (nbr_dist < INF), nbr_dist + in_w, INF
-        ).min(axis=1)
-        return jnp.minimum(dist, cand)
-
-    dist, _, _ = relax_ops.run_sync(relax, dist0, relax_ops.max_trips(n))
-    return dist
-
-
-def _next_hop_kernel(in_nbr, in_w, in_up, node_over, root, dist, root_nbr, root_w, root_up):
-    """First-hop slot masks nh[v, d] over the shortest-path DAG."""
-    import jax
-    import jax.numpy as jnp
-
-    n, _ = in_nbr.shape
-    d_cap = root_nbr.shape[0]
-    slot_ok = (root_nbr >= 0) & root_up & (dist[jnp.clip(root_nbr, 0, n - 1)] == root_w)
-    seed = jnp.zeros((n, d_cap), bool).at[
-        jnp.where(root_nbr >= 0, root_nbr, n), jnp.arange(d_cap)
-    ].set(slot_ok, mode="drop")
-    ok_parent = (
-        in_up
-        & (in_nbr >= 0)
-        & (in_nbr != root)
-        & ~node_over[in_nbr]
-        & (dist[in_nbr] < INF)
-        & (dist[in_nbr] + in_w == dist[:, None])
-    )
-
-    def step(nh):
-        prop = jnp.any(ok_parent[:, :, None] & nh[in_nbr], axis=1)
-        return seed | prop
-
-    nh, _, _ = relax_ops.run_sync(step, seed, relax_ops.max_trips(n))
-    return nh
-
-
-def _select_metric_kernel(dist, node_over, ann_node, ann_valid, path_pref, source_pref, dist_adv):
-    """Vectorized per-prefix best-route selection (no next-hop union);
-    shared with the sharded step so the selection semantics exist once."""
-    import jax.numpy as jnp
-
-    n = dist.shape[0]
-    idx = jnp.clip(ann_node, 0, n - 1)
-    ann_dist = dist[idx]
-    reach = ann_valid & (ann_dist < INF)
-    pp = jnp.where(reach, path_pref, _NEG)
-    s = reach & (pp == pp.max(axis=1, keepdims=True))
-    sp = jnp.where(s, source_pref, _NEG)
-    s = s & (sp == sp.max(axis=1, keepdims=True))
-    da = jnp.where(s, dist_adv, INF)
-    s2 = s & (da == da.min(axis=1, keepdims=True))
-    nd = s2 & ~node_over[idx]
-    s3 = jnp.where(nd.any(axis=1, keepdims=True), nd, s2)
-    igp = jnp.where(s3, ann_dist, INF)
-    metric = igp.min(axis=1)
-    s4 = s3 & (igp == metric[:, None])
-    return metric, s3, s4, idx
-
-
-def _select_kernel(dist, nh, node_over, ann_node, ann_valid, path_pref, source_pref, dist_adv):
-    """Selection + next-hop union."""
-    import jax.numpy as jnp
-
-    metric, s3, s4, idx = _select_metric_kernel(
-        dist, node_over, ann_node, ann_valid, path_pref, source_pref, dist_adv
-    )
-    nh_mask = jnp.any(s4[:, :, None] & nh[idx], axis=1)
-    has_route = s3.any(axis=1) & (metric < INF)
-    return metric, s3, nh_mask, has_route
-
-
-@bounded_jit_cache()
-def _jitted_pipeline():
-    import jax
-
-    def pipeline(
-        in_nbr, in_w, in_up, node_over,
-        root, root_nbr, root_w, root_up,
-        ann_node, ann_valid, path_pref, source_pref, dist_adv,
-    ):
-        dist = _sssp_kernel(in_nbr, in_w, in_up, node_over, root)
-        nh = _next_hop_kernel(
-            in_nbr, in_w, in_up, node_over, root, dist, root_nbr, root_w, root_up
-        )
-        metric, s3, nh_mask, has_route = _select_kernel(
-            dist, nh, node_over, ann_node, ann_valid, path_pref, source_pref, dist_adv
-        )
-        return dist, metric, s3, nh_mask, has_route
-
-    return jax.jit(pipeline)
-
-
-@bounded_jit_cache()
-def _jitted_sssp_batch():
-    import jax
-
-    return jax.jit(
-        jax.vmap(_sssp_kernel, in_axes=(None, None, None, None, 0))
-    )
-
-
-def sssp_all_pairs(graph: EllGraph, roots: Optional[np.ndarray] = None):
-    """Batched SSSP from many roots — [R, N_cap] int32 distances."""
-    import jax
-
-    if roots is None:
-        roots = np.arange(graph.n_nodes, dtype=np.int32)
-    fn = _jitted_sssp_batch()
-    args = jax.device_put(
-        [
-            graph.in_nbr,
-            graph.in_w,
-            graph.in_up,
-            graph.node_overloaded,
-            roots.astype(np.int32),
-        ]
-    )
-    return fn(*args)
-
-
-# ---------------------------------------------------------------------------
 # plan pipeline (the production path)
 # ---------------------------------------------------------------------------
 
@@ -372,9 +235,9 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    incr: bool = False, mesh=None,
                    kernel: str = "sync", delta_exp: int = 0,
                    stream: int = 0):
-    """The fused production pipeline (raw closure — _plan_pipeline jits
-    it for the single-area path, _fused_pipeline vmaps it over a group
-    of same-shape areas). Outputs:
+    """The fused production pipeline (raw closure — _build_pipeline jits
+    it under the options a PipelineVariant names, vmapped over a group
+    of same-shape areas for a `fused` one). Outputs:
       delta_buf int32 [2 + B + B + B*wa + B*wd (+ 2B with lfa)]: count,
                 trips, idx, metric, s3 words, nh words (and lfa slot +
                 metric) for up to B changed rows
@@ -660,250 +523,127 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     return pipeline
 
 
-@bounded_jit_cache()
-def _plan_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-                   has_res: bool,
-                   d_cap: int, p_cap: int, a_cap: int, budget: int,
-                   lfa: bool = False, block_v4: bool = False,
-                   sentinels: bool = True, emit_dist: bool = False,
-                   kernel: str = "sync", delta_exp: int = 0):
-    import jax
+class PipelineVariant(NamedTuple):
+    """Everything that tells one pipeline executable from another — the
+    whole key of `pipeline_for`. The ints are the capacity signature
+    `bounded_jit_cache` buckets by (a bucket's flag variants live and
+    die together); bools and the mesh choose a variant within a bucket.
+    `budget` is a field so an executable baked at one delta budget is
+    never served at another."""
 
-    return jax.jit(_make_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, lfa, block_v4, sentinels, emit_dist,
-        kernel=kernel, delta_exp=delta_exp,
-    ))
+    # shape class: exactly the shapes _lane_args uploads (_pipeline_avals)
+    n_cap: int
+    s_cap: int
+    r_cap: int
+    kr_cap: int
+    has_res: bool
+    d_cap: int
+    p_cap: int
+    a_cap: int
+    budget: int               # rows of a classic delta pull
+    lfa: bool = False
+    block_v4: bool = False
+    sentinels: bool = True
+    emit_dist: bool = False   # the [D, N] plane is an output
+    delta_exp: int = 0        # > 0: bucketed Δ-stepping at 2^delta_exp
+    # what kind of executable
+    dirty_cap: int = 0        # > 0: incremental, both dirty buffers' pad
+    stream: int = 0           # a STREAM_BUDGETS bucket: streaming epoch
+    fused: int = 0            # g same-shape areas vmapped in one dispatch
+    donate: bool = False      # prev planes + warm seed donated (stream)
+    mesh: object = None       # the multichip tier's ('batch','graph') mesh
 
+    @classmethod
+    def checked(cls, *fields, **named) -> "PipelineVariant":
+        """The record, or ValueError for a combination no dispatch
+        path builds (and _make_pipeline was never run with)."""
+        v = cls(*fields, **named)
+        one_chip = v.mesh is None
+        if v.stream and not (v.incr and one_chip):
+            raise ValueError(f"stream: incremental, on one chip: {v}")
+        if v.fused and (v.incr or not one_chip):
+            raise ValueError(f"fused: a full solve on one chip: {v}")
+        if v.incr and not v.emit_dist:
+            raise ValueError(f"an incremental solve emits the plane: {v}")
+        if v.donate and not v.stream:
+            raise ValueError(f"only a stream epoch donates: {v}")
+        return v
 
-@bounded_jit_cache(namespace="incr")
-def _incr_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-                   has_res: bool,
-                   d_cap: int, p_cap: int, a_cap: int, budget: int,
-                   dirty_cap: int, lfa: bool = False,
-                   block_v4: bool = False, sentinels: bool = True,
-                   kernel: str = "sync", delta_exp: int = 0):
-    """Incremental-solve executable. `dirty_cap` is the quantized pad
-    size of BOTH dirty buffers — part of the capacity signature so
-    dirty-set shape churn buckets under the `incr` namespace and can
-    never evict the full-solve or what-if executables. Always emits the
-    distance plane (it is the next solve's warm seed)."""
-    import jax
+    def at(self, shape_key: tuple, mesh) -> "PipelineVariant":
+        """This variant at another shape class, on that class's tier."""
+        return self.checked(*shape_key, *self[len(shape_key):-1], mesh)
 
-    return jax.jit(_make_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, lfa, block_v4, sentinels, emit_dist=True, incr=True,
-        kernel=kernel, delta_exp=delta_exp,
-    ))
+    @property
+    def shape_key(self) -> tuple:
+        return self[:8]
 
+    @property
+    def incr(self) -> bool:
+        return self.dirty_cap > 0
 
-@bounded_jit_cache()
-def _fused_pipeline(g: int, n_cap: int, s_cap: int, r_cap: int,
-                    kr_cap: int, has_res: bool,
-                    d_cap: int, p_cap: int, a_cap: int, budget: int,
-                    lfa: bool, block_v4: bool, sentinels: bool,
-                    kernel: str = "sync", delta_exp: int = 0):
-    """`g` same-shape areas in ONE device dispatch: each of the 14
-    pipeline inputs arrives as a g-tuple of per-area arrays (a pytree —
-    still one dispatch), stacks inside the jit, and vmaps through the
-    raw pipeline. Per-call dispatch overhead is paid once for the whole
-    group instead of per area; the while_loop trip count becomes the
-    max across the group (extra trips past a lane's fixpoint are
-    no-ops). Outputs unstack back to per-area tuples so the existing
-    per-area materialization consumes them unchanged."""
-    import jax
-    import jax.numpy as jnp
+    @property
+    def kernel(self) -> str:
+        """ops/relax.py's round loop (_prep_vantage maps the spf_kernel
+        knob and the plan's Δ onto delta_exp)."""
+        return "bucketed" if self.delta_exp > 0 else "sync"
 
-    raw = _make_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, lfa, block_v4, sentinels,
-        kernel=kernel, delta_exp=delta_exp,
-    )
+    @property
+    def namespace(self) -> str:
+        """jit-cache namespace: sharded, streaming and incremental
+        executables each churn their own LRU (and count under their own
+        xla_cache.<ns>_factory_*), never evicting a full solve."""
+        if self.mesh is not None:
+            return "multichip"
+        if self.stream:
+            return "stream"
+        return "incr" if self.incr else ""
 
-    def fused(*area_args):
-        stacked = [jnp.stack(xs) for xs in area_args]
-        outs = jax.vmap(raw)(*stacked)
-        return tuple(tuple(o[i] for o in outs) for i in range(g))
+    @property
+    def name(self) -> str:
+        """Display name (kernel ledger, ctrl.tpu.kernels, last_timing,
+        the tpu.dispatch span, the replay log). It under-keys on
+        purpose — no r_cap/kr_cap/budget, no block or sentinel flag;
+        identity is `aot_key`."""
+        kind = (
+            ("_mc" if self.mesh is not None else "")
+            + ("_fused" if self.fused else "")
+            + ("_stream" if self.stream else "_incr" if self.incr else "")
+        )
+        parts = [
+            f"g={self.fused}" if self.fused else "",
+            f"n={self.n_cap},s={self.s_cap},d={self.d_cap}",
+            f"p={self.p_cap},a={self.a_cap}",
+            f"dd={self.dirty_cap}" if self.incr else "",
+            f"sb={self.stream}" if self.stream else "",
+            f"mesh={_mesh_tag(self.mesh)}" if self.mesh is not None else "",
+            "res" if self.has_res else "",
+            "lfa" if self.lfa else "",
+            f"bk{self.delta_exp}" if self.delta_exp > 0 else "",
+        ]
+        return f"pipeline{kind}[{','.join(filter(None, parts))}]"
 
-    return jax.jit(fused)
-
-
-@bounded_jit_cache()
-def _instrumented_fused(
-    g: int, n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-    has_res: bool, d_cap: int, p_cap: int, a_cap: int, budget: int,
-    lfa: bool, block_v4: bool, sentinels: bool,
-    kernel: str = "sync", delta_exp: int = 0,
-) -> tuple:
-    """(kernel name, instrumented callable) for a fused group shape —
-    the fused analogue of _instrumented_pipeline."""
-    from openr_tpu.ops.xla_cache import instrument_jit
-
-    name = (
-        f"pipeline_fused[g={g},n={n_cap},s={s_cap},d={d_cap},"
-        f"p={p_cap},a={a_cap}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
-    )
-    jitted = _fused_pipeline(
-        g, n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, lfa, block_v4, sentinels, kernel, delta_exp,
-    )
-    # the AOT key carries EVERY factory arg: the display name above
-    # omits r_cap/kr_cap/budget and the block/sentinel flags, and two
-    # variants must never alias one serialized executable
-    aot_key = repr((
-        "fused", g, n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, lfa, block_v4, sentinels, kernel, delta_exp,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
-
-
-@bounded_jit_cache()
-def _instrumented_pipeline(
-    n_cap: int, s_cap: int, r_cap: int, kr_cap: int, has_res: bool,
-    d_cap: int, p_cap: int, a_cap: int, budget: int,
-    lfa: bool, block_v4: bool, sentinels: bool,
-    emit_dist: bool = False,
-    kernel: str = "sync", delta_exp: int = 0,
-) -> tuple:
-    """(kernel name, instrumented callable) for a pipeline shape class.
-    The wrapper AOT-compiles on first call, recording compile time +
-    XLA cost_analysis into the kernel ledger (ops/xla_cache.ledger) so
-    ctrl.tpu.kernels can report estimated vs achieved throughput.
-    lru-cached on the same key as _plan_pipeline: one wrapper instance
-    per shape class keeps the compile-once state stable."""
-    from openr_tpu.ops.xla_cache import instrument_jit
-
-    name = (
-        f"pipeline[n={n_cap},s={s_cap},d={d_cap},p={p_cap},a={a_cap}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
-    )
-    jitted = _plan_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, lfa, block_v4, sentinels, emit_dist,
-        kernel, delta_exp,
-    )
-    aot_key = repr((
-        "full", n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, lfa, block_v4, sentinels, emit_dist, kernel,
-        delta_exp,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
+    @property
+    def aot_key(self) -> str:
+        """Persistent-executable key: every field, so two variants can
+        never alias one serialized executable."""
+        mesh = None if self.mesh is None else _mesh_tag(self.mesh)
+        return repr(self._replace(mesh=mesh))
 
 
-@bounded_jit_cache(namespace="incr")
-def _instrumented_incr(
-    n_cap: int, s_cap: int, r_cap: int, kr_cap: int, has_res: bool,
-    d_cap: int, p_cap: int, a_cap: int, budget: int, dirty_cap: int,
-    lfa: bool, block_v4: bool, sentinels: bool,
-    kernel: str = "sync", delta_exp: int = 0,
-) -> tuple:
-    """(kernel name, instrumented callable) for an incremental-solve
-    shape class — the incr-namespace analogue of
-    _instrumented_pipeline."""
-    from openr_tpu.ops.xla_cache import instrument_jit
-
-    name = (
-        f"pipeline_incr[n={n_cap},s={s_cap},d={d_cap},p={p_cap},"
-        f"a={a_cap},dd={dirty_cap}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
-    )
-    jitted = _incr_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, dirty_cap, lfa, block_v4, sentinels,
-        kernel, delta_exp,
-    )
-    aot_key = repr((
-        "incr", n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, dirty_cap, lfa, block_v4, sentinels, kernel,
-        delta_exp,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
-
-
-@bounded_jit_cache(namespace="stream")
-def _stream_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-                     has_res: bool,
-                     d_cap: int, p_cap: int, a_cap: int, budget: int,
-                     dirty_cap: int, sbudget: int, lfa: bool = False,
-                     block_v4: bool = False, sentinels: bool = True,
-                     kernel: str = "sync", delta_exp: int = 0,
-                     donate: bool = True):
-    """Streaming-epoch executable: one dispatch chains the incremental
-    relax, selection/LFA and the on-device column diff, downloading a
-    `sbudget`-row compacted payload with the device route-ok bit
-    (ops/stream.py). The previous epoch's published planes and warm
-    distance seed are DONATED — the epoch double-buffer updates HBM in
-    place, so keeping the columns resident across solves costs one
-    plane set, not two. `sbudget` (a STREAM_BUDGETS bucket) and
-    `dirty_cap` are both capacity-signature ints, so budget churn
-    buckets inside the "stream" namespace and can never evict the
-    full-solve or incr executables. Donation is gated off whenever a
-    transfer guard is armed (the guarded-retry path would replay
-    consumed buffers)."""
-    import jax
-
-    kw = {"donate_argnums": (9, 10, 11, 12, 13, 14)} if donate else {}
-    return jax.jit(
-        _make_pipeline(
-            n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-            budget, lfa, block_v4, sentinels, emit_dist=True, incr=True,
-            kernel=kernel, delta_exp=delta_exp, stream=sbudget,
-        ),
-        **kw,
-    )
-
-
-@bounded_jit_cache(namespace="stream")
-def _instrumented_stream(
-    n_cap: int, s_cap: int, r_cap: int, kr_cap: int, has_res: bool,
-    d_cap: int, p_cap: int, a_cap: int, budget: int, dirty_cap: int,
-    sbudget: int, lfa: bool, block_v4: bool, sentinels: bool,
-    kernel: str = "sync", delta_exp: int = 0, donate: bool = True,
-) -> tuple:
-    """(kernel name, instrumented callable) for a streaming-epoch shape
-    class — the stream-namespace analogue of _instrumented_incr."""
-    from openr_tpu.ops.xla_cache import instrument_jit
-
-    name = (
-        f"pipeline_stream[n={n_cap},s={s_cap},d={d_cap},p={p_cap},"
-        f"a={a_cap},dd={dirty_cap},sb={sbudget}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
-    )
-    jitted = _stream_pipeline(
-        n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-        budget, dirty_cap, sbudget, lfa, block_v4, sentinels,
-        kernel, delta_exp, donate,
-    )
-    aot_key = repr((
-        "stream", n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, dirty_cap, sbudget, lfa, block_v4, sentinels,
-        kernel, delta_exp, donate,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
+def _mesh_tag(mesh) -> str:
+    return f"{mesh.shape['batch']}x{mesh.shape['graph']}"
 
 
 def _mc_shardings(mesh, n_cap: int, r_cap: int, d_cap: int,
-                  emit_dist: bool):
-    """(in_shardings, out_shardings) for the 14-arg pipeline closure
-    under the multichip tier's ('batch','graph') mesh. Input placements
-    come from parallel.sharding.plan_shardings (weight state over
-    'graph', root tables over 'batch', small planes replicated); BOTH
-    sides are pinned so the executable is stable across calls — without
-    pinned out_shardings the second call would see prev outputs in
-    whatever layout GSPMD chose and recompile."""
+                  emit_dist: bool, incr: bool):
+    """(in_shardings, out_shardings) for the pipeline closure under the
+    multichip tier's ('batch','graph') mesh. Input placements come from
+    parallel.sharding.plan_shardings (weight state over 'graph', root
+    tables over 'batch', small planes replicated); BOTH sides are
+    pinned so the executable is stable across calls — without pinned
+    out_shardings the second call would see prev outputs in whatever
+    layout GSPMD chose and recompile (and the incremental solve's warm
+    seed plane would reshard between chained solves)."""
     from openr_tpu.parallel.sharding import plan_shardings
 
     sh = plan_shardings(mesh, n_cap, r_cap, d_cap)
@@ -920,136 +660,77 @@ def _mc_shardings(mesh, n_cap: int, r_cap: int, d_cap: int,
         sh["root_vec"],   # root_w
         rep, rep, rep, rep, rep,  # prev outputs
     )
-    out_sh = [rep] * 7
-    if emit_dist:
-        out_sh.append(sh["dist"])
-    return in_sh, tuple(out_sh), sh
+    if incr:
+        # + prev_dist [D, N] and the five replicated dirty-tail args
+        in_sh += (sh["dist"], rep, rep, rep, rep, rep)
+    out_sh = (rep,) * 7 + ((sh["dist"],) if emit_dist else ())
+    return in_sh, out_sh
 
 
-@bounded_jit_cache(namespace="multichip")
-def _mc_pipeline(mesh, n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-                 has_res: bool,
-                 d_cap: int, p_cap: int, a_cap: int, budget: int,
-                 lfa: bool = False, block_v4: bool = False,
-                 sentinels: bool = True, emit_dist: bool = False,
-                 kernel: str = "sync", delta_exp: int = 0):
-    """The multichip capacity tier's full-solve executable: the SAME
-    pipeline closure as _plan_pipeline, jitted with NamedSharding
-    annotations over the ('batch','graph') mesh so GSPMD partitions the
-    weight state across devices — parity with the single-chip tier by
-    construction (the int32 min/add/compare algebra is partitioning-
-    invariant, and XLA argmin keeps lowest-index tie-breaks). The mesh
-    rides the cache key as a within-bucket variant; the "multichip"
-    namespace keeps sharded executables from evicting single-chip
-    ones."""
+def _build_pipeline(*fields) -> tuple:
+    """THE executable factory: variant record -> (kernel name,
+    instrumented callable), the record splatted because
+    bounded_jit_cache reads a key's capacity signature off its
+    positional ints. The wrapper AOT-compiles on first call, recording
+    compile time + XLA cost_analysis into the kernel ledger
+    (ops/xla_cache.ledger). The jit options follow from the record:
+      - `donate`: the previous epoch's published planes and warm seed
+        (args 9-14) update HBM in place — one plane set resident, not
+        two;
+      - `mesh`: NamedSharding annotations, so GSPMD partitions the
+        weight state — parity with one chip by construction (the int32
+        min/add/compare algebra is partitioning-invariant, XLA argmin
+        keeps lowest-index ties);
+      - `fused`: each of the 14 inputs arrives as a g-tuple of per-area
+        arrays (a pytree — still one dispatch), stacks inside the jit
+        and vmaps through the closure; the trip count becomes the
+        group's max (trips past a lane's fixpoint are no-ops) and the
+        outputs unstack to per-area tuples.
+    The traced functions keep the names `pipeline` and `fused`: they
+    name the HLO module, which jax's persistent compile cache keys on."""
     import jax
+    import jax.numpy as jnp
 
-    in_sh, out_sh, _ = _mc_shardings(mesh, n_cap, r_cap, d_cap, emit_dist)
-    return jax.jit(
-        _make_pipeline(
-            n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-            budget, lfa, block_v4, sentinels, emit_dist, mesh=mesh,
-            kernel=kernel, delta_exp=delta_exp,
-        ),
-        in_shardings=in_sh, out_shardings=out_sh,
-    )
-
-
-@bounded_jit_cache(namespace="multichip")
-def _mc_incr_pipeline(mesh, n_cap: int, s_cap: int, r_cap: int,
-                      kr_cap: int, has_res: bool,
-                      d_cap: int, p_cap: int, a_cap: int, budget: int,
-                      dirty_cap: int, lfa: bool = False,
-                      block_v4: bool = False, sentinels: bool = True,
-                      kernel: str = "sync", delta_exp: int = 0):
-    """Incremental-solve executable under the multichip tier: the warm
-    seed plane stays device-resident in its sharded layout (in AND out
-    pinned to the same spec, so chaining solves never reshards)."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    in_sh, out_sh, sh = _mc_shardings(mesh, n_cap, r_cap, d_cap, True)
-    rep = sh["replicated"]
-    # + prev_dist [D, N] and the five replicated dirty-tail args
-    in_sh = in_sh + (sh["dist"], rep, rep, rep, rep, rep)
-    return jax.jit(
-        _make_pipeline(
-            n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap, a_cap,
-            budget, lfa, block_v4, sentinels, emit_dist=True, incr=True,
-            mesh=mesh, kernel=kernel, delta_exp=delta_exp,
-        ),
-        in_shardings=in_sh, out_shardings=out_sh,
-    )
-
-
-def _mesh_tag(mesh) -> str:
-    return f"{mesh.shape['batch']}x{mesh.shape['graph']}"
-
-
-@bounded_jit_cache(namespace="multichip")
-def _instrumented_mc(
-    mesh, n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-    has_res: bool, d_cap: int, p_cap: int, a_cap: int, budget: int,
-    lfa: bool, block_v4: bool, sentinels: bool,
-    emit_dist: bool = False,
-    kernel: str = "sync", delta_exp: int = 0,
-) -> tuple:
-    """(kernel name, instrumented callable) for a multichip shape
-    class — the multichip-namespace analogue of
-    _instrumented_pipeline."""
     from openr_tpu.ops.xla_cache import instrument_jit
 
-    name = (
-        f"pipeline_mc[n={n_cap},s={s_cap},d={d_cap},p={p_cap},"
-        f"a={a_cap},mesh={_mesh_tag(mesh)}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
+    v = PipelineVariant.checked(*fields)
+    pipeline = _make_pipeline(
+        *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
+        v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
+        delta_exp=v.delta_exp, stream=v.stream,
     )
-    jitted = _mc_pipeline(
-        mesh, n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, lfa, block_v4, sentinels, emit_dist,
-        kernel, delta_exp,
-    )
-    aot_key = repr((
-        "mc", _mesh_tag(mesh), n_cap, s_cap, r_cap, kr_cap, has_res,
-        d_cap, p_cap, a_cap, budget, lfa, block_v4, sentinels,
-        emit_dist, kernel, delta_exp,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
+    kw = {}
+    if v.donate:
+        kw = {"donate_argnums": (9, 10, 11, 12, 13, 14)}
+    elif v.mesh is not None:
+        kw["in_shardings"], kw["out_shardings"] = _mc_shardings(
+            v.mesh, v.n_cap, v.r_cap, v.d_cap, v.emit_dist, v.incr
+        )
+    if v.fused:
+        g = v.fused
+
+        def fused(*area_args):
+            stacked = [jnp.stack(xs) for xs in area_args]
+            outs = jax.vmap(pipeline)(*stacked)
+            return tuple(tuple(o[i] for o in outs) for i in range(g))
+
+        jitted = jax.jit(fused)
+    else:
+        jitted = jax.jit(pipeline, **kw)
+    return v.name, instrument_jit(v.name, jitted, aot_key=v.aot_key)
 
 
-@bounded_jit_cache(namespace="multichip")
-def _instrumented_mc_incr(
-    mesh, n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
-    has_res: bool, d_cap: int, p_cap: int, a_cap: int, budget: int,
-    dirty_cap: int, lfa: bool, block_v4: bool, sentinels: bool,
-    kernel: str = "sync", delta_exp: int = 0,
-) -> tuple:
-    """(kernel name, instrumented callable) for a multichip
-    incremental-solve shape class."""
-    from openr_tpu.ops.xla_cache import instrument_jit
+# one cache of the one factory per namespace, each with its own bucket
+# budget and its own xla_cache.<ns>_factory_* counters
+_PIPELINE_CACHES = {
+    ns: bounded_jit_cache(namespace=ns)(_build_pipeline)
+    for ns in ("", "incr", "stream", "multichip")
+}
 
-    name = (
-        f"pipeline_mc_incr[n={n_cap},s={s_cap},d={d_cap},p={p_cap},"
-        f"a={a_cap},dd={dirty_cap},mesh={_mesh_tag(mesh)}"
-        + (",res" if has_res else "")
-        + (",lfa" if lfa else "")
-        + (f",bk{delta_exp}" if kernel == "bucketed" else "")
-        + "]"
-    )
-    jitted = _mc_incr_pipeline(
-        mesh, n_cap, s_cap, r_cap, kr_cap, has_res, d_cap, p_cap,
-        a_cap, budget, dirty_cap, lfa, block_v4, sentinels,
-        kernel, delta_exp,
-    )
-    aot_key = repr((
-        "mc_incr", _mesh_tag(mesh), n_cap, s_cap, r_cap, kr_cap,
-        has_res, d_cap, p_cap, a_cap, budget, dirty_cap, lfa, block_v4,
-        sentinels, kernel, delta_exp,
-    ))
-    return name, instrument_jit(name, jitted, aot_key=aot_key)
+
+def pipeline_for(variant: PipelineVariant) -> tuple:
+    """(kernel name, executable) of a variant, built once."""
+    return _PIPELINE_CACHES[variant.namespace](*variant)
 
 
 # -- speculative next-class bake (ISSUE 20) ---------------------------------
@@ -2655,16 +2336,13 @@ class TpuSpfSolver:
         lfa = self.cpu.enable_lfa
         block_v4 = not (self.cpu.enable_v4 or self.cpu.v4_over_v6_nexthop)
         # round-loop selection (ops/relax.py): the bucketed Δ-stepping
-        # kernel engages only when the plan derived a usable Δ
-        # (delta_exp > 0 — it has nonzero shift classes with finite
-        # weights); ineligible plans fall back to the sync rounds
-        # silently. delta_exp joins the executable's capacity signature,
-        # kernel the fuse key (sync and bucketed lanes never vmap
-        # together).
-        if self.spf_kernel == "bucketed" and plan.delta_exp > 0:
-            spf_kernel, delta_exp = "bucketed", plan.delta_exp
-        else:
-            spf_kernel, delta_exp = "sync", 0
+        # kernel engages only when the plan derived a usable Δ (it has
+        # nonzero shift classes with finite weights); other plans run
+        # the sync rounds silently (delta_exp 0). delta_exp joins the
+        # capacity signature and the fuse key (the two never vmap).
+        delta_exp = 0
+        if self.spf_kernel == "bucketed":
+            delta_exp = max(plan.delta_exp, 0)
         if (
             vs.shape_key != cache_key
             or vs.matrix_version != ad.matrix_version
@@ -2739,10 +2417,9 @@ class TpuSpfSolver:
             "area": area, "ad": ad, "plan": plan, "matrix": matrix,
             "root_idx": root_idx, "root_nbr": root_nbr, "root_w": root_w,
             "shape_key": shape_key,
-            "fuse_key": (shape_key, lfa, block_v4, spf_kernel, delta_exp),
+            "fuse_key": (shape_key, lfa, block_v4, delta_exp),
             "vs": vs, "lfa": lfa, "block_v4": block_v4,
-            "kernel": spf_kernel, "delta_exp": delta_exp,
-            "d_cap": d_cap, "p_cap": p_cap, "a_cap": a_cap,
+            "delta_exp": delta_exp,
             "mc": mc, "incr": incr, "root_sig": root_sig,
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
@@ -2813,11 +2490,11 @@ class TpuSpfSolver:
     # (a misparsed cap would otherwise queue an absurd compile)
     _SPECULATE_MAX_NCAP = 1 << 21
 
-    def _maybe_speculate(self, pv: dict) -> None:
+    def _maybe_speculate(self, full: PipelineVariant) -> None:
         """Hand the background-compile fiber (ops/xla_cache.baker) the
         NEXT capacity class's full-solve executable (ISSUE 20): the
         class one pow2 tier up per _next_shape_key, under this
-        dispatch's variant flags, compiled from abstract avals and
+        dispatch's full-solve variant `full`, compiled from abstract avals and
         persisted to the AOT cache — so a fabric that grows through the
         tier flip finds the executable installed instead of stalling
         its first post-flip solve behind XLA. When the next class
@@ -2830,7 +2507,7 @@ class TpuSpfSolver:
             return
         from openr_tpu.ops.xla_cache import baker
 
-        nxt = _next_shape_key(pv["shape_key"])
+        nxt = _next_shape_key(full.shape_key)
         if nxt[0] > self._SPECULATE_MAX_NCAP:
             return
         mesh = self._mc_mesh_for(nxt[0])
@@ -2838,26 +2515,39 @@ class TpuSpfSolver:
             b = mesh.shape["batch"]
             d_pad = -(-nxt[5] // b) * b
             nxt = nxt[:5] + (d_pad,) + nxt[6:]
-        lfa, block_v4 = pv["lfa"], pv["block_v4"]
-        sent, emit = self.enable_sentinels, self.incremental_spf
-        kern, dexp = pv["kernel"], pv["delta_exp"]
-        tier = _mesh_tag(mesh) if mesh is not None else "1chip"
-        label = f"next:{nxt}:{lfa}:{block_v4}:{kern}:{dexp}:{emit}:{tier}"
+        variant = full.at(nxt, mesh)
 
         def bake():
-            if mesh is not None:
-                _, run = _instrumented_mc(
-                    mesh, *nxt, _DELTA_BUDGET, lfa, block_v4, sent,
-                    emit, kern, dexp,
-                )
-            else:
-                _, run = _instrumented_pipeline(
-                    *nxt, _DELTA_BUDGET, lfa, block_v4, sent, emit,
-                    kern, dexp,
-                )
+            _, run = pipeline_for(variant)
             run.prime(*_pipeline_avals(nxt))
 
-        baker.submit(label, bake)
+        baker.submit(f"next:{variant.aot_key}", bake)
+
+    def _variant(self, pv: dict, dirty_cap: int = 0, stream: int = 0,
+                 fused: int = 0, donate: bool = False) -> PipelineVariant:
+        """The executable a prepared vantage dispatches: its shape
+        class, flags and tier, the solver's knobs, and the kind the
+        dispatcher asks for (none: the full solve). The one place the
+        dispatch path reads _DELTA_BUDGET. The full solve emits the
+        distance plane whenever incremental solves may follow it; a
+        fused group's areas never seed one. Unchecked here, once an
+        event: the factory checks what it builds."""
+        return PipelineVariant(
+            *pv["shape_key"], _DELTA_BUDGET, pv["lfa"], pv["block_v4"],
+            self.enable_sentinels,
+            emit_dist=dirty_cap > 0 or (self.incremental_spf and not fused),
+            delta_exp=pv["delta_exp"], dirty_cap=dirty_cap, stream=stream,
+            fused=fused, donate=donate, mesh=pv["mc"],
+        )
+
+    def _incr_args(self, pv: dict) -> tuple:
+        """_lane_args + the incremental solve's six trailing args."""
+        incr = pv["incr"]
+        return self._lane_args(pv) + (
+            pv["vs"].prev_dist,
+            incr["sd_idx"], incr["sd_old"],
+            incr["rd_idx"], incr["rd_old"], incr["cone_limit"],
+        )
 
     def _dispatch_one(self, pv: dict):
         """Dispatch one area's pipeline and start the async result copy;
@@ -2866,41 +2556,29 @@ class TpuSpfSolver:
         incr-namespace kernel seeded from its resident distance plane;
         either way the distance plane is emitted and kept resident as
         the next solve's seed."""
-        emit = self.incremental_spf
         incr = pv.get("incr")
-        mc = pv.get("mc")
-        self._maybe_speculate(pv)
-        if mc is not None:
+        variant = self._variant(pv)
+        self._maybe_speculate(variant)
+        if pv["mc"] is not None:
             counters.increment("decision.solver.multichip.dispatches")
+        if incr is None:
+            args = self._lane_args(pv)
+        elif pv["mc"] is None and self.streaming_pipeline:
+            # streaming epoch: same eligibility ladder as the
+            # incremental solve (its rungs ARE the fallback ladder
+            # — first solve, shape/root churn, journal gaps all
+            # land in the full solve), different download contract +
+            # donated double-buffer
+            return self._dispatch_stream(pv)
+        else:
+            variant = self._variant(pv, dirty_cap=incr["cap"])
+            args = self._incr_args(pv)
+        kernel_name, run = pipeline_for(variant)
+        delta_buf, full_buf, *new_prev = self._run_exec(
+            variant.namespace, kernel_name, pv["shape_key"], run, args,
+            pv["area"],
+        )
         if incr is not None:
-            if mc is None and self.streaming_pipeline:
-                # streaming epoch: same eligibility ladder as the
-                # incremental solve (its rungs ARE the fallback ladder
-                # — first solve, shape/root churn, journal gaps all
-                # land in the full branch below), different download
-                # contract + donated double-buffer
-                return self._dispatch_stream(pv)
-            if mc is not None:
-                kernel_name, run = _instrumented_mc_incr(
-                    mc, *pv["shape_key"], _DELTA_BUDGET, incr["cap"],
-                    pv["lfa"], pv["block_v4"], self.enable_sentinels,
-                    pv["kernel"], pv["delta_exp"],
-                )
-            else:
-                kernel_name, run = _instrumented_incr(
-                    *pv["shape_key"], _DELTA_BUDGET, incr["cap"],
-                    pv["lfa"], pv["block_v4"], self.enable_sentinels,
-                    pv["kernel"], pv["delta_exp"],
-                )
-            args = self._lane_args(pv) + (
-                pv["vs"].prev_dist,
-                incr["sd_idx"], incr["sd_old"],
-                incr["rd_idx"], incr["rd_old"], incr["cone_limit"],
-            )
-            ns = "multichip" if mc is not None else "incr"
-            delta_buf, full_buf, *new_prev = self._run_exec(
-                ns, kernel_name, pv["shape_key"], run, args, pv["area"]
-            )
             # resident incremental state for the device-only probe
             # (bench.py incr_device_ms): prev outputs chain through
             # o[2:7], the distance plane through o[7], the dirty tail
@@ -2909,39 +2587,20 @@ class TpuSpfSolver:
                 run, args[:9], tuple(new_prev[:5]), new_prev[5],
                 args[15:],
             )
-            return self._make_prepare(
-                pv, kernel_name, delta_buf, full_buf, new_prev,
-                emit=True, incr=True,
-            )
-        if mc is not None:
-            kernel_name, run = _instrumented_mc(
-                mc, *pv["shape_key"], _DELTA_BUDGET, pv["lfa"],
-                pv["block_v4"], self.enable_sentinels, emit,
-                pv["kernel"], pv["delta_exp"],
-            )
         else:
-            kernel_name, run = _instrumented_pipeline(
-                *pv["shape_key"], _DELTA_BUDGET, pv["lfa"],
-                pv["block_v4"], self.enable_sentinels, emit,
-                pv["kernel"], pv["delta_exp"],
-            )
-        args = self._lane_args(pv)
-        ns = "multichip" if mc is not None else ""
-        delta_buf, full_buf, *new_prev = self._run_exec(
-            ns, kernel_name, pv["shape_key"], run, args, pv["area"]
-        )
-        counters.increment("decision.solver.full.solves")
-        if self.incremental_spf:
-            # full dispatch while incremental is on: first / ineligible
-            # solve or a host-gate fallback (journal gap, root churn,
-            # zero-weight edges, oversized dirty set)
-            counters.increment("decision.solver.incr.full_fallbacks")
-        # resident pipeline state for device-only throughput probes
-        # (bench.py device_compute_ms): re-invokable with outputs fed
-        # forward as the next prev
-        self._last_exec = (run, args[:9], tuple(new_prev[:5]))
+            counters.increment("decision.solver.full.solves")
+            if self.incremental_spf:
+                # full dispatch while incremental is on: first /
+                # ineligible solve or a host-gate fallback (journal
+                # gap, root churn, zero-weight edges, oversized dirty
+                # set)
+                counters.increment("decision.solver.incr.full_fallbacks")
+            # resident pipeline state for device-only throughput probes
+            # (bench.py device_compute_ms): re-invokable with outputs
+            # fed forward as the next prev
+            self._last_exec = (run, args[:9], tuple(new_prev[:5]))
         return self._make_prepare(
-            pv, kernel_name, delta_buf, full_buf, new_prev, emit=emit
+            pv, variant, kernel_name, delta_buf, full_buf, new_prev
         )
 
     def _dispatch_stream(self, pv: dict):
@@ -2958,28 +2617,21 @@ class TpuSpfSolver:
         silently diverged from the resident planes. Donation also kills
         the device-probe replay state (its stored prev handles), so
         both probes are cleared."""
-        incr, vs = pv["incr"], pv["vs"]
-        sbudget = int(vs.stream_budget) or 64
-        # the guarded-retry path in _run_exec replays the call after a
-        # finding — impossible once the inputs are donated
-        donate = self._transfer_guard_mode() is None
-        kernel_name, run = _instrumented_stream(
-            *pv["shape_key"], _DELTA_BUDGET, incr["cap"], sbudget,
-            pv["lfa"], pv["block_v4"], self.enable_sentinels,
-            pv["kernel"], pv["delta_exp"], donate,
+        vs = pv["vs"]
+        variant = self._variant(
+            pv, dirty_cap=pv["incr"]["cap"],
+            stream=int(vs.stream_budget) or 64,
+            # the guarded-retry path in _run_exec replays the call
+            # after a finding — impossible once the inputs are donated
+            donate=self._transfer_guard_mode() is None,
         )
-        args = self._lane_args(pv) + (
-            vs.prev_dist,
-            incr["sd_idx"], incr["sd_old"],
-            incr["rd_idx"], incr["rd_old"], incr["cone_limit"],
-        )
+        kernel_name, run = pipeline_for(variant)
         delta_buf, full_buf, *new_prev = self._run_exec(
-            "stream", kernel_name, pv["shape_key"], run, args,
-            pv["area"],
+            variant.namespace, kernel_name, pv["shape_key"], run,
+            self._incr_args(pv), pv["area"],
         )
         prepare = self._make_prepare(
-            pv, kernel_name, delta_buf, full_buf, new_prev,
-            emit=True, incr=True, stream=sbudget,
+            pv, variant, kernel_name, delta_buf, full_buf, new_prev
         )
         # post-donation hygiene, on the dispatch thread before anything
         # can observe the dead handles: advance the double-buffer,
@@ -3000,18 +2652,15 @@ class TpuSpfSolver:
         single path pays per area is paid once for the group."""
         g = len(group)
         pv0 = group[0]
-        kernel_name, run = _instrumented_fused(
-            g, *pv0["shape_key"], _DELTA_BUDGET, pv0["lfa"],
-            pv0["block_v4"], self.enable_sentinels,
-            pv0["kernel"], pv0["delta_exp"],
-        )
+        variant = self._variant(pv0, fused=g)
+        kernel_name, run = pipeline_for(variant)
         lanes = [self._lane_args(pv) for pv in group]
         area_args = tuple(
             tuple(lane[i] for lane in lanes) for i in range(14)
         )
         outs = self._run_exec(
-            "", kernel_name, pv0["shape_key"], run, area_args,
-            pv0["area"],
+            variant.namespace, kernel_name, pv0["shape_key"], run,
+            area_args, pv0["area"],
         )
         counters.increment("decision.device.fused_dispatches")
         counters.increment("decision.device.fused_areas", g)
@@ -3020,21 +2669,21 @@ class TpuSpfSolver:
         for pv, out in zip(group, outs):
             delta_buf, full_buf, *new_prev = out
             result.append((pv, self._make_prepare(
-                pv, kernel_name, delta_buf, full_buf, new_prev, fused=g
+                pv, variant, kernel_name, delta_buf, full_buf, new_prev
             )))
         return result
 
-    def _make_prepare(self, pv: dict, kernel_name: str, delta_buf,
-                      full_buf, new_prev, fused: int = 0,
-                      emit: bool = False, incr: bool = False,
-                      stream: int = 0):
+    def _make_prepare(self, pv: dict, variant: PipelineVariant,
+                      kernel_name: str, delta_buf, full_buf, new_prev):
         """Start the async device->host copy of the buffer the solve
         will consume and build the prepare() closure that patches the
-        vantage's columnar RIB on the materialization worker.
-        Thread-safety: one worker thread, and the caller does not touch
-        this vantage's state until it collects the future.
+        vantage's columnar RIB on the materialization worker; `variant`
+        is the executable that wrote the buffers, so its fields say how
+        to read them. Thread-safety: one worker thread, and the caller
+        does not touch this vantage's state until it collects the
+        future.
 
-        With `stream` (the streaming epoch's changed-rows bucket) the
+        With `variant.stream` (the epoch's changed-rows bucket) the
         delta payload is the bucketed ops/stream.py layout: the device
         route-ok bit rides per changed row, so the patch goes through
         apply_rows_packed — no host word-unpack, and the crib journal
@@ -3049,11 +2698,12 @@ class TpuSpfSolver:
         # the jitted call has just returned: the device runs from here
         t_disp = _time.monotonic()
         plan, matrix, vs = pv["plan"], pv["matrix"], pv["vs"]
-        lfa = pv["lfa"]
-        sentinels = self.enable_sentinels
-        d_cap, p_cap, a_cap = pv["d_cap"], pv["p_cap"], pv["a_cap"]
+        lfa, sentinels = variant.lfa, variant.sentinels
+        fused, stream = variant.fused, variant.stream
+        emit, incr = variant.emit_dist, variant.incr
+        spf_kernel = variant.kernel
+        d_cap, p_cap, a_cap = variant.d_cap, variant.p_cap, variant.a_cap
         t0, t1 = pv["t0"], pv["t1"]
-        spf_kernel = pv.get("kernel", "sync")
         mc = pv.get("mc")
         mc_info = None if mc is None else {
             "shards": mc.size,
@@ -3082,7 +2732,7 @@ class TpuSpfSolver:
                 vs.root_sig = pv["root_sig"]
             wa = -(-a_cap // 16)
             wd = -(-d_cap // 16)
-            b = stream or _DELTA_BUDGET
+            b = stream or variant.budget
             crib = vs.crib
             count = None
             trips = 0

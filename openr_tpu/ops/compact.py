@@ -3,7 +3,7 @@
 `route_ok_device` is the jnp mirror of the host predicate
 `columnar_rib.route_ok_rows`: it decides, per prefix row, whether the
 solver's packed outputs describe a programmable route. The monolithic
-pipeline (`tpu_solver._plan_pipeline`) uses it to compact the cold
+pipeline (`tpu_solver._make_pipeline`) uses it to compact the cold
 full-RIB pull down to ok rows on device; the sharded fabric kernel
 (`parallel/sharding.py`) returns it alongside the unpacked masks so
 the host skips its own O(P*A) filter pass. The two predicates MUST

@@ -1,8 +1,8 @@
 """Streaming churn-epoch device stages: on-device column diff +
 changed-rows compaction.
 
-The streaming pipeline (`tpu_solver._stream_pipeline`, jit-cache
-namespace "stream") fuses one churn epoch into a single dispatch: the
+The streaming pipeline (a `tpu_solver.PipelineVariant` with `stream`
+set, jit-cache namespace "stream") fuses one churn epoch into a single dispatch: the
 incremental bucketed relax (ops/relax.py + ops/incremental.py), the
 best-route selection / LFA tail, and the column diff against the
 PREVIOUS epoch's device-resident published planes — so the download per

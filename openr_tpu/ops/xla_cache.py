@@ -409,16 +409,18 @@ def bounded_jit_cache(max_buckets: int = 8, namespace: str = ""):
     use namespace="whatif" so a burst of interactive sweep shapes
     churns only its own LRU and can never evict a live-solve
     executable — and the counter split shows which workload is
-    compiling. The incremental-SSSP factories (tpu_solver
-    _incr_pipeline/_instrumented_incr) likewise use namespace="incr":
+    compiling. The solver's one pipeline factory (tpu_solver
+    _build_pipeline) is wrapped once per namespace its variants name
+    (PipelineVariant.namespace): incremental solves use "incr" —
     dirty-set cap churn buckets under xla_cache.incr_* and cannot
-    evict the full-solve or sweep executables, and the multichip
-    capacity-tier factories (tpu_solver _mc_pipeline and friends) use
-    namespace="multichip" for the same reason — a sharded executable
-    can never evict a single-chip one or vice versa, so a fabric that
-    oscillates around the tier threshold keeps both resident. The
-    non-int mesh object in a multichip key is a within-bucket variant,
-    exactly like a bool flag. The namespace is also
+    evict the full-solve or sweep executables — streaming epochs
+    "stream", and the multichip capacity tier "multichip" for the
+    same reason: a sharded executable can never evict a single-chip
+    one or vice versa, so a fabric that oscillates around the tier
+    threshold keeps both resident. The non-int mesh object in a
+    multichip key is a within-bucket variant, exactly like a bool
+    flag. A factory that takes a record takes it splatted, so its
+    ints stay positional. The namespace is also
     folded into the bucket signature, so two namespaces can never
     alias a capacity bucket even if they were ever pointed at a
     shared table.
@@ -1190,5 +1192,7 @@ def instrument_jit(name: str, jitted, aot_key: str | None = None):
 
     wrapper.prime = prime
     wrapper.kernel_name = name
+    # the program itself: lower()-able and jittable without installing
+    wrapper.jitted = jitted
     wrapper.is_installed = lambda: state["fn"] is not None
     return wrapper

@@ -170,7 +170,7 @@ def _sharded_fabric_fn(mesh, n_cap: int, s_cap: int, r_cap: int,
             nh_mask = jnp.any(s4[:, :, None] & on_sp[idx], axis=1)
             if lfa:
                 # rfc5286 alternates, same predicate as the single-chip
-                # pipeline (tpu_solver._plan_pipeline): neighbor slot d
+                # pipeline (tpu_solver._make_pipeline): neighbor slot d
                 # backs up prefix p iff its own distance to the selected
                 # announcers beats detouring back through this root
                 d_root = dist_d[:, root]

@@ -16,7 +16,8 @@ production LSDB of that class hits, because capacities are pow2-rounded
 should prewarm those variants too — they are distinct programs.
 
 Beyond the default full-solve executables, the solver keeps four more
-jit-cache namespaces (ops/xla_cache.py bounded_jit_cache): "incr"
+jit-cache namespaces (ops/xla_cache.py bounded_jit_cache; the pipeline's
+are chosen by tpu_solver.PipelineVariant.namespace): "incr"
 (seed-from-previous incremental SSSP), "stream" (the fused streaming
 churn epoch with the on-device column diff), "multichip" (the sharded
 capacity tier), and "whatif" (interactive sweep batches). Each is a

@@ -84,10 +84,11 @@ class TestBoundedJitCache:
         from openr_tpu.decision import tpu_solver as ts
         from openr_tpu.ops import ksp2, ucmp
 
+        assert set(ts._PIPELINE_CACHES) == {
+            "", "incr", "stream", "multichip"
+        }
         for fn in (
-            ts._jitted_pipeline, ts._jitted_sssp_batch, ts._plan_pipeline,
-            ts._fused_pipeline, ts._instrumented_pipeline,
-            ts._instrumented_fused, ts._scatter_jit,
+            *ts._PIPELINE_CACHES.values(), ts._scatter_jit,
             ksp2._base_sssp_fn, ksp2._masked_rows_fn,
             ksp2._masked_rows_delta_fn, ucmp._ucmp_fn,
         ):

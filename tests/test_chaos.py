@@ -1029,19 +1029,14 @@ class TestDeviceRetraceFlightRecorderDrill:
         import os
         import tempfile
 
-        from openr_tpu.decision import tpu_solver as ts
+        from openr_tpu.ops import xla_cache
         from openr_tpu.ops.xla_cache import retrace
 
         def _clear_factories():
             # the injection: python-level caches drop their executables
             # WITHOUT the eviction path's retrace.forget() — the next
             # dispatch re-jits a kernel the sentinel considers warm
-            for fn in (
-                ts._jitted_pipeline, ts._jitted_sssp_batch,
-                ts._plan_pipeline, ts._fused_pipeline,
-                ts._instrumented_pipeline, ts._instrumented_fused,
-                ts._scatter_jit,
-            ):
+            for fn in xla_cache._BOUNDED_CACHES:
                 fn.cache_clear()
 
         def _retraces():

@@ -1,6 +1,6 @@
 """Incremental device SSSP (ISSUE 7) — parity + fallback drills.
 
-The incremental path (ops/incremental.py, tpu_solver._incr_pipeline)
+The incremental path (ops/incremental.py, tpu_solver.pipeline_for)
 seeds each solve from the previous device-resident distance plane,
 re-anchors the subtree behind any metric increase, and re-relaxes only
 the affected cone. Its one promise is EXACT parity with a cold full

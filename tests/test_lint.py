@@ -830,7 +830,7 @@ def test_purity_and_donation_trace_stream_epoch_roots():
     """ISSUE 16: the fused streaming-epoch kernel is device code end to
     end. ops/stream.py rides the ops/ traced prefix (its column-diff +
     compaction stages are purity-analyzed), the solver module's
-    `pipeline` jit root — which _stream_pipeline wraps for the fused
+    `pipeline` jit root — which _build_pipeline jits for the fused
     epoch — is discovered, and the stream stages' function-local
     imports resolve to the traced module, so a host impurity seeded in
     either stage would flow to the root's findings. The donation
@@ -854,7 +854,7 @@ def test_purity_and_donation_trace_stream_epoch_roots():
     # (positions 9-14) through the conditional dict form — the
     # read-after-donate rule must see every position
     donated = donation_check._factory_donations(
-        g.defs["_stream_pipeline"]
+        g.defs["_build_pipeline"]
     )
     assert {9, 10, 11, 12, 13, 14} <= donated, donated
     findings = [

@@ -1,0 +1,330 @@
+"""The one seam that builds the solver's device programs (ISSUE 31):
+`tpu_solver.PipelineVariant` says which executable, `pipeline_for`
+fetches it from the one factory. What the rest of the system reads off
+that seam is pinned here — display names letter for letter (the kernel
+ledger, ctrl.tpu.kernels, the benchmark's warm-up check and the replay
+log carry them), an identity no two variants share, the jit-cache
+namespace and bucket an executable lands in, and the combinations that
+do not exist."""
+
+import itertools
+
+import jax
+import numpy as np
+import pytest
+
+from openr_tpu.decision import tpu_solver as ts
+from openr_tpu.decision.tpu_solver import PipelineVariant, pipeline_for
+from openr_tpu.ops.xla_cache import bounded_jit_cache
+from openr_tpu.runtime.counters import counters
+
+BUDGET = 4096
+# what makes each kind, beside the shape class and the flags
+KINDS = {
+    "full": {},
+    "incr": {"emit_dist": True, "dirty_cap": 64},
+    "stream": {
+        "emit_dist": True, "dirty_cap": 64, "stream": 256, "donate": True,
+    },
+    "fused": {"fused": 3},
+    "mc": {"mesh": True},
+    "mc_incr": {"emit_dist": True, "dirty_cap": 64, "mesh": True},
+}
+NAMESPACE = {
+    "full": "", "fused": "", "incr": "incr", "stream": "stream",
+    "mc": "multichip", "mc_incr": "multichip",
+}
+
+# (kind, has_res, lfa, delta_exp) -> the name the parent's six
+# _instrumented_* composed, written down before they were deleted
+GOLDEN = {
+    ("full", False, False, 0): "pipeline[n=256,s=4,d=4,p=256,a=2]",
+    ("full", False, False, 3): "pipeline[n=256,s=4,d=4,p=256,a=2,bk3]",
+    ("full", False, True, 0): "pipeline[n=256,s=4,d=4,p=256,a=2,lfa]",
+    ("full", False, True, 3): "pipeline[n=256,s=4,d=4,p=256,a=2,lfa,bk3]",
+    ("full", True, False, 0): "pipeline[n=256,s=4,d=4,p=256,a=2,res]",
+    ("full", True, False, 3): "pipeline[n=256,s=4,d=4,p=256,a=2,res,bk3]",
+    ("full", True, True, 0): "pipeline[n=256,s=4,d=4,p=256,a=2,res,lfa]",
+    ("full", True, True, 3):
+        "pipeline[n=256,s=4,d=4,p=256,a=2,res,lfa,bk3]",
+    ("incr", False, False, 0):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64]",
+    ("incr", False, False, 3):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,bk3]",
+    ("incr", False, True, 0):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,lfa]",
+    ("incr", False, True, 3):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,lfa,bk3]",
+    ("incr", True, False, 0):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res]",
+    ("incr", True, False, 3):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,bk3]",
+    ("incr", True, True, 0):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,lfa]",
+    ("incr", True, True, 3):
+        "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,lfa,bk3]",
+    ("stream", False, False, 0):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256]",
+    ("stream", False, False, 3):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,bk3]",
+    ("stream", False, True, 0):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,lfa]",
+    ("stream", False, True, 3):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,lfa,bk3]",
+    ("stream", True, False, 0):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res]",
+    ("stream", True, False, 3):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,bk3]",
+    ("stream", True, True, 0):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,lfa]",
+    ("stream", True, True, 3):
+        "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,lfa,bk3]",
+    ("fused", False, False, 0):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2]",
+    ("fused", False, False, 3):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,bk3]",
+    ("fused", False, True, 0):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,lfa]",
+    ("fused", False, True, 3):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,lfa,bk3]",
+    ("fused", True, False, 0):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res]",
+    ("fused", True, False, 3):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,bk3]",
+    ("fused", True, True, 0):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,lfa]",
+    ("fused", True, True, 3):
+        "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,lfa,bk3]",
+    ("mc", False, False, 0):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2]",
+    ("mc", False, False, 3):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,bk3]",
+    ("mc", False, True, 0):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,lfa]",
+    ("mc", False, True, 3):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,lfa,bk3]",
+    ("mc", True, False, 0):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res]",
+    ("mc", True, False, 3):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,bk3]",
+    ("mc", True, True, 0):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,lfa]",
+    ("mc", True, True, 3):
+        "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,lfa,bk3]",
+    ("mc_incr", False, False, 0):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2]",
+    ("mc_incr", False, False, 3):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,bk3]",
+    ("mc_incr", False, True, 0):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,lfa]",
+    ("mc_incr", False, True, 3):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,lfa,bk3]",
+    ("mc_incr", True, False, 0):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res]",
+    ("mc_incr", True, False, 3):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,bk3]",
+    ("mc_incr", True, True, 0):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa]",
+    ("mc_incr", True, True, 3):
+        "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa,bk3]",
+}
+FLAGS = list(itertools.product((False, True), (False, True), (0, 3)))
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("batch", "graph"))
+
+
+def variant(kind, mesh, has_res=True, lfa=False, delta_exp=0, n_cap=256,
+            **over) -> PipelineVariant:
+    what = dict(KINDS[kind])
+    if what.get("mesh"):
+        what["mesh"] = mesh
+    what.update(over)
+    what.setdefault("budget", BUDGET)
+    return PipelineVariant.checked(
+        n_cap, 4, 8, 4, has_res, 4, 256, 2, lfa=lfa,
+        delta_exp=delta_exp, **what,
+    )
+
+
+def _count(key: str) -> float:
+    return counters.get_counter(key) or 0
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_display_names_are_the_parents(kind, mesh):
+    for has_res, lfa, delta_exp in FLAGS:
+        v = variant(kind, mesh, has_res, lfa, delta_exp)
+        assert v.name == GOLDEN[kind, has_res, lfa, delta_exp]
+        assert v.kernel == ("bucketed" if delta_exp else "sync")
+    # what the name leaves out is still part of the identity
+    assert v._replace(block_v4=True).name == v.name
+    assert v._replace(block_v4=True).aot_key != v.aot_key
+
+
+def test_aot_keys_are_distinct_and_kinds_name_their_namespace(mesh):
+    records = [
+        variant(kind, mesh, *flags) for kind in KINDS for flags in FLAGS
+    ]
+    base = records[0]
+    # every field that the display name omits, and the variant fields
+    records += [
+        base._replace(r_cap=16), base._replace(kr_cap=8),
+        base._replace(budget=64), base._replace(block_v4=True),
+        base._replace(sentinels=False), base._replace(emit_dist=True),
+        variant("stream", mesh, donate=False),
+        variant("stream", mesh, stream=64),
+        variant("incr", mesh, dirty_cap=256),
+        variant("fused", mesh, fused=2),
+    ]
+    assert len(set(records)) == len(records)
+    keys = {r.aot_key for r in records}
+    assert len(keys) == len(records)
+    # a key is text: the mesh rides as its tag, never as a device list
+    assert all("mesh='2x2'" in r.aot_key for r in records if r.mesh)
+    for kind in KINDS:
+        v = variant(kind, mesh)
+        assert v.namespace == NAMESPACE[kind], kind
+        assert v.incr == ("dirty_cap" in KINDS[kind])
+    assert set(ts._PIPELINE_CACHES) == set(NAMESPACE.values())
+
+
+def test_two_shape_classes_occupy_two_buckets(mesh):
+    """bounded_jit_cache reads the capacity signature off the key's
+    positional ints: the record goes in splatted, so a shape class is a
+    bucket and a flag flip is a variant within it."""
+    cache = bounded_jit_cache(max_buckets=2, namespace="variants_test")(
+        ts._build_pipeline
+    )
+    evictions = "xla_cache.variants_test_executable_evictions"
+    e0 = _count(evictions)
+    for n_cap in (64, 128):
+        for lfa in (False, True):
+            cache(*variant("full", mesh, lfa=lfa, n_cap=n_cap))
+    assert _count(evictions) == e0  # two buckets, two variants each
+    cache(*variant("full", mesh, n_cap=512))
+    # the third class drops the oldest bucket, with both its variants
+    assert _count(evictions) == e0 + 2
+    # dirty_cap, stream, fused and budget are capacity ints as well
+    h0 = _count("xla_cache.variants_test_factory_hits")
+    for other in (
+        variant("incr", mesh, n_cap=512),
+        variant("fused", mesh, n_cap=512),
+        variant("full", mesh, n_cap=512, budget=64),
+    ):
+        cache(*other)
+    assert _count(evictions) == e0 + 2 + 2 + 1 + 1
+    assert _count("xla_cache.variants_test_factory_hits") == h0
+
+
+BAD = {
+    "stream_without_incremental": dict(stream=256),
+    "stream_on_a_mesh": dict(
+        stream=256, dirty_cap=64, emit_dist=True, mesh=True
+    ),
+    "fused_incremental": dict(fused=2, dirty_cap=64, emit_dist=True),
+    "fused_on_a_mesh": dict(fused=2, mesh=True),
+    "incremental_without_the_plane": dict(dirty_cap=64),
+    "donating_full_solve": dict(donate=True),
+    "donating_incremental": dict(
+        donate=True, dirty_cap=64, emit_dist=True
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAD))
+def test_combinations_that_do_not_exist_are_refused(case, mesh):
+    what = dict(BAD[case])
+    if what.get("mesh"):
+        what["mesh"] = mesh
+    with pytest.raises(ValueError):
+        PipelineVariant.checked(256, 4, 8, 4, True, 4, 256, 2, BUDGET, **what)
+    # the factory checks too: a raw record cannot smuggle one in
+    raw = PipelineVariant(256, 4, 8, 4, True, 4, 256, 2, BUDGET, **what)
+    with pytest.raises(ValueError):
+        pipeline_for(raw)
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_one_executable_per_record(kind, mesh):
+    """Fetching a record twice is one miss and one hit in its kind's
+    namespace, and the same callable (the parent cached the jitted
+    program and its instrumented wrapper apiece: two misses)."""
+    v = variant(kind, mesh, n_cap=1 << 20)  # a class no other test has
+    ns = NAMESPACE[kind]
+    prefix = f"xla_cache.{ns}_" if ns else "xla_cache."
+    m0, h0 = _count(prefix + "factory_misses"), _count(prefix + "factory_hits")
+    name, run = pipeline_for(v)
+    assert name == v.name == run.kernel_name
+    assert _count(prefix + "factory_misses") == m0 + 1
+    assert _count(prefix + "factory_hits") == h0
+    again = pipeline_for(PipelineVariant(*v))
+    assert again[1] is run
+    assert _count(prefix + "factory_misses") == m0 + 1
+    assert _count(prefix + "factory_hits") == h0 + 1
+    assert not run.is_installed()  # nothing compiled: the jit is lazy
+
+
+def _avals(v: PipelineVariant) -> tuple:
+    avals = ts._pipeline_avals(v.shape_key)
+    if v.incr:
+        S = jax.ShapeDtypeStruct
+        dirty = S((v.dirty_cap,), np.int32)
+        avals += (
+            S((v.d_cap, v.n_cap), np.int32), dirty, dirty, dirty, dirty,
+            S((), np.int32),
+        )
+    return avals
+
+
+def test_budget_alone_makes_another_executable(mesh):
+    """tests/test_convergence_trace.py shrinks tpu_solver._DELTA_BUDGET
+    mid-test to force a full pull: that works because the budget is part
+    of an executable's identity, not read inside the traced program."""
+    big = variant("full", mesh)
+    small = big._replace(budget=64)
+    assert big.name == small.name and big.aot_key != small.aot_key
+    run_big, run_small = pipeline_for(big)[1], pipeline_for(small)[1]
+    assert run_big is not run_small
+    delta = [
+        jax.eval_shape(r.jitted, *_avals(big))[0].shape[0]
+        for r in (run_big, run_small)
+    ]
+    wa = wd = 1
+    assert delta[0] - delta[1] == (BUDGET - 64) * (2 + wa + wd)
+
+
+def test_jit_options_follow_from_the_record(mesh):
+    def text(v):
+        return pipeline_for(v)[1].jitted.lower(*_avals(v)).as_text()
+
+    donating = text(variant("stream", mesh))
+    assert "jit_pipeline" in donating  # the HLO module's name
+    assert donating.count("jax.buffer_donor") + donating.count(
+        "tf.aliasing_output"
+    ) == 6
+    kept = text(variant("stream", mesh, donate=False))
+    assert "jax.buffer_donor" not in kept and "tf.aliasing_output" not in kept
+    sharded = text(variant("mc_incr", mesh))
+    assert "mhlo.sharding" in sharded or "sdy.sharding" in sharded
+    fused = variant("fused", mesh)
+    lowered = pipeline_for(fused)[1].jitted.lower(
+        *((a,) * fused.fused for a in _avals(fused))
+    )
+    assert "jit_fused" in lowered.as_text()
+    assert len(lowered.out_info) == fused.fused
+
+
+def test_a_variant_moves_to_the_next_shape_class(mesh):
+    v = variant("full", mesh, lfa=True, delta_exp=2, emit_dist=True)
+    nxt = ts._next_shape_key(v.shape_key)
+    up = v.at(nxt, mesh)
+    assert up.shape_key == nxt and up.mesh is mesh
+    assert up[8:-1] == v[8:-1]
+    assert up.namespace == "multichip" and v.namespace == ""
+    with pytest.raises(ValueError):
+        variant("fused", mesh).at(nxt, mesh)

@@ -9,9 +9,9 @@ is then lowered with ShapeDtypeStructs placed on a described v5e:2x2
 device and compiled by the TPU compiler installed here. What that
 compiler refuses — a lowering it has no rule for, a donation it cannot
 alias, a program that does not fit 16 GB — fails here, at no chip time.
-The multichip variant is rebuilt by its own factory on a 4-device Mesh of
-the described devices (its closure binds the mesh), and its text must
-show the cross-device min.
+The multichip variants are rebuilt by the one pipeline factory from their
+variant records, on a 4-device Mesh of the described devices (the closure
+binds the mesh), and their text must show the cross-device min.
 
 Runs at the n_cap 4096 capacity class (2-3 s per program). The real
 class, n_cap 131072, takes ~85 s per program: CHANGES.md PR 23 records
@@ -50,8 +50,8 @@ def _aval(x):
 
 class Capture:
     """Wraps the solver's program factories; `programs` maps a label to
-    (jitted callable, avals of its first call), `mesh_programs` a label
-    to (factory, factory args after the mesh, avals)."""
+    (jitted callable, avals of its first call), `mesh_programs` a kind
+    ("full", "incremental") to (variant record, avals)."""
 
     SINGLE_CHIP_FACTORIES = (
         (ts, "_scatter_jit"),
@@ -59,24 +59,21 @@ class Capture:
         (ksp2_ops, "_masked_rows_fn"),
         (ksp2_ops, "_masked_rows_delta_fn"),
     )
-    MESH_FACTORIES = ((ts, "_mc_pipeline"), (ts, "_mc_incr_pipeline"))
 
     def __init__(self):
         self.programs: dict = {}
         self.mesh_programs: dict = {}
         self._undo: list = []
         self._real_instrument_jit = xla_cache.instrument_jit
+        self._real_pipeline_for = ts.pipeline_for
 
     def __enter__(self):
         # the factories memoize their (already wrapped) results
         xla_cache.clear_all_jit_caches()
         self._patch(xla_cache, "instrument_jit", self._instrument_jit)
+        self._patch(ts, "pipeline_for", self._pipeline_for)
         for mod, name in self.SINGLE_CHIP_FACTORIES:
             self._patch(mod, name, self._factory(name, getattr(mod, name)))
-        for mod, name in self.MESH_FACTORIES:
-            self._patch(
-                mod, name, self._factory(name, getattr(mod, name), True)
-            )
         return self
 
     def __exit__(self, *exc):
@@ -90,8 +87,8 @@ class Capture:
 
     def _instrument_jit(self, name, jitted, aot_key=None):
         run = self._real_instrument_jit(name, jitted, aot_key=aot_key)
-        if isinstance(jitted, _MeshProgram):
-            return run  # recorded by its factory, with the mesh
+        if name.startswith("pipeline_mc"):
+            return run  # recorded by _pipeline_for, with its record
 
         def wrapper(*args, **kwargs):
             self.programs.setdefault(
@@ -102,11 +99,25 @@ class Capture:
         wrapper.__dict__.update(run.__dict__)
         return wrapper
 
-    def _factory(self, label, factory, mesh: bool = False):
+    def _pipeline_for(self, variant):
+        """A mesh-bound pipeline is recorded as its variant record, so
+        the one factory can rebuild it on a mesh of described devices."""
+        name, run = self._real_pipeline_for(variant)
+        if variant.mesh is None:
+            return name, run
+
+        def call(*args):
+            self.mesh_programs.setdefault(
+                "incremental" if variant.incr else "full",
+                (variant, jax.tree.map(_aval, args)),
+            )
+            return run(*args)
+
+        return name, call
+
+    def _factory(self, label, factory):
         def wrapped(*fargs):
             jitted = factory(*fargs)
-            if mesh:
-                return _MeshProgram(self, label, factory, fargs, jitted)
 
             def call(*args):
                 self.programs.setdefault(
@@ -117,23 +128,6 @@ class Capture:
             return call
 
         return wrapped
-
-
-class _MeshProgram:
-    """A mesh-bound jitted pipeline: records its factory arguments at
-    lower() (instrument_jit's compile), so the same factory can rebuild
-    it on a mesh of described devices."""
-
-    def __init__(self, capture, label, factory, fargs, jitted):
-        self._c, self._label, self._factory = capture, label, factory
-        self._fargs, self._jitted = fargs, jitted
-
-    def lower(self, *args, **kwargs):
-        self._c.mesh_programs.setdefault(
-            self._label,
-            (self._factory, self._fargs[1:], jax.tree.map(_aval, args)),
-        )
-        return self._jitted.lower(*args, **kwargs)
 
 
 # -- scenarios: what a user's solves build, per variant --------------------
@@ -202,9 +196,12 @@ def compile_single(one_chip, jitted, avals):
     return jitted.lower(*args).compile()
 
 
-def compile_mesh(mesh, factory, fargs, avals):
+def compile_mesh(mesh, variant, avals):
+    # straight through the factory, past its caches: a mesh of described
+    # devices must not stay behind as a live executable's key.
     # in_shardings are pinned by the factory: bare shapes suffice
-    return factory(mesh, *fargs).lower(*avals).compile()
+    _name, run = ts._build_pipeline(*variant._replace(mesh=mesh))
+    return run.jitted.lower(*avals).compile()
 
 
 @pytest.fixture(scope="module")
@@ -280,7 +277,7 @@ def test_program_compiles_for_a_described_v5e(captured, one_chip, variant):
             assert "input_output_alias" in compiled.as_text(), label
 
 
-@pytest.mark.parametrize("variant", ["_mc_pipeline", "_mc_incr_pipeline"])
+@pytest.mark.parametrize("variant", ["full", "incremental"])
 def test_multichip_program_compiles_on_a_described_mesh(
     captured, topo, variant
 ):
@@ -288,8 +285,9 @@ def test_multichip_program_compiles_on_a_described_mesh(
 
     assert len(topo.devices) == 4
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "graph"))
-    factory, fargs, avals = captured.mesh_programs[variant]
-    compiled = compile_mesh(mesh, factory, fargs, avals)
+    record, avals = captured.mesh_programs[variant]
+    assert record.incr == (variant == "incremental")
+    compiled = compile_mesh(mesh, record, avals)
     # the 'graph' axis shards the weight state: each relaxation round
     # ends in a cross-device min (the pmin of parallel/sharding.py)
     text = compiled.as_text()

@@ -4,17 +4,20 @@ prefixState); their full RIBs must match exactly on every topology
 generator, including drained nodes, anycast selection, metric churn, and
 link flaps. Runs on the virtual-CPU JAX platform (conftest)."""
 
+import functools
 import zlib
 
+import jax
 import numpy as np
 import pytest
 
 from openr_tpu.decision.link_state import LinkState
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.spf_solver import SpfSolver
-from openr_tpu.decision.tpu_solver import TpuSpfSolver, sssp_all_pairs
+from openr_tpu.decision.tpu_solver import TpuSpfSolver, _plan_sssp
 from openr_tpu.models import topologies
-from openr_tpu.ops.csr import INF32, build_ell
+from openr_tpu.ops import relax as relax_ops
+from openr_tpu.ops.edgeplan import INF32E, build_plan
 from openr_tpu.types import (
     Adjacency,
     AdjacencyDatabase,
@@ -48,15 +51,37 @@ def run_both(my_node, states, ps, **kw):
 # -- SSSP kernel against Dijkstra ------------------------------------------
 
 def sssp_vs_dijkstra(link_state, sample_roots=None):
-    graph = build_ell(link_state)
-    roots = sample_roots or graph.node_names
-    root_idx = np.array([graph.node_index[r] for r in roots], np.int32)
-    dist = np.asarray(sssp_all_pairs(graph, root_idx))
-    for ri, root in enumerate(roots):
+    """The production SSSP (the shift-decomposed mirror of build_plan
+    under _plan_sssp, with the round loop the plan's Δ selects) against
+    LinkState.run_spf: the [D, N] plane from the root's out-slot
+    neighbours in G-minus-root, folded to one distance per node as the
+    pipeline's `select` stage folds it."""
+    plan = build_plan(link_state)
+    kernel = "bucketed" if plan.delta_exp > 0 else "sync"
+
+    @functools.lru_cache(None)
+    def sssp_for(d_cap):
+        return jax.jit(functools.partial(
+            _plan_sssp, s_cap=plan.s_cap, has_res=plan.k_res > 0,
+            n_cap=plan.n_cap, d_cap=d_cap,
+            max_trips=relax_ops.max_trips(plan.n_cap), kernel=kernel,
+            delta_exp=plan.delta_exp,
+        ))
+
+    for root in sample_roots or plan.node_names:
+        root_idx = plan.node_index[root]
+        root_nbr, root_w, _ = plan.out_links(link_state, root)
+        dist_d, _, _ = sssp_for(root_nbr.shape[0])(
+            plan.deltas, plan.shift_w, plan.res_rows, plan.res_nbr,
+            plan.res_w, np.int32(root_idx), root_nbr, root_w,
+        )
+        via = root_w[:, None] + np.asarray(dist_d)
+        dist = np.minimum(via.min(axis=0), INF32E)
+        dist[root_idx] = 0
         spf = link_state.run_spf(root)
-        for name in graph.node_names:
-            expect = spf[name].metric if name in spf else int(INF32)
-            got = int(dist[ri, graph.node_index[name]])
+        for name in plan.node_names:
+            expect = spf[name].metric if name in spf else int(INF32E)
+            got = int(dist[plan.node_index[name]])
             assert got == expect, (root, name, got, expect)
 
 
