@@ -488,11 +488,23 @@ class Decision(Actor):
                 kvs.append((key, value, suppressed))
             with tracer.span(
                 ctx, "decision.lsdb_apply.decode", parent_id=parent
-            ):
+            ) as decode_sp:
                 decoded = [
                     None if suppressed else self._decode_key(key, value.value)
                     for key, value, suppressed in kvs
                 ]
+                if decode_sp is not None:
+                    # what the decode was given and what it made, so a
+                    # trace says what it cost per adjacency
+                    given = [v.value for _, v, held in kvs if not held]
+                    decode_sp.set(
+                        keys=len(given),
+                        bytes=sum(map(len, given)),
+                        adjacencies=sum(
+                            len(db.adjacencies) for db in decoded
+                            if isinstance(db, AdjacencyDatabase)
+                        ),
+                    )
             filter0, link0 = self._filter_s, self._link_state_s
             with tracer.span(
                 ctx, "decision.lsdb_apply.update", parent_id=parent

@@ -13,9 +13,12 @@ from __future__ import annotations
 import dataclasses
 import enum
 import json
+import threading
 import typing
 from collections.abc import Mapping as _Mapping
-from typing import Any, Optional, Type, TypeVar, Union
+from typing import Any, Callable, Optional, Type, TypeVar, Union
+
+from openr_tpu.runtime.counters import counters
 
 T = TypeVar("T")
 
@@ -92,47 +95,190 @@ def _strip_optional(tp: Any) -> Any:
     return tp
 
 
+# -- decoding ------------------------------------------------------------
+#
+# A decoder is a callable plain -> object, compiled once per annotation and
+# cached: everything the annotation says (optional-ness, origin and
+# arguments, a dataclass's fields and their hints, enum-ness) is resolved
+# when the decoder is built, none of it per value. Composite decoders are
+# generated source, the way dataclasses generates __init__, so a field or
+# an element whose value already has the annotated type costs one type
+# test and no call.
+
+_DECODERS: dict[Any, Callable[[Any], Any]] = {}
+# decoders of the build in progress, published together when the outermost
+# build is done: no other thread meets a half-built one
+_STAGED: dict[Any, Callable[[Any], Any]] = {}
+_BUILD_LOCK = threading.RLock()
+
+
+def decoder_for(tp: Any) -> Callable[[Any], Any]:
+    """The decoder of annotation `tp`: built on first use, then cached."""
+    dec = _DECODERS.get(tp)
+    if dec is not None:
+        return dec
+    with _BUILD_LOCK:
+        dec = _DECODERS.get(tp) or _STAGED.get(tp)
+        if dec is not None:
+            return dec
+        outermost = not _STAGED
+        try:
+            dec = _STAGED[tp] = _build_decoder(tp)
+            if outermost:
+                _DECODERS.update(_STAGED)
+                counters.set_counter("serde.decoders_built", len(_DECODERS))
+        finally:
+            if outermost:
+                _STAGED.clear()
+        return dec
+
+
+def _bytes_of(value: dict) -> bytes:
+    return bytes.fromhex(value["__bytes__"])
+
+
+def _decode_unresolved(value: Any) -> Any:
+    return value
+
+
+def _decode_bytes(value: Any) -> Any:
+    return _bytes_of(value) if isinstance(value, dict) else value
+
+
+def _decode_untyped(value: Any) -> Any:
+    if isinstance(value, dict) and "__bytes__" in value:
+        return _bytes_of(value)
+    return value
+
+
+def _converting_decoder(convert: Callable[[Any], Any]) -> Callable[[Any], Any]:
+    """int, float, str, bool and enums: the type called on the value."""
+
+    def decode(value: Any) -> Any:
+        if value is None:
+            return None
+        if isinstance(value, dict) and "__bytes__" in value:
+            return _bytes_of(value)
+        return convert(value)
+
+    return decode
+
+
+_SCALARS = (int, float, str, bool)
+
+
+class _Source:
+    """One generated decoder: its namespace, and the expressions that
+    decode a part of the value in it."""
+
+    def __init__(self) -> None:
+        self.namespace: dict[str, Any] = {"_bytes_of": _bytes_of}
+
+    def bind(self, obj: Any) -> str:
+        name = f"_{len(self.namespace)}"
+        self.namespace[name] = obj
+        return name
+
+    def expr(self, var: str, tp: Any) -> str:
+        """Source that decodes the value named `var` per `tp`, without a
+        call where the value needs no conversion."""
+        tp = _strip_optional(tp)
+        dec = decoder_for(tp)
+        if dec is _decode_unresolved:
+            return var
+        call = f"{self.bind(dec)}({var})"
+        if tp in _SCALARS:
+            return (
+                f"({var} if type({var}) is {tp.__name__} or {var} is None"
+                f" else {call})"
+            )
+        if dec is _decode_untyped or dec is _decode_bytes:
+            return f"({call} if isinstance({var}, dict) else {var})"
+        return call
+
+    def compile(self, label: str, body: str) -> Callable[[Any], Any]:
+        """The decoder whose body, after None has passed through, is
+        `body`. The source is derived from the annotations alone, never
+        from a value."""
+        source = (
+            "def decode(value):\n"
+            "    if value is None:\n"
+            "        return None\n"
+            f"{body}"
+        )
+        exec(compile(source, f"<serde {label}>", "exec"), self.namespace)
+        return self.namespace["decode"]
+
+
+def _build_decoder(tp: Any) -> Callable[[Any], Any]:
+    """Compile annotation `tp`. None decodes to None whatever the type; a
+    {"__bytes__": hex} value decodes to bytes under every annotation but a
+    container's."""
+    inner = _strip_optional(tp)
+    if inner is not tp:
+        return decoder_for(inner)
+    if isinstance(tp, str):  # unresolved forward ref; leave as-is
+        return _decode_unresolved
+    origin = typing.get_origin(tp)
+    args = typing.get_args(tp)
+    src = _Source()
+    if origin in (list, set, frozenset):
+        (elem_tp,) = args or (Any,)
+        seq = f"[{src.expr('v', elem_tp)} for v in value]"
+        if origin is not list:
+            seq = f"{origin.__name__}({seq})"
+        return src.compile(repr(tp), f"    return {seq}\n")
+    if origin is tuple:
+        if len(args) == 2 and args[1] is Ellipsis:
+            seq = f"[{src.expr('v', args[0])} for v in value]"
+        else:
+            decs = src.bind([decoder_for(a) for a in args])
+            seq = f"[d(v) for v, d in zip(value, {decs})]"
+        return src.compile(repr(tp), f"    return tuple({seq})\n")
+    if origin is dict:
+        kt, vt = args or (Any, Any)
+        key = "int(k)" if kt is int else "k"
+        return src.compile(
+            repr(tp),
+            f"    return {{{key}: {src.expr('v', vt)}"
+            " for k, v in value.items()}\n",
+        )
+    if tp is bytes:
+        return _decode_bytes
+    if isinstance(tp, type) and issubclass(tp, enum.Enum):
+        return _converting_decoder(tp)
+    if dataclasses.is_dataclass(tp):
+        return _dataclass_decoder(tp, src)
+    if tp in _SCALARS:
+        return _converting_decoder(tp)
+    return _decode_untyped
+
+
+def _dataclass_decoder(cls: type, src: _Source) -> Callable[[Any], Any]:
+    """Unknown fields are ignored and missing ones take the dataclass
+    default (forward compat); the constructor is called, so __post_init__
+    runs."""
+    # a class that refers to itself finds this while its fields compile
+    _STAGED[cls] = lambda value: _DECODERS[cls](value)
+    hints = _type_hints(cls)
+    body = [
+        '    if isinstance(value, dict) and "__bytes__" in value:\n',
+        "        return _bytes_of(value)\n",
+        "    kwargs = {}\n",
+    ]
+    for f in dataclasses.fields(cls):
+        body.append(
+            f"    if {f.name!r} in value:\n"
+            f"        v = value[{f.name!r}]\n"
+            f"        kwargs[{f.name!r}] = {src.expr('v', hints[f.name])}\n"
+        )
+    body.append(f"    return {src.bind(cls)}(**kwargs)\n")
+    return src.compile(cls.__qualname__, "".join(body))
+
+
 def from_plain(value: Any, tp: Any) -> Any:
     """Plain value -> typed object per annotation `tp`."""
-    if value is None:
-        return None
-    tp = _strip_optional(tp)
-    if isinstance(tp, str):  # unresolved forward ref; leave as-is
-        return value
-    origin = typing.get_origin(tp)
-    if origin in (list, set, frozenset):
-        (elem_tp,) = typing.get_args(tp) or (Any,)
-        seq = [from_plain(v, elem_tp) for v in value]
-        return origin(seq) if origin is not list else seq
-    if origin is tuple:
-        args = typing.get_args(tp)
-        if len(args) == 2 and args[1] is Ellipsis:
-            return tuple(from_plain(v, args[0]) for v in value)
-        return tuple(from_plain(v, a) for v, a in zip(value, args))
-    if origin is dict:
-        kt, vt = typing.get_args(tp) or (Any, Any)
-        out = {}
-        for k, v in value.items():
-            key = int(k) if kt is int else k
-            out[key] = from_plain(v, vt)
-        return out
-    if tp is bytes or (isinstance(value, dict) and "__bytes__" in value):
-        if isinstance(value, dict):
-            return bytes.fromhex(value["__bytes__"])
-        return value
-    if isinstance(tp, type) and issubclass(tp, enum.Enum):
-        return tp(value)
-    if dataclasses.is_dataclass(tp):
-        hints = _type_hints(tp)
-        kwargs = {}
-        for f in dataclasses.fields(tp):
-            if f.name in value:
-                kwargs[f.name] = from_plain(value[f.name], hints[f.name])
-            # missing fields fall back to dataclass defaults (forward compat)
-        return tp(**kwargs)
-    if tp in (int, float, str, bool):
-        return tp(value)
-    return value
+    return decoder_for(tp)(value)
 
 
 def serialize(obj: Any) -> bytes:

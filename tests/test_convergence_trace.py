@@ -176,6 +176,10 @@ def test_closed_trace_holds_every_layer_split(topology, kind):
     assert wait["rounds"] == timing["rounds"] and wait["relax_bytes"] > 0
     update = one["decision.lsdb_apply.update"]["attributes"]
     assert update["keys"] >= 1
+    decode = one["decision.lsdb_apply.decode"]["attributes"]
+    assert 1 <= decode["keys"] <= update["keys"]
+    # an adjacency on the wire is a few hundred bytes, a database more
+    assert decode["bytes"] > 100 * decode["adjacencies"] >= 100
     assert 0 <= update["link_state_ms"] <= (
         one["decision.lsdb_apply.update"]["duration_ms"]
     )
@@ -241,6 +245,36 @@ async def test_suppressed_key_is_not_decoded_and_replay_order_holds(
         ]
         dbs = h.decision.area_link_states[AREA].get_adjacency_databases()
         assert dbs["2"].adjacencies[0].metric != 77  # held, not applied
+
+
+@run_async
+async def test_decode_span_says_what_it_decoded():
+    """keys, bytes and adjacencies on decision.lsdb_apply.decode: the
+    values handed to the decoder and what came out, so a trace gives the
+    cost per adjacency."""
+    async with DecisionHarness() as h:
+        key_1, val_1 = adj_db_kv("1", [adj("1", "2"), adj("1", "3")])
+        key_2, val_2 = adj_db_kv("2", [adj("2", "1")])
+        key_p, val_p = prefix_db_kv("2", "10.0.0.2/32")
+        pub = Publication(
+            key_vals={key_1: val_1, key_p: val_p, key_2: val_2}, area=AREA
+        )
+        ctx = tracer.start_trace("convergence")
+        tracer.attach(pub, ctx)
+        h.decision.process_publication(pub)
+        (trace,) = tracer.get_traces(
+            trace_id=ctx.trace_id, include_active=True
+        )
+        (decode,) = [
+            s for s in trace["spans"]
+            if s["name"] == "decision.lsdb_apply.decode"
+        ]
+        assert decode["attributes"] == {
+            "keys": 3,
+            "bytes": sum(len(v.value) for v in (val_1, val_p, val_2)),
+            "adjacencies": 3,
+        }
+        tracer.end_trace(ctx, status="ignored")
 
 
 def test_scope_reducer_gives_each_instant_to_the_innermost_scope():
