@@ -2311,6 +2311,11 @@ class TpuSpfSolver:
                 root_nbr = pad_to(root_nbr, d_pad, -1)
                 root_w = pad_to(root_w, d_pad, INF_E)
                 d_cap = d_pad
+        # one lane of the [d_cap, n_cap] distance plane a link of the
+        # vantage; the rest of d_cap is padding every pass still pays for
+        lanes = {"spf_sources": len(links), "spf_lanes": d_cap}
+        for key, value in lanes.items():
+            counters.set_counter(f"decision.tpu.{key}", value)
         p_cap, a_cap = matrix.ann_node.shape
         r_cap, kr_cap = plan.res_nbr.shape
         has_res = plan.k_res > 0
@@ -2423,6 +2428,7 @@ class TpuSpfSolver:
             "mc": mc, "incr": incr, "root_sig": root_sig,
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
+            "lanes": lanes,
         }
 
     def _lane_args(self, pv: dict) -> tuple:
@@ -2935,6 +2941,7 @@ class TpuSpfSolver:
             (plan0, plan1, up0, up1, up_bytes, dirty_slots,
              mirror) = pv["sync_marks"]
             stats.update(mirror)
+            stats.update(pv["lanes"])
             return {
                 "view": crib.view(),
                 "stats": stats,
@@ -2945,7 +2952,7 @@ class TpuSpfSolver:
                     "mat_ms": (t3 - t2) * 1e3,
                 },
                 "spans": [
-                    ("tpu.sync", None, t0, t1, {}),
+                    ("tpu.sync", None, t0, t1, pv["lanes"]),
                     ("tpu.sync.plan", "tpu.sync", plan0, plan1, mirror),
                     ("tpu.sync.upload", "tpu.sync", up0, up1, {
                         "bytes_uploaded": up_bytes,
@@ -2953,6 +2960,7 @@ class TpuSpfSolver:
                     }),
                     ("tpu.dispatch", None, t1, t_disp, {
                         "kernel": kernel_name, "incremental": incr,
+                        "lanes": d_cap,
                     }),
                     ("tpu.device_wait", None, t_disp, t_ready, {
                         "rounds": rounds, "relax_bytes": relax_bytes,

@@ -6,6 +6,7 @@ whose mirrors are all residual.
     chiprun -- python3 tools/residual_width.py fabric10k  # one
     chiprun -- python3 tools/residual_width.py fabric10k:16  # from width 16 down
     JAX_PLATFORMS=cpu python3 tools/residual_width.py fabric-small  # rehearsal
+    chiprun -- python3 tools/residual_width.py --lanes 4,8,16,32,64 wan50k
 
 For every power-of-two width from 2 to the widest destination's it
 rebuilds the mirror at that width (by replacing the builder's own choice,
@@ -25,6 +26,16 @@ rebuilds the mirror at that width (by replacing the builder's own choice,
 
 The last lines fit `loop` to `a * r_cap * (K + c)` by least squares: `c`
 is `_ROW_COST`. Needs a TPU: a CPU's gather costs nothing like the chip's.
+
+With `--lanes` the widths are left alone (the mirror is built as the
+builder builds it) and the lanes of the distance plane vary instead: seen
+from the deployment's widest router (an aggregation router of the WAN, a
+fabric switch), the first n of its links hold a lane each, for every n of
+the list. A line gives `loop` and `gather` as above and `parent`, ms a
+call of `seed.parent` (`ops/incremental._parent_plane`) over the plane the
+relaxation converges to from those links; the last line is the solver's
+own capture from that router with all its links (`scope`, with the whole
+`by_scope` an event).
 """
 
 from __future__ import annotations
@@ -45,30 +56,31 @@ from openr_tpu.ops import edgeplan  # noqa: E402
 from openr_tpu.ops import relax as relax_ops  # noqa: E402
 
 CONFIGS = {
-    # generator, vantage; the -small ones rehearse the script on a CPU
+    # generator, vantage, the wide vantage of --lanes; the -small ones
+    # rehearse the script on a CPU
     "fabric-small": (
         lambda: topologies.fabric(
             pods=12, planes=2, ssws_per_plane=3, rsws_per_pod=6
         ),
-        "pod000-rsw00",
+        "pod000-rsw00", "pod000-fsw00",
     ),
     "wan-small": (
         lambda: topologies.wan_rtt(
             regions=4, cores=2, aggs=6, access=52, seed=7
         ),
-        "r01-acc0000",
+        "r01-acc0000", "r02-agg03",
     ),
     "fabric10k": (
         lambda: topologies.fabric(
             pods=173, planes=8, ssws_per_plane=36, rsws_per_pod=48
         ),
-        "pod000-rsw00",
+        "pod000-rsw00", "pod000-fsw00",
     ),
     "wan50k": (
         lambda: topologies.wan_rtt(
             regions=50, cores=4, aggs=64, access=932, seed=7
         ),
-        "r25-acc0000",
+        "r25-acc0000", "r25-agg20",
     ),
 }
 CONFIGS_ON_CHIP = ("fabric10k", "wan50k")
@@ -90,6 +102,18 @@ def _forced_width(width: int):
         yield
     finally:
         edgeplan._residual_width = chosen
+
+
+def _median_ms(fn, arg) -> float:
+    """ms per call of a jitted function, by the host's clock round
+    `block_until_ready`: the median of REPS calls after one that compiles."""
+    fn(arg).block_until_ready()
+    times = []
+    for _ in range(REPS):
+        t0 = time.monotonic()
+        fn(arg).block_until_ready()
+        times.append((time.monotonic() - t0) * 1e3)
+    return float(np.median(times))
 
 
 def _loop_ms(plan, root: int, d_cap: int, layout: str) -> float:
@@ -126,13 +150,76 @@ def _loop_ms(plan, root: int, d_cap: int, layout: str) -> float:
 
     dist0 = jnp.full((d_cap, n_cap), inf, jnp.int32)
     dist0 = dist0.at[:, root].set(0)
-    loop(dist0).block_until_ready()
-    times = []
-    for _ in range(REPS):
-        t0 = time.monotonic()
-        loop(dist0).block_until_ready()
-        times.append((time.monotonic() - t0) * 1e3 / PASSES)
-    return float(np.median(times))
+    return _median_ms(loop, dist0) / PASSES
+
+
+def _parent_ms(plan, root: int, seeds: np.ndarray, d_cap: int) -> float:
+    """ms per call of `seed.parent` at `d_cap` lanes, over the plane the
+    relaxation converges to from `seeds` (one lane each, the rest of the
+    lanes padding) in the graph without `root`."""
+    import jax
+    import jax.numpy as jnp
+
+    from openr_tpu.ops.incremental import _parent_plane
+
+    n_cap = plan.n_cap
+    inf = relax_ops.INF_E
+    has_res = plan.k_res > 0
+    deltas = jnp.asarray(plan.deltas)
+    swm = jnp.asarray(plan.shift_w).at[:, root].set(inf)
+    res_rows = jnp.asarray(plan.res_rows)
+    nbr = jnp.asarray(plan.res_nbr)
+    rwm = jnp.where(nbr == root, inf, jnp.asarray(plan.res_w))
+    relax = relax_ops.make_relax(
+        deltas, plan.s_cap, lambda k: swm[k],
+        residual=(
+            jnp.clip(res_rows, 0, n_cap - 1), jnp.clip(nbr, 0, n_cap - 1),
+            rwm,
+        ) if has_res else None,
+    )
+    dist0 = jnp.full((d_cap, n_cap), inf, jnp.int32)
+    dist0 = dist0.at[jnp.arange(len(seeds)), jnp.asarray(seeds)].set(0)
+    prev, _, _ = jax.jit(
+        lambda d: relax_ops.run_sync(relax, d, relax_ops.max_trips(n_cap))
+    )(dist0)
+
+    @jax.jit
+    def parent(prev_dist):
+        return _parent_plane(
+            deltas, swm, res_rows, nbr, rwm, prev_dist,
+            plan.s_cap, has_res, n_cap, d_cap,
+        )
+
+    return _median_ms(parent, prev)
+
+
+def capture_lanes(name: str, lanes: list[int]) -> None:
+    """The mirror as the builder builds it, from the wide vantage, at
+    each count of lanes; then the solver itself at all of its links."""
+    import jax
+
+    gen, _, me = CONFIGS[name]
+    adj_dbs, prefix_dbs = gen()
+    states, _ = topologies.build_states(adj_dbs, prefix_dbs)
+    plan = edgeplan.build_plan(states["0"])
+    root = plan.node_index[me]
+    nbr, _, links = plan.out_links(states["0"], me)
+    r_cap, k_cap = plan.res_nbr.shape
+    for d_cap in lanes:
+        seeds = nbr[: min(d_cap, len(links))]
+        print(json.dumps({
+            "config": name, "vantage": me, "lanes": d_cap,
+            "sources": len(seeds), "width": k_cap, "r_cap": r_cap,
+            "device": jax.devices()[0].device_kind,
+            "loop_ms_per_pass": _loop_ms(plan, root, d_cap, "rk"),
+            "gather_ms_per_pass": _loop_ms(plan, root, d_cap, "gather"),
+            "parent_ms_per_call": _parent_ms(plan, root, seeds, d_cap),
+        }), flush=True)
+    scope, _ = _scope_ms(name, adj_dbs, prefix_dbs, me, k_cap, None)
+    print(json.dumps({
+        "config": name, "vantage": me, "lanes": nbr.shape[0],
+        "sources": len(links), "width": k_cap, **scope,
+    }), flush=True)
 
 
 def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
@@ -179,6 +266,9 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
         "seed.cone_ms_per_event": by_scope.get("seed.cone", 0) / EVENTS,
         "seed.parent_ms_per_event": by_scope.get("seed.parent", 0) / EVENTS,
         "device_ms_per_event": sum(by_scope.values()) / EVENTS,
+        "by_scope_ms_per_event": {
+            scope: ms / EVENTS for scope, ms in sorted(by_scope.items())
+        },
         "rounds": rounds,
         "cones": cones,
         "tables_equal_first_width": same,
@@ -188,7 +278,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
 def capture(name: str, max_width: int = 0) -> None:
     import jax
 
-    gen, me = CONFIGS[name]
+    gen, me, _ = CONFIGS[name]
     adj_dbs, prefix_dbs = gen()
     states, _ = topologies.build_states(adj_dbs, prefix_dbs)
     link_state = states["0"]
@@ -238,13 +328,19 @@ def capture(name: str, max_width: int = 0) -> None:
 def main(argv):
     import jax
 
+    lanes = []
+    if argv[:1] == ["--lanes"]:
+        lanes, argv = [int(n) for n in argv[1].split(",")], argv[2:]
     # "fabric10k:16" starts at width 16 (a second call's way to go on)
     specs = [(spec + ":0").split(":")[:2] for spec in argv or CONFIGS_ON_CHIP]
     small = all(name.endswith("-small") for name, _ in specs)
     if jax.devices()[0].platform != "tpu" and not small:
         raise SystemExit("tools/residual_width.py measures a TPU; none here")
     for name, max_width in specs:
-        capture(name, int(max_width))
+        if lanes:
+            capture_lanes(name, lanes)
+        else:
+            capture(name, int(max_width))
 
 
 if __name__ == "__main__":
