@@ -71,6 +71,52 @@ def _old_planes(shift_w, res_w, s_dirty_idx, s_dirty_old,
     return old_shift, old_res
 
 
+def residual_parents(par, prev_dist, res_rows, res_nbr, rwm_old, n_cap):
+    """The residual half of the parent forest: for every destination
+    still without a parent in `par` [D, N], its first tight residual
+    slot's neighbour — first in slot order within a row, the largest
+    find among the rows a split destination spans. The multichip twin
+    (parallel/sharding.py) calls this too, replicated after its pmax.
+
+    One gather-and-min pass shaped like the relaxation's
+    (ops/relax.py): a slot's key `k * n_cap + nbr` orders the slots of
+    a row as they lie and carries the neighbour in its low bits, so the
+    row's minimum over its tight slots IS the first tight slot, found
+    for what a relaxation's pass costs at any count of lanes."""
+    import jax.numpy as jnp
+
+    k_cap = res_nbr.shape[1]
+    sent = jnp.iinfo(jnp.int32).max
+    # n_cap is a power of two (the low bits hold the neighbour) and the
+    # widest key stays under the sentinel: static shapes, no fallback
+    assert n_cap & (n_cap - 1) == 0 and k_cap * n_cap <= sent, (
+        k_cap, n_cap,
+    )
+    nbr_c = jnp.clip(res_nbr, 0, n_cap - 1)
+    rows_c = jnp.clip(res_rows, 0, n_cap - 1)
+    row_valid = res_rows >= 0
+    # pad scatter target n_cap drops — a clipped pad row would
+    # collide with node 0's real residual row otherwise
+    rows_s = jnp.where(row_valid, res_rows, n_cap)
+    slot = jnp.arange(k_cap, dtype=jnp.int32)[None, :]
+    key = jnp.where(
+        (res_nbr >= 0) & (rwm_old < INF_E), slot * n_cap + res_nbr, sent
+    )  # [R, K], the same for every lane
+    prev_n = prev_dist[:, nbr_c]  # [D, R, K]
+    tgt = prev_dist[:, rows_c]  # [D, R]
+    tight = (prev_n < INF_E) & (prev_n + rwm_old[None] == tgt[:, :, None])
+    best = jnp.where(tight, key[None], sent).min(axis=2)  # [D, R]
+    has = best < sent
+    pick = best & (n_cap - 1)
+    cur = par[:, rows_c]
+    new = jnp.where((cur < 0) & has & row_valid[None], pick, cur)
+    # max, not set: a destination may span several rows, and one
+    # that found no tight parent (-1) must not erase another's.
+    # Either row's find is a tight-edge parent; a shift-class
+    # parent (cur >= 0) is the same in all of them and is kept
+    return par.at[:, rows_s].max(new, mode="drop")
+
+
 def _parent_plane(deltas, swm_old, res_rows, res_nbr, rwm_old,
                   prev_dist, s_cap, has_res, n_cap, d_cap):
     """Per-lane parent forest [D, N] under the OLD (root-masked)
@@ -98,34 +144,9 @@ def _parent_plane(deltas, swm_old, res_rows, res_nbr, rwm_old,
     par = jax.lax.fori_loop(0, s_cap, cls, par)
 
     if has_res:
-        nbr_c = jnp.clip(res_nbr, 0, n_cap - 1)
-        rows_c = jnp.clip(res_rows, 0, n_cap - 1)
-        row_valid = res_rows >= 0
-        # pad scatter target n_cap drops — a clipped pad row would
-        # collide with node 0's real residual row otherwise
-        rows_s = jnp.where(row_valid, res_rows, n_cap)
-        prev_n = prev_dist[:, nbr_c]  # [D, R, K]
-        cand = prev_n + rwm_old[None]
-        tgt = prev_dist[:, rows_c][:, :, None]
-        hit = (
-            (prev_n < INF_E)
-            & (rwm_old < INF_E)[None]
-            & (cand == tgt)
-            & (res_nbr >= 0)[None]
-        )  # [D, R, K]
-        has = hit.any(axis=2)
-        first = jnp.argmax(hit, axis=2)  # first tight slot breaks ties
-        nbr_b = jnp.broadcast_to(res_nbr[None], hit.shape)
-        pick = jnp.take_along_axis(
-            nbr_b, first[:, :, None], axis=2
-        )[:, :, 0]  # [D, R]
-        cur = par[:, rows_c]
-        new = jnp.where((cur < 0) & has & row_valid[None], pick, cur)
-        # max, not set: a destination may span several rows, and one
-        # that found no tight parent (-1) must not erase another's.
-        # Either row's find is a tight-edge parent; a shift-class
-        # parent (cur >= 0) is the same in all of them and is kept
-        par = par.at[:, rows_s].max(new, mode="drop")
+        par = residual_parents(
+            par, prev_dist, res_rows, res_nbr, rwm_old, n_cap
+        )
     return par
 
 

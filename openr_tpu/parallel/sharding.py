@@ -31,6 +31,7 @@ from typing import Optional
 
 import numpy as np
 
+from openr_tpu.ops import incremental as incremental_ops
 from openr_tpu.ops import relax as relax_ops
 from openr_tpu.ops.edgeplan import INF32E
 from openr_tpu.ops.xla_cache import bounded_jit_cache, instrument_jit, retrace
@@ -537,29 +538,10 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             par = jax.lax.fori_loop(0, s_cap, pcls, par)
             par = jax.lax.pmax(par, "graph")
             if has_res:
-                row_valid = res_rows >= 0
-                prev_n = prev_dist[:, nbr_c]
-                cand = prev_n + rwm_old[None]
-                tgt = prev_dist[:, rows_c][:, :, None]
-                hit = (
-                    (prev_n < INF_E)
-                    & (rwm_old < INF_E)[None]
-                    & (cand == tgt)
-                    & (res_nbr >= 0)[None]
+                # replicated after the pmax: the single-chip find
+                par = incremental_ops.residual_parents(
+                    par, prev_dist, res_rows, res_nbr, rwm_old, n_cap
                 )
-                has = hit.any(axis=2)
-                first = jnp.argmax(hit, axis=2)
-                nbr_b = jnp.broadcast_to(res_nbr[None], hit.shape)
-                pick = jnp.take_along_axis(
-                    nbr_b, first[:, :, None], axis=2
-                )[:, :, 0]
-                cur = par[:, rows_c]
-                new = jnp.where(
-                    (cur < 0) & has & row_valid[None], pick, cur
-                )
-                # max: a destination's rows each report (see
-                # ops/incremental._parent_plane)
-                par = par.at[:, rows_s].max(new, mode="drop")
 
         with jax.named_scope("seed.cone"):
             # --- classify increased dirty edges + seed the cone ---

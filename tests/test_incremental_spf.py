@@ -404,3 +404,161 @@ def test_increase_behind_a_first_row_parent_invalidates_the_cone(
     churn.set_metric("node-01", "node-09", 1)
     st = solve("restore")
     assert st.get("incremental"), st
+
+
+# -- the parent forest itself (ops/incremental._parent_plane) ---------------
+
+_INF = 1 << 29
+
+
+def _forest_case(lanes: int, width: int, seed: int):
+    """A random mirror of 256 nodes — two shift classes and a residual ELL
+    `width` wide — with what the parent plane has to step over: hubs whose
+    in-edges span several rows, rows in no order, pad rows that carry
+    plausible neighbours and weights, pad slots (neighbour -1) in the
+    middle of a row with a finite weight, masked (`INF`) weights on real
+    slots, nodes nothing reaches and lanes with no source. `prev` is the
+    fixpoint of the relaxation over it from one source a lane."""
+    rng = np.random.default_rng(seed)
+    n_cap, s_cap = 256, 2
+    deltas = np.array([1, 37], np.int32)
+    shift_w = np.where(
+        rng.random((s_cap, n_cap)) < 0.3,
+        rng.integers(1, 20, (s_cap, n_cap)), _INF,
+    ).astype(np.int32)
+    islands = rng.choice(n_cap, 12, replace=False)  # no in-edge at all
+    for k in range(s_cap):
+        shift_w[k, (islands - deltas[k]) % n_cap] = _INF
+    rows, nbrs, ws = [], [], []
+    hubs = set(rng.choice(n_cap, 6, replace=False)) - set(islands)
+    for v in range(n_cap):
+        if v in islands:
+            continue
+        deg = 2 * width + 3 if v in hubs else int(rng.integers(0, 5))
+        src = rng.integers(0, n_cap, deg)
+        w = np.where(rng.random(deg) < 0.15, _INF, rng.integers(1, 20, deg))
+        hole = rng.random(deg) < 0.1  # a pad slot where an edge was
+        src, w = np.where(hole, -1, src), np.where(hole, 3, w)
+        for at in range(0, deg, width):
+            pad = width - len(src[at:at + width])
+            rows.append(v)
+            nbrs.append(np.r_[src[at:at + width], np.full(pad, -1)])
+            ws.append(np.r_[w[at:at + width], np.full(pad, _INF)])
+    r_cap = 1 << int(np.ceil(np.log2(len(rows) + 5)))
+    for _ in range(r_cap - len(rows)):  # pad rows: row -1, slots look real
+        rows.append(-1)
+        nbrs.append(rng.integers(0, n_cap, width))
+        ws.append(rng.integers(1, 20, width))
+    order = rng.permutation(r_cap)
+    res_rows = np.array(rows, np.int32)[order]
+    res_nbr = np.array(nbrs, np.int32)[order]
+    res_w = np.array(ws, np.int32)[order]
+
+    sources = rng.choice(n_cap, min(lanes, 52), replace=False)
+    prev = np.full((lanes, n_cap), _INF, np.int64)
+    prev[np.arange(len(sources)), sources] = 0
+    live = (res_rows >= 0)[:, None] & (res_nbr >= 0) & (res_w < _INF)
+    while True:
+        new = prev.copy()
+        for k in range(s_cap):
+            cand = np.roll(prev + shift_w[k][None], deltas[k], axis=1)
+            new = np.minimum(new, cand)
+        cand = np.where(live[None], prev[:, res_nbr] + res_w[None], _INF)
+        np.minimum.at(
+            new, (slice(None), np.where(res_rows >= 0, res_rows, 0)),
+            np.where((res_rows >= 0)[None], cand.min(axis=2), _INF),
+        )
+        new = np.minimum(new, _INF)
+        if (new == prev).all():
+            break
+        prev = new
+    return deltas, shift_w, res_rows, res_nbr, res_w, prev.astype(np.int32)
+
+
+def _forest_reference(deltas, shift_w, res_rows, res_nbr, res_w, prev):
+    """The forest, node by node: the first shift class with a tight edge
+    into v; else, of every residual row of v, the first tight slot in
+    slot order, and the largest of those finds where v spans several rows
+    (any tight edge serves; the device combines the rows by max); else -1."""
+    lanes, n_cap = prev.shape
+    par = np.full((lanes, n_cap), -1, np.int32)
+
+    def tight(d, u, w, v):
+        return prev[d, u] < _INF and w < _INF and prev[d, u] + w == prev[d, v]
+
+    for d in range(lanes):
+        for v in range(n_cap):
+            for k, dk in enumerate(deltas):
+                u = (v - dk) % n_cap
+                if tight(d, u, shift_w[k, u], v):
+                    par[d, v] = u
+                    break
+            if par[d, v] >= 0:
+                continue
+            for r in np.flatnonzero(res_rows == v):
+                for u, w in zip(res_nbr[r], res_w[r]):
+                    if u >= 0 and tight(d, u, w, v):
+                        par[d, v] = max(par[d, v], u)
+                        break
+    return par
+
+
+@pytest.mark.parametrize("width", [2, 8, 64])
+@pytest.mark.parametrize("lanes", [4, 8, 64])
+def test_parent_forest_is_the_first_tight_slot(lanes, width):
+    """`_parent_plane` against the plain reference, at the lanes of an
+    access router, a rack switch and an aggregation router and at widths
+    below, at and above the deployments' 8."""
+    import jax
+
+    from openr_tpu.ops.incremental import _parent_plane
+
+    case = _forest_case(lanes, width, seed=1000 * lanes + width)
+    deltas, shift_w, res_rows, res_nbr, res_w, prev = case
+    want = _forest_reference(*case)
+    got = np.asarray(jax.jit(
+        lambda *a: _parent_plane(
+            *a, s_cap=2, has_res=True, n_cap=prev.shape[1], d_cap=lanes
+        )
+    )(deltas, shift_w, res_rows, res_nbr, res_w, prev))
+    np.testing.assert_array_equal(got, want)
+    # the case holds what it says: parents from both halves, split
+    # destinations among them, and nodes with none
+    reached = prev < _INF
+    assert (want[reached] >= 0).sum() > lanes and (want[~reached] < 0).all()
+    split = np.flatnonzero(np.bincount(res_rows[res_rows >= 0]) > 1)
+    assert len(split) and (want[:, split] >= 0).any()
+
+
+def test_parent_key_holds_the_widest_shapes():
+    """The packed key `slot * n_cap + neighbour` at the widest mirror the
+    repo builds, 256 slots a row over 2^20 nodes: the last slot's key is
+    2^28 - 1, under the sentinel, and gives its neighbour back. A shape
+    whose keys would pass the sentinel is refused when traced."""
+    import jax
+
+    from openr_tpu.ops.incremental import _parent_plane
+
+    n_cap, k_cap = 1 << 20, 256
+    prev = np.full((1, n_cap), _INF, np.int32)
+    prev[0, [n_cap - 1, 5, 9]] = [7, 10, 8]
+    res_rows = np.array([5, 9, -1, -1], np.int32)
+    res_nbr = np.full((4, k_cap), -1, np.int32)
+    res_w = np.full((4, k_cap), _INF, np.int32)
+    res_nbr[0, -1], res_w[0, -1] = n_cap - 1, 3  # tight, in the last slot
+    res_nbr[1, 0], res_w[1, 0] = n_cap - 1, 1  # tight, in the first
+    res_nbr[1, -1], res_w[1, -1] = 5, 4  # not tight
+    args = (np.array([1], np.int32), np.full((1, n_cap), _INF, np.int32),
+            res_rows, res_nbr, res_w, prev)
+
+    def plane(*a):
+        return _parent_plane(
+            *a, s_cap=1, has_res=True, n_cap=n_cap, d_cap=1
+        )
+
+    par = np.asarray(jax.jit(plane)(*args))
+    assert par[0, 5] == n_cap - 1 and par[0, 9] == n_cap - 1
+    assert (np.delete(par[0], [5, 9]) == -1).all()
+    wide = jax.ShapeDtypeStruct((4, 4096), np.int32)
+    with pytest.raises(AssertionError):
+        jax.eval_shape(plane, *args[:3], wide, wide, args[5])

@@ -187,17 +187,36 @@ def _churn_node(ls, victim, bump):
     )
 
 
-@pytest.mark.parametrize("incr", [False, True])
-def test_multichip_production_path_parity(incr):
+@pytest.mark.parametrize(
+    "incr, mirror", [(False, "grid"), (True, "grid"), (True, "split-wan")]
+)
+def test_multichip_production_path_parity(incr, mirror, monkeypatch):
     """build_route_db through the multichip capacity tier (threshold
     forced below the graph's n_cap): RIBs bit-identical to BOTH the CPU
     oracle and the single-chip tier — including LFA backups — across
     cold solve, metric churn, restore, link flap, and flap restore, on
     the full-solve and incremental solvers. Tier observability
-    (counters, stats, per-shard timings) is asserted alongside."""
+    (counters, stats, per-shard timings) is asserted alongside.
+
+    `split-wan`: a small RTT-metric WAN whose mirror keeps a residual two
+    slots wide, so its aggregation routers span several rows, churned at
+    its two widest routers: the twin's parent forest comes from the
+    residual find it shares with the single-chip solve
+    (ops/incremental.residual_parents)."""
+    from openr_tpu.ops import edgeplan
     from openr_tpu.runtime.counters import counters
 
-    adj_dbs, prefix_dbs = topologies.grid(8)
+    if mirror == "grid":
+        adj_dbs, prefix_dbs = topologies.grid(8)
+        churned, flapped = adj_dbs[1], adj_dbs[5]
+    else:
+        monkeypatch.setattr(edgeplan, "_residual_width", lambda degrees: 2)
+        adj_dbs, prefix_dbs = topologies.wan_rtt(
+            regions=3, cores=2, aggs=4, access=20, seed=7
+        )
+        churned, flapped = sorted(
+            adj_dbs[1:], key=lambda db: -len(db.adjacencies)
+        )[:2]
     states, ps = topologies.build_states(adj_dbs, prefix_dbs)
     root = adj_dbs[0].this_node_name
     ls = states["0"]
@@ -226,11 +245,17 @@ def test_multichip_production_path_parity(incr):
     assert len(mc_info["shard_ms"]) == 8
     assert mc.last_device_stats["multichip"]["shards"] == 8
 
-    _churn_node(ls, adj_dbs[1], 7)
+    _churn_node(ls, churned, 7)
     check("metric churn")
-    _churn_node(ls, adj_dbs[1], 0)
+    if mirror == "split-wan":
+        occupancy = mc._area_dev["0"].plan.occupancy()
+        assert occupancy["residual_split_rows"] > 0, occupancy
+        stats = mc.last_device_stats
+        assert stats.get("incremental") and stats.get("cone") > 0, stats
+        assert not stats.get("fell_back"), stats
+    _churn_node(ls, churned, 0)
     check("restore")
-    victim = adj_dbs[5]
+    victim = flapped
     ls.update_adjacency_database(
         AdjacencyDatabase(
             this_node_name=victim.this_node_name,
