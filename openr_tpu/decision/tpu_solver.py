@@ -886,7 +886,7 @@ class _AreaDev:
         # the last _sync_area's stages for the tpu.sync.* spans:
         # (plan start, plan end = upload start, upload end) on
         # time.monotonic(), bytes uploaded, slots scattered, the
-        # mirror's occupancy (EdgePlan.occupancy)
+        # mirror's occupancy (EdgePlan.occupancy) and the prefix plane's
         self.sync_marks: tuple = ()
 
 
@@ -2207,10 +2207,8 @@ class TpuSpfSolver:
         mirror = plan.occupancy()
         for key, value in mirror.items():
             counters.set_counter(f"decision.tpu.{key}", value)
-        ad.sync_marks = (
-            t_plan0, t_plan1, t_up0, _time.monotonic(),
-            self._bytes_uploaded - bytes0, dirty_slots, mirror,
-        )
+        t_up1 = _time.monotonic()
+        up_bytes = self._bytes_uploaded - bytes0
 
         # announcer matrix: keyed on prefix churn + node-index stability
         mkey = (prefix_state.generation, plan.index_version)
@@ -2254,6 +2252,21 @@ class TpuSpfSolver:
             if ad.flags is None or not np.array_equal(flags, ad.flags):
                 ad.flags = flags
                 ad.d_mbuf = self._put_counted(mbuf, shp("replicated"))
+        # the prefix plane as d_mbuf carries it: every stage after the
+        # SSSP works over all p_cap x a_cap cells, whatever `prefixes` of
+        # the rows hold a prefix
+        p_cap, a_cap = ad.matrix.ann_node.shape
+        rows = {
+            "prefix_rows": p_cap,
+            "prefixes": len(ad.matrix.prefix_list),
+            "advertiser_cap": a_cap,
+        }
+        for key, value in rows.items():
+            counters.set_counter(f"decision.tpu.{key}", value)
+        ad.sync_marks = (
+            t_plan0, t_plan1, t_up0, t_up1, up_bytes, dirty_slots,
+            {**mirror, **rows},
+        )
         return ad
 
     # -- the fast path ------------------------------------------------------
@@ -2960,7 +2973,7 @@ class TpuSpfSolver:
                     }),
                     ("tpu.dispatch", None, t1, t_disp, {
                         "kernel": kernel_name, "incremental": incr,
-                        "lanes": d_cap,
+                        "lanes": d_cap, "rows": p_cap,
                     }),
                     ("tpu.device_wait", None, t_disp, t_ready, {
                         "rounds": rounds, "relax_bytes": relax_bytes,

@@ -7,6 +7,7 @@ whose mirrors are all residual.
     chiprun -- python3 tools/residual_width.py fabric10k:16  # from width 16 down
     JAX_PLATFORMS=cpu python3 tools/residual_width.py fabric-small  # rehearsal
     chiprun -- python3 tools/residual_width.py --lanes 4,8,16,32,64 wan50k
+    chiprun -- python3 tools/residual_width.py --prefixes-per-node 1,8,32 fabric10k
 
 For every power-of-two width from 2 to the widest destination's it
 rebuilds the mirror at that width (by replacing the builder's own choice,
@@ -36,6 +37,14 @@ call of `seed.parent` (`ops/incremental._parent_plane`) over the plane the
 relaxation converges to from those links; the last line is the solver's
 own capture from that router with all its links (`scope`, with the whole
 `by_scope` an event).
+
+With `--prefixes-per-node` (a fabric: the generator takes the count) the
+mirror and the lanes are left alone and the prefix plane varies instead:
+the same graph with each count of prefixes a switch, one line a count with
+the rows the device carries (`prefix_rows`, `prefixes`) and the solver's
+own capture from the usual vantage: `by_scope` an event, and `row_stages`,
+the sum of the scopes that work over every row (ROW_SCOPES), beside
+`device_ms_per_event`.
 """
 
 from __future__ import annotations
@@ -59,8 +68,8 @@ CONFIGS = {
     # generator, vantage, the wide vantage of --lanes; the -small ones
     # rehearse the script on a CPU
     "fabric-small": (
-        lambda: topologies.fabric(
-            pods=12, planes=2, ssws_per_plane=3, rsws_per_pod=6
+        lambda **kw: topologies.fabric(
+            pods=12, planes=2, ssws_per_plane=3, rsws_per_pod=6, **kw
         ),
         "pod000-rsw00", "pod000-fsw00",
     ),
@@ -71,8 +80,8 @@ CONFIGS = {
         "r01-acc0000", "r02-agg03",
     ),
     "fabric10k": (
-        lambda: topologies.fabric(
-            pods=173, planes=8, ssws_per_plane=36, rsws_per_pod=48
+        lambda **kw: topologies.fabric(
+            pods=173, planes=8, ssws_per_plane=36, rsws_per_pod=48, **kw
         ),
         "pod000-rsw00", "pod000-fsw00",
     ),
@@ -90,6 +99,9 @@ EVENTS = 6
 # the solver's own capture compiles two pipelines a width (49 s each at
 # wan50k): taken at the width before the split and at the narrow ones
 SCOPE_MAX_WIDTH = 8
+# the device program's stages after the SSSP: each works over every
+# prefix row, whatever the event changed
+ROW_SCOPES = ("unpack", "select", "nexthop", "lfa", "pack", "diff", "compact")
 
 
 @contextlib.contextmanager
@@ -222,6 +234,25 @@ def capture_lanes(name: str, lanes: list[int]) -> None:
     }), flush=True)
 
 
+def capture_prefixes(name: str, counts: list[int]) -> None:
+    """The same graph, mirror and vantage at each count of prefixes a
+    node: the solver's own capture, and the rows it worked over."""
+    gen, me, _ = CONFIGS[name]
+    for per_node in counts:
+        adj_dbs, prefix_dbs = gen(prefixes_per_node=per_node)
+        states, _ = topologies.build_states(adj_dbs, prefix_dbs)
+        width = edgeplan.build_plan(states["0"]).res_nbr.shape[1]
+        scope, _ = _scope_ms(name, adj_dbs, prefix_dbs, me, width, None)
+        by_scope = scope["by_scope_ms_per_event"]
+        print(json.dumps({
+            "config": name, "vantage": me, "prefixes_per_node": per_node,
+            "row_stages_ms_per_event": sum(
+                by_scope.get(stage, 0) for stage in ROW_SCOPES
+            ),
+            **scope,
+        }), flush=True)
+
+
 def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
     """by_scope over EVENTS incremental solves at this width; the table
     after each event against `want` (the first width's)."""
@@ -236,7 +267,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
     peer = adj_dbs[-1].adjacencies[0].other_node_name
     base = adj_dbs[-1].adjacencies[0].metric
     solver = TpuSpfSolver(me, enable_lfa=True, incremental_spf=True)
-    tables, rounds, cones = [], [], []
+    tables, rounds, cones, changed = [], [], [], []
 
     def solve(step: int) -> dict:
         """The link's metric up (even steps) or back, then a solve."""
@@ -258,6 +289,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
                 raise SystemExit(f"event {step} did not solve warm: {stats}")
             rounds.append(solver.last_timing.get("rounds"))
             cones.append((stats.get("cone"), bool(stats.get("fell_back"))))
+            changed.append(stats.get("changed_rows"))
         by_scope = device_stats.profiler_stop()["by_scope"] or {}
     same = want is None or all(a == b for a, b in zip(tables, want))
     passes = sum(rounds)
@@ -271,6 +303,9 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
         },
         "rounds": rounds,
         "cones": cones,
+        "changed_rows": changed,
+        "prefix_rows": stats.get("prefix_rows"),
+        "prefixes": stats.get("prefixes"),
         "tables_equal_first_width": same,
     }, tables
 
@@ -328,9 +363,11 @@ def capture(name: str, max_width: int = 0) -> None:
 def main(argv):
     import jax
 
-    lanes = []
+    lanes, per_node = [], []
     if argv[:1] == ["--lanes"]:
         lanes, argv = [int(n) for n in argv[1].split(",")], argv[2:]
+    elif argv[:1] == ["--prefixes-per-node"]:
+        per_node, argv = [int(n) for n in argv[1].split(",")], argv[2:]
     # "fabric10k:16" starts at width 16 (a second call's way to go on)
     specs = [(spec + ":0").split(":")[:2] for spec in argv or CONFIGS_ON_CHIP]
     small = all(name.endswith("-small") for name, _ in specs)
@@ -339,6 +376,8 @@ def main(argv):
     for name, max_width in specs:
         if lanes:
             capture_lanes(name, lanes)
+        elif per_node:
+            capture_prefixes(name, per_node)
         else:
             capture(name, int(max_width))
 
