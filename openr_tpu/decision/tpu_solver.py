@@ -247,10 +247,29 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 ops/compact.route_ok_device), then the packed outputs
                 GATHERED to those rows. The host scatters them straight
                 into ColumnarRib columns without an O(P*A) filter pass.
+                Its count and rows are DEFINED ONLY in an epoch with
+                `want_full` or count > budget — the epochs the host
+                reads it in (_make_prepare's full_pull) — and zeros in
+                every other: the route-ok predicate (where no streaming
+                payload needs it), the compaction and the six gathers
+                over every row sit under one lax.cond on that predicate.
+                trips and the scalar tail are appended outside it and
+                read as delta_buf's in every epoch.
       metric, s3w, nhw, lfa_slot, lfa_metric: resident arrays (the next
                 call's prev_*; lfa arrays are passthrough when lfa=False)
       dist_d (emit_dist): the [D, N] SSSP plane, kept resident as the
                 next incremental solve's warm seed.
+
+    `want_full` (argument 9, a runtime int32 scalar) is the dispatcher's
+    half of that predicate: _lane_args sets it to `not vs.valid` — the
+    vantage has no table to patch (first solve, reset planes, an
+    abandoned streaming prepare), so the host will read full_buf
+    whatever changed. The other half, count > budget, is the device's
+    own, so an overflowing epoch finds its full pull built in the same
+    dispatch. An argument, not a PipelineVariant field: one executable
+    serves both. Under vmap (a `fused` group) the cond lowers to a
+    select and both branches run, as they did before there was a cond;
+    under `mesh` the predicate is replicated.
 
     With `incr=True` the pipeline takes six extra trailing args
     (prev_dist, s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
@@ -283,7 +302,9 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
 
     from openr_tpu.ops.compact import route_ok_device
     from openr_tpu.ops.incremental import incremental_sssp
-    from openr_tpu.ops.stream import column_diff, compact_changed_rows
+    from openr_tpu.ops.stream import (
+        column_diff, compact_changed_rows, true_rows,
+    )
 
     wa = -(-a_cap // 16)
     wd = -(-d_cap // 16)
@@ -309,7 +330,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             )
 
     def pipeline(deltas, shift_w, res_rows, res_nbr, res_w, mbuf,
-                 root, root_nbr, root_w,
+                 root, root_nbr, root_w, want_full,
                  prev_metric, prev_s3w, prev_nhw,
                  prev_lfa_slot, prev_lfa_metric, *incr_args):
         with jax.named_scope("unpack"):
@@ -433,16 +454,21 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             lfa_slot = prev_lfa_slot
             lfa_metric = prev_lfa_metric
 
-        with jax.named_scope("pack"):
-            s3w = _pack_words(s3)
-            nhw = _pack_words(nh_mask)
-
+        def route_ok():
             # route-level ok computed on device: compacts the cold full
             # pull to ok rows, and on the streaming path rides the delta
             # payload per changed row (the host apply is then unpack-free)
-            ok = route_ok_device(
+            return route_ok_device(
                 metric, s3, nh_mask, ann_node, min_nh, v4_blocked, root,
             )
+
+        with jax.named_scope("pack"):
+            s3w = _pack_words(s3)
+            nhw = _pack_words(nh_mask)
+            # a streaming epoch ships ok with every changed row; any
+            # other needs it for the cold pull alone, and computes it
+            # there
+            ok = route_ok() if stream else None
         with jax.named_scope("diff"):
             changed = column_diff(
                 metric, s3w, nhw, lfa_slot, lfa_metric,
@@ -450,28 +476,43 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 prev_lfa_slot, prev_lfa_metric, lfa,
             )
         with jax.named_scope("compact"):
+            delta_rows = stream or budget
             count, delta_parts = compact_changed_rows(
-                changed, trips, metric, s3w, nhw,
-                ok if stream else None,
-                lfa_slot, lfa_metric, stream or budget, p_cap, lfa,
+                changed, trips, metric, s3w, nhw, ok,
+                lfa_slot, lfa_metric, delta_rows, p_cap, lfa,
             )
-            # cold-rebuild compaction: only ok rows' outputs ship (gathered
-            # to the front — pad slots past okc carry the last ok row's
-            # values and are ignored)
-            okc = ok.sum().astype(jnp.int32)
-            oidx = jnp.nonzero(ok, size=p_cap, fill_value=p_cap)[0]
-            osafe = jnp.clip(oidx, 0, p_cap - 1).astype(jnp.int32)
-            full_parts = [
-                okc[None],
-                trips[None].astype(jnp.int32),
-                oidx.astype(jnp.int32),
-                metric[osafe],
-                s3w[osafe].ravel(),
-                nhw[osafe].ravel(),
-            ]
-            if lfa:
-                # delta-side lfa columns already rode compact_changed_rows
-                full_parts += [lfa_slot[osafe], lfa_metric[osafe]]
+
+            def cold_rows():
+                # cold-rebuild compaction: only ok rows' outputs ship
+                # (gathered to the front — pad slots past okc carry the
+                # last ok row's values and are ignored)
+                row_ok = route_ok() if ok is None else ok
+                oidx = true_rows(row_ok, p_cap)
+                osafe = jnp.clip(oidx, 0, p_cap - 1)
+                rows = [
+                    oidx,
+                    metric[osafe],
+                    s3w[osafe].ravel(),
+                    nhw[osafe].ravel(),
+                ]
+                if lfa:
+                    # delta-side lfa columns already rode
+                    # compact_changed_rows
+                    rows += [lfa_slot[osafe], lfa_metric[osafe]]
+                return row_ok.sum().astype(jnp.int32), jnp.concatenate(rows)
+
+            def no_rows():
+                return jax.tree.map(
+                    lambda x: jnp.zeros(x.shape, x.dtype),
+                    jax.eval_shape(cold_rows),
+                )
+
+            # the cold pull is compacted only in an epoch that reads it:
+            # the host's rule (full_pull in _make_prepare) on the device
+            okc, full_rows = jax.lax.cond(
+                (want_full != 0) | (count > delta_rows), cold_rows, no_rows,
+            )
+            full_parts = [okc[None], trips[None].astype(jnp.int32), full_rows]
             if sentinels:
                 # numerical-health sentinels: two scalar reductions riding
                 # the tail of BOTH pull buffers (free — the pull happens
@@ -658,6 +699,7 @@ def _mc_shardings(mesh, n_cap: int, r_cap: int, d_cap: int,
         rep,              # root scalar
         sh["root_vec"],   # root_nbr
         sh["root_vec"],   # root_w
+        rep,              # want_full scalar
         rep, rep, rep, rep, rep,  # prev outputs
     )
     if incr:
@@ -675,13 +717,13 @@ def _build_pipeline(*fields) -> tuple:
     compile time + XLA cost_analysis into the kernel ledger
     (ops/xla_cache.ledger). The jit options follow from the record:
       - `donate`: the previous epoch's published planes and warm seed
-        (args 9-14) update HBM in place — one plane set resident, not
+        (args 10-15) update HBM in place — one plane set resident, not
         two;
       - `mesh`: NamedSharding annotations, so GSPMD partitions the
         weight state — parity with one chip by construction (the int32
         min/add/compare algebra is partitioning-invariant, XLA argmin
         keeps lowest-index ties);
-      - `fused`: each of the 14 inputs arrives as a g-tuple of per-area
+      - `fused`: each of the 15 inputs arrives as a g-tuple of per-area
         arrays (a pytree — still one dispatch), stacks inside the jit
         and vmaps through the closure; the trip count becomes the
         group's max (trips past a lane's fixpoint are no-ops) and the
@@ -701,7 +743,7 @@ def _build_pipeline(*fields) -> tuple:
     )
     kw = {}
     if v.donate:
-        kw = {"donate_argnums": (9, 10, 11, 12, 13, 14)}
+        kw = {"donate_argnums": (10, 11, 12, 13, 14, 15)}
     elif v.mesh is not None:
         kw["in_shardings"], kw["out_shardings"] = _mc_shardings(
             v.mesh, v.n_cap, v.r_cap, v.d_cap, v.emit_dist, v.incr
@@ -737,9 +779,10 @@ def pipeline_for(variant: PipelineVariant) -> tuple:
 
 
 def _pipeline_avals(shape_key: tuple) -> tuple:
-    """Abstract avals for the 14-arg pipeline closure of a shape class —
+    """Abstract avals for the 15-arg pipeline closure of a shape class —
     exactly the shapes _lane_args uploads (deltas, shift plane,
-    residual tables, packed matrix buffer, root tables, prev outputs).
+    residual tables, packed matrix buffer, root tables, the want_full
+    scalar, prev outputs).
     jitted.lower() accepts these in place of real arrays, so the
     speculative baker compiles a class the fabric has not reached yet
     without materializing a single array."""
@@ -759,6 +802,7 @@ def _pipeline_avals(shape_key: tuple) -> tuple:
         S((), i32),                 # root index
         S((d_cap,), i32),           # root_nbr
         S((d_cap,), i32),           # root_w
+        S((), i32),                 # want_full
         S((p_cap,), i32),           # prev metric
         S((p_cap, wa), i32),        # prev s3 words
         S((p_cap, wd), i32),        # prev nh words
@@ -2448,6 +2492,10 @@ class TpuSpfSolver:
         ad, vs = pv["ad"], pv["vs"]
         root_idx = np.int32(pv["root_idx"])
         root_nbr, root_w = pv["root_nbr"], pv["root_w"]
+        # a vantage with no table yet (first solve, reset, an abandoned
+        # streaming prepare) will read the cold pull whatever changed:
+        # _make_prepare's `was_valid`, told to the device
+        want_full = np.int32(not vs.valid)
         if self._transfer_guard_mode() is not None and pv.get("mc") is None:
             # under the guard the per-dispatch root-table uploads go
             # explicit (jax.device_put), so only UNexpected implicit
@@ -2455,10 +2503,11 @@ class TpuSpfSolver:
             root_idx = self._put_counted(np.asarray(root_idx))
             root_nbr = self._put_counted(np.ascontiguousarray(root_nbr))
             root_w = self._put_counted(np.ascontiguousarray(root_w))
+            want_full = self._put_counted(np.asarray(want_full))
         return (
             ad.d_deltas, ad.d_shift_w, ad.d_res_rows, ad.d_res_nbr,
             ad.d_res_w, ad.d_mbuf,
-            root_idx, root_nbr, root_w,
+            root_idx, root_nbr, root_w, want_full,
             *vs.prev,
         )
 
@@ -2603,8 +2652,8 @@ class TpuSpfSolver:
             # o[2:7], the distance plane through o[7], the dirty tail
             # re-applies verbatim
             self._last_exec_incr = (
-                run, args[:9], tuple(new_prev[:5]), new_prev[5],
-                args[15:],
+                run, args[:10], tuple(new_prev[:5]), new_prev[5],
+                args[16:],
             )
         else:
             counters.increment("decision.solver.full.solves")
@@ -2617,7 +2666,7 @@ class TpuSpfSolver:
             # resident pipeline state for device-only throughput probes
             # (bench.py device_compute_ms): re-invokable with outputs
             # fed forward as the next prev
-            self._last_exec = (run, args[:9], tuple(new_prev[:5]))
+            self._last_exec = (run, args[:10], tuple(new_prev[:5]))
         return self._make_prepare(
             pv, variant, kernel_name, delta_buf, full_buf, new_prev
         )
@@ -2674,9 +2723,7 @@ class TpuSpfSolver:
         variant = self._variant(pv0, fused=g)
         kernel_name, run = pipeline_for(variant)
         lanes = [self._lane_args(pv) for pv in group]
-        area_args = tuple(
-            tuple(lane[i] for lane in lanes) for i in range(14)
-        )
+        area_args = tuple(zip(*lanes))
         outs = self._run_exec(
             variant.namespace, kernel_name, pv0["shape_key"], run,
             area_args, pv0["area"],
@@ -2782,7 +2829,13 @@ class TpuSpfSolver:
                 count = int(dbuf[0])
                 trips = int(dbuf[1])
             t2 = _time.monotonic()
+            # the device's own predicate (want_full | count > budget,
+            # _make_pipeline's `compact`): full_buf holds rows in
+            # exactly the epochs that read it
             full_pull = count is None or count > b
+            counters.increment("decision.tpu.epochs")
+            if full_pull:
+                counters.increment("decision.tpu.cold_compactions")
             stats = {
                 "n_cap": plan.n_cap,
                 "s_cap": plan.s_cap,
@@ -2981,6 +3034,7 @@ class TpuSpfSolver:
                     ("tpu.pull", None, t_ready, t2, {
                         "bytes_downloaded": bytes_dl,
                         "full_pull": full_pull,
+                        "cold_compact": full_pull,
                         "changed_rows": count,
                     }),
                     ("tpu.mat", None, t2, t3, mat_attrs),

@@ -386,14 +386,16 @@ class TestSpeculativeBaker:
         key = (16, 4, 8, 4, False, 4, 16, 1)
         assert _next_shape_key(key) == (32, 4, 8, 4, False, 4, 32, 1)
 
-    def test_pipeline_avals_cover_the_14_arg_closure(self):
+    def test_pipeline_avals_cover_the_15_arg_closure(self):
         key = (16, 4, 8, 4, True, 4, 16, 1)
         avals = _pipeline_avals(key)
-        assert len(avals) == 14
+        assert len(avals) == 15
         assert avals[0].shape == (4,)  # deltas [S]
         assert avals[1].shape == (4, 16)  # shift_w [S, N]
         assert avals[5].shape == (6 * 16 * 1,)  # packed matrix buffer
         assert avals[6].shape == ()  # root scalar
+        assert avals[9].shape == ()  # want_full scalar
+        assert avals[10].shape == (16,)  # prev metric [P]
 
 
 # -- solver-level: warm restart + tier flip --------------------------------

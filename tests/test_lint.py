@@ -851,12 +851,13 @@ def test_purity_and_donation_trace_stream_epoch_roots():
         "openr_tpu.ops.stream", "compact_changed_rows"
     )
     # the streaming executable donates the prev planes + distance seed
-    # (positions 9-14) through the conditional dict form — the
-    # read-after-donate rule must see every position
+    # (positions 10-15, after the want_full scalar) through the
+    # conditional dict form — the read-after-donate rule must see every
+    # position
     donated = donation_check._factory_donations(
         g.defs["_build_pipeline"]
     )
-    assert {9, 10, 11, 12, 13, 14} <= donated, donated
+    assert {10, 11, 12, 13, 14, 15} <= donated, donated
     findings = [
         f
         for f in purity_check.run(project) + donation_check.run(project)

@@ -28,6 +28,7 @@ from openr_tpu.models import topologies
 from openr_tpu.ops.edgeplan import _next_pow2
 from openr_tpu.runtime.counters import counters
 from openr_tpu.types import PrefixDatabase, PrefixEntry, PrefixType
+from tests.test_compact_rows import MODES, Recorder
 from tests.test_incremental_spf import _Churn
 from tests.test_tpu_solver import assert_rib_equal
 from tests.test_wan_agg_lanes import _bench_module
@@ -176,6 +177,44 @@ def test_many_prefixes_a_node_with_alternates_match_the_oracle(seed, mode):
         random.Random(f"{seed}/wan/rows"), rows, per_node,
     )
     assert seen["backups"] * 2 >= (n - 1) * per_node, seen
+
+
+@pytest.mark.parametrize("mode", ["full", "incremental", "streaming"])
+def test_32_a_node_ships_what_the_parent_shipped(monkeypatch, mode):
+    """2,048 rows over 64 node columns, LFA on: every dispatch of seeded
+    link downs and ups replayed through the parent commit's pipeline
+    (tests/test_compact_rows.py) — the changed-rows payload bit for bit,
+    32 rows or a multiple an event, found among 16 blocks of 128 rows;
+    the cold pull's 1,504 rows equal where the host reads them and not
+    built in a warm epoch."""
+    per_node = 32
+    adj_dbs, _, states, ps = _fabric(per_node)
+    churn = _Churn(adj_dbs, states)
+    cpu = SpfSolver(ME, enable_lfa=True)
+    tpu = TpuSpfSolver(ME, enable_lfa=True, **MODES[mode])
+    rec = Recorder(monkeypatch, tpu)
+
+    def solve(ctx: str):
+        want = cpu.build_route_db(ME, states, ps)
+        assert_rib_equal(want, tpu.build_route_db(ME, states, ps), ctx)
+
+    solve("the first solve")
+    variant, want_full, count, cold = rec.epochs[0]
+    assert variant.p_cap == 2048 and want_full == 1 and cold
+    # against zeroed planes every row differs (no alternate reads -1), and
+    # 2,048 is under the budget: it is want_full that asks for the table
+    assert count == variant.p_cap < variant.budget
+    rng = random.Random(f"{mode}/32/payload")
+    for u, v in rng.sample([e for e in churn.edges() if ME not in e], 3):
+        saved = churn.dbs[u], churn.dbs[v]
+        churn.link_down(u, v)
+        solve(f"{u} - {v} down")
+        churn.link_up(u, v, *saved)
+        solve(f"{u} - {v} up")
+    warm = rec.epochs[1:]
+    assert len(warm) == 6 and not any(cold for *_, cold in warm), warm
+    assert all(count % per_node == 0 for _, _, count, _ in warm), warm
+    assert any(count for _, _, count, _ in warm), warm
 
 
 @pytest.mark.parametrize("graph", ["fabric5", "fabric32", "wan5"])

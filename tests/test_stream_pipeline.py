@@ -28,6 +28,7 @@ import asyncio
 import errno
 
 import numpy as np
+import pytest
 
 from openr_tpu.config import DecisionConfig
 from openr_tpu.decision.spf_solver import SpfSolver
@@ -37,6 +38,7 @@ from openr_tpu.runtime.counters import counters
 from openr_tpu.runtime.faults import registry
 from openr_tpu.serde import to_plain
 from tests.conftest import run_async
+from tests.test_compact_rows import Recorder, drive_randomized_churn
 from tests.test_column_spine import (
     _per_prefix_ops,
     _scripted_dataplane,
@@ -113,6 +115,57 @@ def test_randomized_churn_stream_parity():
     # the sequence must exercise the streamed lane, not fall back on
     # every round (root-link churn legitimately falls back)
     assert engaged >= 5, engaged
+
+
+@pytest.mark.parametrize("seed", [23, 41])
+def test_randomized_churn_stream_payload_is_the_parents(monkeypatch, seed):
+    """The streamed payload bit for bit: every dispatch of randomized
+    churn replayed through the parent commit's pipeline (`jnp.nonzero`
+    in both compactions, the cold pull built in every epoch) — the delta
+    payload equal in every epoch, the cold pull equal where the host
+    reads it and zeros where it does not (tests/test_compact_rows.py)."""
+    adj_dbs, states, ps = _grid()
+    rec = drive_randomized_churn(
+        monkeypatch, adj_dbs, states, ps, ME, "streaming", seed, steps=10
+    )
+    streamed = [e for e in rec.epochs if e[0].stream]
+    assert len(streamed) >= 5, rec.epochs
+    assert not any(cold for *_, cold in streamed), rec.epochs
+
+
+def test_stream_overflow_finds_its_full_pull_in_the_same_dispatch(
+    monkeypatch
+):
+    """A column of links changes at once: 72 rows against the 64-row
+    floor. The device's own count takes the cold half in that dispatch
+    (want_full 0), the host reads the table it built — no second round
+    trip — and the budget grows; the same change inside the grown
+    budget streams, and builds no table."""
+    adj_dbs, prefix_dbs = topologies.grid(12, node_labels=False)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    churn = _Churn(adj_dbs, states)
+    cpu = SpfSolver(ME)
+    strm = TpuSpfSolver(ME, streaming_pipeline=True)
+    rec = Recorder(monkeypatch, strm)
+    strm.build_route_db(ME, states, ps)
+    assert rec.epochs[-1][1:] == (1, rec.epochs[-1][2], True)
+    seen = []
+    for metric in (5, 1, 7, 7, 1):
+        for row in range(12):
+            churn.set_metric(f"node-{row}-5", f"node-{row}-6", metric)
+        runs0 = len(rec.epochs)
+        got = strm.build_route_db(ME, states, ps)
+        assert_rib_equal(cpu.build_route_db(ME, states, ps), got,
+                         f"column at {metric}")
+        assert len(rec.epochs) == runs0 + 1  # one dispatch an epoch
+        variant, want_full, count, cold = rec.epochs[-1]
+        info = _stream_info(strm)
+        assert want_full == 0 and bool(info.get("overflows")) == cold
+        seen.append((variant.stream, count, cold))
+    assert seen == [
+        (64, 72, True), (256, 72, False), (256, 72, False),
+        (256, 0, False), (64, 72, True),
+    ], seen
 
 
 def test_device_diff_matches_host_column_diff_with_withdrawals():

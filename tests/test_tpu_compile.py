@@ -24,6 +24,7 @@ worker imports every test file (on-chip-measurement guide §2).
 
 import dataclasses
 import os
+import re
 
 import jax
 import numpy as np
@@ -292,4 +293,36 @@ def test_multichip_program_compiles_on_a_described_mesh(
     # ends in a cross-device min (the pmin of parallel/sharding.py)
     text = compiled.as_text()
     assert "all-reduce" in text and "minimum" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_the_prefix_plane_class_compiles_with_one_conditional(
+    one_chip, cache_off
+):
+    """fabric10k_pfx's incremental executable (524,288 rows over 16,384
+    node columns, LFA, the 4,096-row budget) from its variant record
+    alone: the cold pull's half of `compact` is one conditional of the
+    entry computation, and neither compaction left a scan over every row
+    behind (`jnp.nonzero`'s cumsum is a reduce-window of 524,288: 5 s of
+    this compile each, 40 s inside a conditional; PERF.md section 6,
+    PR 37)."""
+    S = jax.ShapeDtypeStruct
+    key = (16384, 1, 32768, 8, True, 8, 524288, 2)
+    record = ts.PipelineVariant.checked(
+        *key, ts._DELTA_BUDGET, True, True, True, emit_dist=True,
+        dirty_cap=64,
+    )
+    avals = ts._pipeline_avals(key) + (
+        S((8, 16384), np.int32),
+        *(S((64,), np.int32) for _ in range(4)),
+        S((), np.int32),
+    )
+    _name, run = ts._build_pipeline(*record)
+    compiled = compile_single(one_chip, run.jitted, avals)
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 1
+    assert 'op_name="jit(pipeline)/compact/cond"' in text
+    # the blocks' totals are scanned ([32, 128]); the rows are not
+    assert re.search(r"= s32\[32,128\]\S* reduce-window\(", text)
+    assert not re.search(r"= s32\[4096,128\]\S* reduce-window\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
