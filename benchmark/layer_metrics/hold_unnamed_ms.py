@@ -1,0 +1,14 @@
+"""Milliseconds a timed event was held by what nobody named: a late heartbeat
+of an actor found the stretch covered by no hold and by no span of the
+loop's thread (a lower bound). The sum of the `runtime.unnamed_hold` hold
+spans the program copied into the traces of the carrying epochs, over the
+window's timed events. A mean per timed event like every span metric, so it
+stands beside the stage the hold was charged to. 0.0 where the program
+keeps holds and none fell in an event; None (left out) on a program without
+the track."""
+
+from loop_holds import per_timed_event
+
+
+def read(series: dict):
+    return per_timed_event(series, "runtime.unnamed_hold")

@@ -675,9 +675,11 @@ class Decision(Actor):
         pending = self.pending
         self.pending = PendingUpdates()
         if pending.first_trigger is not None:
+            # wait=True: the loop was free meanwhile, so the lag probe
+            # (tracer.note_loop_lag) does not count it as work
             tracer.record_span(
                 pending.trace, "decision.debounce",
-                pending.first_trigger, time.monotonic(),
+                pending.first_trigger, time.monotonic(), wait=True,
             )
         if self._solve_q is not None:
             # async dispatch: hand the snapshot to the dispatch fiber
@@ -1284,21 +1286,29 @@ class Decision(Actor):
         (they perturb the RIB now, so replay must apply them)."""
         rec = self._replay
         released = 0
-        for area, key, held in self._overload.damper.releasable():
-            if held is None:
-                continue  # suppressed but never saw another event
-            if held[0] == "kv":
-                _, version, originator, raw = held
-                self._update_key_in_lsdb(area, key, raw)
-                self._note_ingest(area, key, originator)
-                if rec is not None:
-                    rec.record_kv(area, key, version, originator, raw)
-            else:  # ("expire",)
-                self._delete_key_from_lsdb(area, key)
-                self._note_ingest(area, key, "<expired>")
-                if rec is not None:
-                    rec.record_expired(area, key)
-            released += 1
+        damper = self._overload.damper
+        # releasable() walks the damper's whole table on the loop, once a
+        # tick: a hold no event asked for (the tracer's background track)
+        with tracer.hold(
+            "decision.damper_sweep", records=damper.tracked_count()
+        ) as sweep:
+            for area, key, held in damper.releasable():
+                if held is None:
+                    continue  # suppressed but never saw another event
+                if held[0] == "kv":
+                    _, version, originator, raw = held
+                    self._update_key_in_lsdb(area, key, raw)
+                    self._note_ingest(area, key, originator)
+                    if rec is not None:
+                        rec.record_kv(area, key, version, originator, raw)
+                else:  # ("expire",)
+                    self._delete_key_from_lsdb(area, key)
+                    self._note_ingest(area, key, "<expired>")
+                    if rec is not None:
+                        rec.record_expired(area, key)
+                released += 1
+            if sweep is not None:
+                sweep.set(released=released)
         if released and self.pending.count > 0:
             self._trigger_rebuild()
 

@@ -142,6 +142,8 @@ class KvStoreArea:
         self.digest_history: collections.deque[str] = collections.deque(
             maxlen=_DIGEST_HISTORY
         )
+        # calls of digest(): each hashes every key (kvstore.digest hold)
+        self.digests_taken = 0
 
     def hashes(self) -> dict[str, Value]:
         return dump_hash_with_filters(self.area, self.kv).key_vals
@@ -154,6 +156,7 @@ class KvStoreArea:
         stores with equal digests hold the same protocol state; the
         `monitor:` telemetry namespace is excluded (per-node by
         design)."""
+        self.digests_taken += 1
         h = hashlib.blake2b(digest_size=8)
         n = 0
         for key in sorted(self.kv):
@@ -1138,8 +1141,25 @@ class KvStore(Actor):
             await asyncio.sleep(self.cfg.digest_interval_s)
             if not self._probe_admitted():
                 continue
-            self._advertise_digests()
-            self._check_divergence()
+            # each digest() hashes the whole store in one stretch of the
+            # loop: a hold no event asked for, so it goes on the tracer's
+            # background track
+            taken = self._digests_taken()
+            with tracer.hold(
+                "kvstore.digest", areas=len(self.areas)
+            ) as beat:
+                self._advertise_digests()
+                report = self._check_divergence()
+                if beat is not None:
+                    beat.set(
+                        keys=sum(
+                            a["keys"] for a in report["areas"].values()
+                        ),
+                        digests=self._digests_taken() - taken,
+                    )
+
+    def _digests_taken(self) -> int:
+        return sum(st.digests_taken for st in self.areas.values())
 
     def _advertise_digests(self) -> None:
         ttl_ms = max(

@@ -24,6 +24,7 @@ from openr_tpu.runtime import affinity
 from openr_tpu.runtime.counters import counters
 from openr_tpu.runtime.tasks import record_crash, spawn_logged
 from openr_tpu.runtime.throttle import ExponentialBackoff
+from openr_tpu.runtime.tracing import tracer
 
 log = logging.getLogger(__name__)
 
@@ -34,6 +35,11 @@ log = logging.getLogger(__name__)
 SUPERVISOR_CRASH_BUDGET = 3
 SUPERVISOR_BACKOFF_INITIAL_S = 0.05
 SUPERVISOR_BACKOFF_MAX_S = 2.0
+HEARTBEAT_S = 0.1
+# a heartbeat this late asks the tracer what held the loop (the
+# benchmark harness's own SETTLE_LATE_S: the lateness it will not settle
+# over)
+LOOP_LAG_HOLD_S = 0.020
 
 
 class Timer:
@@ -106,6 +112,8 @@ class Actor:
         if affinity.enabled():
             affinity.bind_owner(self, self.name)
         self._running = True
+        # every stack built of actors names the collector's pauses
+        tracer.watch_gc()
         self.add_task(self._heartbeat_loop(), name=f"{self.name}.heartbeat")
         await self.on_start()
 
@@ -285,8 +293,6 @@ class Actor:
             except Exception:  # pragma: no cover - telemetry must not kill
                 log.debug("%s: restart log sample failed", self.name)
         try:
-            from openr_tpu.runtime.tracing import tracer
-
             ctx = tracer.start_trace(
                 "runtime.supervisor.restart",
                 actor=self.name,
@@ -315,9 +321,19 @@ class Actor:
     # -- watchdog hook -----------------------------------------------------
 
     async def _heartbeat_loop(self) -> None:
+        """Stamp liveness for the Watchdog, and read what the stamp's
+        own lateness says: the beat knows when it was due, so a late one
+        is a witness that the loop was held (tracer.note_loop_lag names
+        what nothing else accounts for)."""
+        due = time.monotonic()
         while self._running:
-            self.last_alive_ts = time.monotonic()
-            await asyncio.sleep(0.1)
+            now = self.last_alive_ts = time.monotonic()
+            counters.add_stat_value("runtime.loop_lag_ms", (now - due) * 1e3)
+            tracer.drain_gc()
+            if now - due >= LOOP_LAG_HOLD_S:
+                tracer.note_loop_lag(self.name, due, now)
+            due = now + HEARTBEAT_S
+            await asyncio.sleep(HEARTBEAT_S)
 
     def seconds_since_alive(self) -> float:
         return time.monotonic() - self.last_alive_ts

@@ -181,6 +181,10 @@ class FlapDamper:
                 del self._keys[(area, key)]  # fully calmed: forget
         return out
 
+    def tracked_count(self) -> int:
+        """Records in the table: what one releasable() walks."""
+        return len(self._keys)
+
     def damped_count(self) -> int:
         return sum(1 for rec in self._keys.values() if rec[2])
 

@@ -19,10 +19,21 @@ Design constraints:
 - Opt-out cheap: with tracing disabled start_trace returns None and
   every other entry point takes the None fast path (one attribute
   check); context_of is one dict lookup.
+
+The background track: work that belongs to no event — the interpreter's
+collector, KvStore's digest beacon, the flap damper's sweep — holds the
+one event loop all the same. `hold` / `record_hold` record such a
+stretch as a span that belongs to no trace, kept in a ring of its own
+(`get_holds`, a lane of `export_chrome`), and copy it into every trace
+that was active while it ran, so the one trace an operator pulls says
+what held it. `watch_gc` names the collector's pauses, `note_loop_lag`
+(asked by the actors' heartbeat) the stretches nobody named.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import itertools
 import json
 import math
@@ -45,6 +56,21 @@ MAX_ACTIVE_TRACES = 256
 # many, orphans (contexts of no-longer-active traces) are evicted
 # first, then the oldest entries
 MAX_TRACE_CONTEXTS = 1024
+# ring of holds: one run's, from process start to the end of a
+# benchmark window, with room (a 190 s run at lsdb100k leaves a few
+# hundred: a sweep a second, a beacon every 15 s, the collector's long
+# pauses); oldest dropped, counted in tracing.holds_dropped
+MAX_HOLDS = 16384
+# a trace that never closes (the safety valve above) must not grow by a
+# span a sweep for as long as it lingers: past this many copies a trace
+# takes no more (the ring still has every hold)
+MAX_HOLD_COPIES = 256
+# a collection shorter than this is counted and leaves no span: the
+# young generations run thousands of times a minute, tens of µs each
+GC_HOLD_MIN_S = 0.001
+# an uncovered stretch of a late heartbeat shorter than this is the
+# loop's ordinary turn-taking, not a hold
+UNNAMED_HOLD_MIN_S = 0.005
 
 
 class Span:
@@ -110,12 +136,19 @@ class TraceContext:
 
 
 class _Trace:
-    __slots__ = ("trace_id", "name", "spans", "status", "started", "ended")
+    __slots__ = (
+        "trace_id", "name", "spans", "status", "started", "ended",
+        "hold_copies", "touched",
+    )
 
     def __init__(self, trace_id: int, name: str, started: float):
         self.trace_id = trace_id
         self.name = name
         self.spans: list[Span] = []
+        self.hold_copies = 0
+        # the latest time a span of this trace began or ended: a trace
+        # that lingers is passed over by the lag probe at a glance
+        self.touched = started
         self.status = "active"
         self.started = started
         self.ended: Optional[float] = None
@@ -162,6 +195,18 @@ class _LiveSpan:
         self.span.attributes.update(attrs)
 
 
+class _LiveHold(_LiveSpan):
+    """An open hold: closes like a span, then is filed on the background
+    track (Tracer._file_hold)."""
+
+    __slots__ = ()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        super().__exit__(exc_type, exc, tb)
+        self._tracer._file_hold(self.span)
+        return False
+
+
 class Tracer:
     def __init__(self):
         self._lock = threading.Lock()
@@ -175,6 +220,24 @@ class Tracer:
         # anchor for monotonic -> wall-clock µs mapping in exports
         self._wall_anchor = time.time()
         self._mono_anchor = time.monotonic()
+        # the background track: (when filed, closed hold), oldest first.
+        # A hold is filed at or after its end, so the filing times bound
+        # a search from the newest end back (note_loop_lag)
+        self._holds: collections.deque[tuple[float, Span]] = (
+            collections.deque(maxlen=MAX_HOLDS)
+        )
+        # what the lag probe has looked at: with many actors on one loop
+        # every heartbeat is late by the same hold, and one look is enough
+        self._lag_seen = (0.0, 0.0)
+        self.holds_dropped = 0  # by this ring; the counter is the process's
+        # the collector's hook takes no lock (see _on_gc): it keeps
+        # running totals by generation and queues its long pauses;
+        # drain_gc turns both into counters and holds
+        self._gc_t0: Optional[float] = None  # no collection seen to start
+        self._gc_runs = [0, 0, 0]
+        self._gc_seconds = [0.0, 0.0, 0.0]
+        self._gc_counted = ([0, 0, 0], [0.0, 0.0, 0.0])
+        self._gc_long: collections.deque[tuple] = collections.deque()
 
     # -- config -----------------------------------------------------------
 
@@ -227,9 +290,6 @@ class Tracer:
 
     def active_context_count(self) -> int:
         return len(self._ctx_by_id)
-
-    def detach(self, item: Any) -> Optional[TraceContext]:
-        return self._ctx_by_id.pop(id(item), None)
 
     # -- span lifecycle ---------------------------------------------------
 
@@ -285,6 +345,7 @@ class Tracer:
                 thread=threading.current_thread().name,
             )
             tr.spans.append(span)
+            tr.touched = now
             return span
 
     def end_span(self, span: Optional[Span], **attributes) -> None:
@@ -293,6 +354,9 @@ class Tracer:
         span.end = time.monotonic()
         if attributes:
             span.attributes.update(attributes)
+        tr = self._active.get(span.trace_id)
+        if tr is not None:
+            tr.touched = span.end
 
     def span(
         self,
@@ -334,6 +398,7 @@ class Tracer:
             )
             span.end = end
             tr.spans.append(span)
+            tr.touched = max(tr.touched, end)
             return span
 
     def root_attributes(self, ctx: Optional[TraceContext]) -> dict:
@@ -378,6 +443,9 @@ class Tracer:
         coalesced/no_change closures are not convergence events)."""
         if ctx is None:
             return
+        # a pause of the collector inside this trace is copied into it
+        # while it is still active
+        self.drain_gc()
         now = time.monotonic()
         with self._lock:
             tr = self._active.pop(ctx.trace_id, None)
@@ -405,6 +473,219 @@ class Tracer:
             counters.increment("tracing.traces_closed")
         else:
             counters.increment(f"tracing.traces_{status}")
+
+    # -- the background track: holds of the event loop ---------------------
+
+    def hold(self, name: str, **attributes):
+        """`with tracer.hold("kvstore.digest", areas=1) as h: ...` — a
+        span that belongs to no trace: work on the event loop that no
+        event asked for. Filed in the hold ring when it closes, and
+        copied into every trace active then (see _file_hold). No-op
+        when tracing is off."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _LiveHold(self, Span(
+            next(self._span_seq), 0, name, time.monotonic(),
+            attributes=attributes,
+            thread=threading.current_thread().name,
+        ))
+
+    def record_hold(
+        self,
+        name: str,
+        start: float,
+        end: float,
+        thread: Optional[str] = None,
+        **attributes,
+    ) -> Optional[Span]:
+        """File an already-timed hold (time.monotonic() seconds).
+        `thread` names the thread it ran on where that is not the
+        caller's (a collection is filed by whoever drains the hook)."""
+        if not self.enabled:
+            return None
+        if thread is not None:
+            attributes["thread"] = thread
+        span = Span(
+            next(self._span_seq), 0, name, start,
+            attributes=attributes,
+            thread=thread or threading.current_thread().name,
+        )
+        span.end = end
+        self._file_hold(span)
+        return span
+
+    def _file_hold(self, span: Span) -> None:
+        """Into the ring, and into every trace the hold overlapped as a
+        child of the root, clipped to the trace, attribute hold=True: a
+        trace that took 900 ms then says "kvstore.digest 850". The
+        traces: the active ones (root start < hold end), and those that
+        closed after the hold began — a hold found after the fact (the
+        lag probe's) is found just after the trace it delayed has
+        closed. A trace takes MAX_HOLD_COPIES at most."""
+        with self._lock:
+            dropped = len(self._holds) == self._holds.maxlen
+            self.holds_dropped += dropped
+            self._holds.append((time.monotonic(), span))
+            overlapped = list(self._active.values())
+            for tr in reversed(self._closed):  # in the order they closed
+                if tr.ended <= span.start:
+                    break
+                overlapped.append(tr)
+            for tr in overlapped:
+                start = max(span.start, tr.started)
+                end = span.end if tr.ended is None else min(
+                    span.end, tr.ended
+                )
+                if start >= end or tr.hold_copies >= MAX_HOLD_COPIES:
+                    continue
+                tr.hold_copies += 1
+                copy = Span(
+                    next(self._span_seq), tr.trace_id, span.name, start,
+                    parent_id=tr.spans[0].span_id,
+                    attributes={**span.attributes, "hold": True},
+                    thread=span.thread,
+                )
+                copy.end = end
+                tr.spans.append(copy)  # not a touch: the ring covers it
+        if dropped:
+            counters.increment("tracing.holds_dropped")
+
+    def get_holds(
+        self, since: Optional[float] = None, until: Optional[float] = None
+    ) -> list[dict]:
+        """The ring's holds that overlap [since, until] (monotonic
+        seconds; None = unbounded), oldest filed first, as to_dict()
+        gives them. The ring keeps the newest MAX_HOLDS: where
+        `holds_dropped` has moved, whatever ended before the first
+        entry's end may be missing."""
+        self.drain_gc()
+        with self._lock:
+            holds = list(self._holds)
+        return [
+            h.to_dict() for _, h in holds
+            if (since is None or h.end > since)
+            and (until is None or h.start < until)
+        ]
+
+    def watch_gc(self) -> None:
+        """Hang the collector's hook on gc.callbacks, once however
+        often it is asked (every Actor.start asks)."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """gc.callbacks hook. A collection starts wherever the
+        interpreter happens to be — inside this tracer's lock or the
+        counter registry's, neither re-entrant — so the hook takes no
+        lock and calls nothing that does: two clock reads and two adds,
+        and a queue entry for a pause long enough to be a hold. The
+        interpreter runs one collection at a time, whichever thread set
+        it off, so the fields have one writer."""
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+            return
+        start, end = self._gc_t0, time.monotonic()
+        if start is None:  # hung on gc.callbacks in mid-collection
+            return
+        gen = info["generation"]
+        self._gc_runs[gen] += 1
+        self._gc_seconds[gen] += end - start
+        if end - start >= GC_HOLD_MIN_S:
+            self._gc_long.append((
+                gen, start, end, info["collected"],
+                threading.current_thread().name,
+            ))
+
+    def drain_gc(self) -> None:
+        """What the hook left, from a place where locks may be taken
+        (a heartbeat, a trace's end, a reader): the counters
+        runtime.gc.collections / .pause_ms and their .gen2 twins, and a
+        runtime.gc hold for each long pause."""
+        if self._gc_runs == self._gc_counted[0] and not self._gc_long:
+            return
+        with self._lock:
+            runs, seconds = list(self._gc_runs), list(self._gc_seconds)
+            (was_runs, was_seconds) = self._gc_counted
+            self._gc_counted = (runs, seconds)
+        n = sum(runs) - sum(was_runs)
+        if n > 0:
+            counters.increment("runtime.gc.collections", n)
+            counters.increment(
+                "runtime.gc.pause_ms",
+                (sum(seconds) - sum(was_seconds)) * 1e3,
+            )
+        if runs[2] > was_runs[2]:
+            counters.increment(
+                "runtime.gc.collections.gen2", runs[2] - was_runs[2]
+            )
+            counters.increment(
+                "runtime.gc.pause_ms.gen2",
+                (seconds[2] - was_seconds[2]) * 1e3,
+            )
+        while self._gc_long:
+            try:
+                gen, start, end, collected, thread = self._gc_long.popleft()
+            except IndexError:  # another drainer took the last
+                break
+            self.record_hold(
+                "runtime.gc", start, end, thread=thread,
+                generation=gen, collected=collected,
+            )
+
+    def note_loop_lag(self, actor: str, due: float, now: float) -> None:
+        """An actor's heartbeat was due at `due` and ran at `now`: the
+        loop was not free in between. Whatever of [due, now] nothing
+        accounts for becomes a runtime.unnamed_hold. What accounts for
+        time: the holds of any thread (a collection stops the
+        interpreter whichever thread set it off), and the closed spans
+        that active and just-closed traces recorded on this, the loop's,
+        thread — a 160 ms decision.rib_diff is work, not a hold. A span
+        still open cannot be what held the loop (its coroutine is
+        suspended, or this callback would not run), and a span marked
+        wait=True (decision.debounce) timed a wait with the loop free.
+        A lower bound: a hold is seen from the first beat due inside it,
+        so up to one heartbeat interval of its start is not. Of the
+        many actors of one loop, all late by the same hold, only those
+        whose beat reaches further back than what was looked at look
+        again."""
+        if not self.enabled:
+            return
+        lo, hi = self._lag_seen
+        if lo - UNNAMED_HOLD_MIN_S <= due and now <= hi + UNNAMED_HOLD_MIN_S:
+            return  # another actor's beat has looked at this stretch
+        self._lag_seen = (min(lo, due) if due <= hi else due, now)
+        self.drain_gc()
+        me = threading.current_thread().name
+        with self._lock:
+            cover = []
+            for filed, h in reversed(self._holds):
+                if filed < due:
+                    break  # it ended by then, and so did all before it
+                if h.end > due and h.start < now:
+                    cover.append((h.start, h.end))
+            traces = [
+                tr for tr in self._active.values() if tr.touched > due
+            ]
+            for tr in reversed(self._closed):
+                if tr.ended is None or tr.ended < due:
+                    break
+                traces.append(tr)
+            for tr in traces:
+                cover.extend(
+                    (sp.start, sp.end) for sp in tr.spans[1:]
+                    if sp.end is not None and sp.thread == me
+                    and sp.end > due and sp.start < now
+                    and not sp.attributes.get("wait")
+                )
+        cover.sort()
+        at = due
+        for start, end in cover + [(now, now)]:
+            if start - at >= UNNAMED_HOLD_MIN_S:
+                self.record_hold(
+                    "runtime.unnamed_hold", at, start,
+                    actor=actor, lag_ms=(now - due) * 1e3,
+                )
+            at = max(at, end)
 
     # -- introspection (ctrl server / breeze) -----------------------------
 
@@ -442,13 +723,21 @@ class Tracer:
         """Chrome trace-event JSON (the `{"traceEvents": [...]}` object
         form): one "X" complete event per closed span with ts/dur in
         wall-clock µs, plus "M" thread_name metadata rows. Load in
-        chrome://tracing or ui.perfetto.dev."""
+        chrome://tracing or ui.perfetto.dev. The holds of the event loop
+        that overlap the exported traces (all of the ring where no trace
+        is exported) go in a process lane of their own, "loop holds"."""
+        self.drain_gc()
         with self._lock:
             picked = [
                 t for t in self._closed
                 if trace_id is None or t.trace_id == trace_id
             ][-max(1, limit):]
             wall0, mono0 = self._wall_anchor, self._mono_anchor
+            lo = min((t.started for t in picked), default=-math.inf)
+            hi = max((t.ended for t in picked), default=math.inf)
+            holds = [
+                h for _, h in self._holds if h.end >= lo and h.start <= hi
+            ]
         # one process lane per NODE (the root span's `node` attribute):
         # a stitched fleet trace renders each node's kvstore→decision→fib
         # tree in its own lane; traces without a node attr (e.g.
@@ -457,10 +746,15 @@ class Tracer:
         pids: dict[str, int] = {}
         tids: dict[tuple[int, str], int] = {}
         events: list[dict] = []
-        for t in picked:
-            node = str(t.spans[0].attributes.get("node") or fallback)
+        lanes = [
+            (str(t.spans[0].attributes.get("node") or fallback), t.name,
+             t.spans) for t in picked
+        ]
+        if holds:
+            lanes.append(("loop holds", "hold", holds))
+        for node, cat, spans in lanes:
             pid = pids.setdefault(node, len(pids) + 1)
-            for s in t.spans:
+            for s in spans:
                 if s.end is None:
                     continue
                 tid = tids.setdefault(
@@ -469,7 +763,7 @@ class Tracer:
                 ts_us = (wall0 + (s.start - mono0)) * 1e6
                 events.append({
                     "name": s.name,
-                    "cat": t.name,
+                    "cat": cat,
                     "ph": "X",
                     "ts": ts_us,
                     "dur": max(0.0, (s.end - s.start) * 1e6),
@@ -550,6 +844,10 @@ class Tracer:
             self._active.clear()
             self._closed.clear()
             self._ctx_by_id.clear()
+            self._holds.clear()
+            self.holds_dropped = 0
+            self._lag_seen = (0.0, 0.0)
+            self._gc_long.clear()
 
 
 # the process-wide instance (pattern of runtime.counters.counters)

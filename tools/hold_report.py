@@ -1,0 +1,80 @@
+#!/usr/bin/env python3
+"""One run of a benchmark cell with its holds of the event loop written out.
+
+    python3 tools/hold_report.py --workload <cell> --seed <n> --seconds 45 \
+        --trace 1 [--root ...] [--rehearse]
+
+Runs `benchmark/run.py`'s `main` with the same arguments (so the run's own
+lines, the result line among them, are printed as ever) and then writes
+`chiprun_out/holds.<cell>.<seed>.json`: every hold the tracer's background
+track kept from the process's start (`tracer.get_holds()`), the measured
+window's bounds and events (due, sent, acked), and the harness's own stamps
+of the collector — what PERF.md's section 5 and 6 quote per hold. A
+builder's tool: it edits nothing of the benchmark and the program has no
+such exporter.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OUT = os.path.join(ROOT, "chiprun_out")
+sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
+
+import run  # noqa: E402  (stamps T_PROCESS)
+
+
+def main(argv=None) -> int:
+    import harness
+
+    args = run.parse_args(argv)
+    kept: dict = {}
+    window = harness.Session.window
+
+    async def keep(self, *a, **kw):
+        result = await window(self, *a, **kw)
+        if "sample_seed" in kw:  # the measured window, not the warm-up's
+            kept.update(window=result, collections=list(self.collections))
+        return result
+
+    harness.Session.window = keep
+    try:
+        rc = run.main(argv)
+    finally:
+        harness.Session.window = window
+    from openr_tpu.runtime.counters import counters
+    from openr_tpu.runtime.tracing import tracer
+
+    w = kept.get("window") or {}
+    out = {
+        "workload": args.workload, "seed": args.seed, "rc": rc,
+        "t_process": run.T_PROCESS,
+        "window": {k: w.get(k) for k in ("start", "end", "seconds")},
+        "events": [
+            {k: ev.get(k) for k in
+             ("due", "sent", "acked", "timed", "class", "ack_epoch")}
+            for ev in w.get("events", ())
+        ],
+        "harness_collections": kept.get("collections", []),
+        "holds": tracer.get_holds(),
+        "holds_dropped": tracer.holds_dropped,
+        "counters": {
+            **counters.get_counters("runtime.gc."),
+            **counters.get_counters("tracing.holds"),
+        },
+        "loop_lag_ms": counters.get_statistics("runtime.loop_lag_ms"),
+    }
+    os.makedirs(OUT, exist_ok=True)
+    path = os.path.join(OUT, f"holds.{args.workload}.{args.seed}.json")
+    with open(path, "w") as f:
+        json.dump(out, f)
+    print(json.dumps({"holds_written": path, "holds": len(out["holds"])}),
+          file=sys.stderr)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
