@@ -103,10 +103,6 @@ _NEG = -(2**31)
 # pull (one extra round trip, still a single buffer)
 _DELTA_BUDGET = 4096
 
-# relaxation steps fused per while_loop trip (steps past the fixpoint are
-# no-ops; fusing amortizes per-trip dispatch) — owned by ops/relax.py
-_UNROLL = relax_ops.UNROLL
-
 # numerical-health sentinel threshold: finite metrics past 2^28 sit one
 # metric-add away from the 2^29 INF_E encoding — saturation territory
 # the int32 metric algebra cannot flag on its own
@@ -223,7 +219,10 @@ def _plan_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
             relax, dist0, deltas, sw, lambda k: sw[k],
             n_cap, s_cap, delta_exp,
         )
-    dist, trips, rounds = relax_ops.run_sync(relax, dist0, max_trips)
+    quantum = relax_ops.sync_quantum(has_res)
+    dist, trips, rounds = relax_ops.run_sync(
+        relax, dist0, max_trips * relax_ops.UNROLL // quantum, quantum
+    )
     return dist, trips, rounds
 
 
@@ -355,7 +354,8 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 (prev_dist, s_dirty_idx, s_dirty_old,
                  r_dirty_idx, r_dirty_old, cone_limit) = incr_args
                 if mesh is not None:
-                    dist_d, trips_v, cone_v, fell_v, rounds_v = mc_sssp_incr(
+                    (dist_d, trips_v, cone_v, fell_v, rounds_v,
+                     cone_passes_v) = mc_sssp_incr(
                         deltas, shift_w, res_rows, res_nbr, res_w, root,
                         root_nbr, root_w, prev_dist,
                         s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
@@ -363,9 +363,11 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                     )
                     trips = trips_v.max()
                     rounds = rounds_v.max()
+                    cone_passes = cone_passes_v.max()
                     cone, fell_back = cone_v[0], fell_v[0]
                 else:
-                    dist_d, trips, cone, fell_back, rounds = incremental_sssp(
+                    (dist_d, trips, cone, fell_back, rounds,
+                     cone_passes) = incremental_sssp(
                         deltas, shift_w, res_rows, res_nbr, res_w, root,
                         root_nbr, root_w, prev_dist,
                         s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
@@ -533,14 +535,17 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 delta_parts += [unreach[None], saturated[None]]
                 full_parts += [unreach[None], saturated[None]]
             if incr:
-                # cone + in-kernel-fallback flag (the host parses the tail
-                # back to front: [-3]=cone, [-2]=fell_back, with the
-                # sentinels at [-5]/[-4] when enabled, rounds always at [-1])
-                tail = [cone[None], fell_back.astype(jnp.int32)[None]]
+                # the cone loop's executed passes, cone + in-kernel-fallback
+                # flag (the host parses the tail back to front:
+                # [-4]=cone_passes, [-3]=cone, [-2]=fell_back, with the
+                # sentinels at [-6]/[-5] when enabled, rounds always at [-1])
+                tail = [cone_passes[None].astype(jnp.int32), cone[None],
+                        fell_back.astype(jnp.int32)[None]]
                 delta_parts += tail
                 full_parts += tail
             # executed-relaxation work metric rides LAST unconditionally:
-            # sync rounds = trips * UNROLL; bucketed rounds = ladder passes
+            # sync rounds = trips * the trip's quantum (relax_ops.
+            # sync_quantum); bucketed rounds = ladder passes
             # + one handoff relaxation per bucket epoch (trips = epochs)
             delta_parts += [rounds[None].astype(jnp.int32)]
             full_parts += [rounds[None].astype(jnp.int32)]
@@ -1632,6 +1637,7 @@ class TpuSpfSolver:
         incremental = False
         multichip: dict | bool = False
         rounds_total = 0
+        cone_passes_total = 0
         bucket_epochs_total = 0
         halo_total = 0
         bucketed_engaged = False
@@ -1646,6 +1652,7 @@ class TpuSpfSolver:
             # relaxation-work ledger (ISSUE 13): per-solve totals feed
             # decision.device.* stats + last_timing for bench/convergence
             rounds_total += int(stats.get("rounds") or 0)
+            cone_passes_total += int(stats.get("cone_passes") or 0)
             bucket_epochs_total += int(stats.get("bucket_epochs") or 0)
             halo_total += int(stats.get("halo_exchanges") or 0)
             # download ledger (ISSUE 16): every path reports its pulled
@@ -1662,8 +1669,12 @@ class TpuSpfSolver:
                 # a warm re-relax converges in a trip or two — not a
                 # diameter bound the sharded fabric path may reuse
                 incremental = True
-            else:
+            elif stats.get("spf_kernel") == "bucketed":
                 self.last_trips = stats["trips"]
+            else:
+                # in trips of UNROLL, whatever the loop's own quantum:
+                # the sharded fabric path iterates in those
+                self.last_trips = -(-stats["rounds"] // relax_ops.UNROLL)
             if stats.get("multichip"):
                 multichip = stats["multichip"]
             self.last_device_stats = stats
@@ -1726,6 +1737,7 @@ class TpuSpfSolver:
             "incremental": incremental,
             "multichip": multichip,
             "rounds": rounds_total,
+            "cone_passes": cone_passes_total,
             "bucket_epochs": bucket_epochs_total,
             "halo_exchanges": halo_total,
             "spf_kernel": "bucketed" if bucketed_engaged else "sync",
@@ -2902,16 +2914,24 @@ class TpuSpfSolver:
                     )
             # tail layout, back to front: [-1] is always the executed-
             # relaxation rounds scalar; the incremental kernel's
-            # [cone, fell_back] sit at [-3]/[-2]; the sentinel scalars
-            # precede whichever of those are present
+            # [cone_passes, cone, fell_back] sit at [-4]/[-3]/[-2]; the
+            # sentinel scalars precede whichever of those are present
             sbuf = fbuf if full_pull else dbuf
             rounds = int(sbuf[-1])
+            wait_attrs = {"rounds": rounds}
             if incr:
+                cone_passes = int(sbuf[-4])
                 cone = int(sbuf[-3])
                 fell_back = bool(sbuf[-2])
                 stats["incremental"] = True
                 stats["cone"] = cone
                 stats["fell_back"] = fell_back
+                # how often the cone loop engaged: its passes, and the
+                # epochs in which no edge grew and it did not start
+                stats["cone_passes"] = wait_attrs["cone_passes"] = cone_passes
+                counters.increment("decision.tpu.cone_passes", cone_passes)
+                if not cone_passes:
+                    counters.increment("decision.tpu.cone_skips")
                 if fell_back:
                     counters.increment(
                         "decision.solver.incr.full_fallbacks"
@@ -2926,7 +2946,7 @@ class TpuSpfSolver:
                     "decision.solver.incr.changed_rows", count or 0
                 )
             if sentinels:
-                off = -3 if incr else -1
+                off = -4 if incr else -1
                 stats["sentinels"] = {
                     "unreachable_rows": int(sbuf[off - 2]),
                     "saturated_rows": int(sbuf[off - 1]),
@@ -3029,7 +3049,7 @@ class TpuSpfSolver:
                         "lanes": d_cap, "rows": p_cap,
                     }),
                     ("tpu.device_wait", None, t_disp, t_ready, {
-                        "rounds": rounds, "relax_bytes": relax_bytes,
+                        **wait_attrs, "relax_bytes": relax_bytes,
                     }),
                     ("tpu.pull", None, t_ready, t2, {
                         "bytes_downloaded": bytes_dl,

@@ -45,7 +45,6 @@ from __future__ import annotations
 from openr_tpu.ops import relax as relax_ops
 
 INF_E = 1 << 29  # matches edgeplan.INF32E / tpu_solver.INF_E
-_UNROLL = relax_ops.UNROLL  # relax/propagate steps per while_loop trip
 
 
 def _old_planes(shift_w, res_w, s_dirty_idx, s_dirty_old,
@@ -156,7 +155,7 @@ def incremental_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
                      r_dirty_idx, r_dirty_old, cone_limit,
                      s_cap: int, has_res: bool, n_cap: int, d_cap: int,
                      max_trips: int, kernel: str = "sync",
-                     delta_exp: int = 0):
+                     delta_exp: int = 0, quantum: int | None = None):
     """Incremental counterpart of tpu_solver._plan_sssp. Same resident
     inputs plus: prev_dist [D, N] (the last solve's per-slot plane),
     consolidated dirty tuples (flat index into the raveled shift /
@@ -165,10 +164,20 @@ def incremental_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
     affected-cone budget in node-lanes). `kernel` selects the final
     re-relaxation's implementation (ops/relax.py sync rounds or
     bucketed Δ-stepping) — either way the fixpoint is unique, so the
-    output stays bit-identical to the cold solve. Returns
-    (dist [D, N], trips, cone, fell_back, rounds)."""
+    output stays bit-identical to the cold solve. `quantum` is the
+    passes a trip of the cone's closure and of the sync re-relaxation
+    (relax_ops.sync_quantum(has_res) unless a test says otherwise):
+    how often they test for change, never what they converge to.
+    Returns (dist [D, N], trips, cone, fell_back, rounds, cone_passes)
+    — cone_passes the closure's executed passes: 0 where no edge grew
+    (a restore, a decrease: the loop does not start), depth + 1 at
+    quantum 1."""
     import jax
     import jax.numpy as jnp
+
+    if quantum is None:
+        quantum = relax_ops.sync_quantum(has_res)
+    bound = max_trips * relax_ops.UNROLL // quantum
 
     # root-masked weight planes, new and old
     swm_new = shift_w.at[:, root].set(INF_E)
@@ -252,18 +261,11 @@ def incremental_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
                 acc = acc.at[:, rows_s].max(contrib, mode="drop")
             return acc
 
-        def aff_body(state):
-            acc, _, t = state
-            new = acc
-            for _ in range(_UNROLL):
-                new = aff_step(new)
-            return new, jnp.any(new != acc), t + 1
-
-        def aff_cond(state):
-            return state[1] & (state[2] < max_trips)
-
-        aff, _, _ = jax.lax.while_loop(
-            aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
+        # an empty cone has no descendants: the loop opens only where
+        # an increased edge seeded one
+        aff, _, cone_passes = relax_ops.run_sync(
+            aff_step, aff, bound, quantum, start=jnp.any(aff > 0),
+            scope="seed.cone",
         )
 
         cone = aff.sum().astype(jnp.int32)
@@ -293,13 +295,16 @@ def incremental_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
             n_cap, s_cap, delta_exp,
         )
     else:
-        dist, trips, rounds = relax_ops.run_sync(relax, dist0, max_trips)
-    return dist, trips, cone, fell_back, rounds
+        dist, trips, rounds = relax_ops.run_sync(
+            relax, dist0, bound, quantum
+        )
+    return dist, trips, cone, fell_back, rounds, cone_passes
 
 
 def jit_incremental_sssp(s_cap: int, has_res: bool, n_cap: int,
                          d_cap: int, max_trips: int,
-                         kernel: str = "sync", delta_exp: int = 0):
+                         kernel: str = "sync", delta_exp: int = 0,
+                         quantum: int | None = None):
     """Standalone jitted wrapper for unit tests; production composes
     incremental_sssp into the solver pipeline tail instead."""
     import jax
@@ -309,4 +314,5 @@ def jit_incremental_sssp(s_cap: int, has_res: bool, n_cap: int,
         incremental_sssp,
         s_cap=s_cap, has_res=has_res, n_cap=n_cap, d_cap=d_cap,
         max_trips=max_trips, kernel=kernel, delta_exp=delta_exp,
+        quantum=quantum,
     ))

@@ -9,7 +9,9 @@ relaxation semantics exist exactly once and every path picks its
 implementation through the same two entry points:
 
 - ``run_sync``:   the classic synchronous rounds. One full relaxation
-  per round, ``UNROLL`` rounds per while_loop trip, data-dependent exit.
+  per round, ``quantum`` rounds per while_loop trip and one no-change
+  test a trip: every round where a round is dear (``sync_quantum``: a
+  mirror with a residual), every ``UNROLL`` where it is a few rolls.
   In the multichip tier each relaxation carries one ``lax.pmin`` halo
   exchange — rounds are the unit of inter-chip traffic.
 - ``run_bucketed``: bucketed Δ-stepping. Edges are classified light
@@ -49,9 +51,13 @@ import numpy as np
 # effectively-infinite metric, same discipline as ops/edgeplan.INF32E
 INF_E = 1 << 29
 
-# relaxations fused per while_loop trip. Shared by every consumer so
-# trip counts stay comparable across the full / incremental / sweep /
-# multichip paths (bench and last_timing report them side by side).
+# relaxations fused per while_loop trip where a relaxation is cheap
+# beside the trip's no-change test, and the unit `max_trips` counts in.
+# What stays comparable across the full / incremental / sweep /
+# multichip paths (bench and last_timing report them side by side) is
+# `rounds`, the executed relaxations; a trip is `UNROLL` of them in the
+# sweep, KSP2 and multichip loops and `sync_quantum(has_res)` in the
+# single-chip solves.
 UNROLL = 8
 
 # bucketed ladder shape: at most this many light shift classes ride the
@@ -69,6 +75,18 @@ def max_trips(n_cap: int) -> int:
     shortest path visits <= n_cap nodes, +2 trips of slack for the
     detect-no-change exit."""
     return max(2, -(-n_cap // UNROLL) + 2)
+
+
+def sync_quantum(has_res: bool) -> int:
+    """Applications a trip of the single-chip solves' fixpoint loops
+    (the relaxation and seed.cone's closure), from what a pass costs.
+    Over a residual a pass gathers and scatters the whole ELL (1-2.4 ms
+    at the 10k-50k classes) and the test reads two [lanes, n_cap] planes
+    (tens of µs): test after every pass, so the loop stops one pass
+    after the last change. With shift classes alone a pass is a few
+    rolls (~0.08 ms at n_cap 131072), the test is no longer free beside
+    it, and trips of ``UNROLL`` amortise it."""
+    return 1 if has_res else UNROLL
 
 
 def fixpoint_bound(n_cap: int) -> int:
@@ -129,7 +147,9 @@ def relax_bytes(kernel: str, rounds: int, trips: int, s_cap: int,
     laddered class reads and writes the plane and reads its rung's
     weight row, then the rungs double (two rows read, one written). A
     trip's no-change test reads two planes. The sync kernel runs
-    ``rounds`` full relaxations in ``trips`` trips; the bucketed one
+    ``rounds`` full relaxations in ``trips`` trips — ``run_sync``'s
+    ``quantum`` relaxations each, so ``trips == rounds`` where the loop
+    tests after every pass; the bucketed one
     ``trips`` epochs of one full relaxation each and ``rounds - trips``
     ladder passes. Pad classes and pad rows count: the device runs
     them. Over a measured loop time this gives an achieved rate to set
@@ -188,14 +208,21 @@ def make_relax(deltas, s_cap: int, w_of, residual=None, combine=None):
     return relax
 
 
-def run_sync(relax, state0, bound: int):
-    """Synchronous rounds to fixpoint: ``UNROLL`` applications of
+def run_sync(relax, state0, bound: int, quantum: int = UNROLL, start=True,
+             scope: str = "relax"):
+    """Synchronous rounds to fixpoint: ``quantum`` applications of
     ``relax`` per trip, exiting on the first no-change trip or at
-    ``bound`` trips. Generic over the plane type (int32 distance
-    planes, the legacy ELL mirror, boolean next-hop planes) — ``relax``
-    must be monotone so the no-change exit certifies the fixpoint.
+    ``bound`` trips (a caller holding ``max_trips(n_cap)`` trips of
+    ``UNROLL`` passes ``max_trips * UNROLL // quantum``: the bound on
+    applications stays). ``start`` is the carried "changed" flag the loop
+    opens with, traced or not: a caller that can tell an input already at
+    its fixpoint passes False there and the loop runs no trip. Generic
+    over the plane type (int32 distance planes, the cone's 0/1 closure,
+    boolean next-hop planes) — ``relax`` must be monotone so the
+    no-change exit certifies the fixpoint. ``scope`` names the loop for
+    the device trace's ``by_scope``.
 
-    Returns ``(state, trips, rounds)`` with ``rounds = trips * UNROLL``
+    Returns ``(state, trips, rounds)`` with ``rounds = trips * quantum``
     (every executed relaxation counts, converged tail included)."""
     import jax
     import jax.numpy as jnp
@@ -203,18 +230,18 @@ def run_sync(relax, state0, bound: int):
     def body(s):
         cur, _, t = s
         new = cur
-        for _ in range(UNROLL):
+        for _ in range(quantum):
             new = relax(new)
         return new, jnp.any(new != cur), t + 1
 
     def cond(s):
         return s[1] & (s[2] < bound)
 
-    with jax.named_scope("relax"):
+    with jax.named_scope(scope):
         state, _, trips = jax.lax.while_loop(
-            cond, body, (state0, jnp.bool_(True), jnp.int32(0))
+            cond, body, (state0, jnp.asarray(start, bool), jnp.int32(0))
         )
-    return state, trips, trips * jnp.int32(UNROLL)
+    return state, trips, trips * jnp.int32(quantum)
 
 
 def run_bucketed(relax, dist0, deltas, score_w, w_of, n_cap: int,

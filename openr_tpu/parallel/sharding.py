@@ -455,9 +455,10 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
 
     Returns a callable (...incremental_sssp args...) ->
     (dist [D, N] P('batch', None), trips [batch], cone [1],
-    fell_back [1], rounds [batch]). The final re-relaxation consumes
-    ops/relax.py like make_mc_sssp — under the bucketed kernel its
-    halo exchange likewise drops to one pmin per bucket epoch."""
+    fell_back [1], rounds [batch], cone_passes [batch]: the passes the
+    cone's closure ran, whole trips of 8 here). The final re-relaxation
+    consumes ops/relax.py like make_mc_sssp — under the bucketed kernel
+    its halo exchange likewise drops to one pmin per bucket epoch."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -617,7 +618,7 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             def aff_cond(state):
                 return state[1] & (state[2] < max_trips)
 
-            aff, _, _ = jax.lax.while_loop(
+            aff, _, cone_trips = jax.lax.while_loop(
                 aff_cond, aff_body, (aff, jnp.bool_(True), jnp.int32(0))
             )
 
@@ -660,7 +661,8 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             dist, trips, rounds = relax_ops.run_sync(
                 relax, dist0, max_trips
             )
-        return dist, trips[None], cone[None], fell_back[None], rounds[None]
+        return (dist, trips[None], cone[None], fell_back[None], rounds[None],
+                (cone_trips * _UNROLL)[None])
 
     from jax import shard_map
 
@@ -679,7 +681,7 @@ def make_mc_incremental_sssp(mesh, s_cap: int, has_res: bool,
             P(),                 # cone_limit
         ),
         out_specs=(
-            P("batch", None), P("batch"), P(), P(), P("batch"),
+            P("batch", None), P("batch"), P(), P(), P("batch"), P("batch"),
         ),
         check_vma=False,
     )

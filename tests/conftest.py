@@ -52,8 +52,12 @@ def fresh_xla_cache_state():
     )
     old_applied = xc._applied
     old_cfg = {k: getattr(jax.config, k) for k in keys}
+    old_aot = xc.get_aot().dir
     xc._applied = None
     yield xc
     xc._applied = old_applied
+    # the prewarm tool turns the AOT store on ('auto'): left on, it
+    # answers whichever test file this worker runs next
+    xc.configure_aot(old_aot or "off")
     for k, v in old_cfg.items():
         jax.config.update(k, v)

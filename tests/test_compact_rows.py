@@ -183,7 +183,7 @@ def record_variants(monkeypatch) -> dict:
 
 def tail_len(variant: ts.PipelineVariant) -> int:
     """Scalars after the rows of either pull buffer."""
-    return 2 * variant.sentinels + 2 * variant.incr + 1
+    return 2 * variant.sentinels + 3 * variant.incr + 1
 
 
 class Recorder:

@@ -17,6 +17,8 @@ edges, dirty-set overflow) are driven explicitly and checked against
 the decision.solver.incr.* counter split.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -562,3 +564,219 @@ def test_parent_key_holds_the_widest_shapes():
     wide = jax.ShapeDtypeStruct((4, 4096), np.int32)
     with pytest.raises(AssertionError):
         jax.eval_shape(plane, *args[:3], wide, wide, args[5])
+
+
+# -- how often the two fixpoint loops test for change (ISSUE 39) ------------
+
+_N, _CHAIN, _ROOT = 16, 14, 15  # n_cap, the chain 0 - 1 - ... - 13, the vantage
+
+
+def _chain_weights(**links):
+    """Directed weights of the chain at unit metrics, both directions of
+    link `a_b` at the metric given."""
+    w = {}
+    for i in range(_CHAIN - 1):
+        w[i, i + 1] = w[i + 1, i] = 1
+    for name, metric in links.items():
+        a, b = map(int, name[1:].split("_"))
+        w[a, b] = w[b, a] = metric
+    return w
+
+
+# event -> (old links, new links, cone_limit, the cone it invalidates)
+_CHAIN_EVENTS = {
+    "restore": (dict(l6_7=_INF), {}, 1000, range(0)),
+    "leaf": ({}, dict(l12_13=5), 1000, range(13, 14)),
+    "spread": ({}, dict(l2_3=5), 1000, range(3, 14)),  # depth 10
+    "fallback": ({}, dict(l2_3=5), 3, range(3, 14)),
+    "decrease": (dict(l2_3=5), {}, 1000, range(0)),
+}
+
+
+def _chain_mirror(has_res: bool, w: dict):
+    """The chain as the device holds it: two shift classes (+1, -1), or
+    one residual row a destination, two slots wide, beside a shift class
+    with no edge (offset 5: the cone's closure follows parents, not
+    weights, and no node's parent lies 5 behind it). Returns (mirror arrays, flat slot of each directed edge
+    in the plane that carries it)."""
+    slot = {}
+    if has_res:
+        deltas = np.array([5], np.int32)
+        shift_w = np.full((1, _N), _INF, np.int32)
+        res_rows = np.where(np.arange(_N) < _CHAIN, np.arange(_N), -1)
+        res_nbr = np.full((_N, 2), -1, np.int32)
+        res_w = np.full((_N, 2), _INF, np.int32)
+        for v in range(_CHAIN):
+            for c, u in enumerate((v - 1, v + 1)):
+                if (u, v) in w:
+                    res_nbr[v, c], res_w[v, c] = u, w[u, v]
+                    slot[u, v] = v * 2 + c
+    else:
+        deltas = np.array([1, _N - 1], np.int32)
+        shift_w = np.full((2, _N), _INF, np.int32)
+        res_rows = np.full(1, -1, np.int32)
+        res_nbr = np.full((1, 1), -1, np.int32)
+        res_w = np.full((1, 1), _INF, np.int32)
+        for (u, v), metric in w.items():
+            k = 0 if v == u + 1 else 1
+            shift_w[k, u] = metric
+            slot[u, v] = k * _N + u
+    return (deltas, shift_w, res_rows.astype(np.int32), res_nbr, res_w), slot
+
+
+def _chain_fixpoint(w: dict, dist0: np.ndarray):
+    """Jacobi rounds over the chain from `dist0` [N]: the fixpoint and
+    the number of rounds in which a distance moved."""
+    dist, moved = dist0.astype(np.int64), 0
+    while True:
+        new = dist.copy()
+        for (u, v), metric in w.items():
+            new[v] = min(new[v], dist[u] + metric)
+        new = np.minimum(new, _INF)
+        if (new == dist).all():
+            return dist.astype(np.int32), moved
+        dist, moved = new, moved + 1
+
+
+def _chain_cold():
+    cold = np.full(_N, _INF, np.int32)
+    cold[0] = 0  # the vantage's one live link lands on node 0
+    return cold
+
+
+@functools.lru_cache(maxsize=None)
+def _chain_kernels(has_res: bool, quantum: int):
+    """(incremental, cold) executables of the chain's shape class."""
+    import jax
+
+    from openr_tpu.decision.tpu_solver import _plan_sssp
+    from openr_tpu.ops import relax as relax_ops
+    from openr_tpu.ops.incremental import jit_incremental_sssp
+
+    shape = dict(
+        s_cap=1 if has_res else 2, has_res=has_res, n_cap=_N, d_cap=2,
+        max_trips=relax_ops.max_trips(_N),
+    )
+    return (
+        jit_incremental_sssp(**shape, quantum=quantum),
+        jax.jit(functools.partial(_plan_sssp, **shape)),
+    )
+
+
+@pytest.mark.parametrize("event", sorted(_CHAIN_EVENTS))
+@pytest.mark.parametrize("has_res", [True, False], ids=["res", "shift"])
+@pytest.mark.parametrize("quantum", [1, 2, 8])
+def test_loops_stop_when_converged_at_any_quantum(quantum, has_res, event):
+    """The planes do not depend on how often the loops test for change:
+    `dist`, `cone` and `fell_back` are the cold solve's at every quantum,
+    with and without a residual. What depends on it is counted: the cone
+    loop makes no pass where no edge grew, one at a leaf and depth + 1
+    behind an edge that heads a subtree (in whole trips of the quantum);
+    the relaxation makes k + 1 rounds at quantum 1, k the rounds in which
+    a distance moved, and whole trips of 8 at 8."""
+    old_links, new_links, cone_limit, cone_nodes = _CHAIN_EVENTS[event]
+    w_old, w_new = _chain_weights(**old_links), _chain_weights(**new_links)
+    mirror, slot = _chain_mirror(has_res, w_new)
+    dirty = sorted(e for e in w_new if w_new[e] != w_old[e])
+
+    def dirty_buffers(edges, plane):
+        # pads index one past the plane they would write: dropped
+        idx = np.full(64, plane.size, np.int32)
+        old = np.zeros(64, np.int32)
+        idx[:len(edges)] = [slot[e] for e in edges]
+        old[:len(edges)] = [w_old[e] for e in edges]
+        return idx, old
+
+    s_dirty = dirty_buffers([] if has_res else dirty, mirror[1])
+    r_dirty = dirty_buffers(dirty if has_res else [], mirror[4])
+    # lane 0 leaves by the vantage's live link, lane 1 is a pad lane
+    seeds_nbr = np.array([0, 0], np.int32)
+    seeds_w = np.array([1, _INF], np.int32)
+    prev = np.full((2, _N), _INF, np.int32)
+    prev[0], _ = _chain_fixpoint(w_old, _chain_cold())
+    want, _ = _chain_fixpoint(w_new, _chain_cold())
+
+    incr, cold = _chain_kernels(has_res, quantum)
+    dist, trips, cone, fell_back, rounds, cone_passes = map(np.asarray, incr(
+        *mirror, np.int32(_ROOT), seeds_nbr, seeds_w, prev,
+        *s_dirty, *r_dirty, np.int32(cone_limit),
+    ))
+    cold_dist, _, _ = cold(*mirror, np.int32(_ROOT), seeds_nbr, seeds_w)
+    np.testing.assert_array_equal(dist, np.asarray(cold_dist))
+    np.testing.assert_array_equal(dist[0], want)
+    assert (dist[1] == _INF).all()
+    assert cone == len(cone_nodes)
+    assert fell_back == (len(cone_nodes) > cone_limit)
+    # the cone's closure: none without a seed, else depth + 1 passes
+    # rounded up to whole trips
+    depth = len(cone_nodes) - 1
+    assert cone_passes == (
+        quantum * (-(-depth // quantum) + 1) if cone_nodes else 0
+    )
+    # the re-relaxation, from the seed the kernel must have chosen
+    dist0 = prev[0].copy()
+    dist0[list(cone_nodes)] = _INF
+    dist0[0] = 0
+    _, moved = _chain_fixpoint(w_new, _chain_cold() if fell_back else dist0)
+    assert rounds == quantum * (-(-moved // quantum) + 1)
+    assert trips * quantum == rounds
+
+
+@pytest.mark.parametrize("tier", ["single", "multichip"])
+def test_cone_passes_reach_the_host(tier):
+    """The cone loop's executed passes ride the scalar tail to
+    `last_device_stats`, `last_timing`, the `tpu.device_wait` span and
+    the counters `decision.tpu.cone_passes` / `.cone_skips`. On one chip
+    over a residual the loop tests after every pass: depth + 1 passes
+    behind an increase, none where no edge grew. The multichip twin
+    keeps trips of 8 and starts its loop every time."""
+    me = "node-00"
+    adj_dbs, states, ps = _hub_graph()
+    churn = _Churn(adj_dbs, states)
+    kw = {} if tier == "single" else dict(
+        multichip_n_cap_threshold=4, multichip_batch=4
+    )
+    cpu = SpfSolver(me)
+    incr = TpuSpfSolver(me, incremental_spf=True, spf_kernel="sync", **kw)
+
+    def solve(ctx):
+        assert_rib_equal(
+            cpu.build_route_db(me, states, ps),
+            incr.build_route_db(me, states, ps), ctx,
+        )
+        return incr.last_device_stats
+
+    st = solve("cold")
+    assert "cone_passes" not in st and incr.last_timing["cone_passes"] == 0
+    assert bool(incr.last_timing.get("multichip")) == (tier == "multichip")
+    want = {  # metric of node-01 <-> node-09 -> passes on one chip
+        # the hub, node-10 behind it, node-11 behind that: depth 2
+        50: 3,
+        4: 0,
+        1: 0,
+    }
+    for metric, passes in want.items():
+        if tier == "multichip":
+            # a trip that spreads the cone and one that finds it spread,
+            # or the one trip that finds nothing to spread
+            passes = 16 if passes else 8
+        p0, s0 = (_cnt("decision.tpu.cone_passes"),
+                  _cnt("decision.tpu.cone_skips"))
+        churn.set_metric("node-01", "node-09", metric)
+        st = solve(f"node-01 <-> node-09 = {metric}")
+        assert st.get("incremental") and not st.get("fell_back"), st
+        assert st["cone_passes"] == passes, st
+        assert incr.last_timing["cone_passes"] == passes
+        wait = next(
+            attrs for name, _, _, _, attrs in incr.last_timing["spans"]
+            if name == "tpu.device_wait"
+        )
+        assert wait["cone_passes"] == passes and wait["rounds"] == st["rounds"]
+        assert _cnt("decision.tpu.cone_passes") - p0 == passes
+        assert _cnt("decision.tpu.cone_skips") - s0 == (passes == 0)
+        if tier == "single":
+            # every edge is residual: a trip is one relaxation, so the
+            # loop stops one round after the last that moved a distance
+            assert st["trips"] == st["rounds"] < 8, st
+        else:
+            assert st["rounds"] % 8 == 0, st

@@ -43,6 +43,58 @@ def test_round_ledger_units():
     assert relax_ops.ladder_depth(2) == 4
     assert relax_ops.ladder_depth(64) == 7
     assert relax_ops.ladder_depth(1 << 20) == 16
+    # a pass over a residual is dear beside the no-change test: test
+    # after every one; a pass of rolls alone is not: trips of UNROLL
+    assert relax_ops.sync_quantum(True) == 1
+    assert relax_ops.sync_quantum(False) == relax_ops.UNROLL
+
+
+def _counted_run_sync(step, quantum, trips_of_unroll=relax_ops.max_trips(64)):
+    """A jitted run_sync under the bound a caller holding
+    `trips_of_unroll` trips of UNROLL passes at this quantum."""
+    import jax
+
+    bound = trips_of_unroll * relax_ops.UNROLL // quantum
+    return jax.jit(
+        lambda x, start: relax_ops.run_sync(
+            step, x, bound, quantum, start=start
+        )
+    )
+
+
+@pytest.mark.parametrize("moved", [0, 1, 5, 8, 9])
+@pytest.mark.parametrize("quantum", [1, 2, 8])
+def test_run_sync_counts_executed_applications(quantum, moved):
+    """`rounds` is every application run: the `moved` that changed the
+    state and the tail that found nothing, in whole trips."""
+    import jax.numpy as jnp
+
+    run = _counted_run_sync(lambda x: jnp.maximum(x - 1, 0), quantum)
+    state, trips, rounds = run(jnp.array([moved, 0], jnp.int32), True)
+    assert not np.asarray(state).any()
+    assert int(rounds) == quantum * (-(-moved // quantum) + 1)
+    assert int(trips) * quantum == int(rounds)
+
+
+@pytest.mark.parametrize("quantum", [1, 2, 8])
+def test_run_sync_bound_is_on_applications(quantum):
+    """A step that never settles is cut at max_trips * UNROLL
+    applications whatever the trip's length."""
+    run = _counted_run_sync(lambda x: x + 1, quantum)
+    state, trips, rounds = run(np.zeros(2, np.int32), True)
+    cap = relax_ops.max_trips(64) * relax_ops.UNROLL
+    assert int(rounds) == cap and int(trips) == cap // quantum
+    assert (np.asarray(state) == cap).all()
+
+
+@pytest.mark.parametrize("quantum", [1, 8])
+def test_run_sync_does_not_start_without_cause(quantum):
+    """`start` is the carried flag the loop opens with: a traced False
+    runs no application, even of a step that would move the state."""
+    run = _counted_run_sync(lambda x: x + 1, quantum)
+    state, trips, rounds = run(np.full(2, 7, np.int32), False)
+    assert int(rounds) == 0 and int(trips) == 0
+    assert (np.asarray(state) == 7).all()
 
 
 def test_derive_delta_exp_boundaries():

@@ -8,8 +8,10 @@ Runs `benchmark/run.py`'s `main` with the same arguments (so the run's own
 lines, the result line among them, are printed as ever) and then writes
 `chiprun_out/holds.<cell>.<seed>.json`: every hold the tracer's background
 track kept from the process's start (`tracer.get_holds()`), the measured
-window's bounds and events (due, sent, acked), and the harness's own stamps
-of the collector — what PERF.md's section 5 and 6 quote per hold. A
+window's bounds and events (due, sent, acked), the harness's own stamps
+of the collector — what PERF.md's section 5 and 6 quote per hold — and
+`window_counters`, what the solver's `decision.tpu.*` counters (`epochs`,
+`cold_compactions`, `cone_passes`, `cone_skips`) gained over that window. A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
 """
@@ -26,18 +28,32 @@ sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 
 import run  # noqa: E402  (stamps T_PROCESS)
 
+# of `decision.tpu.*`, the counters (the rest are gauges of the mirror)
+WINDOW_COUNTERS = ("epochs", "cold_compactions", "cone_passes", "cone_skips")
+
 
 def main(argv=None) -> int:
     import harness
+    from openr_tpu.runtime.counters import counters
+    from openr_tpu.runtime.tracing import tracer
 
     args = run.parse_args(argv)
     kept: dict = {}
     window = harness.Session.window
 
     async def keep(self, *a, **kw):
+        before = counters.get_counters("decision.tpu.")
         result = await window(self, *a, **kw)
         if "sample_seed" in kw:  # the measured window, not the warm-up's
-            kept.update(window=result, collections=list(self.collections))
+            kept.update(
+                window=result, collections=list(self.collections),
+                counters={
+                    key: value - before.get(key, 0)
+                    for key, value in
+                    counters.get_counters("decision.tpu.").items()
+                    if key.split(".")[-1] in WINDOW_COUNTERS
+                },
+            )
         return result
 
     harness.Session.window = keep
@@ -45,8 +61,6 @@ def main(argv=None) -> int:
         rc = run.main(argv)
     finally:
         harness.Session.window = window
-    from openr_tpu.runtime.counters import counters
-    from openr_tpu.runtime.tracing import tracer
 
     w = kept.get("window") or {}
     out = {
@@ -66,13 +80,16 @@ def main(argv=None) -> int:
             **counters.get_counters("tracing.holds"),
         },
         "loop_lag_ms": counters.get_statistics("runtime.loop_lag_ms"),
+        "window_counters": kept.get("counters", {}),
     }
     os.makedirs(OUT, exist_ok=True)
     path = os.path.join(OUT, f"holds.{args.workload}.{args.seed}.json")
     with open(path, "w") as f:
         json.dump(out, f)
-    print(json.dumps({"holds_written": path, "holds": len(out["holds"])}),
-          file=sys.stderr)
+    print(json.dumps({
+        "holds_written": path, "holds": len(out["holds"]),
+        "window_counters": out["window_counters"],
+    }), file=sys.stderr)
     return rc
 
 

@@ -24,6 +24,9 @@ rebuilds the mirror at that width (by replacing the builder's own choice,
            solver itself (a remote link's metric up, then back), whose
            tables are compared with the first width's; taken at the
            first width and at widths up to 8 (two compiles a width).
+           `rounds` and `cone_passes` list what each event's two loops
+           ran (the latter null on a tree that does not count them), so
+           a scope's ms a pass can be read off the same line.
 
 The last lines fit `loop` to `a * r_cap * (K + c)` by least squares: `c`
 is `_ROW_COST`. Needs a TPU: a CPU's gather costs nothing like the chip's.
@@ -267,7 +270,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
     peer = adj_dbs[-1].adjacencies[0].other_node_name
     base = adj_dbs[-1].adjacencies[0].metric
     solver = TpuSpfSolver(me, enable_lfa=True, incremental_spf=True)
-    tables, rounds, cones, changed = [], [], [], []
+    tables, rounds, cone_passes, cones, changed = [], [], [], [], []
 
     def solve(step: int) -> dict:
         """The link's metric up (even steps) or back, then a solve."""
@@ -288,6 +291,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
             if not stats.get("incremental"):
                 raise SystemExit(f"event {step} did not solve warm: {stats}")
             rounds.append(solver.last_timing.get("rounds"))
+            cone_passes.append(solver.last_timing.get("cone_passes"))
             cones.append((stats.get("cone"), bool(stats.get("fell_back"))))
             changed.append(stats.get("changed_rows"))
         by_scope = device_stats.profiler_stop()["by_scope"] or {}
@@ -302,6 +306,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
             scope: ms / EVENTS for scope, ms in sorted(by_scope.items())
         },
         "rounds": rounds,
+        "cone_passes": cone_passes,
         "cones": cones,
         "changed_rows": changed,
         "prefix_rows": stats.get("prefix_rows"),
