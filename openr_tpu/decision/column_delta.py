@@ -57,17 +57,34 @@ def prefix_codec(matrix) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     matrix — every subsequent batch build indexes these arrays instead of
     re-parsing prefix strings per route."""
     codec = getattr(matrix, "_prefix_codec", None)
-    if codec is not None:
-        return codec
     plist = matrix.prefix_list
-    p_n = len(plist)
-    family = np.zeros(p_n, np.uint8)
-    plen = np.zeros(p_n, np.uint8)
-    addr = np.zeros((p_n, 16), np.uint8)
+    if codec is not None:
+        stale = matrix._codec_stale
+        if stale:
+            # rows that took a prefix since (PrefixMatrix.apply_changes)
+            _parse_rows(codec, plist, stale)
+            stale.clear()
+        return codec
+    # every row of the plane, so that a row taken later is a patch
+    p_cap = max(matrix.ann_node.shape[0], len(plist))
+    codec = (
+        np.zeros(p_cap, np.uint8), np.zeros(p_cap, np.uint8),
+        np.zeros((p_cap, 16), np.uint8),
+    )
+    _parse_rows(codec, plist, range(len(plist)))
+    matrix._prefix_codec = codec
+    matrix._codec_stale = []
+    return codec
+
+
+def _parse_rows(codec, plist: list, rows) -> None:
+    family, plen, addr = codec
     v4 = _socket.AF_INET
     v6 = _socket.AF_INET6
-    for i, pfx in enumerate(plist):
+    for i in rows:
+        pfx = plist[i]
         ip, _, ln = pfx.partition("/")
+        addr[i] = 0
         if ":" in ip:
             family[i] = v6
             plen[i] = int(ln) if ln else 128
@@ -78,15 +95,13 @@ def prefix_codec(matrix) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
             addr[i, :4] = np.frombuffer(_socket.inet_pton(v4, ip), np.uint8)
     # mask host bits so addr is the NETWORK address, matching what the
     # per-route pack derives via ip_network(prefix, strict=False)
+    rows = np.fromiter(rows, np.int64)
     span = np.clip(
-        plen.astype(np.int32)[:, None]
+        plen[rows].astype(np.int32)[:, None]
         - np.arange(16, dtype=np.int32) * 8,
         0, 8,
     )
-    addr &= ((0xFF00 >> span) & 0xFF).astype(np.uint8)
-    codec = (family, plen, addr)
-    matrix._prefix_codec = codec
-    return codec
+    addr[rows] &= ((0xFF00 >> span) & 0xFF).astype(np.uint8)
 
 
 def _plain_entry(entry) -> dict:
@@ -550,8 +565,11 @@ def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
     for i, (so, sn, crib) in enumerate(pairs):
         jrows = crib.changed_rows_since(so.epoch)
         jrows = jrows[jrows < crib.p_n]
+        # rows whose advertisement changed (ColumnarRib.touch_rows): the
+        # entry changes with it, whatever the columns say
+        forced = crib.forced_rows_since(so.epoch)
         oc, nc = so.cols, sn.cols
-        if not len(jrows) or oc is nc:
+        if not len(jrows) or (oc is nc and not len(forced)):
             segments.append((sn, np.zeros(0, np.int64)))
             continue
         if crib.exact_since(so.epoch):
@@ -560,7 +578,10 @@ def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
             # is exactly the changed set — no host re-compare needed
             changed = jrows
         else:
-            changed = jrows[_col_changed_mask(oc, nc, jrows)]
+            mask = _col_changed_mask(oc, nc, jrows)
+            if len(forced):
+                mask |= np.isin(jrows, forced)
+            changed = jrows[mask]
         plist = crib.matrix.prefix_list
         upd = changed[nc.ok[changed]]
         dels = changed[oc.ok[changed] & ~nc.ok[changed]]

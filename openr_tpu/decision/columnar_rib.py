@@ -260,20 +260,35 @@ def build_entries(
 
 def row_index(matrix) -> dict:
     """prefix -> matrix row, built ONCE per PrefixMatrix and kept on it
-    (`prefix_list` is never mutated; a new matrix means new cribs). The
-    key index of every generation of every crib over the matrix is this
-    dict plus the generation's own `ok` mask, so a copy-on-write epoch
-    builds no Python key structure. Every O(rows) key build (here and
-    `LazyUnicastRoutes._key_set`) counts in
-    decision.crib.key_index_builds, which must stand still across warm
-    epochs."""
-    idx = matrix._row_index
-    if idx is None:
-        idx = matrix._row_index = {
-            p: r for r, p in enumerate(matrix.prefix_list)
-        }
-        counters.increment("decision.crib.key_index_builds")
-    return idx
+    (`PrefixMatrix.row_index`; `apply_changes` keeps it up to date when
+    a prefix takes or frees a row). The key index of every generation of
+    every crib over the matrix is this dict plus the generation's own
+    `ok` mask, so a copy-on-write epoch builds no Python key structure.
+    Every O(rows) key build (there and `LazyUnicastRoutes._key_set`)
+    counts in decision.crib.key_index_builds, which must stand still
+    across warm epochs."""
+    return matrix.row_index()
+
+
+def crib_rows(matrix) -> int:
+    """Rows a crib's columns hold: the rows the matrix has ever used and
+    some room, so that a fresh prefix's row is a patch and not a rebuild
+    (a row past them makes the solver start a new crib)."""
+    n = len(matrix.prefix_list)
+    return min(matrix.ann_node.shape[0], n + max(64, n >> 6))
+
+
+def row_quiet(matrix, r: int) -> bool:
+    """No live view of any crib over the matrix reads row `r` as a route:
+    the row may take another prefix's name. What a view reads of a row —
+    its name, its entry refs — it reads from the matrix as it is NOW, so
+    a row that some generation still holds a route in keeps them."""
+    for crib in matrix._cribs or ():
+        for view in list(crib._views):
+            ok = None if view.cols is None else view.cols.ok
+            if ok is not None and r < len(ok) and ok[r]:
+                return False
+    return True
 
 
 class _Cols:
@@ -326,7 +341,14 @@ class ColumnarRib:
         self.block_v4 = block_v4
         self.use_v4_allowed = use_v4_allowed
         self.lfa = lfa
-        self.p_n = len(matrix.prefix_list)
+        # rows of the columns, NOT a test of a live row: a row in them
+        # may be free or never used (its `ok` is then False)
+        self.p_n = crib_rows(matrix)
+        if matrix._cribs is None:
+            matrix._cribs = weakref.WeakSet()
+        matrix._cribs.add(self)
+        # the matrix's row changes this crib has dropped its cache for
+        self.matrix_seq = matrix.touch_seq
         self.cols: Optional[_Cols] = None
         self.epoch = 0
         # oldest epoch the journal can still diff against; reset by
@@ -337,6 +359,10 @@ class ColumnarRib:
         # previous epoch (the streaming pipeline's on-device diff), not
         # a superset a consumer must re-compare
         self.journal: list[tuple[int, np.ndarray, bool]] = []
+        # (epoch, rows) whose advertisement changed in the matrix: an
+        # update of the diff whatever the columns say (the entry's
+        # best_prefix_entry is the advertisement)
+        self.forced: list[tuple[int, np.ndarray]] = []
         self.routes: dict[str, RibUnicastEntry] = {}
         # routes is COMPLETE iff materialized; otherwise it is a partial
         # per-row cache (invalidated row-wise by apply_rows)
@@ -385,8 +411,44 @@ class ColumnarRib:
         self.epoch += 1
         self.journal_floor = self.epoch
         self.journal = []
+        self.forced = []
         self.routes = {}
         self.materialized = False
+
+    def touch_rows(self, rows, old_names=()) -> None:
+        """The matrix changed these rows (`PrefixMatrix.apply_changes`:
+        an advertisement changed, a prefix took or freed the row; rows
+        that took another prefix's name were called `old_names`). The
+        columns are the device's to change; what was built from the rows
+        goes, and the rows are journaled as forced, so the next diff
+        sends each that is a route whether or not its columns moved (a
+        superset of what changed: where the changed advertisement is not
+        the route's best one, an equal route is sent again)."""
+        rows = np.asarray(rows, np.int64)
+        if not len(rows):
+            return
+        plist = self.matrix.prefix_list
+        for r in rows.tolist():
+            self.routes.pop(plist[r], None)
+        for name in old_names:
+            self.routes.pop(name, None)
+        if self.materialized:
+            # complete again from the columns as they stand; what the
+            # device changes of these rows, apply_rows patches after
+            live = rows[self.cols.ok[rows]]
+            if len(live):
+                self._build_rows_into(self.cols, live, self.routes)
+        self.epoch += 1
+        self.journal.append((self.epoch, rows, False))
+        self.forced.append((self.epoch, rows))
+        self._trim_journal()
+
+    def _trim_journal(self) -> None:
+        if len(self.journal) > _JOURNAL_MAX:
+            dropped_epoch, _, _ = self.journal.pop(0)
+            self.journal_floor = dropped_epoch
+            while self.forced and self.forced[0][0] <= dropped_epoch:
+                self.forced.pop(0)
 
     def set_full_arrays(self, met, s3, nh, lfa_slot=None, lfa_metric=None,
                         ok=None) -> None:
@@ -394,7 +456,7 @@ class ColumnarRib:
         whose kernel returns bool masks + a device-computed ok)."""
         if ok is None:
             ok = route_ok_rows(
-                self.matrix, self.root_idx, slice(0, self.p_n),
+                self.matrix, self.root_idx, slice(0, len(met)),
                 met, s3, nh, self.block_v4,
             )
         rows = np.flatnonzero(ok)
@@ -451,9 +513,7 @@ class ColumnarRib:
         c._key_rows = None
         self.epoch += 1
         self.journal.append((self.epoch, np.asarray(rows), exact))
-        if len(self.journal) > _JOURNAL_MAX:
-            dropped_epoch, _, _ = self.journal.pop(0)
-            self.journal_floor = dropped_epoch
+        self._trim_journal()
         # keep the route cache coherent: eager patch when complete
         # (preserves the seed's O(changed) steady-state cost), row-wise
         # invalidation when partial
@@ -497,6 +557,12 @@ class ColumnarRib:
 
     def changed_rows_since(self, epoch: int) -> np.ndarray:
         parts = [r for e, r, _x in self.journal if e > epoch]
+        if not parts:
+            return np.zeros(0, np.int64)
+        return np.unique(np.concatenate(parts))
+
+    def forced_rows_since(self, epoch: int) -> np.ndarray:
+        parts = [r for e, r in self.forced if e > epoch]
         if not parts:
             return np.zeros(0, np.int64)
         return np.unique(np.concatenate(parts))
@@ -607,7 +673,9 @@ class RibView:
         if idx is None:
             idx = row_index(matrix)
         r = idx.get(prefix)
-        if r is None or not self.cols.ok[r]:
+        ok = self.cols.ok
+        # a row past this generation's columns was taken after it
+        if r is None or r >= len(ok) or not ok[r]:
             return None
         return r
 
@@ -793,6 +861,14 @@ class LazyUnicastRoutes(MutableMapping):
             self._merged[k] = v
         self._keys = None
 
+    def set_host_route(self, k, v):
+        """A route computed on the host after the build (Decision's
+        per-prefix path): it is a host route like the build's own, so it
+        joins `base`, and shadows a segment's row of the same prefix as
+        any later write does."""
+        self[k] = v
+        self.base[k] = v
+
     def __delitem__(self, k):
         if k not in self:
             raise KeyError(k)
@@ -870,12 +946,18 @@ def fast_unicast_diff(old, new):
         | set(old.overrides) | set(new.overrides)
         | old.deleted | new.deleted
     )
+    forced = set()
     for so, crib in pairs:
         plist = crib.matrix.prefix_list
         p_n = crib.p_n
         for r in crib.changed_rows_since(so.epoch).tolist():
             if r < p_n:
                 candidates.add(plist[r])
+        # a changed advertisement is an update even where the old entry,
+        # rebuilt from the matrix as it is now, would compare equal
+        forced.update(
+            plist[r] for r in crib.forced_rows_since(so.epoch).tolist()
+        )
 
     to_update: dict = {}
     to_delete: list = []
@@ -885,7 +967,7 @@ def fast_unicast_diff(old, new):
         if nv is None:
             if ov is not None:
                 to_delete.append(k)
-        elif ov is None or ov != nv:
+        elif ov is None or ov != nv or k in forced:
             to_update[k] = nv
     to_delete.sort()
     return to_update, to_delete

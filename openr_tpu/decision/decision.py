@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from openr_tpu.config import DecisionConfig
+from openr_tpu.decision.columnar_rib import LazyUnicastRoutes
 from openr_tpu.decision.link_state import LinkState, LinkStateChange
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.rib import (
@@ -804,10 +805,30 @@ class Decision(Actor):
         )
         return ctx, spf_sp, full, t0
 
+    def _device_serves(self, pending: PendingUpdates, spf_sp) -> bool:
+        """A prefix-only epoch that the device solver takes: the solver
+        is the TPU's and says every route of the table is one of its
+        rows' (`TpuSpfSolver.serves_prefix_epoch`). It then goes the
+        full solve's way, where the solver runs the row stages alone."""
+        serves = getattr(self.solver, "serves_prefix_epoch", None)
+        if serves is None or not pending.updated_prefixes or not serves(
+            self.area_link_states, self.prefix_state,
+            pending.updated_prefixes,
+        ):
+            return False
+        if spf_sp is not None:
+            spf_sp.attributes["prefix_only"] = True
+        return True
+
     def _incremental_db(self, pending: PendingUpdates) -> DecisionRouteDb:
-        # incremental: recompute only changed prefixes
+        # incremental: recompute only changed prefixes. A lazy table is
+        # copied as one: its rows stay columns, and the changed prefixes
+        # join its host routes (`base`, where a route the device did not
+        # compute is counted) and its deletions
+        routes = self.route_db.unicast_routes
+        lazy = isinstance(routes, LazyUnicastRoutes)
         new_db = DecisionRouteDb(
-            unicast_routes=dict(self.route_db.unicast_routes),
+            unicast_routes=routes.snapshot() if lazy else dict(routes),
             mpls_routes=dict(self.route_db.mpls_routes),
         )
         for prefix in pending.updated_prefixes:
@@ -819,13 +840,15 @@ class Decision(Actor):
             )
             if route is None:
                 new_db.unicast_routes.pop(prefix, None)
+            elif lazy:
+                new_db.unicast_routes.set_host_route(prefix, route)
             else:
                 new_db.unicast_routes[prefix] = route
         return new_db
 
     def _rebuild(self, pending: PendingUpdates) -> None:
         ctx, spf_sp, full, t0 = self._begin_rebuild(pending)
-        if full:
+        if full or self._device_serves(pending, spf_sp):
             new_db = self._solve_full(ctx, spf_sp)
         else:
             new_db = self._incremental_db(pending)
@@ -844,7 +867,7 @@ class Decision(Actor):
         finishes overlap — dispatch N+1 never starts before collect N
         (the solver's vantage state is single-flight by construction)."""
         ctx, spf_sp, full, t0 = self._begin_rebuild(pending)
-        if full:
+        if full or self._device_serves(pending, spf_sp):
             new_db = await self._solve_full_async(ctx, spf_sp)
         else:
             new_db = self._incremental_db(pending)

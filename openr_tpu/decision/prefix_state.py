@@ -8,6 +8,7 @@ incremental recomputation, plus received-routes dump for the ctrl API.
 
 from __future__ import annotations
 
+from collections import deque
 from typing import Optional
 
 import functools
@@ -16,6 +17,10 @@ from openr_tpu.types import PrefixDatabase, PrefixEntry, parse_prefix
 
 # (node, area) -> advertised entry
 PrefixEntries = dict
+
+# applied changes the state remembers (`changes_since`): a debounced epoch
+# folds a handful, a burst of a held loop some dozens
+_CHANGE_LOG = 1024
 
 
 # unbounded: the LSDB-scale target is ~100k prefixes and an LRU bound
@@ -33,6 +38,31 @@ class PrefixState:
         # bumped on every applied change; derived structures (the device
         # announcer matrix, ops/csr.py) key their caches on it
         self.generation = 0
+        # (generation, changed prefixes) of the last applied changes, so
+        # that what is derived from the state (the solver's partition,
+        # the device's announcer rows) follows the changed prefixes and
+        # does not walk all of them; a reader further behind than the
+        # log reaches rebuilds
+        self._changes: deque = deque(maxlen=_CHANGE_LOG)
+
+    def _changed(self, changed: set) -> None:
+        self.generation += 1
+        self._changes.append((self.generation, frozenset(changed)))
+
+    def changes_since(self, generation: int) -> Optional[set]:
+        """The prefixes whose advertisements changed after `generation`,
+        or None where the log no longer reaches back that far."""
+        if generation == self.generation:
+            return set()
+        log = self._changes
+        if not log or log[0][0] > generation + 1 or generation > self.generation:
+            return None
+        out: set = set()
+        for gen, changed in reversed(log):
+            if gen <= generation:
+                break
+            out |= changed
+        return out
 
     def prefixes(self) -> dict[str, PrefixEntries]:
         return self._prefixes
@@ -60,7 +90,7 @@ class PrefixState:
                     entries[node_area] = entry
                     changed.add(pfx)
         if changed:
-            self.generation += 1
+            self._changed(changed)
         return changed
 
     def delete_entries_of(self, node: str, area: str) -> set[str]:
@@ -75,7 +105,7 @@ class PrefixState:
                     del self._prefixes[pfx]
                 changed.add(pfx)
         if changed:
-            self.generation += 1
+            self._changed(changed)
         return changed
 
     def received_routes(

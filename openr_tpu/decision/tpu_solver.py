@@ -70,6 +70,7 @@ log = logging.getLogger(__name__)
 from openr_tpu.decision.columnar_rib import (
     ColumnarRib,
     LazyUnicastRoutes,
+    row_quiet,
 )
 from openr_tpu.decision.link_state import LinkState, NodeUcmpResult
 from openr_tpu.decision.prefix_state import PrefixState
@@ -233,7 +234,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    sentinels: bool = True, emit_dist: bool = False,
                    incr: bool = False, mesh=None,
                    kernel: str = "sync", delta_exp: int = 0,
-                   stream: int = 0):
+                   stream: int = 0, rows_only: bool = False):
     """The fused production pipeline (raw closure — _build_pipeline jits
     it under the options a PipelineVariant names, vmapped over a group
     of same-shape areas for a `fused` one). Outputs:
@@ -278,6 +279,13 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     is bit-identical to the cold one, so the ENTIRE selection / LFA /
     packing / delta tail below is shared verbatim between the two
     kernels — output parity by construction.
+
+    With `rows_only=True` (the prefix-only solve: no weight of the
+    mirror changed since the vantage's resident plane was computed, so
+    the plane stands) the pipeline takes ONE trailing arg, prev_dist,
+    and runs no relaxation and no cone at all: trips and rounds read 0
+    and the row stages below run over the resident plane as they would
+    over a fresh one. The plane is not an output.
 
     With `mesh` (the multichip capacity tier) the SSSP core swaps for
     parallel/sharding.py's shard_mapped twins — shift columns over
@@ -350,7 +358,10 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             )
 
         with jax.named_scope("seed"):
-            if incr:
+            if rows_only:
+                (dist_d,) = incr_args
+                trips = rounds = jnp.int32(0)
+            elif incr:
                 (prev_dist, s_dirty_idx, s_dirty_old,
                  r_dirty_idx, r_dirty_old, cone_limit) = incr_args
                 if mesh is not None:
@@ -597,6 +608,7 @@ class PipelineVariant(NamedTuple):
     stream: int = 0           # a STREAM_BUDGETS bucket: streaming epoch
     fused: int = 0            # g same-shape areas vmapped in one dispatch
     donate: bool = False      # prev planes + warm seed donated (stream)
+    rows_only: bool = False   # prefix-only: row stages over the resident plane
     mesh: object = None       # the multichip tier's ('batch','graph') mesh
 
     @classmethod
@@ -613,6 +625,13 @@ class PipelineVariant(NamedTuple):
             raise ValueError(f"an incremental solve emits the plane: {v}")
         if v.donate and not v.stream:
             raise ValueError(f"only a stream epoch donates: {v}")
+        if v.rows_only and (
+            v.incr or v.fused or v.emit_dist or not one_chip
+        ):
+            raise ValueError(
+                f"rows_only: no solve, one area, one chip, the plane "
+                f"stays where it is: {v}"
+            )
         return v
 
     def at(self, shape_key: tuple, mesh) -> "PipelineVariant":
@@ -642,7 +661,9 @@ class PipelineVariant(NamedTuple):
             return "multichip"
         if self.stream:
             return "stream"
-        return "incr" if self.incr else ""
+        # the prefix-only executable, one bucket a shape class, lies
+        # with the class's dirty-cap buckets
+        return "incr" if self.incr or self.rows_only else ""
 
     @property
     def name(self) -> str:
@@ -654,6 +675,7 @@ class PipelineVariant(NamedTuple):
             ("_mc" if self.mesh is not None else "")
             + ("_fused" if self.fused else "")
             + ("_stream" if self.stream else "_incr" if self.incr else "")
+            + ("_rows" if self.rows_only else "")
         )
         parts = [
             f"g={self.fused}" if self.fused else "",
@@ -673,7 +695,18 @@ class PipelineVariant(NamedTuple):
         """Persistent-executable key: every field, so two variants can
         never alias one serialized executable."""
         mesh = None if self.mesh is None else _mesh_tag(self.mesh)
-        return repr(self._replace(mesh=mesh))
+        # a field that joined the record after executables were
+        # persisted is left out at its default, so that those keys
+        # stay what they were
+        fields = ", ".join(
+            f"{name}={value!r}"
+            for name, value in self._replace(mesh=mesh)._asdict().items()
+            if name not in _LATER_FIELDS or value
+        )
+        return f"PipelineVariant({fields})"
+
+
+_LATER_FIELDS = frozenset({"rows_only"})
 
 
 def _mesh_tag(mesh) -> str:
@@ -744,7 +777,7 @@ def _build_pipeline(*fields) -> tuple:
     pipeline = _make_pipeline(
         *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
         v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
-        delta_exp=v.delta_exp, stream=v.stream,
+        delta_exp=v.delta_exp, stream=v.stream, rows_only=v.rows_only,
     )
     kw = {}
     if v.donate:
@@ -864,12 +897,7 @@ def _pack_matrix(matrix: PrefixMatrix, node_over: np.ndarray) -> tuple:
     and the per-prefix v4 bit (flag bit 2, announcer slot 0) fold into
     flag bits host-side; min_nexthop ships so the device can run the
     route-level ok filter (ops/compact.route_ok_device)."""
-    idx = np.clip(matrix.ann_node, 0, None)
-    flags = matrix.ann_valid.astype(np.int32) | (
-        node_over[idx].astype(np.int32) << 1
-    )
-    if flags.shape[1]:
-        flags[:, 0] |= matrix.is_v4.astype(np.int32) << 2
+    flags = _row_flags(matrix, node_over, slice(None))
     mbuf = matrix._mbuf
     if mbuf is None:
         mbuf = matrix._mbuf = np.concatenate([
@@ -889,6 +917,37 @@ def _pack_matrix(matrix: PrefixMatrix, node_over: np.ndarray) -> tuple:
     return flags, mbuf
 
 
+def _row_flags(matrix: PrefixMatrix, node_over: np.ndarray,
+               rows) -> np.ndarray:
+    """The flags plane of `rows` (an index array or a slice): validity,
+    the announcer's drain, and the prefix's v4 bit on announcer slot 0."""
+    idx = np.clip(matrix.ann_node[rows], 0, None)
+    flags = matrix.ann_valid[rows].astype(np.int32) | (
+        node_over[idx].astype(np.int32) << 1
+    )
+    if flags.shape[1]:
+        flags[:, 0] |= matrix.is_v4[rows].astype(np.int32) << 2
+    return flags
+
+
+def _pack_rows(matrix: PrefixMatrix, rows: np.ndarray,
+               node_over: np.ndarray) -> tuple:
+    """_pack_matrix for some rows: (flags [R, A], flat indices into mbuf,
+    their values), all six planes of each row, in the planes' order."""
+    p_cap, a_cap = matrix.ann_node.shape
+    flags = _row_flags(matrix, node_over, rows)
+    cells = rows[:, None] * a_cap + np.arange(a_cap)
+    idx = (
+        np.arange(6)[:, None, None] * (p_cap * a_cap) + cells[None]
+    ).ravel().astype(np.int32)
+    vals = np.stack([
+        matrix.ann_node[rows], flags,
+        matrix.path_pref[rows], matrix.source_pref[rows],
+        matrix.dist_adv[rows], matrix.min_nexthop[rows],
+    ]).ravel().astype(np.int32, copy=False)
+    return flags, idx, vals
+
+
 class _AreaDev:
     """Per-area resident device state: plan arrays + announcer matrix."""
 
@@ -896,7 +955,7 @@ class _AreaDev:
         "plan", "d_deltas", "d_shift_w", "d_res_rows", "d_res_nbr",
         "d_res_w", "matrix_key", "matrix", "flags", "d_mbuf",
         "matrix_version", "pack_over", "drain_epoch", "drain_log",
-        "mc_mesh", "sync_marks",
+        "mc_mesh", "sync_marks", "prefix_span",
     )
 
     def __init__(self):
@@ -937,6 +996,9 @@ class _AreaDev:
         # time.monotonic(), bytes uploaded, slots scattered, the
         # mirror's occupancy (EdgePlan.occupancy) and the prefix plane's
         self.sync_marks: tuple = ()
+        # the last _sync_area's tpu.sync.prefix span, or None where the
+        # announcements had not changed
+        self.prefix_span: Optional[tuple] = None
 
 
 class _VantageState:
@@ -1418,13 +1480,45 @@ class TpuSpfSolver:
     def create_route_for_prefix_or_get_static(
         self, my_node_name, area_link_states, prefix_state, prefix
     ):
-        """Incremental per-prefix path (Decision's changed-prefix rebuild):
-        single-prefix work has no batch to amortize a device launch over,
-        so it delegates to the CPU oracle. Topology churn takes the full
-        device path, which is itself incremental end-to-end (on-device
-        output delta -> O(changed) host work)."""
+        """Per-prefix path on the CPU oracle, for a table that holds
+        what the device's prefix rows do not: static routes, KSP2 and
+        UCMP prefixes, prefixes announced in more than one area
+        (Decision asks `serves_prefix_epoch` first). Where every prefix
+        is IP/SP_ECMP in one area a changed one does not come here: its
+        row of the announcer matrix is scattered to the device and the
+        epoch is a prefix-only solve (`_sync_prefix_rows`,
+        `_dispatch_one`). No device ran for this route, so the last
+        solve's breakdown does not stand for its epoch."""
+        self.last_timing = {}
         return self.cpu.create_route_for_prefix_or_get_static(
             my_node_name, area_link_states, prefix_state, prefix
+        )
+
+    def serves_prefix_epoch(
+        self,
+        area_link_states: dict[str, LinkState],
+        prefix_state: PrefixState,
+        prefixes,
+    ) -> bool:
+        """Whether an epoch that changed these prefixes and no link is
+        the device's. Such an epoch goes through build_route_db like any
+        other, which computes every host route again, so it is the
+        device's only where there is none to compute: by the partition
+        (`_classify`'s rule, followed to this generation) every prefix
+        is a fast one of an area the device solves, and no changed one
+        has a static route. The solver then finds the resident plane
+        standing and runs the row stages alone. With a slow, KSP2 or
+        small-area prefix anywhere, Decision's per-prefix path
+        recomputes the changed ones and no other."""
+        statics = self.cpu.static_unicast_routes
+        if any(prefix in statics for prefix in prefixes):
+            return False
+        fast_by_area, slow, ksp2, _ = self._partition_prefixes(
+            prefix_state, area_link_states
+        )
+        return bool(fast_by_area) and not slow and not ksp2 and all(
+            area_link_states[area].node_count() >= self.small_graph_nodes
+            for area in fast_by_area
         )
 
     @property
@@ -1607,7 +1701,7 @@ class TpuSpfSolver:
             )
         self._host_routes(
             my_node_name, area_link_states, prefix_state,
-            slow + ksp2 + small, route_db,
+            [*slow, *ksp2, *small], route_db,
         )
         pending = _PendingBuild(route_db, futures, t_pipe0)
         pending.ksp2_timing = self._ksp2_timing
@@ -1635,6 +1729,7 @@ class TpuSpfSolver:
         stages = {"sync_ms": 0.0, "exec_ms": 0.0, "mat_ms": 0.0}
         area_timing: dict[str, dict] = {}
         incremental = False
+        prefix_only_areas = 0
         multichip: dict | bool = False
         rounds_total = 0
         cone_passes_total = 0
@@ -1669,6 +1764,9 @@ class TpuSpfSolver:
                 # a warm re-relax converges in a trip or two — not a
                 # diameter bound the sharded fabric path may reuse
                 incremental = True
+            elif stats.get("prefix_only"):
+                # no relaxation ran: no bound either
+                prefix_only_areas += 1
             elif stats.get("spf_kernel") == "bucketed":
                 self.last_trips = stats["trips"]
             else:
@@ -1735,6 +1833,9 @@ class TpuSpfSolver:
             "bytes_uploaded": float(pending.bytes_uploaded),
             "bytes_downloaded": float(bytes_downloaded),
             "incremental": incremental,
+            # every area's dispatch ran the row stages over its resident
+            # plane and nothing else (_dispatch_one's rows_only)
+            "prefix_only": prefix_only_areas == len(pending.futures),
             "multichip": multichip,
             "rounds": rounds_total,
             "cone_passes": cone_passes_total,
@@ -1809,40 +1910,73 @@ class TpuSpfSolver:
         """-> (fast prefixes grouped by their single announcer area,
         slow prefixes for the oracle — ineligible attributes OR announcers
         spanning areas, all ksp2 prefixes, ksp2 prefixes grouped by
-        single announcer area for device priming). Cached per
-        (prefix generation, area set)."""
+        single announcer area for device priming), each a dict used as
+        an ordered set. Cached per (prefix generation, area set); a later
+        generation follows the prefixes that changed since
+        (`PrefixState.changes_since`) and walks all of them only where
+        that log does not reach."""
         areas_key = tuple(sorted(area_link_states))
-        if (
-            self._partition is not None
-            and self._partition[0] == (prefix_state.generation, areas_key)
-        ):
-            return self._partition[1:]
-        fast_by_area: dict[str, list] = {}
-        ksp2_by_area: dict[str, list] = {}
-        slow, ksp2 = [], []
+        key = (prefix_state.generation, areas_key)
+        part = self._partition
+        if part is not None and part[0] == key:
+            return part[1:]
+        if part is not None and part[0][1] == areas_key:
+            changed = prefix_state.changes_since(part[0][0])
+            if changed is not None and _dirty_bucket(len(changed)):
+                # the changed prefixes alone, in the containers that are
+                # there: each leaves the class it was in and joins the
+                # one its advertisements put it in now
+                fast_by_area, slow, ksp2, ksp2_by_area = part[1:]
+                state_map = prefix_state.prefixes()
+                for prefix in changed:
+                    slow.pop(prefix, None)
+                    ksp2.pop(prefix, None)
+                    for by_area in (fast_by_area, ksp2_by_area):
+                        for area in list(by_area):
+                            held = by_area[area]
+                            if held.pop(prefix, 0) is None and not held:
+                                del by_area[area]
+                    entries = state_map.get(prefix)
+                    if entries:
+                        self._classify(
+                            prefix, entries, area_link_states, *part[1:]
+                        )
+                self._partition = (key, *part[1:])
+                return part[1:]
+        # each container a dict used as an ordered set, so that one
+        # prefix leaves or joins it without a walk
+        fast_by_area: dict[str, dict] = {}
+        ksp2_by_area: dict[str, dict] = {}
+        slow: dict = {}
+        ksp2: dict = {}
         for prefix, entries in prefix_state.prefixes().items():
-            areas = {a for _, a in entries}
-            single = (
-                next(iter(areas))
-                if len(areas) == 1 and next(iter(areas)) in area_link_states
-                else None
+            self._classify(
+                prefix, entries, area_link_states,
+                fast_by_area, slow, ksp2, ksp2_by_area,
             )
-            if _fast_path_eligible(entries):
-                if single is not None:
-                    fast_by_area.setdefault(single, []).append(prefix)
-                else:
-                    slow.append(prefix)
-            elif _ksp2_eligible(entries):
-                ksp2.append(prefix)
-                if single is not None:
-                    ksp2_by_area.setdefault(single, []).append(prefix)
-            else:
-                slow.append(prefix)
-        self._partition = (
-            (prefix_state.generation, areas_key),
-            fast_by_area, slow, ksp2, ksp2_by_area,
-        )
+        self._partition = (key, fast_by_area, slow, ksp2, ksp2_by_area)
         return fast_by_area, slow, ksp2, ksp2_by_area
+
+    @staticmethod
+    def _classify(prefix, entries, area_link_states,
+                  fast_by_area, slow, ksp2, ksp2_by_area) -> None:
+        areas = {a for _, a in entries}
+        single = (
+            next(iter(areas))
+            if len(areas) == 1 and next(iter(areas)) in area_link_states
+            else None
+        )
+        if _fast_path_eligible(entries):
+            if single is not None:
+                fast_by_area.setdefault(single, {})[prefix] = None
+            else:
+                slow[prefix] = None
+        elif _ksp2_eligible(entries):
+            ksp2[prefix] = None
+            if single is not None:
+                ksp2_by_area.setdefault(single, {})[prefix] = None
+        else:
+            slow[prefix] = None
 
     def _host_routes(
         self, my_node_name, area_link_states, prefix_state, slow, route_db
@@ -1998,7 +2132,7 @@ class TpuSpfSolver:
                     nm, area, link_state, prefix_state, ksp2, fast
                 )
             self._host_routes(
-                nm, area_link_states, prefix_state, slow + ksp2, db
+                nm, area_link_states, prefix_state, [*slow, *ksp2], db
             )
         return result
 
@@ -2086,8 +2220,111 @@ class TpuSpfSolver:
         vals = np.ascontiguousarray(new_np.ravel()[diff])
         return self._scatter_counted(d_arr, idx, vals, sharding)
 
+    def _build_matrix(self, ad: _AreaDev, plan, link_state: LinkState,
+                      prefix_state: PrefixState, area: str,
+                      prefixes) -> None:
+        """The whole announcer matrix anew (the first solve, a renumbered
+        node index, a change `_sync_prefix_rows` cannot follow): every
+        vantage over it starts a new crib and pulls its table in full."""
+        # packed matrices are pure derivations — memoized on the
+        # PrefixState so a fresh solver over live state (restart-in-
+        # process, any-vantage, sharded fabric) skips the ~1s
+        # 100k-prefix packing loop
+        cache = getattr(prefix_state, "_matrix_memo", None)
+        if cache is None:
+            cache = prefix_state._matrix_memo = {}
+        # link_state.generation pins the node-index mapping (the
+        # mirror_source memo rebuilds it only on a new generation)
+        ckey = (prefix_state.generation, area, link_state.generation)
+        hit = cache.get(area)
+        if (
+            hit is not None
+            and hit[0] == ckey
+            and hit[1] == list(prefixes)
+        ):
+            matrix = hit[2]
+        else:
+            matrix = build_prefix_matrix(
+                prefix_state, plan.node_index, area, prefixes
+            )
+            # the names as they stand now: `prefixes` is the partition's
+            # own container and follows later changes
+            cache[area] = (ckey, list(prefixes), matrix)
+            counters.increment("decision.tpu.prefix_matrix_rebuilds")
+        if matrix is not ad.matrix:
+            matrix.holders += 1
+        ad.matrix = matrix
+        ad.matrix_version += 1
+        ad.flags = None  # force re-pack
+
+    def _sync_prefix_rows(self, ad: _AreaDev, plan, prefix_state, area: str,
+                          prefixes, mkey: tuple, shp) -> Optional[dict]:
+        """Follow the changed announcements row by row: the matrix in
+        place (`PrefixMatrix.apply_changes`), its packed copy, and the
+        device's by ONE scatter of the changed rows' cells, the rows
+        padded to a `_dirty_bucket` so that no count of rows compiles a
+        program of its own. -> the span's attributes, or None where only
+        a new matrix will do: none yet, a renumbered node index, a matrix
+        another solver holds too, a log that does not reach back, more
+        rows than a bucket holds, no room in p_cap or a_cap."""
+        matrix = ad.matrix
+        if (
+            matrix is None
+            or ad.flags is None
+            or ad.d_mbuf is None
+            or ad.matrix_key[1] != mkey[1]
+            or matrix.holders != 1
+        ):
+            return None
+        changed = prefix_state.changes_since(ad.matrix_key[0])
+        if changed is None or not _dirty_bucket(len(changed)):
+            return None
+        wanted = (
+            prefixes if isinstance(prefixes, dict)
+            else dict.fromkeys(prefixes)
+        )
+        done = matrix.apply_changes(
+            prefix_state, plan.node_index, area, changed, wanted,
+            lambda r: row_quiet(matrix, r),
+        )
+        if done is None:
+            return None
+        # the matrix is this solver's alone from here: nobody finds it
+        # under the generation it was built at
+        memo = getattr(prefix_state, "_matrix_memo", {})
+        if area in memo and memo[area][2] is matrix:
+            del memo[area]
+        n = len(done["rows"])
+        if n:
+            rows = np.full(_dirty_bucket(n), done["rows"][0], np.int64)
+            rows[:n] = done["rows"]
+            # the pad repeats a row: its cells are written twice with
+            # the same values
+            flags, idx, vals = _pack_rows(
+                matrix, rows, plan.node_overloaded
+            )
+            ad.flags[rows] = flags
+            if matrix._mbuf is not None:
+                matrix._mbuf[idx] = vals
+            ad.d_mbuf = self._scatter_counted(
+                ad.d_mbuf, idx, vals, shp("replicated")
+            )
+            self._count("decision.tpu.prefix_rows_changed", n)
+        return {
+            "rows_changed": n, "rows_allocated": done["allocated"],
+            "rows_freed": done["freed"],
+        }
+
+    @staticmethod
+    def _count(key: str, n: int = 1) -> None:
+        """A counter whose every addition is also a stamped sample (the
+        stat of the same name), so that a reader can tell what a window
+        of time added to it."""
+        counters.increment(key, n)
+        counters.add_stat_value(key, n)
+
     def _sync_area(self, area: str, link_state: LinkState,
-                   prefix_state: PrefixState, prefixes: list) -> _AreaDev:
+                   prefix_state: PrefixState, prefixes) -> _AreaDev:
         # guards the LSDB reads AND the drain-journal writes
         # (ad.drain_log / drain_epoch) — the state a cross-thread
         # caller would silently corrupt
@@ -2268,34 +2505,19 @@ class TpuSpfSolver:
 
         # announcer matrix: keyed on prefix churn + node-index stability
         mkey = (prefix_state.generation, plan.index_version)
+        t_pfx0 = _time.monotonic()
+        bytes_pfx0 = self._bytes_uploaded
+        synced = None
         if ad.matrix_key != mkey or ad.matrix is None:
-            # packed matrices are pure derivations — memoized on the
-            # PrefixState so a fresh solver over live state (restart-in-
-            # process, any-vantage, sharded fabric) skips the ~1s
-            # 100k-prefix packing loop
-            cache = getattr(prefix_state, "_matrix_memo", None)
-            if cache is None:
-                cache = prefix_state._matrix_memo = {}
-            # link_state.generation pins the node-index mapping (the
-            # mirror_source memo rebuilds it only on a new generation)
-            ckey = (
-                prefix_state.generation, area, link_state.generation,
+            synced = self._sync_prefix_rows(
+                ad, plan, prefix_state, area, prefixes, mkey, shp,
             )
-            hit = cache.get(area)
-            if (
-                hit is not None
-                and hit[0] == ckey
-                and hit[1] == prefixes
-            ):
-                ad.matrix = hit[2]
-            else:
-                ad.matrix = build_prefix_matrix(
-                    prefix_state, plan.node_index, area, prefixes
+            if synced is None:
+                self._build_matrix(
+                    ad, plan, link_state, prefix_state, area, prefixes
                 )
-                cache[area] = (ckey, prefixes, ad.matrix)
+                synced = {"rebuilt": True}
             ad.matrix_key = mkey
-            ad.matrix_version += 1
-            ad.flags = None  # force re-pack
         # packing is a pure function of (matrix, overload set): with an
         # unchanged matrix and an unchanged overload snapshot the packed
         # mirror on device is already current — skip the O(6*P*A) host
@@ -2308,17 +2530,29 @@ class TpuSpfSolver:
             if ad.flags is None or not np.array_equal(flags, ad.flags):
                 ad.flags = flags
                 ad.d_mbuf = self._put_counted(mbuf, shp("replicated"))
+        # the changed announcements' rows planned and shipped (or, where
+        # only a new matrix would do, all of them built and put)
+        ad.prefix_span = None if synced is None else (
+            "tpu.sync.prefix", "tpu.sync", t_pfx0, _time.monotonic(), {
+                "rows_changed": 0, "rows_allocated": 0, "rows_freed": 0,
+                "rebuilt": False, **synced,
+                "bytes": self._bytes_uploaded - bytes_pfx0,
+            },
+        )
         # the prefix plane as d_mbuf carries it: every stage after the
         # SSSP works over all p_cap x a_cap cells, whatever `prefixes` of
         # the rows hold a prefix
         p_cap, a_cap = ad.matrix.ann_node.shape
         rows = {
             "prefix_rows": p_cap,
-            "prefixes": len(ad.matrix.prefix_list),
+            "prefixes": ad.matrix.n_prefixes,
             "advertiser_cap": a_cap,
         }
         for key, value in rows.items():
             counters.set_counter(f"decision.tpu.{key}", value)
+        counters.set_counter(
+            "decision.tpu.prefix_rows_free", p_cap - ad.matrix.n_prefixes
+        )
         ad.sync_marks = (
             t_plan0, t_plan1, t_up0, t_up1, up_bytes, dirty_slots,
             {**mirror, **rows},
@@ -2417,11 +2651,20 @@ class TpuSpfSolver:
         delta_exp = 0
         if self.spf_kernel == "bucketed":
             delta_exp = max(plan.delta_exp, 0)
+        # the rows the matrix changed since this vantage's crib last
+        # looked (PrefixMatrix.apply_changes): None where its log no
+        # longer reaches back, or where a prefix took a row past the
+        # crib's columns, and the vantage then starts anew
+        touched = None
+        if vs.crib is not None and vs.crib.matrix is matrix:
+            if len(matrix.prefix_list) <= vs.crib.p_n:
+                touched = matrix.touched_since(vs.crib.matrix_seq)
         if (
             vs.shape_key != cache_key
             or vs.matrix_version != ad.matrix_version
             or not vs.valid
             or vs.links_tuple != links_tuple
+            or touched is None
         ):
             # (re)initialize prev outputs to zeros -> every row reads as
             # changed -> full pull path below
@@ -2445,6 +2688,9 @@ class TpuSpfSolver:
             vs.prev_dist = None
             vs.dist_epoch = -1
             vs.root_sig = None
+        elif touched[0]:
+            vs.crib.touch_rows(*touched)
+            vs.crib.matrix_seq = matrix.touch_seq
 
         # incremental eligibility: a resident distance plane whose
         # epoch window is covered by the drain journal, an unchanged
@@ -2484,6 +2730,9 @@ class TpuSpfSolver:
                             self.incremental_cone_frac * denom
                         ),
                         "denom": denom,
+                        # no weight of the mirror changed since the
+                        # resident plane was computed: it stands
+                        "still": not s_map and not r_map,
                     }
 
         t1 = _time.monotonic()
@@ -2497,6 +2746,7 @@ class TpuSpfSolver:
             "mc": mc, "incr": incr, "root_sig": root_sig,
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
+            "prefix_span": ad.prefix_span,
             "lanes": lanes,
         }
 
@@ -2604,7 +2854,8 @@ class TpuSpfSolver:
         baker.submit(f"next:{variant.aot_key}", bake)
 
     def _variant(self, pv: dict, dirty_cap: int = 0, stream: int = 0,
-                 fused: int = 0, donate: bool = False) -> PipelineVariant:
+                 fused: int = 0, donate: bool = False,
+                 rows_only: bool = False) -> PipelineVariant:
         """The executable a prepared vantage dispatches: its shape
         class, flags and tier, the solver's knobs, and the kind the
         dispatcher asks for (none: the full solve). The one place the
@@ -2615,9 +2866,11 @@ class TpuSpfSolver:
         return PipelineVariant(
             *pv["shape_key"], _DELTA_BUDGET, pv["lfa"], pv["block_v4"],
             self.enable_sentinels,
-            emit_dist=dirty_cap > 0 or (self.incremental_spf and not fused),
+            emit_dist=not rows_only and (
+                dirty_cap > 0 or (self.incremental_spf and not fused)
+            ),
             delta_exp=pv["delta_exp"], dirty_cap=dirty_cap, stream=stream,
-            fused=fused, donate=donate, mesh=pv["mc"],
+            fused=fused, donate=donate, rows_only=rows_only, mesh=pv["mc"],
         )
 
     def _incr_args(self, pv: dict) -> tuple:
@@ -2641,8 +2894,20 @@ class TpuSpfSolver:
         self._maybe_speculate(variant)
         if pv["mc"] is not None:
             counters.increment("decision.solver.multichip.dispatches")
+        rows_only = (
+            incr is not None and incr["still"] and pv["mc"] is None
+            and not self.streaming_pipeline
+        )
         if incr is None:
             args = self._lane_args(pv)
+        elif rows_only:
+            # a prefix-only epoch: what changed is in d_mbuf's rows, the
+            # resident plane stands, and the row stages run over it with
+            # no relaxation and no cone (on one chip, outside the
+            # streaming pipeline; elsewhere the incremental solve below
+            # finds nothing dirty and converges at once)
+            variant = self._variant(pv, rows_only=True)
+            args = self._lane_args(pv) + (pv["vs"].prev_dist,)
         elif pv["mc"] is None and self.streaming_pipeline:
             # streaming epoch: same eligibility ladder as the
             # incremental solve (its rungs ARE the fallback ladder
@@ -2658,7 +2923,9 @@ class TpuSpfSolver:
             variant.namespace, kernel_name, pv["shape_key"], run, args,
             pv["area"],
         )
-        if incr is not None:
+        if rows_only:
+            self._count("decision.tpu.prefix_only_epochs")
+        elif incr is not None:
             # resident incremental state for the device-only probe
             # (bench.py incr_device_ms): prev outputs chain through
             # o[2:7], the distance plane through o[7], the dirty tail
@@ -2779,6 +3046,7 @@ class TpuSpfSolver:
         lfa, sentinels = variant.lfa, variant.sentinels
         fused, stream = variant.fused, variant.stream
         emit, incr = variant.emit_dist, variant.incr
+        rows_only = variant.rows_only
         spf_kernel = variant.kernel
         d_cap, p_cap, a_cap = variant.d_cap, variant.p_cap, variant.a_cap
         t0, t1 = pv["t0"], pv["t1"]
@@ -2808,6 +3076,9 @@ class TpuSpfSolver:
                 vs.prev_dist = new_prev[5]
                 vs.dist_epoch = pv["dist_epoch"]
                 vs.root_sig = pv["root_sig"]
+            elif rows_only:
+                # the resident plane stood through this epoch too
+                vs.dist_epoch = pv["dist_epoch"]
             wa = -(-a_cap // 16)
             wd = -(-d_cap // 16)
             b = stream or variant.budget
@@ -2852,7 +3123,7 @@ class TpuSpfSolver:
                 "n_cap": plan.n_cap,
                 "s_cap": plan.s_cap,
                 "k_res": plan.k_res,
-                "n_prefixes": len(matrix.prefix_list),
+                "n_prefixes": matrix.n_prefixes,
                 "changed_rows": count,
                 "full_pull": full_pull,
                 "kernel": kernel_name,
@@ -2919,6 +3190,8 @@ class TpuSpfSolver:
             sbuf = fbuf if full_pull else dbuf
             rounds = int(sbuf[-1])
             wait_attrs = {"rounds": rounds}
+            if rows_only:
+                stats["prefix_only"] = wait_attrs["prefix_only"] = True
             if incr:
                 cone_passes = int(sbuf[-4])
                 cone = int(sbuf[-3])
@@ -3028,6 +3301,7 @@ class TpuSpfSolver:
              mirror) = pv["sync_marks"]
             stats.update(mirror)
             stats.update(pv["lanes"])
+            prefix_spans = [pv["prefix_span"]] if pv["prefix_span"] else []
             return {
                 "view": crib.view(),
                 "stats": stats,
@@ -3044,6 +3318,7 @@ class TpuSpfSolver:
                         "bytes_uploaded": up_bytes,
                         "dirty_slots": dirty_slots,
                     }),
+                    *prefix_spans,
                     ("tpu.dispatch", None, t1, t_disp, {
                         "kernel": kernel_name, "incremental": incr,
                         "lanes": d_cap, "rows": p_cap,

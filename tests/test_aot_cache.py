@@ -411,6 +411,10 @@ class TestSolverWarmRestart:
         states, ps, me = _grid_states(4)
         oracle = SpfSolver(me).build_route_db(me, states, ps)
 
+        # cold as a fresh process is: under xdist the worker brings the
+        # executables of the files before this one, and one of this
+        # shape class would be called, not compiled and written
+        clear_all_jit_caches()
         cold = TpuSpfSolver(me)
         rib_cold = cold.build_route_db(me, states, ps)
         assert_rib_equal(oracle, rib_cold, "cold solve")

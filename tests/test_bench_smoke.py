@@ -7,6 +7,23 @@ rot between full bench runs. Parity vs the CPU oracle is asserted
 inside bench_config itself.
 """
 
+import pytest
+
+
+@pytest.fixture(autouse=True, scope="module")
+def own_jit_caches():
+    """The shape-keyed jit caches are the process's: under xdist a worker
+    brings what the files before this one compiled, and a namespace that
+    arrives with its eight buckets full evicts on this file's first
+    compile, which the "no eviction" assertions below would read as
+    churn. This file starts from empty caches and a sentinel that calls
+    nothing warm."""
+    from openr_tpu.ops import xla_cache
+
+    for factory in xla_cache._BOUNDED_CACHES:
+        factory.cache_clear()
+    xla_cache.retrace.reset()
+
 
 def test_bench_config_smoke_device_path():
     from bench import bench_config

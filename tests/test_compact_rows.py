@@ -219,6 +219,14 @@ class Recorder:
     def check(self, variant, want_full, outs, want_d, want_f):
         got_d, got_f = np.asarray(outs[0]), np.asarray(outs[1])
         ctx = f"{variant.name} want_full={want_full}"
+        if variant.rows_only:
+            # a prefix-only solve (no weight changed: the resident plane
+            # stands): its oracle is the parent's FULL solve of the same
+            # arguments, which it must equal in everything but the work
+            # it did not do: trips (word 1) and rounds (the last) read 0
+            want_d, want_f = want_d.copy(), want_f.copy()
+            for buf in (want_d, want_f):
+                buf[1] = buf[-1] = 0
         np.testing.assert_array_equal(got_d, want_d, err_msg=ctx)
         count = int(got_d[0])
         cold = bool(want_full) or count > (variant.stream or variant.budget)

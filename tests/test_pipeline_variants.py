@@ -29,10 +29,12 @@ KINDS = {
     "fused": {"fused": 3},
     "mc": {"mesh": True},
     "mc_incr": {"emit_dist": True, "dirty_cap": 64, "mesh": True},
+    # the prefix-only solve: the row stages over the resident plane
+    "rows": {"rows_only": True},
 }
 NAMESPACE = {
     "full": "", "fused": "", "incr": "incr", "stream": "stream",
-    "mc": "multichip", "mc_incr": "multichip",
+    "mc": "multichip", "mc_incr": "multichip", "rows": "incr",
 }
 
 # (kind, has_res, lfa, delta_exp) -> the name the parent's six
@@ -128,6 +130,12 @@ GOLDEN = {
     ("mc_incr", True, True, 3):
         "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa,bk3]",
 }
+# the prefix-only solve has no parent: it is the full solve's shape
+# class and flags under a kernel name of its own
+GOLDEN.update({
+    ("rows", *key[1:]): name.replace("pipeline[", "pipeline_rows[")
+    for key, name in list(GOLDEN.items()) if key[0] == "full"
+})
 FLAGS = list(itertools.product((False, True), (False, True), (0, 3)))
 
 
@@ -233,6 +241,12 @@ BAD = {
     "donating_incremental": dict(
         donate=True, dirty_cap=64, emit_dist=True
     ),
+    "rows_only_incremental": dict(
+        rows_only=True, dirty_cap=64, emit_dist=True
+    ),
+    "rows_only_emitting_the_plane": dict(rows_only=True, emit_dist=True),
+    "rows_only_fused": dict(rows_only=True, fused=2),
+    "rows_only_on_a_mesh": dict(rows_only=True, mesh=True),
 }
 
 

@@ -11,7 +11,9 @@ track kept from the process's start (`tracer.get_holds()`), the measured
 window's bounds and events (due, sent, acked), the harness's own stamps
 of the collector — what PERF.md's section 5 and 6 quote per hold — and
 `window_counters`, what the solver's `decision.tpu.*` counters (`epochs`,
-`cold_compactions`, `cone_passes`, `cone_skips`) gained over that window. A
+`cold_compactions`, `cone_passes`, `cone_skips` and, of the prefix rows,
+`prefix_rows_changed`, `prefix_only_epochs`, `prefix_matrix_rebuilds`) and
+`decision.crib.key_index_builds` gained over that window. A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
 """
@@ -28,8 +30,12 @@ sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 
 import run  # noqa: E402  (stamps T_PROCESS)
 
-# of `decision.tpu.*`, the counters (the rest are gauges of the mirror)
-WINDOW_COUNTERS = ("epochs", "cold_compactions", "cone_passes", "cone_skips")
+# of `decision.tpu.*`, the counters (the rest are gauges of the mirror),
+# and the one that counts O(rows) key structures built
+WINDOW_COUNTERS = tuple(f"decision.tpu.{name}" for name in (
+    "epochs", "cold_compactions", "cone_passes", "cone_skips",
+    "prefix_rows_changed", "prefix_only_epochs", "prefix_matrix_rebuilds",
+)) + ("decision.crib.key_index_builds",)
 
 
 def main(argv=None) -> int:
@@ -42,16 +48,16 @@ def main(argv=None) -> int:
     window = harness.Session.window
 
     async def keep(self, *a, **kw):
-        before = counters.get_counters("decision.tpu.")
+        before = counters.raw_counters()
         result = await window(self, *a, **kw)
         if "sample_seed" in kw:  # the measured window, not the warm-up's
             kept.update(
                 window=result, collections=list(self.collections),
                 counters={
-                    key: value - before.get(key, 0)
-                    for key, value in
-                    counters.get_counters("decision.tpu.").items()
-                    if key.split(".")[-1] in WINDOW_COUNTERS
+                    key.removeprefix("decision.tpu."):
+                        value - before.get(key, 0)
+                    for key, value in counters.raw_counters().items()
+                    if key in WINDOW_COUNTERS
                 },
             )
         return result
