@@ -227,6 +227,130 @@ def _plan_sssp(deltas, shift_w, res_rows, res_nbr, res_w, root,
     return dist, trips, rounds
 
 
+class _Cells(NamedTuple):
+    """Announcer cells of R prefix rows, [R, a_cap] each but the last:
+    the planes of the packed matrix buffer (_pack_matrix) with the flags
+    plane taken apart."""
+
+    ann_node: object
+    ann_valid: object
+    ann_over: object
+    path_pref: object
+    source_pref: object
+    dist_adv: object
+    min_nh: object
+    v4_blocked: object  # bool [R]
+
+
+def _cells(ann_node, ann_flags, path_pref, source_pref, dist_adv, min_nh,
+           block_v4: bool) -> _Cells:
+    import jax.numpy as jnp
+
+    ann_valid = (ann_flags & 1).astype(bool)
+    ann_over = (ann_flags & 2).astype(bool)
+    # per-prefix v4 bit rides flag bit 2 of announcer slot 0
+    v4_blocked = (
+        (ann_flags[:, 0] & 4).astype(bool)
+        if block_v4
+        else jnp.zeros((ann_flags.shape[0],), bool)
+    )
+    return _Cells(ann_node, ann_valid, ann_over, path_pref, source_pref,
+                  dist_adv, min_nh, v4_blocked)
+
+
+def _row_stages(cells: _Cells, dist_d, root, root_w, n_cap: int, lfa: bool):
+    """THE row stages (scopes `select`, `nexthop`, `lfa`) over the R rows
+    of `cells` and the [D, N] plane: -> metric [R], s3 bool [R, a_cap],
+    nh_mask bool [R, D], lfa_slot and lfa_metric [R] (None without
+    `lfa`). Every pipeline runs this one body — all p_cap rows after a
+    solve, a few candidate rows after none (_make_rows_pipeline) — so a
+    row reads the same whichever computed it."""
+    import jax
+    import jax.numpy as jnp
+
+    ann_node, ann_valid, ann_over, path_pref, source_pref, dist_adv = cells[:6]
+    with jax.named_scope("select"):
+        via = root_w[:, None] + dist_d  # <= 2^30, overflow-free
+        dist = jnp.minimum(via.min(axis=0), INF_E).at[root].set(0)  # [N]
+
+        # selection (reference order; drain via flags)
+        idx = jnp.clip(ann_node, 0, n_cap - 1)
+        ann_dist = dist[idx]
+        reach = ann_valid & (ann_dist < INF_E)
+        pp = jnp.where(reach, path_pref, _NEG)
+        s = reach & (pp == pp.max(axis=1, keepdims=True))
+        sp = jnp.where(s, source_pref, _NEG)
+        s = s & (sp == sp.max(axis=1, keepdims=True))
+        da = jnp.where(s, dist_adv, INF_E)
+        s2 = s & (da == da.min(axis=1, keepdims=True))
+        nd = s2 & ~ann_over
+        s3 = jnp.where(nd.any(axis=1, keepdims=True), nd, s2)
+        igp = jnp.where(s3, ann_dist, INF_E)
+        metric = igp.min(axis=1)
+        s4 = s3 & (igp == metric[:, None])
+
+    with jax.named_scope("nexthop"):
+        on_sp = (via == dist[None, :]).T  # [N, D]
+        nh_mask = jnp.any(s4[:, :, None] & on_sp[idx], axis=1)  # [R, D]
+
+    if not lfa:
+        return metric, s3, nh_mask, None, None
+    with jax.named_scope("lfa"):
+        # rfc5286 loop-free alternates from the SAME per-slot distance
+        # fields: slot d is a valid backup for prefix row p iff its
+        # neighbor's own distance to the selected announcer set
+        # (min over s3 of dist_d) beats detouring back through the
+        # root (dist_d[root] + route metric). Strict < guarantees no
+        # micro-loop. One [R, A, D] row-gather — the same shape the
+        # ECMP predicate's on_sp[idx] gather already pays.
+        d_root = dist_d[:, root]  # [D] neighbor -> root distance
+        ann_nd = dist_d.T[idx]  # [R, A, D]
+        nbr_pd = jnp.where(
+            s3[:, :, None], ann_nd, INF_E
+        ).min(axis=1)  # [R, D]
+        link_up = root_w < INF_E
+        ok_lfa = (
+            link_up[None, :]
+            & ~nh_mask
+            & (nbr_pd < INF_E)  # neighbor actually reaches the prefix
+            & (nbr_pd < d_root[None, :] + metric[:, None])
+        )
+        # alternate cost <= 2^29 + 2^28 < the 2^30 mask fill
+        alt = jnp.where(
+            ok_lfa, root_w[None, :] + nbr_pd, jnp.int32(1 << 30)
+        )
+        has_lfa = ok_lfa.any(axis=1)
+        # argmin returns the FIRST minimum: lowest slot breaks ties,
+        # matching the oracle's ordered-link iteration
+        lfa_slot = jnp.where(
+            has_lfa, jnp.argmin(alt, axis=1).astype(jnp.int32), -1
+        )
+        lfa_metric = jnp.where(has_lfa, alt.min(axis=1), 0)
+    return metric, s3, nh_mask, lfa_slot, lfa_metric
+
+
+def _sentinels(announced, metric) -> tuple:
+    """Numerical-health sentinels, (unreachable, saturated): two scalar
+    reductions over ALL rows riding the tail of BOTH pull buffers (free
+    — the pull happens anyway). unreachable = rows with a live announcer
+    (`announced`, bool [P]) but no finite metric; saturated = finite
+    metrics past 2^28, within one metric-add of the 2^29 INF_E encoding —
+    the overflow precursor the encoding cannot represent failing."""
+    import jax.numpy as jnp
+
+    unreach = (
+        (announced & (metric >= INF_E))
+        .sum()
+        .astype(jnp.int32)
+    )
+    saturated = (
+        ((metric < INF_E) & (metric > _SENTINEL_SAT))
+        .sum()
+        .astype(jnp.int32)
+    )
+    return unreach, saturated
+
+
 def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    has_res: bool,
                    d_cap: int, p_cap: int, a_cap: int, budget: int,
@@ -234,7 +358,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    sentinels: bool = True, emit_dist: bool = False,
                    incr: bool = False, mesh=None,
                    kernel: str = "sync", delta_exp: int = 0,
-                   stream: int = 0, rows_only: bool = False):
+                   stream: int = 0):
     """The fused production pipeline (raw closure — _build_pipeline jits
     it under the options a PipelineVariant names, vmapped over a group
     of same-shape areas for a `fused` one). Outputs:
@@ -279,13 +403,6 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     is bit-identical to the cold one, so the ENTIRE selection / LFA /
     packing / delta tail below is shared verbatim between the two
     kernels — output parity by construction.
-
-    With `rows_only=True` (the prefix-only solve: no weight of the
-    mirror changed since the vantage's resident plane was computed, so
-    the plane stands) the pipeline takes ONE trailing arg, prev_dist,
-    and runs no relaxation and no cone at all: trips and rounds read 0
-    and the row stages below run over the resident plane as they would
-    over a fresh one. The plane is not an output.
 
     With `mesh` (the multichip capacity tier) the SSSP core swaps for
     parallel/sharding.py's shard_mapped twins — shift columns over
@@ -341,27 +458,13 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                  prev_metric, prev_s3w, prev_nhw,
                  prev_lfa_slot, prev_lfa_metric, *incr_args):
         with jax.named_scope("unpack"):
-            o = 0
-            ann_node = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            ann_flags = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            path_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            source_pref = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            dist_adv = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            min_nh = mbuf[o:o + pa].reshape(p_cap, a_cap); o += pa
-            ann_valid = (ann_flags & 1).astype(bool)
-            ann_over = (ann_flags & 2).astype(bool)
-            # per-prefix v4 bit rides flag bit 2 of announcer slot 0
-            v4_blocked = (
-                (ann_flags[:, 0] & 4).astype(bool)
-                if block_v4
-                else jnp.zeros((p_cap,), bool)
-            )
+            cells = _cells(*(
+                mbuf[o:o + pa].reshape(p_cap, a_cap)
+                for o in range(0, 6 * pa, pa)
+            ), block_v4)
 
         with jax.named_scope("seed"):
-            if rows_only:
-                (dist_d,) = incr_args
-                trips = rounds = jnp.int32(0)
-            elif incr:
+            if incr:
                 (prev_dist, s_dirty_idx, s_dirty_old,
                  r_dirty_idx, r_dirty_old, cone_limit) = incr_args
                 if mesh is not None:
@@ -407,63 +510,10 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             # partitioner never touches a sharded gather axis
             dist_res = dist_d
             dist_d = jax.lax.with_sharding_constraint(dist_d, mc_rep)
-        with jax.named_scope("select"):
-            via = root_w[:, None] + dist_d  # <= 2^30, overflow-free
-            dist = jnp.minimum(via.min(axis=0), INF_E).at[root].set(0)  # [N]
-
-            # selection (reference order; drain via flags)
-            idx = jnp.clip(ann_node, 0, n_cap - 1)
-            ann_dist = dist[idx]
-            reach = ann_valid & (ann_dist < INF_E)
-            pp = jnp.where(reach, path_pref, _NEG)
-            s = reach & (pp == pp.max(axis=1, keepdims=True))
-            sp = jnp.where(s, source_pref, _NEG)
-            s = s & (sp == sp.max(axis=1, keepdims=True))
-            da = jnp.where(s, dist_adv, INF_E)
-            s2 = s & (da == da.min(axis=1, keepdims=True))
-            nd = s2 & ~ann_over
-            s3 = jnp.where(nd.any(axis=1, keepdims=True), nd, s2)
-            igp = jnp.where(s3, ann_dist, INF_E)
-            metric = igp.min(axis=1)
-            s4 = s3 & (igp == metric[:, None])
-
-        with jax.named_scope("nexthop"):
-            on_sp = (via == dist[None, :]).T  # [N, D]
-            nh_mask = jnp.any(s4[:, :, None] & on_sp[idx], axis=1)  # [P, D]
-
-        if lfa:
-            with jax.named_scope("lfa"):
-                # rfc5286 loop-free alternates from the SAME per-slot distance
-                # fields: slot d is a valid backup for prefix row p iff its
-                # neighbor's own distance to the selected announcer set
-                # (min over s3 of dist_d) beats detouring back through the
-                # root (dist_d[root] + route metric). Strict < guarantees no
-                # micro-loop. One [P, A, D] row-gather — the same shape the
-                # ECMP predicate's on_sp[idx] gather already pays.
-                d_root = dist_d[:, root]  # [D] neighbor -> root distance
-                ann_nd = dist_d.T[idx]  # [P, A, D]
-                nbr_pd = jnp.where(
-                    s3[:, :, None], ann_nd, INF_E
-                ).min(axis=1)  # [P, D]
-                link_up = root_w < INF_E
-                ok_lfa = (
-                    link_up[None, :]
-                    & ~nh_mask
-                    & (nbr_pd < INF_E)  # neighbor actually reaches the prefix
-                    & (nbr_pd < d_root[None, :] + metric[:, None])
-                )
-                # alternate cost <= 2^29 + 2^28 < the 2^30 mask fill
-                alt = jnp.where(
-                    ok_lfa, root_w[None, :] + nbr_pd, jnp.int32(1 << 30)
-                )
-                has_lfa = ok_lfa.any(axis=1)
-                # argmin returns the FIRST minimum: lowest slot breaks ties,
-                # matching the oracle's ordered-link iteration
-                lfa_slot = jnp.where(
-                    has_lfa, jnp.argmin(alt, axis=1).astype(jnp.int32), -1
-                )
-                lfa_metric = jnp.where(has_lfa, alt.min(axis=1), 0)
-        else:
+        metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
+            cells, dist_d, root, root_w, n_cap, lfa
+        )
+        if not lfa:
             lfa_slot = prev_lfa_slot
             lfa_metric = prev_lfa_metric
 
@@ -472,7 +522,8 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             # pull to ok rows, and on the streaming path rides the delta
             # payload per changed row (the host apply is then unpack-free)
             return route_ok_device(
-                metric, s3, nh_mask, ann_node, min_nh, v4_blocked, root,
+                metric, s3, nh_mask, cells.ann_node, cells.min_nh,
+                cells.v4_blocked, root,
             )
 
         with jax.named_scope("pack"):
@@ -527,21 +578,8 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             )
             full_parts = [okc[None], trips[None].astype(jnp.int32), full_rows]
             if sentinels:
-                # numerical-health sentinels: two scalar reductions riding
-                # the tail of BOTH pull buffers (free — the pull happens
-                # anyway). unreachable = rows with a live announcer but no
-                # finite metric; saturated = finite metrics past 2^28,
-                # within one metric-add of the 2^29 INF_E encoding — the
-                # overflow precursor the encoding cannot represent failing.
-                unreach = (
-                    (ann_valid.any(axis=1) & (metric >= INF_E))
-                    .sum()
-                    .astype(jnp.int32)
-                )
-                saturated = (
-                    ((metric < INF_E) & (metric > _SENTINEL_SAT))
-                    .sum()
-                    .astype(jnp.int32)
+                unreach, saturated = _sentinels(
+                    cells.ann_valid.any(axis=1), metric
                 )
                 delta_parts += [unreach[None], saturated[None]]
                 full_parts += [unreach[None], saturated[None]]
@@ -580,6 +618,104 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     return pipeline
 
 
+def _make_rows_pipeline(n_cap: int, d_cap: int, p_cap: int, a_cap: int,
+                        budget: int, rows: int, lfa: bool, block_v4: bool,
+                        sentinels: bool):
+    """The prefix-only solve (a PipelineVariant with `rows_only`): no
+    weight of the mirror changed since the vantage's resident plane was
+    computed, so the plane stands, and the dispatcher knows which rows
+    of the announcer matrix were scattered since the resident outputs
+    were computed (_prep_vantage's `cand_rows`) — no other row's inputs
+    differ from those its outputs came from. So nothing is solved and
+    nothing is searched for: the row stages (_row_stages, the body every
+    pipeline runs) go over `rows` candidate rows alone.
+
+    _make_pipeline's arguments, then TWO trailing ones: the resident
+    [D, N] plane and cand_rows int32 [rows] — ascending, each row once,
+    the pad repeating the last (so a row equal to its left neighbour is
+    pad). Outputs as _make_pipeline's, in the one download format:
+      delta_buf  its layout word for word — count, trips 0, the changed
+                 candidates' indices and columns (pad slots as a clipped
+                 read of the last row gives them), sentinels, rounds 0;
+      full_buf   the scalars alone: such an epoch never reads it
+                 (want_full is 0 and count <= rows <= budget);
+      metric, s3w, nhw, lfa_slot, lfa_metric: the previous arrays with
+                 the candidate rows written in. They are not donated (an
+                 abandoned prepare must still find them), so each is
+                 copied: a few MB.
+    The sentinels stay what they are, reductions over every row: of the
+    resident metric after the scatter, and of the flags plane."""
+    import jax
+    import jax.numpy as jnp
+
+    from openr_tpu.ops.stream import column_diff, first_true_rows, rows_any
+
+    pa = p_cap * a_cap
+
+    def pipeline(deltas, shift_w, res_rows, res_nbr, res_w, mbuf,
+                 root, root_nbr, root_w, want_full,
+                 prev_metric, prev_s3w, prev_nhw,
+                 prev_lfa_slot, prev_lfa_metric, dist_d, cand_rows):
+        with jax.named_scope("unpack"):
+            at = cand_rows[:, None] * a_cap + jnp.arange(a_cap)  # [R, A]
+            cells = _cells(*(mbuf[o + at] for o in range(0, 6 * pa, pa)),
+                           block_v4)
+        zero = jnp.zeros((1,), jnp.int32)  # trips, rounds: nothing ran
+        metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
+            cells, dist_d, root, root_w, n_cap, lfa
+        )
+        with jax.named_scope("pack"):
+            cols = [metric, _pack_words(s3), _pack_words(nh_mask),
+                    lfa_slot, lfa_metric]
+            prev = [prev_metric, prev_s3w, prev_nhw,
+                    prev_lfa_slot, prev_lfa_metric]
+            n = 5 if lfa else 3  # without it the lfa columns pass through
+        with jax.named_scope("diff"):
+            was = [p[cand_rows] for p in prev[:n]] + [None] * (5 - n)
+            changed = column_diff(*cols, *was, lfa) & jnp.concatenate([
+                jnp.ones((1,), bool), cand_rows[1:] != cand_rows[:-1],
+            ])
+        with jax.named_scope("compact"):
+            new = [
+                p.at[cand_rows].set(c) for p, c in zip(prev[:n], cols)
+            ] + prev[n:]
+            slot = first_true_rows(changed, rows, rows)
+            live = slot < rows
+            slot = jnp.minimum(slot, rows - 1)
+
+            def lay(col, whole):
+                # the changed rows to the front; every slot after them
+                # reads the last row, as compact_changed_rows' clipped
+                # read of the pad index p_cap does
+                last = whole[p_cap - 1]
+                head = jnp.where(
+                    live.reshape((rows,) + (1,) * last.ndim), col[slot], last
+                )
+                pad = jnp.broadcast_to(last, (budget - rows,) + last.shape)
+                return jnp.concatenate([head, pad]).ravel()
+
+            tail = []
+            if sentinels:
+                unreach, saturated = _sentinels(
+                    rows_any(mbuf[pa:2 * pa] & 1, p_cap, a_cap), new[0]
+                )
+                tail = [unreach[None], saturated[None]]
+            tail += [zero]
+            delta_buf = jnp.concatenate([
+                changed.sum().astype(jnp.int32)[None], zero,
+                jnp.concatenate([
+                    jnp.where(live, cand_rows[slot], p_cap),
+                    jnp.full((budget - rows,), p_cap, jnp.int32),
+                ]),
+                *(lay(c, w) for c, w in zip(cols[:n], new)),
+                *tail,
+            ])
+            full_buf = jnp.concatenate([zero, zero, *tail])
+        return (delta_buf, full_buf, *new)
+
+    return pipeline
+
+
 class PipelineVariant(NamedTuple):
     """Everything that tells one pipeline executable from another — the
     whole key of `pipeline_for`. The ints are the capacity signature
@@ -608,7 +744,7 @@ class PipelineVariant(NamedTuple):
     stream: int = 0           # a STREAM_BUDGETS bucket: streaming epoch
     fused: int = 0            # g same-shape areas vmapped in one dispatch
     donate: bool = False      # prev planes + warm seed donated (stream)
-    rows_only: bool = False   # prefix-only: row stages over the resident plane
+    rows_only: int = 0        # prefix-only: the candidate rows' bucket
     mesh: object = None       # the multichip tier's ('batch','graph') mesh
 
     @classmethod
@@ -627,10 +763,11 @@ class PipelineVariant(NamedTuple):
             raise ValueError(f"only a stream epoch donates: {v}")
         if v.rows_only and (
             v.incr or v.fused or v.emit_dist or not one_chip
+            or v.rows_only > v.budget
         ):
             raise ValueError(
                 f"rows_only: no solve, one area, one chip, the plane "
-                f"stays where it is: {v}"
+                f"stays where it is, the rows fit a delta pull: {v}"
             )
         return v
 
@@ -661,8 +798,8 @@ class PipelineVariant(NamedTuple):
             return "multichip"
         if self.stream:
             return "stream"
-        # the prefix-only executable, one bucket a shape class, lies
-        # with the class's dirty-cap buckets
+        # the prefix-only executables, one a row bucket, lie with the
+        # class's dirty-cap buckets
         return "incr" if self.incr or self.rows_only else ""
 
     @property
@@ -683,6 +820,7 @@ class PipelineVariant(NamedTuple):
             f"p={self.p_cap},a={self.a_cap}",
             f"dd={self.dirty_cap}" if self.incr else "",
             f"sb={self.stream}" if self.stream else "",
+            f"rr={self.rows_only}" if self.rows_only else "",
             f"mesh={_mesh_tag(self.mesh)}" if self.mesh is not None else "",
             "res" if self.has_res else "",
             "lfa" if self.lfa else "",
@@ -774,11 +912,17 @@ def _build_pipeline(*fields) -> tuple:
     from openr_tpu.ops.xla_cache import instrument_jit
 
     v = PipelineVariant.checked(*fields)
-    pipeline = _make_pipeline(
-        *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
-        v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
-        delta_exp=v.delta_exp, stream=v.stream, rows_only=v.rows_only,
-    )
+    if v.rows_only:
+        pipeline = _make_rows_pipeline(
+            v.n_cap, v.d_cap, v.p_cap, v.a_cap, v.budget, v.rows_only,
+            v.lfa, v.block_v4, v.sentinels,
+        )
+    else:
+        pipeline = _make_pipeline(
+            *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
+            v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
+            delta_exp=v.delta_exp, stream=v.stream,
+        )
     kw = {}
     if v.donate:
         kw = {"donate_argnums": (10, 11, 12, 13, 14, 15)}
@@ -955,7 +1099,7 @@ class _AreaDev:
         "plan", "d_deltas", "d_shift_w", "d_res_rows", "d_res_nbr",
         "d_res_w", "matrix_key", "matrix", "flags", "d_mbuf",
         "matrix_version", "pack_over", "drain_epoch", "drain_log",
-        "mc_mesh", "sync_marks", "prefix_span",
+        "mc_mesh", "sync_marks", "prefix_span", "mbuf_puts",
     )
 
     def __init__(self):
@@ -968,6 +1112,10 @@ class _AreaDev:
         self.matrix: Optional[PrefixMatrix] = None
         self.flags: Optional[np.ndarray] = None
         self.d_mbuf = None
+        # times d_mbuf was put whole (a new matrix, a changed overload
+        # snapshot); between two of them it is only scattered into, row
+        # by row, and the matrix's touch log says which rows
+        self.mbuf_puts = 0
         # drain journal for the incremental solver: one entry per
         # _sync_area epoch — ({shift_flat: old_w}, {res_flat: old_w})
         # maps of that drain's pre-write weights, or (None, None) as a
@@ -1008,13 +1156,19 @@ class _VantageState:
     __slots__ = (
         "shape_key", "matrix_version", "prev", "crib",
         "links_tuple", "valid", "prev_dist", "dist_epoch", "root_sig",
-        "stream_budget",
+        "stream_budget", "rows_stamp",
     )
 
     def __init__(self):
         self.shape_key = None
         self.matrix_version = -1
         self.prev = None  # (metric, s3w, nhw) device handles
+        # what `prev` was computed from, beside the plane: (the area's
+        # d_mbuf puts, the matrix's touch_seq) at its dispatch. Where
+        # they still name d_mbuf's last put and the point the crib's
+        # touch log is read from, the rows touched since are the only
+        # rows whose inputs moved
+        self.rows_stamp = None
         self.crib: Optional[ColumnarRib] = None
         self.links_tuple: tuple = ()
         self.valid = False
@@ -2530,6 +2684,7 @@ class TpuSpfSolver:
             if ad.flags is None or not np.array_equal(flags, ad.flags):
                 ad.flags = flags
                 ad.d_mbuf = self._put_counted(mbuf, shp("replicated"))
+                ad.mbuf_puts += 1
         # the changed announcements' rows planned and shipped (or, where
         # only a new matrix would do, all of them built and put)
         ad.prefix_span = None if synced is None else (
@@ -2659,6 +2814,16 @@ class TpuSpfSolver:
         if vs.crib is not None and vs.crib.matrix is matrix:
             if len(matrix.prefix_list) <= vs.crib.p_n:
                 touched = matrix.touched_since(vs.crib.matrix_seq)
+        # and, where the resident outputs were computed at the point that
+        # list starts from and d_mbuf has only been scattered into since,
+        # the only rows a prefix-only solve has to look at (ascending,
+        # each once; none is a list too). An abandoned prepare leaves the
+        # stamp behind the crib's: all rows then, once
+        cand_rows = None
+        if touched is not None and vs.rows_stamp == (
+            ad.mbuf_puts, vs.crib.matrix_seq
+        ):
+            cand_rows = np.unique(np.asarray(touched[0], np.int32))
         if (
             vs.shape_key != cache_key
             or vs.matrix_version != ad.matrix_version
@@ -2744,6 +2909,8 @@ class TpuSpfSolver:
             "vs": vs, "lfa": lfa, "block_v4": block_v4,
             "delta_exp": delta_exp,
             "mc": mc, "incr": incr, "root_sig": root_sig,
+            "cand_rows": cand_rows,
+            "rows_stamp": (ad.mbuf_puts, matrix.touch_seq),
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
             "prefix_span": ad.prefix_span,
@@ -2855,7 +3022,7 @@ class TpuSpfSolver:
 
     def _variant(self, pv: dict, dirty_cap: int = 0, stream: int = 0,
                  fused: int = 0, donate: bool = False,
-                 rows_only: bool = False) -> PipelineVariant:
+                 rows_only: int = 0) -> PipelineVariant:
         """The executable a prepared vantage dispatches: its shape
         class, flags and tier, the solver's knobs, and the kind the
         dispatcher asks for (none: the full solve). The one place the
@@ -2894,20 +3061,32 @@ class TpuSpfSolver:
         self._maybe_speculate(variant)
         if pv["mc"] is not None:
             counters.increment("decision.solver.multichip.dispatches")
-        rows_only = (
+        cand = pv["cand_rows"]
+        rows_only = 0
+        if (
             incr is not None and incr["still"] and pv["mc"] is None
-            and not self.streaming_pipeline
-        )
+            and not self.streaming_pipeline and cand is not None
+        ):
+            # more rows than a bucket or a delta pull holds: not this way
+            rows_only = _dirty_bucket(len(cand)) or 0
+            if rows_only > variant.budget:
+                rows_only = 0
         if incr is None:
             args = self._lane_args(pv)
         elif rows_only:
             # a prefix-only epoch: what changed is in d_mbuf's rows, the
-            # resident plane stands, and the row stages run over it with
-            # no relaxation and no cone (on one chip, outside the
-            # streaming pipeline; elsewhere the incremental solve below
-            # finds nothing dirty and converges at once)
-            variant = self._variant(pv, rows_only=True)
-            args = self._lane_args(pv) + (pv["vs"].prev_dist,)
+            # resident plane stands, and the row stages run over the
+            # rows scattered since the resident outputs were computed
+            # and over no other, with no relaxation and no cone (on one
+            # chip, outside the streaming pipeline, where those rows are
+            # known and fit; elsewhere the incremental solve below finds
+            # nothing dirty, converges at once and looks at every row)
+            variant = self._variant(pv, rows_only=rows_only)
+            rows = np.full(rows_only, cand[-1] if len(cand) else 0, np.int32)
+            rows[:len(cand)] = cand
+            if self._transfer_guard_mode() is not None:
+                rows = self._put_counted(rows)
+            args = self._lane_args(pv) + (pv["vs"].prev_dist, rows)
         elif pv["mc"] is None and self.streaming_pipeline:
             # streaming epoch: same eligibility ladder as the
             # incremental solve (its rungs ARE the fallback ladder
@@ -2925,6 +3104,10 @@ class TpuSpfSolver:
         )
         if rows_only:
             self._count("decision.tpu.prefix_only_epochs")
+            # how often the candidate rows served one, and how many
+            # (pads not counted)
+            self._count("decision.tpu.candidate_epochs")
+            self._count("decision.tpu.candidate_rows", len(cand))
         elif incr is not None:
             # resident incremental state for the device-only probe
             # (bench.py incr_device_ms): prev outputs chain through
@@ -3069,6 +3252,7 @@ class TpuSpfSolver:
             # compares against the outputs last applied, so the aborted
             # solve's changed rows are not silently treated as applied
             vs.prev = tuple(new_prev[:5])
+            vs.rows_stamp = pv["rows_stamp"]
             if emit:
                 # the emitted distance plane becomes the next solve's
                 # warm seed, stamped with the drain epoch and root
@@ -3192,6 +3376,8 @@ class TpuSpfSolver:
             wait_attrs = {"rounds": rounds}
             if rows_only:
                 stats["prefix_only"] = wait_attrs["prefix_only"] = True
+                wait_attrs["cand_rows"] = len(pv["cand_rows"])
+                wait_attrs["cand_cap"] = rows_only
             if incr:
                 cone_passes = int(sbuf[-4])
                 cone = int(sbuf[-3])

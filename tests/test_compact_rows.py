@@ -2,7 +2,8 @@
 is bit for bit what left it before, and the cold pull's half runs only in
 an epoch that reads it.
 
-  rows     `ops/stream.first_true_rows` and `true_rows` equal
+  rows     `ops/stream.rows_any` equals the reshaped `any`, and
+           `ops/stream.first_true_rows` and `true_rows` equal
            `jnp.nonzero(mask, size=..., fill_value=...)[0]` for every
            mask: lengths from the smallest prefix plane a test builds
            (8 rows) to fabric10k_pfx's 524,288, every delta budget, masks
@@ -13,7 +14,9 @@ an epoch that reads it.
            pipeline of the parent commit (`jnp.nonzero` in both halves,
            the cold half unconditional): `delta_buf` equal in every
            epoch, `full_buf` equal whenever the host reads it, and zeros
-           between its scalars whenever it does not;
+           between its scalars whenever it does not; the five resident
+           arrays equal in every epoch (a prefix-only solve writes only
+           its candidate rows into them: tests/test_prefix_churn.py);
   predicate  `want_full | count > budget`: at budget + 1 changed rows
            exactly the cold half runs and at budget it does not, and a
            vantage with no table asks for the whole table though fewer
@@ -107,6 +110,24 @@ def test_first_true_rows_under_vmap():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("p,a", [
+    (8, 1), (8, 2), (64, 4), (256, 2), (16, 128), (8, 256), (24, 3),
+    (524288, 2),
+])
+def test_rows_any_is_any_over_a_rows_cells(p, a):
+    """`rows_any` (the prefix-only solve's sentinel pass over the flags
+    plane) equals the reshaped `any` at every width: pooled a lane tile
+    wide where a row's cells divide one, plain where they do not."""
+    rng = np.random.default_rng(p * 131 + a)
+    for density in (0.0, 0.02, 0.5, 1.0):
+        cells = rng.random(p * a) < density
+        got = np.asarray(jax.jit(
+            lambda c: stream.rows_any(c, p, a)
+        )(jnp.asarray(cells.astype(np.int32))))
+        assert got.dtype == bool and got.shape == (p,)
+        np.testing.assert_array_equal(got, cells.reshape(p, a).any(axis=1))
+
+
 # -- the pipeline's buffers against the parent's ----------------------------
 
 
@@ -158,12 +179,15 @@ def parent_pipeline(monkeypatch, variant: ts.PipelineVariant):
 
 
 def parent_buffers(oracle, args) -> tuple:
-    """(delta_buf, full_buf) of the parent's pipeline on a dispatch's
-    arguments (want_full, argument 9, forced to 1)."""
+    """(delta_buf, full_buf, the five resident arrays) of the parent's
+    pipeline on a dispatch's arguments (want_full, argument 9, forced
+    to 1)."""
     args = list(args)
     args[9] = np.int32(1)
-    delta_buf, full_buf, *_ = oracle(*args)
-    return np.asarray(delta_buf), np.asarray(full_buf)
+    delta_buf, full_buf, *resident = oracle(*args)
+    return np.asarray(delta_buf), np.asarray(full_buf), [
+        np.asarray(r) for r in resident[:5]
+    ]
 
 
 def record_variants(monkeypatch) -> dict:
@@ -207,10 +231,15 @@ class Recorder:
                 oracle = self.oracles[variant] = parent_pipeline(
                     monkeypatch, variant
                 )
-            want_d, want_f = parent_buffers(oracle, args)
+            want_d, want_f, want_res = parent_buffers(oracle, args)
             want_full = int(np.asarray(args[9]))
             outs = real_exec(namespace, kernel_name, signature, run, args,
                              area)
+            ctx = f"{variant.name} want_full={want_full}"
+            for k, want in enumerate(want_res):
+                np.testing.assert_array_equal(
+                    np.asarray(outs[2 + k]), want, err_msg=f"{ctx} [{k}]"
+                )
             self.check(variant, want_full, outs, want_d, want_f)
             return outs
 
@@ -219,18 +248,28 @@ class Recorder:
     def check(self, variant, want_full, outs, want_d, want_f):
         got_d, got_f = np.asarray(outs[0]), np.asarray(outs[1])
         ctx = f"{variant.name} want_full={want_full}"
+        tail = tail_len(variant)
         if variant.rows_only:
             # a prefix-only solve (no weight changed: the resident plane
-            # stands): its oracle is the parent's FULL solve of the same
-            # arguments, which it must equal in everything but the work
-            # it did not do: trips (word 1) and rounds (the last) read 0
+            # stands; only its candidate rows are looked at): its oracle
+            # is the parent's FULL solve of the same arguments, every row
+            # of it, which it must equal in everything but the work it
+            # did not do: trips (word 1) and rounds (the last) read 0,
+            # and of the cold pull, which such an epoch never reads, the
+            # scalars alone are there
             want_d, want_f = want_d.copy(), want_f.copy()
             for buf in (want_d, want_f):
                 buf[1] = buf[-1] = 0
+            np.testing.assert_array_equal(got_d, want_d, err_msg=ctx)
+            assert not want_full and int(got_d[0]) <= variant.rows_only, ctx
+            np.testing.assert_array_equal(
+                got_f, np.concatenate([[0, 0], want_f[-tail:]]), err_msg=ctx
+            )
+            self.epochs.append((variant, want_full, int(got_d[0]), False))
+            return
         np.testing.assert_array_equal(got_d, want_d, err_msg=ctx)
         count = int(got_d[0])
         cold = bool(want_full) or count > (variant.stream or variant.budget)
-        tail = tail_len(variant)
         assert got_f.shape == want_f.shape, ctx
         # trips and the scalar tail read the same in every epoch
         assert got_f[1] == want_f[1], ctx
@@ -383,7 +422,7 @@ def test_cold_half_runs_from_budget_plus_one(monkeypatch, kind, over):
     args[10:15] = prev
     _name, run = ts._build_pipeline(*variant)
     oracle = parent_pipeline(monkeypatch, variant)
-    want_d, want_f = parent_buffers(oracle, args)
+    want_d, want_f, _resident = parent_buffers(oracle, args)
     tail = tail_len(variant)
     for want_full in (0, 1):
         args[9] = np.int32(want_full)

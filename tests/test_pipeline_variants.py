@@ -29,8 +29,9 @@ KINDS = {
     "fused": {"fused": 3},
     "mc": {"mesh": True},
     "mc_incr": {"emit_dist": True, "dirty_cap": 64, "mesh": True},
-    # the prefix-only solve: the row stages over the resident plane
-    "rows": {"rows_only": True},
+    # the prefix-only solve: the row stages over a bucket of candidate
+    # rows and the resident plane
+    "rows": {"rows_only": 64},
 }
 NAMESPACE = {
     "full": "", "fused": "", "incr": "incr", "stream": "stream",
@@ -131,9 +132,11 @@ GOLDEN = {
         "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa,bk3]",
 }
 # the prefix-only solve has no parent: it is the full solve's shape
-# class and flags under a kernel name of its own
+# class and flags under a kernel name of its own, with its row bucket
 GOLDEN.update({
-    ("rows", *key[1:]): name.replace("pipeline[", "pipeline_rows[")
+    ("rows", *key[1:]): name.replace("pipeline[", "pipeline_rows[").replace(
+        "a=2", "a=2,rr=64"
+    )
     for key, name in list(GOLDEN.items()) if key[0] == "full"
 })
 FLAGS = list(itertools.product((False, True), (False, True), (0, 3)))
@@ -188,6 +191,7 @@ def test_aot_keys_are_distinct_and_kinds_name_their_namespace(mesh):
         variant("stream", mesh, stream=64),
         variant("incr", mesh, dirty_cap=256),
         variant("fused", mesh, fused=2),
+        variant("rows", mesh, rows_only=256),
     ]
     assert len(set(records)) == len(records)
     keys = {r.aot_key for r in records}
@@ -199,6 +203,22 @@ def test_aot_keys_are_distinct_and_kinds_name_their_namespace(mesh):
         assert v.namespace == NAMESPACE[kind], kind
         assert v.incr == ("dirty_cap" in KINDS[kind])
     assert set(ts._PIPELINE_CACHES) == set(NAMESPACE.values())
+    # the row bucket is in the key of the executable that has one, and
+    # the keys that were there before the field are what they were
+    rows = variant("rows", mesh)
+    assert rows.aot_key == variant("full", mesh).aot_key.replace(
+        "mesh=None", "rows_only=64, mesh=None"
+    )
+    assert not any(
+        "rows_only" in r.aot_key for r in records if not r.rows_only
+    )
+    assert variant("full", mesh).aot_key == (
+        "PipelineVariant(n_cap=256, s_cap=4, r_cap=8, kr_cap=4, "
+        "has_res=True, d_cap=4, p_cap=256, a_cap=2, budget=4096, "
+        "lfa=False, block_v4=False, sentinels=True, emit_dist=False, "
+        "delta_exp=0, dirty_cap=0, stream=0, fused=0, donate=False, "
+        "mesh=None)"
+    )
 
 
 def test_two_shape_classes_occupy_two_buckets(mesh):
@@ -242,11 +262,12 @@ BAD = {
         donate=True, dirty_cap=64, emit_dist=True
     ),
     "rows_only_incremental": dict(
-        rows_only=True, dirty_cap=64, emit_dist=True
+        rows_only=64, dirty_cap=64, emit_dist=True
     ),
-    "rows_only_emitting_the_plane": dict(rows_only=True, emit_dist=True),
-    "rows_only_fused": dict(rows_only=True, fused=2),
-    "rows_only_on_a_mesh": dict(rows_only=True, mesh=True),
+    "rows_only_emitting_the_plane": dict(rows_only=64, emit_dist=True),
+    "rows_only_fused": dict(rows_only=64, fused=2),
+    "rows_only_on_a_mesh": dict(rows_only=64, mesh=True),
+    "rows_only_past_a_delta_pull": dict(rows_only=2 * BUDGET),
 }
 
 
@@ -292,7 +313,152 @@ def _avals(v: PipelineVariant) -> tuple:
             S((v.d_cap, v.n_cap), np.int32), dirty, dirty, dirty, dirty,
             S((), np.int32),
         )
+    if v.rows_only:
+        S = jax.ShapeDtypeStruct
+        avals += (
+            S((v.d_cap, v.n_cap), np.int32), S((v.rows_only,), np.int32),
+        )
     return avals
+
+
+# sha256 (16 hex digits) of `jitted.lower(*avals).as_text()` of every
+# kind but the prefix-only one, at every combination of FLAGS, taken on
+# the parent of PR 42 (560e0bc) with jax 0.9.0 — PR 31's check, kept: the
+# row stages were lifted into one body (`tpu_solver._row_stages`) that
+# the prefix-only program shares, and no other program may read another
+# word for it. A PR that changes these programs on purpose writes the
+# digests anew (`_lowered_digest` below prints what it finds); a change
+# of jax may move them all at once.
+LOWERED = {
+    "pipeline[n=256,s=4,d=4,p=256,a=2,bk3]":
+        "f40c5d4f1eccb28c",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,lfa,bk3]":
+        "bd54ae60722d74f4",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,lfa]":
+        "0eb84c4719d242b8",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,res,bk3]":
+        "57af2efdec5e6c5c",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,res,lfa,bk3]":
+        "4b0df23d3ebcb25c",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,res,lfa]":
+        "35e08c33f52c7582",
+    "pipeline[n=256,s=4,d=4,p=256,a=2,res]":
+        "06c8f1c925ca3d02",
+    "pipeline[n=256,s=4,d=4,p=256,a=2]":
+        "fde8945907359b22",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,bk3]":
+        "2a4aa037ad856b7a",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,lfa,bk3]":
+        "72a55688745cec3a",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,lfa]":
+        "b121d5ba07f0dc89",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,bk3]":
+        "d190e09a0004c607",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,lfa,bk3]":
+        "47f7c0489d28992c",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res,lfa]":
+        "f5dc897044da6681",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2,res]":
+        "8ea84e0336974fff",
+    "pipeline_fused[g=3,n=256,s=4,d=4,p=256,a=2]":
+        "c62f4da0819198c0",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,bk3]":
+        "2ef5e651f7859760",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,lfa,bk3]":
+        "6bc39b1a01a0853e",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,lfa]":
+        "ce20afa0cbdb9e2e",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,bk3]":
+        "fa9a57ccee98ad52",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,lfa,bk3]":
+        "970f355be0b42567",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res,lfa]":
+        "5c876354db6eef67",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64,res]":
+        "572aceaefaf34973",
+    "pipeline_incr[n=256,s=4,d=4,p=256,a=2,dd=64]":
+        "ca9fa326087ca334",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,bk3]":
+        "338a5a70112b1372",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,lfa,bk3]":
+        "747df264345a898b",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,lfa]":
+        "c94392866f1c2c90",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,bk3]":
+        "423c5adb640d21a3",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,lfa,bk3]":
+        "b85eae15daa6eb6b",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res,lfa]":
+        "a4d7efac696f87af",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2,res]":
+        "ce0ab1817fc4e953",
+    "pipeline_mc[n=256,s=4,d=4,p=256,a=2,mesh=2x2]":
+        "d4fae5dbf9c5abba",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,bk3]":
+        "094a1cc3bc93dfb0",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,lfa,bk3]":
+        "ba8bae09ae490d29",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,lfa]":
+        "c119b93f51e905d7",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,bk3]":
+        "9da06852c1363670",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa,bk3]":
+        "ca3c286c33ba53e0",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res,lfa]":
+        "18fbd753f9da7c30",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2,res]":
+        "3604bfec2bfe2d16",
+    "pipeline_mc_incr[n=256,s=4,d=4,p=256,a=2,dd=64,mesh=2x2]":
+        "061f12554fe6f56c",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,bk3]":
+        "554da090e7bd0cec",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,lfa,bk3]":
+        "ce44f3bebf4e2ee5",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,lfa]":
+        "853116260d10905a",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,bk3]":
+        "4f6d4cb4c27a6a4e",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,lfa,bk3]":
+        "03c95fd40bec8286",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res,lfa]":
+        "8611613bd9664c44",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256,res]":
+        "9f987105ccb033d3",
+    "pipeline_stream[n=256,s=4,d=4,p=256,a=2,dd=64,sb=256]":
+        "3dd8699a810509fe",
+}
+
+
+def _lowered_digest(v: PipelineVariant) -> str:
+    import hashlib
+
+    avals = _avals(v)
+    if v.fused:
+        avals = tuple((a,) * v.fused for a in avals)
+    text = pipeline_for(v)[1].jitted.lower(*avals).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("kind", sorted(set(KINDS) - {"rows"}))
+def test_lowered_text_is_the_parents(kind, mesh):
+    got = {}
+    for flags in FLAGS:
+        v = variant(kind, mesh, *flags)
+        got[v.name] = _lowered_digest(v)
+    assert got == {name: LOWERED[name] for name in got}
+
+
+def test_the_prefix_only_program_is_another_at_every_bucket(mesh):
+    """The one program that did change: it takes the plane and the
+    candidate rows, and neither solves nor compacts a cold pull."""
+    digests = set(LOWERED.values())
+    for rows in ts._DIRTY_BUCKETS:
+        v = variant("rows", mesh, lfa=True, rows_only=rows)
+        text = pipeline_for(v)[1].jitted.lower(*_avals(v)).as_text()
+        assert "stablehlo.while" not in text and "stablehlo.case" not in text
+        assert f"tensor<{rows}xi32>" in text  # cand_rows
+        digests.add(_lowered_digest(v))
+    assert len(digests) == len(LOWERED) + len(ts._DIRTY_BUCKETS)
 
 
 def test_budget_alone_makes_another_executable(mesh):

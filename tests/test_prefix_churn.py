@@ -14,6 +14,13 @@ past a crib's columns, the vantage's own prefix, a prefix of a node a down
 link has cut off; that a prefix-only epoch makes no relaxation round, no
 whole-matrix build and no key-index build; that the lazy table's length
 and diff stay O(changed); and the same through the Decision actor.
+
+The prefix-only program looks at the rows the dispatcher hands it and at
+no other (ISSUE 42): every such dispatch is replayed through the all-rows
+row stages on the same arguments (`tests/test_compact_rows.Recorder`: the
+five resident arrays and `delta_buf` word for word); the epochs it cannot
+serve take the incremental or the full solve and end equal to the oracle;
+and two counters say how often it served and how many rows.
 """
 
 import random
@@ -40,7 +47,10 @@ from openr_tpu.types import (
     adj_key,
     prefix_key,
 )
+from openr_tpu.decision import tpu_solver as ts
+from openr_tpu.ops import csr
 from tests.conftest import run_async
+from tests.test_compact_rows import Recorder
 from tests.test_decision import DecisionHarness
 from tests.test_incremental_spf import _Churn
 from tests.test_tpu_solver import assert_rib_equal
@@ -67,7 +77,8 @@ class World:
     """The LSDB, both solvers over it, and their last tables: every
     `solve` compares the tables and the updates that lead to them."""
 
-    def __init__(self, per_node: int = PER_NODE, lfa: bool = True, **tpu_kw):
+    def __init__(self, per_node: int = PER_NODE, lfa: bool = True,
+                 enable_v4: bool = True, **tpu_kw):
         tpu_kw.setdefault("incremental_spf", True)  # Decision's default
         self.adj_dbs, prefix_dbs = topologies.fabric(
             **FABRIC, prefixes_per_node=per_node
@@ -76,8 +87,10 @@ class World:
             self.adj_dbs, prefix_dbs
         )
         self.churn = _Churn(self.adj_dbs, self.states)
-        self.cpu = SpfSolver(ME, enable_lfa=lfa)
-        self.tpu = TpuSpfSolver(ME, enable_lfa=lfa, **tpu_kw)
+        self.cpu = SpfSolver(ME, enable_lfa=lfa, enable_v4=enable_v4)
+        self.tpu = TpuSpfSolver(
+            ME, enable_lfa=lfa, enable_v4=enable_v4, **tpu_kw
+        )
         # who advertises what, as the state holds it
         self.held = {
             (db.this_node_name, e.prefix): e
@@ -394,6 +407,213 @@ def test_a_matrix_two_solvers_hold_is_not_changed_in_place():
     assert_rib_equal(
         w.want, other.build_route_db(ME, w.states, w.ps), "the other solver"
     )
+
+
+# -- the candidate rows (ISSUE 42) -------------------------------------------
+
+
+@pytest.mark.parametrize("v4", [True, False], ids=["v4", "block_v4"])
+@pytest.mark.parametrize("lfa", [True, False], ids=["lfa", "no_lfa"])
+def test_candidate_rows_equal_the_all_rows_stages(monkeypatch, lfa, v4):
+    """Every dispatch of the prefix-only program against the all-rows row
+    stages on its own arguments: the five resident arrays and `delta_buf`
+    word for word (the Recorder's check), the tables and the updates
+    against the oracle's (`World.solve`), over the life of a row and over
+    more rows in one epoch than a bucket holds."""
+    w = World(lfa=lfa, enable_v4=v4)
+    rec = Recorder(monkeypatch, w.tpu)
+    matrix = w.tpu._area_dev[AREA].matrix
+    nodes = rsws(w, but=(ME,))
+    # advertise (rows never used), an IPv4 prefix among them; withdraw
+    w.advertise(nodes[0], entry_of(w.fresh_prefix()))
+    w.advertise(nodes[1], entry_of("10.42.1.0/24"))
+    w.solve("two fresh prefixes")
+    node, prefix = next(k for k in sorted(w.held) if k[0] == nodes[2])
+    row = matrix.row_index()[prefix]
+    w.withdraw(node, prefix)
+    w.solve("a withdraw")
+    # the freed row taken by another prefix, once no table reads it
+    w.want = w.got = None
+    w.advertise(nodes[3], entry_of("10.42.2.0/24"))
+    w.solve("a freed row taken")
+    assert matrix.row_index()["10.42.2.0/24"] == row
+    # a second advertiser, preferred; then changed, and the first one
+    # withdrawn and back inside one epoch
+    other, shared = next(k for k in sorted(w.held) if k[0] == nodes[4])
+    w.advertise(nodes[5], entry_of(shared, path_preference=1100))
+    w.solve("a second advertiser, preferred")
+    w.advertise(nodes[5], entry_of(shared, distance=3))
+    w.withdraw(other, shared)
+    w.advertise(other, entry_of(shared, distance=1))
+    w.solve("changed, withdrawn and back inside one epoch")
+    # several rows an epoch: up to the first bucket, and past it
+    for count in (63, 64, 65):
+        for k in range(count):
+            n, p = sorted(w.held)[2 * k]
+            w.advertise(n, entry_of(
+                p, distance=count % 3 + 1, path_preference=1000 + count,
+            ))
+        assert w.solve(f"{count} rows in one epoch").get("prefix_only")
+    assert w.tpu._area_dev[AREA].matrix is matrix
+    caps = [v.rows_only for v, *_ in rec.epochs]
+    assert all(caps) and caps[-3:] == [64, 64, 256], caps
+    assert all(
+        v.lfa == lfa and v.block_v4 == (not v4) for v, *_ in rec.epochs
+    )
+    assert any(count for _, _, count, _ in rec.epochs)
+    # a third advertiser does not fit the row's two cells: a new matrix
+    # and the full solve, and candidate rows again after it
+    w.advertise(nodes[6], entry_of(shared))
+    stats = w.solve("a third advertiser")
+    assert stats["full_pull"] and not stats.get("prefix_only")
+    assert not rec.epochs[-1][0].rows_only
+    w.withdraw(nodes[6], shared)
+    assert w.solve("and gone again").get("prefix_only")
+    assert rec.epochs[-1][0].rows_only == 64
+
+
+def _foreign(w: World, node: str) -> None:
+    """Another vantage's solve: the area is synced (rows scattered, the
+    matrix's log written) and ME's resident outputs do not move."""
+    assert_rib_equal(
+        SpfSolver(node, enable_lfa=True).build_route_db(
+            node, w.states, w.ps
+        ),
+        w.tpu.build_route_db(node, w.states, w.ps), f"from {node}",
+    )
+
+
+def _abandon(w: World) -> None:
+    """A dispatch whose prepare never runs (the host work between
+    dispatch and collection raised): the crib has dropped what it cached
+    of the changed rows, the resident outputs stand."""
+    fast = w.tpu._partition_prefixes(w.ps, w.states)[0]
+    w.tpu._dispatch_one(w.tpu._prep_vantage(
+        ME, AREA, w.states[AREA], w.ps, fast[AREA]
+    ))
+
+
+# case -> the path that was there and that it takes
+DECLINES = {
+    "overload_snapshot_changed": "incremental",
+    "matrix_rebuilt": "full",
+    "touch_log_does_not_reach_back": "full",
+    "more_rows_than_the_largest_bucket": "incremental",
+    "vantage_not_valid": "full",
+    "a_link_event_in_the_same_epoch": "incremental",
+    "an_abandoned_prepare": "incremental",
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECLINES))
+def test_an_epoch_the_candidate_rows_cannot_serve(monkeypatch, case):
+    """Each takes a path that was there (the incremental solve, with
+    nothing dirty where no weight changed, or the full solve), looks at
+    every row, ends equal to the oracle, and leaves the next prefix-only
+    epoch to the candidate rows again."""
+    if case == "touch_log_does_not_reach_back":
+        monkeypatch.setattr(csr, "_TOUCH_LOG", 2)
+    w = World()
+    ad = w.tpu._area_dev[AREA]
+    vs = w.tpu._vstates[(AREA, ME)]
+    nodes = rsws(w, but=(ME,))
+    w.advertise(nodes[0], entry_of(w.fresh_prefix()))
+    assert w.solve("a candidate epoch first").get("prefix_only")
+    puts = ad.mbuf_puts
+    assert vs.rows_stamp == (puts, ad.matrix.touch_seq)
+    node, prefix = next(k for k in sorted(w.held) if k[0] == nodes[1])
+    w.withdraw(node, prefix)
+    if case == "overload_snapshot_changed":
+        # a drained switch's advertisements carry the drain in their
+        # flags: the packed matrix goes up whole
+        w.churn._put(replace(w.churn.dbs[nodes[2]], is_overloaded=True))
+    elif case == "matrix_rebuilt":
+        _, shared = next(k for k in sorted(w.held) if k[0] == nodes[3])
+        w.advertise(nodes[4], entry_of(shared))
+        w.advertise(nodes[5], entry_of(shared))
+    elif case == "touch_log_does_not_reach_back":
+        for k in range(3):
+            _foreign(w, nodes[6])
+            w.advertise(nodes[k], entry_of(w.fresh_prefix()))
+    elif case == "more_rows_than_the_largest_bucket":
+        monkeypatch.setattr(ts, "_DIRTY_BUCKETS", (2, 4))
+        _foreign(w, nodes[6])
+        for k in range(4):
+            w.advertise(nodes[k], entry_of(w.fresh_prefix()))
+    elif case == "vantage_not_valid":
+        vs.valid = False  # as an abandoned streaming prepare leaves it
+    elif case == "a_link_event_in_the_same_epoch":
+        u, v = next(e for e in w.churn.edges() if ME not in e)
+        w.churn.link_down(u, v)
+    elif case == "an_abandoned_prepare":
+        _abandon(w)
+        assert vs.crib.matrix_seq == ad.matrix.touch_seq
+        assert vs.rows_stamp[1] < ad.matrix.touch_seq
+        w.advertise(nodes[3], entry_of(w.fresh_prefix()))
+    served = counter("decision.tpu.candidate_epochs")
+    only = counter("decision.tpu.prefix_only_epochs")
+    stats = w.solve(case)
+    assert not stats.get("prefix_only"), stats
+    assert counter("decision.tpu.candidate_epochs") == served
+    assert counter("decision.tpu.prefix_only_epochs") == only
+    if DECLINES[case] == "full":
+        assert stats["full_pull"] and not stats.get("incremental")
+    else:
+        assert stats.get("incremental") and not stats["fell_back"]
+        assert not stats["full_pull"]
+    if case == "overload_snapshot_changed":
+        assert ad.mbuf_puts == puts + 1
+    if case == "more_rows_than_the_largest_bucket":
+        # nothing dirty: the solve converges at once, all rows looked at
+        assert stats["cone"] == 0 and stats["changed_rows"] == 5
+        monkeypatch.undo()
+    # the stamp follows whichever program computed the resident outputs
+    ad, vs = w.tpu._area_dev[AREA], w.tpu._vstates[(AREA, ME)]
+    assert vs.rows_stamp == (ad.mbuf_puts, ad.matrix.touch_seq)
+    w.advertise(node, entry_of(prefix, distance=2))
+    assert w.solve("a prefix alone, after").get("prefix_only")
+    assert counter("decision.tpu.candidate_epochs") == served + 1
+
+
+def test_the_counters_say_how_often_and_how_many_rows():
+    w = World()
+    nodes = rsws(w, but=(ME,))
+    before = {
+        key: counter(f"decision.tpu.{key}") for key in (
+            "candidate_epochs", "candidate_rows", "prefix_only_epochs",
+            "prefix_rows_changed", "epochs",
+        )
+    }
+    handed = []
+    for step, count in enumerate((1, 3, 0, 2, 70)):
+        for node, prefix in sorted(w.held)[:count]:
+            w.advertise(node, entry_of(prefix, path_preference=900 + step))
+        stats = w.solve(f"{count} rows")
+        assert stats.get("prefix_only")
+        attrs = {
+            name: a for name, _, _, _, a in w.tpu.last_timing["spans"]
+        }["tpu.device_wait"]
+        assert attrs["prefix_only"] is True and attrs["rounds"] == 0
+        handed.append((attrs["cand_rows"], attrs["cand_cap"]))
+    assert handed == [(1, 64), (3, 64), (0, 64), (2, 64), (70, 256)]
+    # a link event: neither counter moves
+    u, v = next(e for e in w.churn.edges() if ME not in e)
+    w.churn.link_down(u, v)
+    assert not w.solve("a link down").get("prefix_only")
+    gained = {
+        key: counter(f"decision.tpu.{key}") - was
+        for key, was in before.items()
+    }
+    assert gained == {
+        "candidate_epochs": 5, "candidate_rows": 76,
+        "prefix_only_epochs": 5, "prefix_rows_changed": 76, "epochs": 6,
+    }
+    # each addition is a stamped sample too: a window's gain is readable
+    for key, least in (("candidate_epochs", 5), ("candidate_rows", 76)):
+        stat = counters.get_statistics(
+            f"decision.tpu.{key}", windows=(3600.0,)
+        )[f"decision.tpu.{key}"]["3600"]
+        assert stat["sum"] >= least and stat["count"] >= 5
 
 
 # -- what a prefix-only epoch does not do ------------------------------------

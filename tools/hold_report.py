@@ -12,7 +12,9 @@ window's bounds and events (due, sent, acked), the harness's own stamps
 of the collector — what PERF.md's section 5 and 6 quote per hold — and
 `window_counters`, what the solver's `decision.tpu.*` counters (`epochs`,
 `cold_compactions`, `cone_passes`, `cone_skips` and, of the prefix rows,
-`prefix_rows_changed`, `prefix_only_epochs`, `prefix_matrix_rebuilds`) and
+`prefix_rows_changed`, `prefix_only_epochs`, `prefix_matrix_rebuilds`,
+`candidate_epochs`, `candidate_rows`: the prefix-only epochs, which are
+the candidate rows' since PR 42, and the rows handed to them) and
 `decision.crib.key_index_builds` gained over that window. A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
@@ -35,6 +37,7 @@ import run  # noqa: E402  (stamps T_PROCESS)
 WINDOW_COUNTERS = tuple(f"decision.tpu.{name}" for name in (
     "epochs", "cold_compactions", "cone_passes", "cone_skips",
     "prefix_rows_changed", "prefix_only_epochs", "prefix_matrix_rebuilds",
+    "candidate_epochs", "candidate_rows",
 )) + ("decision.crib.key_index_builds",)
 
 

@@ -47,7 +47,12 @@ the same graph with each count of prefixes a switch, one line a count with
 the rows the device carries (`prefix_rows`, `prefixes`) and the solver's
 own capture from the usual vantage: `by_scope` an event, and `row_stages`,
 the sum of the scopes that work over every row (ROW_SCOPES), beside
-`device_ms_per_event`.
+`device_ms_per_event`. Then, under `prefix_only`, the same solver's
+capture of prefix events alone (one prefix of the far switch withdrawn,
+then advertised back: the prefix-only program, which since PR 42 works
+over the candidate rows it is handed): `by_scope` and the device's ms an
+event again, with the rows handed and their bucket (null on a tree that
+hands none) — flat in the count of rows where the program is.
 """
 
 from __future__ import annotations
@@ -245,7 +250,9 @@ def capture_prefixes(name: str, counts: list[int]) -> None:
         adj_dbs, prefix_dbs = gen(prefixes_per_node=per_node)
         states, _ = topologies.build_states(adj_dbs, prefix_dbs)
         width = edgeplan.build_plan(states["0"]).res_nbr.shape[1]
-        scope, _ = _scope_ms(name, adj_dbs, prefix_dbs, me, width, None)
+        scope, _ = _scope_ms(
+            name, adj_dbs, prefix_dbs, me, width, None, prefix_events=True
+        )
         by_scope = scope["by_scope_ms_per_event"]
         print(json.dumps({
             "config": name, "vantage": me, "prefixes_per_node": per_node,
@@ -256,9 +263,58 @@ def capture_prefixes(name: str, counts: list[int]) -> None:
         }), flush=True)
 
 
-def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
+def _per_event(by_scope: dict) -> dict:
+    return {scope: ms / EVENTS for scope, ms in sorted(by_scope.items())}
+
+
+def _prefix_only_ms(solver, me: str, states, ps, prefix_db) -> dict:
+    """by_scope over EVENTS prefix-only solves of a warm solver: the
+    first prefix of `prefix_db`'s switch withdrawn (even steps) or
+    advertised back."""
+    from openr_tpu.runtime import device_stats
+    from openr_tpu.types import PrefixDatabase, PrefixEntry
+
+    node, area = prefix_db.this_node_name, prefix_db.area
+    entry = prefix_db.prefix_entries[0]
+    handed, changed = [], []
+
+    def solve(step: int) -> dict:
+        gone = step % 2 == 0
+        ps.update_prefix_database(PrefixDatabase(
+            node, (PrefixEntry(prefix=entry.prefix) if gone else entry,),
+            area, delete_prefix=gone,
+        ))
+        solver.build_route_db(me, states, ps)
+        return solver.last_device_stats
+
+    for step in range(2):  # compile the program and the rows' scatter
+        solve(step)
+    device_stats.profiler_start()
+    for step in range(EVENTS):
+        stats = solve(step)
+        if not stats.get("prefix_only"):
+            raise SystemExit(f"prefix event {step} was solved: {stats}")
+        wait = {
+            span[0]: span[4] for span in solver.last_timing["spans"]
+        }["tpu.device_wait"]
+        handed.append((wait.get("cand_rows"), wait.get("cand_cap")))
+        changed.append(stats.get("changed_rows"))
+    by_scope = device_stats.profiler_stop()["by_scope"] or {}
+    return {
+        "device_ms_per_event": sum(by_scope.values()) / EVENTS,
+        "by_scope_ms_per_event": _per_event(by_scope),
+        "kernel": stats.get("kernel"),
+        "rows_handed": handed,
+        "changed_rows": changed,
+    }
+
+
+def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want,
+              prefix_events: bool = False):
     """by_scope over EVENTS incremental solves at this width; the table
-    after each event against `want` (the first width's)."""
+    after each event against `want` (the first width's). With
+    `prefix_events`, the same solver's prefix-only solves after them
+    (`_prefix_only_ms`), under the key `prefix_only`."""
     from openr_tpu.decision.tpu_solver import TpuSpfSolver
     from openr_tpu.runtime import device_stats
 
@@ -295,16 +351,18 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want):
             cones.append((stats.get("cone"), bool(stats.get("fell_back"))))
             changed.append(stats.get("changed_rows"))
         by_scope = device_stats.profiler_stop()["by_scope"] or {}
+        prefix_only = {"prefix_only": _prefix_only_ms(
+            solver, me, states, ps, prefix_dbs[-1]
+        )} if prefix_events else {}
     same = want is None or all(a == b for a, b in zip(tables, want))
     passes = sum(rounds)
     return {
+        **prefix_only,
         "relax.residual_ms_per_pass": by_scope.get("relax.residual", 0) / passes,
         "seed.cone_ms_per_event": by_scope.get("seed.cone", 0) / EVENTS,
         "seed.parent_ms_per_event": by_scope.get("seed.parent", 0) / EVENTS,
         "device_ms_per_event": sum(by_scope.values()) / EVENTS,
-        "by_scope_ms_per_event": {
-            scope: ms / EVENTS for scope, ms in sorted(by_scope.items())
-        },
+        "by_scope_ms_per_event": _per_event(by_scope),
         "rounds": rounds,
         "cone_passes": cone_passes,
         "cones": cones,
