@@ -1099,7 +1099,7 @@ class _AreaDev:
         "plan", "d_deltas", "d_shift_w", "d_res_rows", "d_res_nbr",
         "d_res_w", "matrix_key", "matrix", "flags", "d_mbuf",
         "matrix_version", "pack_over", "drain_epoch", "drain_log",
-        "mc_mesh", "sync_marks", "prefix_span", "mbuf_puts",
+        "mc_mesh", "sync_marks", "prefix_span", "pack_span", "mbuf_puts",
     )
 
     def __init__(self):
@@ -1147,6 +1147,9 @@ class _AreaDev:
         # the last _sync_area's tpu.sync.prefix span, or None where the
         # announcements had not changed
         self.prefix_span: Optional[tuple] = None
+        # the last _sync_area's tpu.sync.pack span, or None where no
+        # node's drain bit had changed
+        self.pack_span: Optional[tuple] = None
 
 
 class _VantageState:
@@ -2676,15 +2679,46 @@ class TpuSpfSolver:
         # unchanged matrix and an unchanged overload snapshot the packed
         # mirror on device is already current — skip the O(6*P*A) host
         # concat that used to run on every sync
-        if ad.flags is None or not np.array_equal(
-            plan.node_overloaded, ad.pack_over
-        ):
-            flags, mbuf = _pack_matrix(ad.matrix, plan.node_overloaded)
-            ad.pack_over = plan.node_overloaded.copy()
-            if ad.flags is None or not np.array_equal(flags, ad.flags):
+        ad.pack_span = None
+        over = plan.node_overloaded
+        if ad.flags is None or not np.array_equal(over, ad.pack_over):
+            t_pack0 = _time.monotonic()
+            flags, mbuf = _pack_matrix(ad.matrix, over)
+            # nodes whose drain bit differs from the last packed snapshot
+            # (from none drained, where there is no snapshot of this shape)
+            was = ad.pack_over
+            flips = int(np.count_nonzero(
+                over if was is None or was.shape != over.shape
+                else over != was
+            ))
+            ad.pack_over = over.copy()
+            # cells of the flags plane that differ from the device's
+            fresh = ad.flags is None or ad.flags.shape != flags.shape
+            changed = int(
+                flags.size if fresh else np.count_nonzero(flags != ad.flags)
+            )
+            put = fresh or changed > 0
+            if put:
                 ad.flags = flags
                 ad.d_mbuf = self._put_counted(mbuf, shp("replicated"))
                 ad.mbuf_puts += 1
+                self._count("decision.tpu.mbuf_put_bytes", mbuf.nbytes)
+            counters.set_counter(
+                "decision.tpu.drained_nodes", int(np.count_nonzero(over))
+            )
+            if flips:
+                # a drained (or given-back) node: every cell of the flags
+                # plane packed and compared again, and where an announcer's
+                # bit moved the whole matrix put again
+                self._count("decision.tpu.overload_flips", flips)
+                ad.pack_span = (
+                    "tpu.sync.pack", "tpu.sync", t_pack0, _time.monotonic(),
+                    {
+                        "cells": int(flags.size), "flags_changed": changed,
+                        "put": put, "bytes": int(mbuf.nbytes) if put else 0,
+                        "overload_flips": flips,
+                    },
+                )
         # the changed announcements' rows planned and shipped (or, where
         # only a new matrix would do, all of them built and put)
         ad.prefix_span = None if synced is None else (
@@ -2913,7 +2947,7 @@ class TpuSpfSolver:
             "rows_stamp": (ad.mbuf_puts, matrix.touch_seq),
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
-            "prefix_span": ad.prefix_span,
+            "prefix_span": ad.prefix_span, "pack_span": ad.pack_span,
             "lanes": lanes,
         }
 
@@ -3487,7 +3521,9 @@ class TpuSpfSolver:
              mirror) = pv["sync_marks"]
             stats.update(mirror)
             stats.update(pv["lanes"])
-            prefix_spans = [pv["prefix_span"]] if pv["prefix_span"] else []
+            matrix_spans = [
+                span for span in (pv["prefix_span"], pv["pack_span"]) if span
+            ]
             return {
                 "view": crib.view(),
                 "stats": stats,
@@ -3504,7 +3540,7 @@ class TpuSpfSolver:
                         "bytes_uploaded": up_bytes,
                         "dirty_slots": dirty_slots,
                     }),
-                    *prefix_spans,
+                    *matrix_spans,
                     ("tpu.dispatch", None, t1, t_disp, {
                         "kernel": kernel_name, "incremental": incr,
                         "lanes": d_cap, "rows": p_cap,
