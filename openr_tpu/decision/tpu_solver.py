@@ -104,6 +104,14 @@ _NEG = -(2**31)
 # pull (one extra round trip, still a single buffer)
 _DELTA_BUDGET = 4096
 
+# node columns an incremental solve may move and still run its row
+# stages over candidate rows: the candidate mask compares every announcer
+# cell with each moved node (cells x this many compares on the flat
+# planes — a gather of a million single cells out of the moved vector
+# costs what `select`'s own does, 7 ns a cell). Past it every row is
+# looked at, as before
+_MOVED_CAP = 256
+
 # numerical-health sentinel threshold: finite metrics past 2^28 sit one
 # metric-add away from the 2^29 INF_E encoding — saturation territory
 # the int32 metric algebra cannot flag on its own
@@ -150,6 +158,17 @@ def _merge_drain_log(ad: "_AreaDev", since_epoch: int):
     if expected != ad.drain_epoch + 1:
         return None
     return s_map, r_map
+
+
+def _rows_put_since(ad: "_AreaDev", puts: int) -> Optional[list]:
+    """The rows whose cells the whole puts of the area's d_mbuf after its
+    `puts`-th changed, an array a put (none where there was none): or
+    None where one of them made every row new, or the log no longer
+    holds it."""
+    since = [rows for n, rows in ad.put_log if n > puts]
+    if len(since) != ad.mbuf_puts - puts or any(r is None for r in since):
+        return None
+    return since
 
 
 def _ucmp_weight_anomalies(w) -> int:
@@ -358,7 +377,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    sentinels: bool = True, emit_dist: bool = False,
                    incr: bool = False, mesh=None,
                    kernel: str = "sync", delta_exp: int = 0,
-                   stream: int = 0):
+                   stream: int = 0, narrow: bool = False):
     """The fused production pipeline (raw closure — _build_pipeline jits
     it under the options a PipelineVariant names, vmapped over a group
     of same-shape areas for a `fused` one). Outputs:
@@ -398,11 +417,50 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     With `incr=True` the pipeline takes six extra trailing args
     (prev_dist, s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
     cone_limit) and swaps the cold SSSP for ops/incremental.py's
-    seed-from-previous solve; [cone, fell_back] ride the tail of both
-    pull buffers AFTER the sentinel scalars. The incremental fixpoint
-    is bit-identical to the cold one, so the ENTIRE selection / LFA /
-    packing / delta tail below is shared verbatim between the two
-    kernels — output parity by construction.
+    seed-from-previous solve; [cone_passes, cone, fell_back] ride the
+    tail of both pull buffers AFTER the sentinel scalars. The
+    incremental fixpoint is bit-identical to the cold one, so the ENTIRE
+    selection / LFA / packing / delta tail below is shared verbatim
+    between the two kernels — output parity by construction.
+
+    With `narrow` (an incremental solve on one chip outside the
+    streaming pipeline: what _variant asks for) the row stages run over
+    CANDIDATE ROWS where they can, and the pipeline takes two more
+    trailing args (host_rows int32 [budget], wide). A row's five outputs
+    are computed from its own cells in mbuf, from column n of the plane
+    for each announcer node n of the row, and from three things every
+    row shares: root_w, its link-up mask and the root's own column. The
+    resident outputs were computed from exactly prev_dist
+    (_make_prepare advances both in one step; the dispatcher's
+    shared_stamp says so), so after the solve
+      moved = (dist_d != prev_dist).any(axis=0)
+    names the node columns that differ, and a row is a candidate iff a
+    valid announcer cell of it names a moved node, or it is one of
+    `host_rows`: the rows whose cells the host wrote since the resident
+    outputs were computed (the touch log's, a drain's repack's; pads
+    p_cap). The mask works on the flat [p_cap * a_cap] planes (scope
+    `candidates`): every announcer cell compared with each of the first
+    _MOVED_CAP moved nodes, not a gather of a cell apiece out of
+    `moved`. One lax.cond then chooses, on the device, in the
+    epoch: the all-rows text that stands (every other variant's whole
+    text) where what every row shares moved (`wide`, the host's word for
+    root_w and for rows it does not know; moved[root]), where
+    `want_full`, where more columns moved than the mask compares, or
+    where the candidates outnumber `budget` (such an
+    epoch needs the cold pull and so every row anyway) — and otherwise
+    _candidate_row_stages over the first `budget` candidates, the body
+    the prefix-only program runs: same delta layout, the five resident
+    arrays written in place, no [rows, a_cap] array formed. Every
+    candidate row is computed by _row_stages from the same inputs as the
+    all-rows pass would give it; every other row's inputs are bit for
+    bit those its resident outputs came from. One word joins the scalar
+    tail of both pull buffers, before the cone's three: the rows the row
+    stages looked at (the candidates' count, p_cap on the all-rows
+    side). `fused` groups (under vmap the cond lowers to a select and
+    both sides run), `mesh` (a sharded gather axis), `stream` (its
+    payload carries the device's route-ok bit a row; off in every
+    deployment measured) and the full solve (every row is new there)
+    keep the all-rows text alone.
 
     With `mesh` (the multichip capacity tier) the SSSP core swaps for
     parallel/sharding.py's shard_mapped twins — shift columns over
@@ -427,13 +485,15 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     from openr_tpu.ops.compact import route_ok_device
     from openr_tpu.ops.incremental import incremental_sssp
     from openr_tpu.ops.stream import (
-        column_diff, compact_changed_rows, true_rows,
+        column_diff, compact_changed_rows, first_true_rows, rows_any,
+        true_rows,
     )
 
     wa = -(-a_cap // 16)
     wd = -(-d_cap // 16)
     pa = p_cap * a_cap
     max_trips = relax_ops.max_trips(n_cap)
+    moved_cap = min(_MOVED_CAP, budget, n_cap)
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
@@ -457,11 +517,21 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                  root, root_nbr, root_w, want_full,
                  prev_metric, prev_s3w, prev_nhw,
                  prev_lfa_slot, prev_lfa_metric, *incr_args):
-        with jax.named_scope("unpack"):
-            cells = _cells(*(
-                mbuf[o:o + pa].reshape(p_cap, a_cap)
-                for o in range(0, 6 * pa, pa)
-            ), block_v4)
+        prev = (prev_metric, prev_s3w, prev_nhw, prev_lfa_slot,
+                prev_lfa_metric)
+        if narrow:
+            *incr_args, host_rows, wide = incr_args
+
+        def unpack():
+            with jax.named_scope("unpack"):
+                return _cells(*(
+                    mbuf[o:o + pa].reshape(p_cap, a_cap)
+                    for o in range(0, 6 * pa, pa)
+                ), block_v4)
+
+        # every row's cells, where every row is looked at: with `narrow`
+        # that is the wide branch's business
+        cells = None if narrow else unpack()
 
         with jax.named_scope("seed"):
             if incr:
@@ -510,94 +580,169 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             # partitioner never touches a sharded gather axis
             dist_res = dist_d
             dist_d = jax.lax.with_sharding_constraint(dist_d, mc_rep)
-        metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
-            cells, dist_d, root, root_w, n_cap, lfa
-        )
-        if not lfa:
-            lfa_slot = prev_lfa_slot
-            lfa_metric = prev_lfa_metric
+        delta_rows = stream or budget
 
-        def route_ok():
-            # route-level ok computed on device: compacts the cold full
-            # pull to ok rows, and on the streaming path rides the delta
-            # payload per changed row (the host apply is then unpack-free)
-            return route_ok_device(
-                metric, s3, nh_mask, cells.ann_node, cells.min_nh,
-                cells.v4_blocked, root,
+        def all_rows(cells):
+            """The row stages over every row: (delta_buf's and full_buf's
+            count, trips and rows, the five resident arrays, the
+            sentinels)."""
+            metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
+                cells, dist_d, root, root_w, n_cap, lfa
             )
+            if not lfa:
+                lfa_slot = prev_lfa_slot
+                lfa_metric = prev_lfa_metric
 
-        with jax.named_scope("pack"):
-            s3w = _pack_words(s3)
-            nhw = _pack_words(nh_mask)
-            # a streaming epoch ships ok with every changed row; any
-            # other needs it for the cold pull alone, and computes it
-            # there
-            ok = route_ok() if stream else None
-        with jax.named_scope("diff"):
-            changed = column_diff(
-                metric, s3w, nhw, lfa_slot, lfa_metric,
-                prev_metric, prev_s3w, prev_nhw,
-                prev_lfa_slot, prev_lfa_metric, lfa,
-            )
-        with jax.named_scope("compact"):
-            delta_rows = stream or budget
-            count, delta_parts = compact_changed_rows(
-                changed, trips, metric, s3w, nhw, ok,
-                lfa_slot, lfa_metric, delta_rows, p_cap, lfa,
-            )
+            def route_ok():
+                # route-level ok computed on device: compacts the cold
+                # full pull to ok rows, and on the streaming path rides
+                # the delta payload per changed row (the host apply is
+                # then unpack-free)
+                return route_ok_device(
+                    metric, s3, nh_mask, cells.ann_node, cells.min_nh,
+                    cells.v4_blocked, root,
+                )
 
-            def cold_rows():
-                # cold-rebuild compaction: only ok rows' outputs ship
-                # (gathered to the front — pad slots past okc carry the
-                # last ok row's values and are ignored)
-                row_ok = route_ok() if ok is None else ok
-                oidx = true_rows(row_ok, p_cap)
-                osafe = jnp.clip(oidx, 0, p_cap - 1)
-                rows = [
-                    oidx,
-                    metric[osafe],
-                    s3w[osafe].ravel(),
-                    nhw[osafe].ravel(),
+            with jax.named_scope("pack"):
+                s3w = _pack_words(s3)
+                nhw = _pack_words(nh_mask)
+                # a streaming epoch ships ok with every changed row; any
+                # other needs it for the cold pull alone, and computes it
+                # there
+                ok = route_ok() if stream else None
+            with jax.named_scope("diff"):
+                changed = column_diff(
+                    metric, s3w, nhw, lfa_slot, lfa_metric, *prev, lfa,
+                )
+            with jax.named_scope("compact"):
+                count, delta_parts = compact_changed_rows(
+                    changed, trips, metric, s3w, nhw, ok,
+                    lfa_slot, lfa_metric, delta_rows, p_cap, lfa,
+                )
+
+                def cold_rows():
+                    # cold-rebuild compaction: only ok rows' outputs ship
+                    # (gathered to the front — pad slots past okc carry
+                    # the last ok row's values and are ignored)
+                    row_ok = route_ok() if ok is None else ok
+                    oidx = true_rows(row_ok, p_cap)
+                    osafe = jnp.clip(oidx, 0, p_cap - 1)
+                    rows = [
+                        oidx,
+                        metric[osafe],
+                        s3w[osafe].ravel(),
+                        nhw[osafe].ravel(),
+                    ]
+                    if lfa:
+                        # delta-side lfa columns already rode
+                        # compact_changed_rows
+                        rows += [lfa_slot[osafe], lfa_metric[osafe]]
+                    return (row_ok.sum().astype(jnp.int32),
+                            jnp.concatenate(rows))
+
+                def no_rows():
+                    return jax.tree.map(
+                        lambda x: jnp.zeros(x.shape, x.dtype),
+                        jax.eval_shape(cold_rows),
+                    )
+
+                # the cold pull is compacted only in an epoch that reads
+                # it: the host's rule (full_pull in _make_prepare) on the
+                # device
+                okc, full_rows = jax.lax.cond(
+                    (want_full != 0) | (count > delta_rows),
+                    cold_rows, no_rows,
+                )
+                full_parts = [
+                    okc[None], trips[None].astype(jnp.int32), full_rows,
                 ]
-                if lfa:
-                    # delta-side lfa columns already rode
-                    # compact_changed_rows
-                    rows += [lfa_slot[osafe], lfa_metric[osafe]]
-                return row_ok.sum().astype(jnp.int32), jnp.concatenate(rows)
+                sent = []
+                if sentinels:
+                    unreach, saturated = _sentinels(
+                        cells.ann_valid.any(axis=1), metric
+                    )
+                    sent = [unreach, saturated]
+            return (delta_parts, full_parts,
+                    [metric, s3w, nhw, lfa_slot, lfa_metric], sent)
 
-            def no_rows():
-                return jax.tree.map(
-                    lambda x: jnp.zeros(x.shape, x.dtype),
-                    jax.eval_shape(cold_rows),
+        if narrow:
+            with jax.named_scope("candidates"):
+                # the node columns the solve moved (the first moved_cap
+                # of them; pads n_cap, which no cell names), and the rows
+                # one of them can reach: those with a valid announcer
+                # cell on a moved node, and those the host says were
+                # written since the resident outputs were computed (pads
+                # p_cap: dropped)
+                moved = (dist_d != prev_dist).any(axis=0)  # [N]
+                nodes = first_true_rows(moved, moved_cap, n_cap)
+                on_moved = ((mbuf[pa:2 * pa] & 1) != 0) & (
+                    nodes[:, None] == mbuf[None, :pa]
+                ).any(axis=0)
+                cand = rows_any(on_moved, p_cap, a_cap).at[host_rows].set(
+                    True, mode="drop"
+                )
+                n_cand = cand.sum().astype(jnp.int32)
+                # what every row shares moved (`wide`: the host's word,
+                # for root_w and for rows it does not know; the root's
+                # own column), or more columns moved than the mask
+                # compares, or the candidates do not fit a delta pull
+                # (or are every row), or the host reads the cold pull:
+                # every row then
+                go_wide = (
+                    (wide != 0) | (want_full != 0) | moved[root]
+                    | (moved.sum() > moved_cap)
+                    | (n_cand > min(budget, p_cap - 1))
+                )
+                cand_rows = first_true_rows(cand, budget, p_cap)
+                # the pad repeats the last candidate (row 0 where none)
+                last = cand_rows[jnp.maximum(n_cand, 1) - 1]
+                cand_rows = jnp.where(
+                    cand_rows < p_cap, cand_rows,
+                    jnp.where(n_cand > 0, last, 0),
                 )
 
-            # the cold pull is compacted only in an epoch that reads it:
-            # the host's rule (full_pull in _make_prepare) on the device
-            okc, full_rows = jax.lax.cond(
-                (want_full != 0) | (count > delta_rows), cold_rows, no_rows,
+            def candidate_rows():
+                count, head, new, sent = _candidate_row_stages(
+                    mbuf, dist_d, root, root_w, prev, cand_rows,
+                    n_cap, p_cap, a_cap, budget, lfa, block_v4, sentinels,
+                )
+                trips1 = trips[None].astype(jnp.int32)
+                # such an epoch never reads the cold pull
+                no_full = [
+                    jnp.zeros((1,), jnp.int32), trips1,
+                    jnp.zeros((p_cap * (2 + wa + wd + 2 * lfa),), jnp.int32),
+                ]
+                return [count[None], trips1, *head], no_full, new, sent
+
+            delta_parts, full_parts, new, sent = jax.lax.cond(
+                go_wide, lambda: all_rows(unpack()), candidate_rows
             )
-            full_parts = [okc[None], trips[None].astype(jnp.int32), full_rows]
-            if sentinels:
-                unreach, saturated = _sentinels(
-                    cells.ann_valid.any(axis=1), metric
-                )
-                delta_parts += [unreach[None], saturated[None]]
-                full_parts += [unreach[None], saturated[None]]
+            looked = jnp.where(go_wide, p_cap, n_cand).astype(jnp.int32)
+        else:
+            delta_parts, full_parts, new, sent = all_rows(cells)
+
+        with jax.named_scope("compact"):
+            delta_parts += [x[None] for x in sent]
+            full_parts += [x[None] for x in sent]
+            tail = []
+            if narrow:
+                # the rows the row stages looked at: the candidates, or
+                # p_cap on the wide branch ([-5], before the cone's words)
+                tail += [looked[None]]
             if incr:
                 # the cone loop's executed passes, cone + in-kernel-fallback
                 # flag (the host parses the tail back to front:
                 # [-4]=cone_passes, [-3]=cone, [-2]=fell_back, with the
-                # sentinels at [-6]/[-5] when enabled, rounds always at [-1])
-                tail = [cone_passes[None].astype(jnp.int32), cone[None],
-                        fell_back.astype(jnp.int32)[None]]
-                delta_parts += tail
-                full_parts += tail
+                # sentinels before them, and before `narrow`'s word, when
+                # enabled, rounds always at [-1])
+                tail += [cone_passes[None].astype(jnp.int32), cone[None],
+                         fell_back.astype(jnp.int32)[None]]
             # executed-relaxation work metric rides LAST unconditionally:
             # sync rounds = trips * the trip's quantum (relax_ops.
             # sync_quantum); bucketed rounds = ladder passes
             # + one handoff relaxation per bucket epoch (trips = epochs)
-            delta_parts += [rounds[None].astype(jnp.int32)]
-            full_parts += [rounds[None].astype(jnp.int32)]
+            delta_parts += tail + [rounds[None].astype(jnp.int32)]
+            full_parts += tail + [rounds[None].astype(jnp.int32)]
             delta_buf = jnp.concatenate(delta_parts)
             full_buf = jnp.concatenate(full_parts)
         if mesh is not None:
@@ -609,8 +754,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             # alone does not reach back through the concatenate.
             delta_buf = jax.lax.with_sharding_constraint(delta_buf, mc_rep)
             full_buf = jax.lax.with_sharding_constraint(full_buf, mc_rep)
-        outs = (delta_buf, full_buf, metric, s3w, nhw, lfa_slot,
-                lfa_metric)
+        outs = (delta_buf, full_buf, *new)
         if emit_dist:
             outs += (dist_res if mesh is not None else dist_d,)
         return outs
@@ -618,9 +762,89 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     return pipeline
 
 
-def _make_rows_pipeline(n_cap: int, d_cap: int, p_cap: int, a_cap: int,
-                        budget: int, rows: int, lfa: bool, block_v4: bool,
-                        sentinels: bool):
+def _candidate_row_stages(mbuf, dist_d, root, root_w, prev, cand_rows,
+                          n_cap: int, p_cap: int, a_cap: int, budget: int,
+                          lfa: bool, block_v4: bool, sentinels: bool):
+    """The row stages over the candidate rows alone: the one body of the
+    prefix-only program (_make_rows_pipeline: the dispatcher's rows, a
+    bucket of them) and of the incremental solve's narrow branch
+    (_make_pipeline with `narrow`: the rows its moved node columns can
+    reach, a delta pull's worth). `cand_rows` int32 [R], R <= budget:
+    ascending, each row once, the pad repeating the last (so a row equal
+    to its left neighbour is pad); `prev` the five resident arrays; `mbuf`
+    the flat planes of the packed matrix, read at the candidates' cells
+    and nowhere else but for the sentinels. ->
+      count   rows of the candidates whose columns differ from `prev`'s
+      head    what follows count and trips in delta_buf, laid out word
+              for word as compact_changed_rows lays it: the changed
+              candidates' indices and columns to the front, pad slots as
+              a clipped read of the last row gives them
+      new     `prev` with the candidate rows written in. They are not
+              donated (an abandoned prepare must still find them), so
+              each is copied: a few MB
+      sent    the two sentinels (none without `sentinels`), which stay
+              what they are, reductions over every row: of the resident
+              metric after the scatter, and of the flags plane."""
+    import jax
+    import jax.numpy as jnp
+
+    from openr_tpu.ops.stream import column_diff, first_true_rows, rows_any
+
+    pa = p_cap * a_cap
+    rows = cand_rows.shape[0]
+    with jax.named_scope("unpack"):
+        at = cand_rows[:, None] * a_cap + jnp.arange(a_cap)  # [R, A]
+        cells = _cells(*(mbuf[o + at] for o in range(0, 6 * pa, pa)),
+                       block_v4)
+    metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
+        cells, dist_d, root, root_w, n_cap, lfa
+    )
+    with jax.named_scope("pack"):
+        cols = [metric, _pack_words(s3), _pack_words(nh_mask),
+                lfa_slot, lfa_metric]
+        n = 5 if lfa else 3  # without it the lfa columns pass through
+    with jax.named_scope("diff"):
+        was = [p[cand_rows] for p in prev[:n]] + [None] * (5 - n)
+        changed = column_diff(*cols, *was, lfa) & jnp.concatenate([
+            jnp.ones((1,), bool), cand_rows[1:] != cand_rows[:-1],
+        ])
+    with jax.named_scope("compact"):
+        new = [
+            p.at[cand_rows].set(c) for p, c in zip(prev[:n], cols)
+        ] + list(prev[n:])
+        slot = first_true_rows(changed, rows, rows)
+        live = slot < rows
+        slot = jnp.minimum(slot, rows - 1)
+
+        def lay(col, whole):
+            # the changed rows to the front; every slot after them
+            # reads the last row, as compact_changed_rows' clipped
+            # read of the pad index p_cap does
+            last = whole[p_cap - 1]
+            head = jnp.where(
+                live.reshape((rows,) + (1,) * last.ndim), col[slot], last
+            )
+            pad = jnp.broadcast_to(last, (budget - rows,) + last.shape)
+            return jnp.concatenate([head, pad]).ravel()
+
+        sent = []
+        if sentinels:
+            unreach, saturated = _sentinels(
+                rows_any(mbuf[pa:2 * pa] & 1, p_cap, a_cap), new[0]
+            )
+            sent = [unreach, saturated]
+        head = [
+            jnp.concatenate([
+                jnp.where(live, cand_rows[slot], p_cap),
+                jnp.full((budget - rows,), p_cap, jnp.int32),
+            ]),
+            *(lay(c, w) for c, w in zip(cols[:n], new)),
+        ]
+    return changed.sum().astype(jnp.int32), head, new, sent
+
+
+def _make_rows_pipeline(n_cap: int, p_cap: int, a_cap: int, budget: int,
+                        lfa: bool, block_v4: bool, sentinels: bool):
     """The prefix-only solve (a PipelineVariant with `rows_only`): no
     weight of the mirror changed since the vantage's resident plane was
     computed, so the plane stands, and the dispatcher knows which rows
@@ -628,89 +852,36 @@ def _make_rows_pipeline(n_cap: int, d_cap: int, p_cap: int, a_cap: int,
     were computed (_prep_vantage's `cand_rows`) — no other row's inputs
     differ from those its outputs came from. So nothing is solved and
     nothing is searched for: the row stages (_row_stages, the body every
-    pipeline runs) go over `rows` candidate rows alone.
+    pipeline runs) go over the candidate rows alone
+    (_candidate_row_stages).
 
     _make_pipeline's arguments, then TWO trailing ones: the resident
-    [D, N] plane and cand_rows int32 [rows] — ascending, each row once,
-    the pad repeating the last (so a row equal to its left neighbour is
-    pad). Outputs as _make_pipeline's, in the one download format:
+    [D, N] plane and cand_rows int32 [`rows_only`], as
+    _candidate_row_stages takes them. Outputs as _make_pipeline's, in
+    the one download format:
       delta_buf  its layout word for word — count, trips 0, the changed
-                 candidates' indices and columns (pad slots as a clipped
-                 read of the last row gives them), sentinels, rounds 0;
+                 candidates' indices and columns, sentinels, rounds 0;
       full_buf   the scalars alone: such an epoch never reads it
                  (want_full is 0 and count <= rows <= budget);
       metric, s3w, nhw, lfa_slot, lfa_metric: the previous arrays with
-                 the candidate rows written in. They are not donated (an
-                 abandoned prepare must still find them), so each is
-                 copied: a few MB.
-    The sentinels stay what they are, reductions over every row: of the
-    resident metric after the scatter, and of the flags plane."""
-    import jax
+                 the candidate rows written in."""
     import jax.numpy as jnp
-
-    from openr_tpu.ops.stream import column_diff, first_true_rows, rows_any
-
-    pa = p_cap * a_cap
 
     def pipeline(deltas, shift_w, res_rows, res_nbr, res_w, mbuf,
                  root, root_nbr, root_w, want_full,
                  prev_metric, prev_s3w, prev_nhw,
                  prev_lfa_slot, prev_lfa_metric, dist_d, cand_rows):
-        with jax.named_scope("unpack"):
-            at = cand_rows[:, None] * a_cap + jnp.arange(a_cap)  # [R, A]
-            cells = _cells(*(mbuf[o + at] for o in range(0, 6 * pa, pa)),
-                           block_v4)
-        zero = jnp.zeros((1,), jnp.int32)  # trips, rounds: nothing ran
-        metric, s3, nh_mask, lfa_slot, lfa_metric = _row_stages(
-            cells, dist_d, root, root_w, n_cap, lfa
+        count, head, new, sent = _candidate_row_stages(
+            mbuf, dist_d, root, root_w,
+            (prev_metric, prev_s3w, prev_nhw, prev_lfa_slot,
+             prev_lfa_metric),
+            cand_rows, n_cap, p_cap, a_cap, budget, lfa, block_v4,
+            sentinels,
         )
-        with jax.named_scope("pack"):
-            cols = [metric, _pack_words(s3), _pack_words(nh_mask),
-                    lfa_slot, lfa_metric]
-            prev = [prev_metric, prev_s3w, prev_nhw,
-                    prev_lfa_slot, prev_lfa_metric]
-            n = 5 if lfa else 3  # without it the lfa columns pass through
-        with jax.named_scope("diff"):
-            was = [p[cand_rows] for p in prev[:n]] + [None] * (5 - n)
-            changed = column_diff(*cols, *was, lfa) & jnp.concatenate([
-                jnp.ones((1,), bool), cand_rows[1:] != cand_rows[:-1],
-            ])
-        with jax.named_scope("compact"):
-            new = [
-                p.at[cand_rows].set(c) for p, c in zip(prev[:n], cols)
-            ] + prev[n:]
-            slot = first_true_rows(changed, rows, rows)
-            live = slot < rows
-            slot = jnp.minimum(slot, rows - 1)
-
-            def lay(col, whole):
-                # the changed rows to the front; every slot after them
-                # reads the last row, as compact_changed_rows' clipped
-                # read of the pad index p_cap does
-                last = whole[p_cap - 1]
-                head = jnp.where(
-                    live.reshape((rows,) + (1,) * last.ndim), col[slot], last
-                )
-                pad = jnp.broadcast_to(last, (budget - rows,) + last.shape)
-                return jnp.concatenate([head, pad]).ravel()
-
-            tail = []
-            if sentinels:
-                unreach, saturated = _sentinels(
-                    rows_any(mbuf[pa:2 * pa] & 1, p_cap, a_cap), new[0]
-                )
-                tail = [unreach[None], saturated[None]]
-            tail += [zero]
-            delta_buf = jnp.concatenate([
-                changed.sum().astype(jnp.int32)[None], zero,
-                jnp.concatenate([
-                    jnp.where(live, cand_rows[slot], p_cap),
-                    jnp.full((budget - rows,), p_cap, jnp.int32),
-                ]),
-                *(lay(c, w) for c, w in zip(cols[:n], new)),
-                *tail,
-            ])
-            full_buf = jnp.concatenate([zero, zero, *tail])
+        zero = jnp.zeros((1,), jnp.int32)  # trips, rounds: nothing ran
+        tail = [*(x[None] for x in sent), zero]
+        delta_buf = jnp.concatenate([count[None], zero, *head, *tail])
+        full_buf = jnp.concatenate([zero, zero, *tail])
         return (delta_buf, full_buf, *new)
 
     return pipeline
@@ -745,6 +916,7 @@ class PipelineVariant(NamedTuple):
     fused: int = 0            # g same-shape areas vmapped in one dispatch
     donate: bool = False      # prev planes + warm seed donated (stream)
     rows_only: int = 0        # prefix-only: the candidate rows' bucket
+    narrow: bool = False      # incremental: row stages over candidate rows
     mesh: object = None       # the multichip tier's ('batch','graph') mesh
 
     @classmethod
@@ -768,6 +940,10 @@ class PipelineVariant(NamedTuple):
             raise ValueError(
                 f"rows_only: no solve, one area, one chip, the plane "
                 f"stays where it is, the rows fit a delta pull: {v}"
+            )
+        if v.narrow and not (v.incr and one_chip and not v.stream):
+            raise ValueError(
+                f"narrow: an incremental solve on one chip, no stream: {v}"
             )
         return v
 
@@ -844,7 +1020,7 @@ class PipelineVariant(NamedTuple):
         return f"PipelineVariant({fields})"
 
 
-_LATER_FIELDS = frozenset({"rows_only"})
+_LATER_FIELDS = frozenset({"rows_only", "narrow"})
 
 
 def _mesh_tag(mesh) -> str:
@@ -914,14 +1090,14 @@ def _build_pipeline(*fields) -> tuple:
     v = PipelineVariant.checked(*fields)
     if v.rows_only:
         pipeline = _make_rows_pipeline(
-            v.n_cap, v.d_cap, v.p_cap, v.a_cap, v.budget, v.rows_only,
-            v.lfa, v.block_v4, v.sentinels,
+            v.n_cap, v.p_cap, v.a_cap, v.budget, v.lfa, v.block_v4,
+            v.sentinels,
         )
     else:
         pipeline = _make_pipeline(
             *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
             v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
-            delta_exp=v.delta_exp, stream=v.stream,
+            delta_exp=v.delta_exp, stream=v.stream, narrow=v.narrow,
         )
     kw = {}
     if v.donate:
@@ -1100,6 +1276,7 @@ class _AreaDev:
         "d_res_w", "matrix_key", "matrix", "flags", "d_mbuf",
         "matrix_version", "pack_over", "drain_epoch", "drain_log",
         "mc_mesh", "sync_marks", "prefix_span", "pack_span", "mbuf_puts",
+        "put_log",
     )
 
     def __init__(self):
@@ -1116,6 +1293,11 @@ class _AreaDev:
         # snapshot); between two of them it is only scattered into, row
         # by row, and the matrix's touch log says which rows
         self.mbuf_puts = 0
+        # (mbuf_puts after it, the rows whose flags cells it changed —
+        # a drain's repack — or None where every row is new) of the last
+        # whole puts: with it a put of known rows does not void a
+        # vantage's rows_stamp (_rows_put_since)
+        self.put_log = deque(maxlen=16)
         # drain journal for the incremental solver: one entry per
         # _sync_area epoch — ({shift_flat: old_w}, {res_flat: old_w})
         # maps of that drain's pre-write weights, or (None, None) as a
@@ -1159,7 +1341,7 @@ class _VantageState:
     __slots__ = (
         "shape_key", "matrix_version", "prev", "crib",
         "links_tuple", "valid", "prev_dist", "dist_epoch", "root_sig",
-        "stream_budget", "rows_stamp",
+        "stream_budget", "rows_stamp", "shared_stamp",
     )
 
     def __init__(self):
@@ -1172,6 +1354,12 @@ class _VantageState:
         # touch log is read from, the rows touched since are the only
         # rows whose inputs moved
         self.rows_stamp = None
+        # and what every row of `prev` shares: (the drain epoch of the
+        # plane it was computed from, the root's link weights). Where
+        # they are the resident plane's epoch and this dispatch's
+        # weights, a row's outputs can differ from `prev`'s only through
+        # its own cells or its announcers' columns of the plane
+        self.shared_stamp = None
         self.crib: Optional[ColumnarRib] = None
         self.links_tuple: tuple = ()
         self.valid = False
@@ -2692,16 +2880,26 @@ class TpuSpfSolver:
                 else over != was
             ))
             ad.pack_over = over.copy()
-            # cells of the flags plane that differ from the device's
+            # cells of the flags plane that differ from the device's, and
+            # their rows (not kept past a delta pull's worth: an epoch
+            # over more rows than that looks at every row anyway)
             fresh = ad.flags is None or ad.flags.shape != flags.shape
-            changed = int(
-                flags.size if fresh else np.count_nonzero(flags != ad.flags)
-            )
+            put_rows = None
+            if fresh:
+                changed = int(flags.size)
+            else:
+                cells = np.flatnonzero((flags != ad.flags).ravel())
+                changed = len(cells)
+                if changed <= _DELTA_BUDGET:
+                    put_rows = np.unique(
+                        cells // flags.shape[1]
+                    ).astype(np.int32)
             put = fresh or changed > 0
             if put:
                 ad.flags = flags
                 ad.d_mbuf = self._put_counted(mbuf, shp("replicated"))
                 ad.mbuf_puts += 1
+                ad.put_log.append((ad.mbuf_puts, put_rows))
                 self._count("decision.tpu.mbuf_put_bytes", mbuf.nbytes)
             counters.set_counter(
                 "decision.tpu.drained_nodes", int(np.count_nonzero(over))
@@ -2850,14 +3048,33 @@ class TpuSpfSolver:
                 touched = matrix.touched_since(vs.crib.matrix_seq)
         # and, where the resident outputs were computed at the point that
         # list starts from and d_mbuf has only been scattered into since,
-        # the only rows a prefix-only solve has to look at (ascending,
-        # each once; none is a list too). An abandoned prepare leaves the
-        # stamp behind the crib's: all rows then, once
-        cand_rows = None
-        if touched is not None and vs.rows_stamp == (
-            ad.mbuf_puts, vs.crib.matrix_seq
+        # or put whole with known rows changed (a drain's repack), the
+        # only rows whose cells moved under the resident outputs:
+        # ascending, each once; none is a list too. An abandoned prepare
+        # leaves the stamp behind the crib's: all rows then, once.
+        # `wide` says why an epoch has to look at every row, where the
+        # host knows: the rows are unknown, or what every row shares
+        # moved (the resident outputs are not the resident plane's, the
+        # root's link weights changed)
+        cand_rows = wide = put_rows = None
+        root_w_sig = root_w.tobytes()
+        if (
+            touched is not None and vs.rows_stamp is not None
+            and vs.rows_stamp[1] == vs.crib.matrix_seq
         ):
-            cand_rows = np.unique(np.asarray(touched[0], np.int32))
+            put_rows = _rows_put_since(ad, vs.rows_stamp[0])
+        if put_rows is None:
+            wide = "rows_unknown"
+        elif vs.shared_stamp is None or vs.shared_stamp[0] != vs.dist_epoch:
+            wide = "plane"
+        elif vs.shared_stamp[1] != root_w_sig:
+            wide = "root_w"
+        else:
+            cand_rows = np.unique(np.concatenate(
+                [np.asarray(touched[0], np.int32), *put_rows]
+            ))
+            if len(cand_rows) > _DELTA_BUDGET:
+                wide = "host_rows"  # more of them than a delta pull holds
         if (
             vs.shape_key != cache_key
             or vs.matrix_version != ad.matrix_version
@@ -2887,6 +3104,7 @@ class TpuSpfSolver:
             vs.prev_dist = None
             vs.dist_epoch = -1
             vs.root_sig = None
+            vs.shared_stamp = None
         elif touched[0]:
             vs.crib.touch_rows(*touched)
             vs.crib.matrix_seq = matrix.touch_seq
@@ -2943,8 +3161,9 @@ class TpuSpfSolver:
             "vs": vs, "lfa": lfa, "block_v4": block_v4,
             "delta_exp": delta_exp,
             "mc": mc, "incr": incr, "root_sig": root_sig,
-            "cand_rows": cand_rows,
+            "cand_rows": cand_rows, "wide": wide,
             "rows_stamp": (ad.mbuf_puts, matrix.touch_seq),
+            "shared_stamp": (ad.drain_epoch, root_w_sig),
             "dist_epoch": ad.drain_epoch,
             "t0": t0, "t1": t1, "sync_marks": ad.sync_marks,
             "prefix_span": ad.prefix_span, "pack_span": ad.pack_span,
@@ -3060,9 +3279,12 @@ class TpuSpfSolver:
         """The executable a prepared vantage dispatches: its shape
         class, flags and tier, the solver's knobs, and the kind the
         dispatcher asks for (none: the full solve). The one place the
-        dispatch path reads _DELTA_BUDGET. The full solve emits the
-        distance plane whenever incremental solves may follow it; a
-        fused group's areas never seed one. Unchecked here, once an
+        dispatch path reads _DELTA_BUDGET for an executable (_sync_area
+        and _prep_vantage bound by it the rows they keep). The full
+        solve emits the distance plane whenever incremental solves may
+        follow it; a fused group's areas never seed one. An incremental solve on one
+        chip, outside the streaming pipeline, is the `narrow` one: its
+        row stages may run over candidate rows. Unchecked here, once an
         event: the factory checks what it builds."""
         return PipelineVariant(
             *pv["shape_key"], _DELTA_BUDGET, pv["lfa"], pv["block_v4"],
@@ -3071,17 +3293,33 @@ class TpuSpfSolver:
                 dirty_cap > 0 or (self.incremental_spf and not fused)
             ),
             delta_exp=pv["delta_exp"], dirty_cap=dirty_cap, stream=stream,
-            fused=fused, donate=donate, rows_only=rows_only, mesh=pv["mc"],
+            fused=fused, donate=donate, rows_only=rows_only,
+            narrow=dirty_cap > 0 and not stream and pv["mc"] is None,
+            mesh=pv["mc"],
         )
 
-    def _incr_args(self, pv: dict) -> tuple:
-        """_lane_args + the incremental solve's six trailing args."""
+    def _incr_args(self, pv: dict, variant: PipelineVariant) -> tuple:
+        """_lane_args + the incremental solve's six trailing args and,
+        for a `narrow` variant, two more: the rows the host knows were
+        written since the resident outputs were computed (a delta pull's
+        worth, pads p_cap) and `wide`, nonzero where every row has to be
+        looked at whatever the solve moves (pv["wide"])."""
         incr = pv["incr"]
-        return self._lane_args(pv) + (
+        args = self._lane_args(pv) + (
             pv["vs"].prev_dist,
             incr["sd_idx"], incr["sd_old"],
             incr["rd_idx"], incr["rd_old"], incr["cone_limit"],
         )
+        if not variant.narrow:
+            return args
+        rows = np.full(variant.budget, variant.p_cap, np.int32)
+        wide = np.int32(pv["wide"] is not None)
+        if not wide:
+            rows[:len(pv["cand_rows"])] = pv["cand_rows"]
+        if self._transfer_guard_mode() is not None:
+            rows = self._put_counted(rows)
+            wide = self._put_counted(np.asarray(wide))
+        return args + (rows, wide)
 
     def _dispatch_one(self, pv: dict):
         """Dispatch one area's pipeline and start the async result copy;
@@ -3130,7 +3368,7 @@ class TpuSpfSolver:
             return self._dispatch_stream(pv)
         else:
             variant = self._variant(pv, dirty_cap=incr["cap"])
-            args = self._incr_args(pv)
+            args = self._incr_args(pv, variant)
         kernel_name, run = pipeline_for(variant)
         delta_buf, full_buf, *new_prev = self._run_exec(
             variant.namespace, kernel_name, pv["shape_key"], run, args,
@@ -3192,7 +3430,7 @@ class TpuSpfSolver:
         kernel_name, run = pipeline_for(variant)
         delta_buf, full_buf, *new_prev = self._run_exec(
             variant.namespace, kernel_name, pv["shape_key"], run,
-            self._incr_args(pv), pv["area"],
+            self._incr_args(pv, variant), pv["area"],
         )
         prepare = self._make_prepare(
             pv, variant, kernel_name, delta_buf, full_buf, new_prev
@@ -3287,6 +3525,7 @@ class TpuSpfSolver:
             # solve's changed rows are not silently treated as applied
             vs.prev = tuple(new_prev[:5])
             vs.rows_stamp = pv["rows_stamp"]
+            vs.shared_stamp = pv["shared_stamp"]
             if emit:
                 # the emitted distance plane becomes the next solve's
                 # warm seed, stamped with the drain epoch and root
@@ -3412,6 +3651,22 @@ class TpuSpfSolver:
                 stats["prefix_only"] = wait_attrs["prefix_only"] = True
                 wait_attrs["cand_rows"] = len(pv["cand_rows"])
                 wait_attrs["cand_cap"] = rows_only
+            if variant.narrow:
+                # the rows the row stages looked at: the candidates (the
+                # rows the moved node columns can reach and the rows the
+                # host handed), or every row, and then why: the host's
+                # reason, else the device's own (the root's own column
+                # moved, or more candidates than a delta pull holds)
+                looked = int(sbuf[-5])
+                stats["rows_looked"] = wait_attrs["rows_looked"] = looked
+                if looked < p_cap:
+                    self._count("decision.tpu.candidate_epochs")
+                    self._count("decision.tpu.candidate_rows", looked)
+                else:
+                    why = pv["wide"] or "device"
+                    stats["wide"] = wait_attrs["wide"] = why
+                    self._count("decision.tpu.wide_epochs")
+                    counters.increment(f"decision.tpu.wide_epochs.{why}")
             if incr:
                 cone_passes = int(sbuf[-4])
                 cone = int(sbuf[-3])
@@ -3439,7 +3694,7 @@ class TpuSpfSolver:
                     "decision.solver.incr.changed_rows", count or 0
                 )
             if sentinels:
-                off = -4 if incr else -1
+                off = -1 - 3 * incr - variant.narrow
                 stats["sentinels"] = {
                     "unreachable_rows": int(sbuf[off - 2]),
                     "saturated_rows": int(sbuf[off - 1]),

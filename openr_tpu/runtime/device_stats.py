@@ -254,8 +254,8 @@ ANCHOR = "openr.anchor"
 # same in every variant of the pipeline
 DEVICE_SCOPES = (
     "unpack", "seed", "seed.parent", "seed.cone", "relax", "relax.ladder",
-    "relax.shift", "relax.residual", "select", "nexthop", "lfa", "pack",
-    "diff", "compact",
+    "relax.shift", "relax.residual", "candidates", "select", "nexthop",
+    "lfa", "pack", "diff", "compact",
 )
 DEVICE_PROCESS = "/device:"
 OPS_THREAD = "XLA Ops"

@@ -178,11 +178,14 @@ def parent_pipeline(monkeypatch, variant: ts.PipelineVariant):
     return jax.jit(closure)
 
 
-def parent_buffers(oracle, args) -> tuple:
+def parent_buffers(oracle, args, variant=None) -> tuple:
     """(delta_buf, full_buf, the five resident arrays) of the parent's
     pipeline on a dispatch's arguments (want_full, argument 9, forced
-    to 1)."""
+    to 1; without the two last of a `narrow` variant's, the host's rows
+    and its word: the parent looks at every row)."""
     args = list(args)
+    if variant is not None and variant.narrow:
+        args = args[:-2]
     args[9] = np.int32(1)
     delta_buf, full_buf, *resident = oracle(*args)
     return np.asarray(delta_buf), np.asarray(full_buf), [
@@ -206,8 +209,17 @@ def record_variants(monkeypatch) -> dict:
 
 
 def tail_len(variant: ts.PipelineVariant) -> int:
-    """Scalars after the rows of either pull buffer."""
+    """Scalars after the rows of either of the parent's pull buffers."""
     return 2 * variant.sentinels + 3 * variant.incr + 1
+
+
+def split_looked(buf: np.ndarray, variant: ts.PipelineVariant) -> tuple:
+    """(a pull buffer as the parent lays it out, the rows the row stages
+    looked at): a `narrow` variant's tail carries that one word more, at
+    [-5], before the cone's three and the rounds."""
+    if not variant.narrow:
+        return buf, None
+    return np.delete(buf, len(buf) - 5), int(buf[-5])
 
 
 class Recorder:
@@ -215,12 +227,16 @@ class Recorder:
     through the parent's pipeline first (a streaming epoch donates
     them), then to the executable under test, and the two pairs of
     buffers are compared by the contract. `epochs` keeps (variant,
-    want_full, count, cold) of each dispatch."""
+    want_full, count, cold) of each dispatch, and `looked` the rows its
+    row stages looked at (None but for a `narrow` variant: whichever
+    branch it took, what it leaves is the parent's, which looks at
+    every row)."""
 
     def __init__(self, monkeypatch, solver: TpuSpfSolver):
         self.oracles: dict = {}
         self.variants = record_variants(monkeypatch)
         self.epochs: list = []
+        self.looked: list = []
         real_exec = solver._run_exec
 
         def run_exec(namespace, kernel_name, signature, run, args, area):
@@ -231,7 +247,7 @@ class Recorder:
                 oracle = self.oracles[variant] = parent_pipeline(
                     monkeypatch, variant
                 )
-            want_d, want_f, want_res = parent_buffers(oracle, args)
+            want_d, want_f, want_res = parent_buffers(oracle, args, variant)
             want_full = int(np.asarray(args[9]))
             outs = real_exec(namespace, kernel_name, signature, run, args,
                              area)
@@ -246,8 +262,11 @@ class Recorder:
         monkeypatch.setattr(solver, "_run_exec", run_exec)
 
     def check(self, variant, want_full, outs, want_d, want_f):
-        got_d, got_f = np.asarray(outs[0]), np.asarray(outs[1])
+        got_d, looked = split_looked(np.asarray(outs[0]), variant)
+        got_f, looked_f = split_looked(np.asarray(outs[1]), variant)
         ctx = f"{variant.name} want_full={want_full}"
+        assert looked == looked_f, ctx
+        self.looked.append(looked)
         tail = tail_len(variant)
         if variant.rows_only:
             # a prefix-only solve (no weight changed: the resident plane
@@ -363,6 +382,16 @@ def test_buffers_equal_the_parents_on_randomized_churn(
     assert {"full": (False, False), "incremental": (True, False),
             "streaming": (True, True)}[mode] in kinds, kinds
     assert mode != "full" or len(kinds) == 1, kinds
+    # an incremental solve outside the streaming pipeline is the narrow
+    # one (ISSUE 44), and on the grid some of its epochs look at fewer
+    # rows than all: what they leave is the parent's all the same
+    looked = [n for (v, *_), n in zip(rec.epochs, rec.looked) if v.narrow]
+    assert bool(looked) == (mode == "incremental"), rec.looked
+    assert all(n is None for (v, *_), n in zip(rec.epochs, rec.looked)
+               if not v.narrow)
+    if looked:
+        p_cap = rec.epochs[0][0].p_cap
+        assert any(n < p_cap for n in looked), looked
 
 
 @pytest.mark.parametrize("mode", list(MODES))
@@ -420,13 +449,20 @@ def test_cold_half_runs_from_budget_plus_one(monkeypatch, kind, over):
     prev[0][rows] += 1
     args = list(args)
     args[10:15] = prev
+    if variant.narrow:
+        # planes made up by hand are not the resident plane's: the host's
+        # word for that is `wide` (and its rows at this budget: none)
+        args[-2:] = [np.full(budget, variant.p_cap, np.int32), np.int32(1)]
     _name, run = ts._build_pipeline(*variant)
     oracle = parent_pipeline(monkeypatch, variant)
-    want_d, want_f, _resident = parent_buffers(oracle, args)
+    want_d, want_f, _resident = parent_buffers(oracle, args, variant)
     tail = tail_len(variant)
     for want_full in (0, 1):
         args[9] = np.int32(want_full)
         got_d, got_f, *_ = (np.asarray(o) for o in run(*args))
+        got_d, looked = split_looked(got_d, variant)
+        got_f, _ = split_looked(got_f, variant)
+        assert looked in (None, variant.p_cap)
         np.testing.assert_array_equal(got_d, want_d)
         assert got_d[0] == budget + over
         np.testing.assert_array_equal(got_f[-tail:], want_f[-tail:])
@@ -436,6 +472,42 @@ def test_cold_half_runs_from_budget_plus_one(monkeypatch, kind, over):
         else:
             assert got_f[0] == 0 and not got_f[2:-tail].any()
             assert got_f[1] == want_f[1]
+
+
+@pytest.mark.parametrize("word", ["neither", "wide", "want_full"])
+def test_the_narrow_variant_takes_the_host_s_word(monkeypatch, word):
+    """A warm incremental dispatch's own arguments (resident outputs of
+    the resident plane, the host's rows known) look at a few rows; the
+    host's `wide` makes it every row, and so does `want_full`, which
+    also builds the cold pull: all three leave what the parent's
+    all-rows program leaves."""
+    variant, args, _planes = _captured_dispatch(
+        monkeypatch, ts._DELTA_BUDGET, "incremental"
+    )
+    assert variant.narrow
+    args = list(args)
+    assert int(args[-1]) == 0 and int(args[9]) == 0
+    args[-1] = np.int32(word == "wide")
+    args[9] = np.int32(word == "want_full")
+    oracle = parent_pipeline(monkeypatch, variant)
+    want_d, want_f, want_res = parent_buffers(oracle, args, variant)
+    _name, run = ts._build_pipeline(*variant)
+    got_d, got_f, *resident = (np.asarray(o) for o in run(*args))
+    got_d, looked = split_looked(got_d, variant)
+    got_f, _ = split_looked(got_f, variant)
+    if word == "neither":
+        assert 0 < looked < variant.p_cap
+    else:
+        assert looked == variant.p_cap
+    np.testing.assert_array_equal(got_d, want_d)
+    for got, want in zip(resident[:5], want_res):
+        np.testing.assert_array_equal(got, want)
+    tail = tail_len(variant)
+    np.testing.assert_array_equal(got_f[-tail:], want_f[-tail:])
+    if word == "want_full":
+        np.testing.assert_array_equal(got_f, want_f)
+    else:
+        assert got_f[0] == 0 and not got_f[2:-tail].any()
 
 
 @pytest.mark.parametrize("mode", list(MODES))

@@ -381,19 +381,29 @@ def test_every_pipeline_variant_carries_the_same_scopes():
     full = jax.jit(tpu_solver._make_pipeline(
         *key, 64, lfa=True, emit_dist=True, kernel="bucketed", delta_exp=1
     )).lower(*avals).as_text(debug_info=True)
-    incr_only = {"seed.parent", "seed.cone"}
+    # the incremental solve's own, and of those `candidates` the narrow
+    # variant's: the mask of the rows its moved node columns can reach
+    incr_only = {"seed.parent", "seed.cone", "candidates"}
     assert scopes_of(full) == set(device_stats.DEVICE_SCOPES) - incr_only
 
     n_cap, d_cap = key[0], key[5]
     dirty = jax.ShapeDtypeStruct((64,), "int32")
+    incr_avals = avals + (
+        jax.ShapeDtypeStruct((d_cap, n_cap), "int32"),
+        dirty, dirty, dirty, dirty, jax.ShapeDtypeStruct((), "int32"),
+    )
     incr = jax.jit(tpu_solver._make_pipeline(
         *key, 64, lfa=True, emit_dist=True, incr=True,
         kernel="bucketed", delta_exp=1,
+    )).lower(*incr_avals).as_text(debug_info=True)
+    assert scopes_of(incr) == set(device_stats.DEVICE_SCOPES) - {"candidates"}
+    narrow = jax.jit(tpu_solver._make_pipeline(
+        *key, 64, lfa=True, emit_dist=True, incr=True,
+        kernel="bucketed", delta_exp=1, narrow=True,
     )).lower(
-        *avals, jax.ShapeDtypeStruct((d_cap, n_cap), "int32"),
-        dirty, dirty, dirty, dirty, jax.ShapeDtypeStruct((), "int32"),
+        *incr_avals, dirty, jax.ShapeDtypeStruct((), "int32"),
     ).as_text(debug_info=True)
-    assert scopes_of(incr) == set(device_stats.DEVICE_SCOPES)
+    assert scopes_of(narrow) == set(device_stats.DEVICE_SCOPES)
 
     # the sync rounds have no ladder, and nothing else differs
     sync = jax.jit(tpu_solver._make_pipeline(

@@ -32,10 +32,14 @@ KINDS = {
     # the prefix-only solve: the row stages over a bucket of candidate
     # rows and the resident plane
     "rows": {"rows_only": 64},
+    # the incremental solve the dispatcher asks for on one chip outside
+    # the streaming pipeline: its row stages may run over candidate rows
+    "narrow": {"emit_dist": True, "dirty_cap": 64, "narrow": True},
 }
 NAMESPACE = {
     "full": "", "fused": "", "incr": "incr", "stream": "stream",
     "mc": "multichip", "mc_incr": "multichip", "rows": "incr",
+    "narrow": "incr",
 }
 
 # (kind, has_res, lfa, delta_exp) -> the name the parent's six
@@ -139,6 +143,13 @@ GOLDEN.update({
     )
     for key, name in list(GOLDEN.items()) if key[0] == "full"
 })
+# the narrow incremental solve is the incremental one by name: the
+# kernel ledger, the benchmark's warm-up check and the replay log read
+# what they read before
+GOLDEN.update({
+    ("narrow", *key[1:]): name
+    for key, name in list(GOLDEN.items()) if key[0] == "incr"
+})
 FLAGS = list(itertools.product((False, True), (False, True), (0, 3)))
 
 
@@ -192,6 +203,7 @@ def test_aot_keys_are_distinct_and_kinds_name_their_namespace(mesh):
         variant("incr", mesh, dirty_cap=256),
         variant("fused", mesh, fused=2),
         variant("rows", mesh, rows_only=256),
+        variant("narrow", mesh, dirty_cap=256),
     ]
     assert len(set(records)) == len(records)
     keys = {r.aot_key for r in records}
@@ -212,6 +224,10 @@ def test_aot_keys_are_distinct_and_kinds_name_their_namespace(mesh):
     assert not any(
         "rows_only" in r.aot_key for r in records if not r.rows_only
     )
+    assert variant("narrow", mesh).aot_key == variant(
+        "incr", mesh
+    ).aot_key.replace("mesh=None", "narrow=True, mesh=None")
+    assert not any("narrow" in r.aot_key for r in records if not r.narrow)
     assert variant("full", mesh).aot_key == (
         "PipelineVariant(n_cap=256, s_cap=4, r_cap=8, kr_cap=4, "
         "has_res=True, d_cap=4, p_cap=256, a_cap=2, budget=4096, "
@@ -268,6 +284,14 @@ BAD = {
     "rows_only_fused": dict(rows_only=64, fused=2),
     "rows_only_on_a_mesh": dict(rows_only=64, mesh=True),
     "rows_only_past_a_delta_pull": dict(rows_only=2 * BUDGET),
+    "narrow_full_solve": dict(narrow=True),
+    "narrow_stream": dict(
+        narrow=True, stream=256, dirty_cap=64, emit_dist=True
+    ),
+    "narrow_on_a_mesh": dict(
+        narrow=True, dirty_cap=64, emit_dist=True, mesh=True
+    ),
+    "narrow_rows_only": dict(narrow=True, rows_only=64),
 }
 
 
@@ -317,6 +341,11 @@ def _avals(v: PipelineVariant) -> tuple:
         S = jax.ShapeDtypeStruct
         avals += (
             S((v.d_cap, v.n_cap), np.int32), S((v.rows_only,), np.int32),
+        )
+    if v.narrow:
+        avals += (
+            jax.ShapeDtypeStruct((v.budget,), np.int32),
+            jax.ShapeDtypeStruct((), np.int32),
         )
     return avals
 
@@ -439,7 +468,7 @@ def _lowered_digest(v: PipelineVariant) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("kind", sorted(set(KINDS) - {"rows"}))
+@pytest.mark.parametrize("kind", sorted(set(KINDS) - {"rows", "narrow"}))
 def test_lowered_text_is_the_parents(kind, mesh):
     got = {}
     for flags in FLAGS:
@@ -459,6 +488,37 @@ def test_the_prefix_only_program_is_another_at_every_bucket(mesh):
         assert f"tensor<{rows}xi32>" in text  # cand_rows
         digests.add(_lowered_digest(v))
     assert len(digests) == len(LOWERED) + len(ts._DIRTY_BUCKETS)
+
+
+def test_the_narrow_incremental_program_is_another(mesh):
+    """ISSUE 44's program: the incremental solve with a second body of
+    the row stages, over a delta pull's worth of candidate rows, beside
+    the all-rows text (whose every other program is the parent's, digest
+    for digest, above) under one conditional on the device; two more
+    arguments, the host's rows and its word; one more word in the tail
+    of both pull buffers."""
+    digests = set(LOWERED.values())
+    for flags in FLAGS:
+        v = variant("narrow", mesh, *flags)
+        wide = variant("incr", mesh, *flags)
+        assert v.name == wide.name and v.aot_key != wide.aot_key
+        text = pipeline_for(v)[1].jitted.lower(*_avals(v)).as_text()
+        base = pipeline_for(wide)[1].jitted.lower(*_avals(wide)).as_text()
+        # the branch, and inside its all-rows side the cold pull's
+        assert text.count("stablehlo.case") == 2
+        assert base.count("stablehlo.case") == 1
+        assert f"tensor<{BUDGET}xi32>" in text  # the host's rows
+        outs = [
+            jax.eval_shape(pipeline_for(r)[1].jitted, *_avals(r))
+            for r in (v, wide)
+        ]
+        for buf in (0, 1):
+            assert outs[0][buf].shape[0] == outs[1][buf].shape[0] + 1
+        assert [o.shape for o in outs[0][2:]] == [
+            o.shape for o in outs[1][2:]
+        ]
+        digests.add(_lowered_digest(v))
+    assert len(digests) == len(LOWERED) + len(FLAGS)
 
 
 def test_budget_alone_makes_another_executable(mesh):

@@ -21,6 +21,14 @@ row stages on the same arguments (`tests/test_compact_rows.Recorder`: the
 five resident arrays and `delta_buf` word for word); the epochs it cannot
 serve take the incremental or the full solve and end equal to the oracle;
 and two counters say how often it served and how many rows.
+
+A link event's row stages run over candidate rows too (ISSUE 44): the
+incremental solve finds on the device the rows its moved node columns can
+reach, adds the rows the host knows were written (the touch log's, a
+drain's repack's), and looks at those alone where they fit a delta pull
+and nothing every row shares moved; each such dispatch is replayed through
+the all-rows program as well, every wide reason takes the all-rows branch
+and ends equal to the oracle, and the counters say which happened.
 """
 
 import random
@@ -52,7 +60,7 @@ from openr_tpu.ops import csr
 from tests.conftest import run_async
 from tests.test_compact_rows import Recorder
 from tests.test_decision import DecisionHarness
-from tests.test_incremental_spf import _Churn
+from tests.test_incremental_spf import _Churn, _rebuild
 from tests.test_tpu_solver import assert_rib_equal
 
 FABRIC = {"pods": 4, "planes": 4, "ssws_per_plane": 2, "rsws_per_pod": 6}
@@ -212,6 +220,7 @@ def test_prefix_events_mixed_with_link_events_match_the_oracle(seed):
     rng = random.Random(seed)
     w = World(incremental_spf=True)
     rebuilds = counter("decision.tpu.prefix_matrix_rebuilds")
+    p_cap = w.tpu._area_dev[AREA].matrix.ann_node.shape[0]
     edges = [e for e in w.churn.edges() if ME not in e]
     for step in range(8):
         u, v = rng.choice(edges)
@@ -223,10 +232,14 @@ def test_prefix_events_mixed_with_link_events_match_the_oracle(seed):
         stats = w.solve(f"seed {seed} step {step}: down and prefixes")
         assert not stats.get("prefix_only")
         assert stats["rounds"] > 0
+        # the two rows the host scattered and the rows the moved node
+        # columns can reach, and no other (ISSUE 44)
+        assert 2 <= stats["rows_looked"] < p_cap and "wide" not in stats
         w.churn.link_up(u, v, *saved)
         w.advertise(node, entry_of(prefix, distance=step % 3))
         stats = w.solve(f"seed {seed} step {step}: up and prefix back")
         assert not stats.get("prefix_only")
+        assert 1 <= stats["rows_looked"] < p_cap and "wide" not in stats
         # and a prefix event alone right after: the plane stands again
         w.advertise(node, entry_of(prefix))
         stats = w.solve(f"seed {seed} step {step}: prefix alone")
@@ -470,6 +483,44 @@ def test_candidate_rows_equal_the_all_rows_stages(monkeypatch, lfa, v4):
     w.withdraw(nodes[6], shared)
     assert w.solve("and gone again").get("prefix_only")
     assert rec.epochs[-1][0].rows_only == 64
+    # link events (ISSUE 44): the incremental solve's row stages over the
+    # rows its moved node columns can reach, each dispatch replayed
+    # through the all-rows program like the ones above — random links
+    # down and up, a prefix event in the same epoch, a metric, a drained
+    # switch and its give-back
+    first = len(rec.epochs)
+    p_cap = w.tpu._area_dev[AREA].matrix.ann_node.shape[0]
+    rng = random.Random(44)
+    edges = [e for e in w.churn.edges() if ME not in e]
+    for step in range(4):
+        u, v = rng.choice(edges)
+        saved = w.churn.dbs[u], w.churn.dbs[v]
+        w.churn.link_down(u, v)
+        if step % 2:
+            w.advertise(rng.choice(nodes), entry_of(w.fresh_prefix()))
+        w.solve(f"link {u} - {v} down")
+        w.churn.link_up(u, v, *saved)
+        if step % 2:
+            n, p = rng.choice(sorted(w.held))
+            w.advertise(n, entry_of(p, distance=2))
+        w.solve(f"link {u} - {v} up")
+    u, v = rng.choice(edges)
+    w.churn.set_metric(u, v, 5)
+    w.solve(f"metric {u} - {v}")
+    fsw = "pod002-fsw01"
+    w.churn._put(replace(w.churn.dbs[fsw], is_overloaded=True))
+    drained = w.solve(f"{fsw} drained")
+    w.churn._put(replace(w.churn.dbs[fsw], is_overloaded=False))
+    w.solve(f"{fsw} given back")
+    link = rec.epochs[first:]
+    assert len(link) == 11 and all(v.narrow for v, *_ in link)
+    # every one of them narrow: fewer rows than all, never none at the
+    # drain (its flagged rows are the host's, the rack switches' behind it
+    # the device's)
+    looked = rec.looked[first:]
+    assert all(0 <= n < p_cap for n in looked), looked
+    assert drained["rows_looked"] == looked[-2] >= PER_NODE * (1 + 6)
+    assert any(count for _, _, count, _ in link)
 
 
 def _foreign(w: World, node: str) -> None:
@@ -493,24 +544,30 @@ def _abandon(w: World) -> None:
     ))
 
 
-# case -> the path that was there and that it takes
+# case -> what serves it, the prefix-only program declining: the
+# incremental solve over its own candidate rows ("narrow": since ISSUE 44
+# the host's rows reach it too), the incremental solve over every row
+# ("wide", where the host's rows are not known), or the full solve
 DECLINES = {
-    "overload_snapshot_changed": "incremental",
+    "overload_snapshot_changed": "narrow",
     "matrix_rebuilt": "full",
     "touch_log_does_not_reach_back": "full",
-    "more_rows_than_the_largest_bucket": "incremental",
+    "more_rows_than_the_largest_bucket": "narrow",
     "vantage_not_valid": "full",
-    "a_link_event_in_the_same_epoch": "incremental",
-    "an_abandoned_prepare": "incremental",
+    "a_link_event_in_the_same_epoch": "narrow",
+    "an_abandoned_prepare": "wide",
 }
 
 
 @pytest.mark.parametrize("case", sorted(DECLINES))
 def test_an_epoch_the_candidate_rows_cannot_serve(monkeypatch, case):
-    """Each takes a path that was there (the incremental solve, with
-    nothing dirty where no weight changed, or the full solve), looks at
-    every row, ends equal to the oracle, and leaves the next prefix-only
-    epoch to the candidate rows again."""
+    """No prefix-only epoch: each takes the incremental solve (with
+    nothing dirty where no weight changed) or the full solve, ends equal
+    to the oracle, and leaves the next prefix-only epoch to the candidate
+    rows again. The incremental solve looks at the candidate rows where
+    the host can name its share of them (a drain's repack, a link event
+    beside the prefix event, more rows than a bucket) and at every row
+    where it cannot (an abandoned prepare)."""
     if case == "touch_log_does_not_reach_back":
         monkeypatch.setattr(csr, "_TOUCH_LOG", 2)
     w = World()
@@ -551,28 +608,160 @@ def test_an_epoch_the_candidate_rows_cannot_serve(monkeypatch, case):
         assert vs.rows_stamp[1] < ad.matrix.touch_seq
         w.advertise(nodes[3], entry_of(w.fresh_prefix()))
     served = counter("decision.tpu.candidate_epochs")
+    rows = counter("decision.tpu.candidate_rows")
+    wide = counter("decision.tpu.wide_epochs")
     only = counter("decision.tpu.prefix_only_epochs")
+    p_cap = ad.matrix.ann_node.shape[0]
     stats = w.solve(case)
     assert not stats.get("prefix_only"), stats
-    assert counter("decision.tpu.candidate_epochs") == served
     assert counter("decision.tpu.prefix_only_epochs") == only
+    narrow = DECLINES[case] == "narrow"
+    assert counter("decision.tpu.candidate_epochs") == served + narrow
+    assert counter("decision.tpu.wide_epochs") == wide + (
+        DECLINES[case] == "wide"
+    )
     if DECLINES[case] == "full":
         assert stats["full_pull"] and not stats.get("incremental")
+        assert "rows_looked" not in stats
     else:
         assert stats.get("incremental") and not stats["fell_back"]
         assert not stats["full_pull"]
+    if narrow:
+        assert 1 <= stats["rows_looked"] < p_cap and "wide" not in stats
+        assert counter("decision.tpu.candidate_rows") == (
+            rows + stats["rows_looked"]
+        )
     if case == "overload_snapshot_changed":
+        # the put's rows are known: the drained switch's own (their flags
+        # cells changed) beside the withdrawn one
         assert ad.mbuf_puts == puts + 1
+        assert list(ad.put_log)[-1][0] == puts + 1
+        assert len(list(ad.put_log)[-1][1]) == PER_NODE
+        assert stats["rows_looked"] >= PER_NODE + 1
     if case == "more_rows_than_the_largest_bucket":
-        # nothing dirty: the solve converges at once, all rows looked at
+        # nothing dirty: the solve converges at once, and no column moved:
+        # the host's five rows are all there is to look at
         assert stats["cone"] == 0 and stats["changed_rows"] == 5
+        assert stats["rows_looked"] == 5
         monkeypatch.undo()
+    if case == "an_abandoned_prepare":
+        assert stats["wide"] == "rows_unknown"
+        assert stats["rows_looked"] == p_cap
     # the stamp follows whichever program computed the resident outputs
     ad, vs = w.tpu._area_dev[AREA], w.tpu._vstates[(AREA, ME)]
     assert vs.rows_stamp == (ad.mbuf_puts, ad.matrix.touch_seq)
     w.advertise(node, entry_of(prefix, distance=2))
     assert w.solve("a prefix alone, after").get("prefix_only")
-    assert counter("decision.tpu.candidate_epochs") == served + 1
+    assert counter("decision.tpu.candidate_epochs") == served + narrow + 1
+    assert counter("decision.tpu.prefix_only_epochs") == only + 1
+
+
+# case -> the reason the epoch's span and counter give
+WIDE = {
+    "a_metric_on_the_vantage_s_own_link": "root_w",
+    "the_root_s_own_column_moved": "device",
+    "candidates_past_the_budget": "device",
+    "more_columns_moved_than_the_mask_compares": "device",
+    "host_rows_past_the_budget": "host_rows",
+    "a_put_of_rows_not_kept": "rows_unknown",
+    "an_abandoned_prepare_then_a_link_event": "rows_unknown",
+    "outputs_not_of_the_resident_plane": "plane",
+}
+BUDGETS = {
+    "candidates_past_the_budget": 16, "host_rows_past_the_budget": 16,
+    "a_put_of_rows_not_kept": 4,
+    # a budget no other test has: its executables are this test's alone,
+    # traced under the _MOVED_CAP it patches
+    "more_columns_moved_than_the_mask_compares": 48,
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE))
+def test_every_wide_reason_looks_at_every_row(monkeypatch, case):
+    """What every row shares moved (the root's link weights, the root's
+    own column of the plane, outputs that are not the resident plane's),
+    or the rows are too many (the device's candidates, the host's) or not
+    known (a put whose rows were not kept, an abandoned prepare): the
+    incremental solve takes its all-rows branch, says why, ends equal to
+    the oracle, and the next link event is narrow again (one column
+    moves: under every cap here). (`want_full`,
+    the device's own too, never reaches an incremental dispatch from the solver:
+    tests/test_compact_rows.py hands it to the executable.)"""
+    if case in BUDGETS:
+        monkeypatch.setattr(ts, "_DELTA_BUDGET", BUDGETS[case])
+    if case == "more_columns_moved_than_the_mask_compares":
+        monkeypatch.setattr(ts, "_MOVED_CAP", 2)
+    w = World()
+    rec = Recorder(monkeypatch, w.tpu)
+    ad = w.tpu._area_dev[AREA]
+    vs = w.tpu._vstates[(AREA, ME)]
+    p_cap = ad.matrix.ann_node.shape[0]
+    nodes = rsws(w, but=(ME,))
+    far = next(
+        e for e in w.churn.edges() if "pod003-rsw02" in e and "fsw" in e[0]
+    )
+    w.churn.set_metric(*far, 2)
+    stats = w.solve("a narrow link event first")
+    assert stats["rows_looked"] == PER_NODE and "wide" not in stats
+    w.churn.set_metric(*far, 1)
+    fsw = "pod002-fsw01"
+    if case == "a_metric_on_the_vantage_s_own_link":
+        # root_sig holds (the same links are up), root_w moved
+        w.churn.set_metric(ME, "pod000-fsw00", 3)
+    elif case == "the_root_s_own_column_moved":
+        # the neighbour's way back to the vantage: one direction alone,
+        # so the vantage's own weights stand
+        db = w.churn.dbs["pod000-fsw00"]
+        w.churn._put(_rebuild(db, [
+            replace(a, metric=4) if a.other_node_name == ME else a
+            for a in db.adjacencies
+        ], AREA))
+    elif case in (
+        "candidates_past_the_budget",
+        "more_columns_moved_than_the_mask_compares",
+    ):
+        # six rack switches behind it and its own three flagged rows: 21
+        # candidates, past a budget of 16; six moved columns, past a cap
+        # of 2 though 21 rows fit a budget of 48
+        w.churn._put(replace(w.churn.dbs[fsw], is_overloaded=True))
+    elif case == "a_put_of_rows_not_kept":
+        # two switches' six changed cells, past a budget of four
+        for node in (fsw, "pod001-fsw02"):
+            w.churn._put(replace(w.churn.dbs[node], is_overloaded=True))
+    elif case == "host_rows_past_the_budget":
+        for node, prefix in sorted(w.held)[:17]:
+            w.advertise(node, entry_of(prefix, distance=2))
+    elif case == "an_abandoned_prepare_then_a_link_event":
+        w.advertise(nodes[0], entry_of(w.fresh_prefix()))
+        _abandon(w)
+    elif case == "outputs_not_of_the_resident_plane":
+        # as a dispatch that emits no plane (a fused group's) leaves it
+        vs.shared_stamp = (vs.shared_stamp[0] - 1, vs.shared_stamp[1])
+    before = {
+        key: counter(f"decision.tpu.{key}") for key in (
+            "candidate_epochs", "wide_epochs",
+            f"wide_epochs.{WIDE[case]}",
+        )
+    }
+    stats = w.solve(case)
+    assert stats.get("incremental") and not stats["fell_back"], stats
+    assert stats["wide"] == WIDE[case] and stats["rows_looked"] == p_cap
+    assert rec.epochs[-1][0].narrow and rec.looked[-1] == p_cap
+    gained = {
+        key: counter(f"decision.tpu.{key}") - was
+        for key, was in before.items()
+    }
+    assert gained == {
+        "candidate_epochs": 0, "wide_epochs": 1,
+        f"wide_epochs.{WIDE[case]}": 1,
+    }
+    if case == "a_put_of_rows_not_kept":
+        assert list(ad.put_log)[-1] == (ad.mbuf_puts, None)
+    # whichever branch computed them, the stamps are the resident
+    # outputs': the next link event is narrow
+    w.churn.set_metric(*far, 2)
+    stats = w.solve("a link event after")
+    assert stats["rows_looked"] == PER_NODE and "wide" not in stats
 
 
 def test_the_counters_say_how_often_and_how_many_rows():
@@ -581,7 +770,8 @@ def test_the_counters_say_how_often_and_how_many_rows():
     before = {
         key: counter(f"decision.tpu.{key}") for key in (
             "candidate_epochs", "candidate_rows", "prefix_only_epochs",
-            "prefix_rows_changed", "epochs",
+            "prefix_rows_changed", "epochs", "wide_epochs",
+            "wide_epochs.root_w",
         )
     }
     handed = []
@@ -596,24 +786,44 @@ def test_the_counters_say_how_often_and_how_many_rows():
         assert attrs["prefix_only"] is True and attrs["rounds"] == 0
         handed.append((attrs["cand_rows"], attrs["cand_cap"]))
     assert handed == [(1, 64), (3, 64), (0, 64), (2, 64), (70, 256)]
-    # a link event: neither counter moves
+    # a link event (ISSUE 44): the candidate rows' counters move by the
+    # rows its row stages looked at, the prefix-only one does not; and a
+    # metric on one of the vantage's own links goes wide, with its reason
     u, v = next(e for e in w.churn.edges() if ME not in e)
     w.churn.link_down(u, v)
-    assert not w.solve("a link down").get("prefix_only")
+    stats = w.solve("a link down")
+    assert not stats.get("prefix_only")
+    looked = stats["rows_looked"]
+    attrs = {
+        name: a for name, _, _, _, a in w.tpu.last_timing["spans"]
+    }["tpu.device_wait"]
+    assert attrs["rows_looked"] == looked and "wide" not in attrs
+    assert 0 < looked < w.tpu._area_dev[AREA].matrix.ann_node.shape[0]
+    mine = next(e for e in w.churn.edges() if ME in e)
+    w.churn.set_metric(*mine, 3)
+    stats = w.solve("a metric on the vantage's own link")
+    attrs = {
+        name: a for name, _, _, _, a in w.tpu.last_timing["spans"]
+    }["tpu.device_wait"]
+    assert stats["wide"] == attrs["wide"] == "root_w"
     gained = {
         key: counter(f"decision.tpu.{key}") - was
         for key, was in before.items()
     }
     assert gained == {
-        "candidate_epochs": 5, "candidate_rows": 76,
-        "prefix_only_epochs": 5, "prefix_rows_changed": 76, "epochs": 6,
+        "candidate_epochs": 6, "candidate_rows": 76 + looked,
+        "prefix_only_epochs": 5, "prefix_rows_changed": 76, "epochs": 7,
+        "wide_epochs": 1, "wide_epochs.root_w": 1,
     }
     # each addition is a stamped sample too: a window's gain is readable
-    for key, least in (("candidate_epochs", 5), ("candidate_rows", 76)):
+    for key, least, times in (
+        ("candidate_epochs", 6, 6), ("candidate_rows", 76 + looked, 6),
+        ("wide_epochs", 1, 1),
+    ):
         stat = counters.get_statistics(
             f"decision.tpu.{key}", windows=(3600.0,)
         )[f"decision.tpu.{key}"]["3600"]
-        assert stat["sum"] >= least and stat["count"] >= 5
+        assert stat["sum"] >= least and stat["count"] >= times
 
 
 # -- what a prefix-only epoch does not do ------------------------------------

@@ -296,8 +296,9 @@ def test_multichip_program_compiles_on_a_described_mesh(
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
 
 
+@pytest.mark.parametrize("narrow", [False, True], ids=["all_rows", "narrow"])
 def test_the_prefix_plane_class_compiles_with_one_conditional(
-    one_chip, cache_off
+    one_chip, cache_off, narrow
 ):
     """fabric10k_pfx's incremental executable (524,288 rows over 16,384
     node columns, LFA, the 4,096-row budget) from its variant record
@@ -305,27 +306,42 @@ def test_the_prefix_plane_class_compiles_with_one_conditional(
     entry computation, and neither compaction left a scan over every row
     behind (`jnp.nonzero`'s cumsum is a reduce-window of 524,288: 5 s of
     this compile each, 40 s inside a conditional; PERF.md section 6,
-    PR 37)."""
+    PR 37). The `narrow` variant (ISSUE 44, what the dispatcher asks
+    for) has that text whole as one side of a second conditional, the
+    row stages over 4,096 candidate rows as the other, and the mask that
+    finds them on the flat planes: no [524288, 2] array outside the
+    all-rows side."""
     S = jax.ShapeDtypeStruct
     key = (16384, 1, 32768, 8, True, 8, 524288, 2)
     record = ts.PipelineVariant.checked(
         *key, ts._DELTA_BUDGET, True, True, True, emit_dist=True,
-        dirty_cap=64,
+        dirty_cap=64, narrow=narrow,
     )
     avals = ts._pipeline_avals(key) + (
         S((8, 16384), np.int32),
         *(S((64,), np.int32) for _ in range(4)),
         S((), np.int32),
     )
+    if narrow:
+        avals += (S((ts._DELTA_BUDGET,), np.int32), S((), np.int32))
     _name, run = ts._build_pipeline(*record)
     compiled = compile_single(one_chip, run.jitted, avals)
     text = compiled.as_text()
-    assert text.count(" conditional(") == 1
-    assert 'op_name="jit(pipeline)/compact/cond"' in text
+    assert text.count(" conditional(") == 1 + narrow
+    cold = "cond/branch_1_fun/compact/cond" if narrow else "compact/cond"
+    assert f'op_name="jit(pipeline)/{cold}"' in text
     # the blocks' totals are scanned ([32, 128]); the rows are not
     assert re.search(r"= s32\[32,128\]\S* reduce-window\(", text)
     assert not re.search(r"= s32\[4096,128\]\S* reduce-window\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+    if narrow:
+        # the mask's operations work on vectors of all 1,048,576 cells
+        # or shorter, never on a plane two cells wide
+        mask = [
+            line for line in text.splitlines()
+            if 'op_name="jit(pipeline)/candidates/' in line
+        ]
+        assert mask and not any("[524288,2]" in line for line in mask)
 
 
 def test_the_prefix_only_solve_compiles_with_no_loop(one_chip, cache_off):

@@ -13,9 +13,12 @@ of the collector — what PERF.md's section 5 and 6 quote per hold — and
 `window_counters`, what the solver's `decision.tpu.*` counters (`epochs`,
 `cold_compactions`, `cone_passes`, `cone_skips` and, of the prefix rows,
 `prefix_rows_changed`, `prefix_only_epochs`, `prefix_matrix_rebuilds`,
-`candidate_epochs`, `candidate_rows`: the prefix-only epochs, which are
-the candidate rows' since PR 42, and the rows handed to them) and
-`decision.crib.key_index_builds` gained over that window. A
+`candidate_epochs`, `candidate_rows`: the epochs whose row stages ran over
+candidate rows — the prefix-only ones since PR 42, and since PR 44 the
+incremental solves that found their moved node columns to reach no more
+rows than a delta pull holds — and those rows; `wide_epochs`, the
+incremental solves that looked at every row, and `wide_epochs.<reason>`)
+and `decision.crib.key_index_builds` gained over that window. A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
 """
@@ -37,8 +40,9 @@ import run  # noqa: E402  (stamps T_PROCESS)
 WINDOW_COUNTERS = tuple(f"decision.tpu.{name}" for name in (
     "epochs", "cold_compactions", "cone_passes", "cone_skips",
     "prefix_rows_changed", "prefix_only_epochs", "prefix_matrix_rebuilds",
-    "candidate_epochs", "candidate_rows",
+    "candidate_epochs", "candidate_rows", "wide_epochs",
 )) + ("decision.crib.key_index_builds",)
+WIDE_REASONS = "decision.tpu.wide_epochs."
 
 
 def main(argv=None) -> int:
@@ -60,7 +64,7 @@ def main(argv=None) -> int:
                     key.removeprefix("decision.tpu."):
                         value - before.get(key, 0)
                     for key, value in counters.raw_counters().items()
-                    if key in WINDOW_COUNTERS
+                    if key in WINDOW_COUNTERS or key.startswith(WIDE_REASONS)
                 },
             )
         return result

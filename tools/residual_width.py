@@ -45,9 +45,13 @@ With `--prefixes-per-node` (a fabric: the generator takes the count) the
 mirror and the lanes are left alone and the prefix plane varies instead:
 the same graph with each count of prefixes a switch, one line a count with
 the rows the device carries (`prefix_rows`, `prefixes`) and the solver's
-own capture from the usual vantage: `by_scope` an event, and `row_stages`,
-the sum of the scopes that work over every row (ROW_SCOPES), beside
-`device_ms_per_event`. Then, under `prefix_only`, the same solver's
+own capture from the usual vantage: `by_scope` an event (`candidates` is
+the incremental solve's mask of the rows its moved node columns can reach,
+beside `select`, `lfa`, `nexthop`, `unpack`: ISSUE 44), `row_stages`, the
+sum of the scopes that work over the rows (ROW_SCOPES), beside
+`device_ms_per_event`, and `rows_looked`, the rows each event's row stages
+looked at (the candidates, or every row: null on a tree that looks at
+every row always). Then, under `prefix_only`, the same solver's
 capture of prefix events alone (one prefix of the far switch withdrawn,
 then advertised back: the prefix-only program, which since PR 42 works
 over the candidate rows it is handed): `by_scope` and the device's ms an
@@ -107,9 +111,12 @@ EVENTS = 6
 # the solver's own capture compiles two pipelines a width (49 s each at
 # wan50k): taken at the width before the split and at the narrow ones
 SCOPE_MAX_WIDTH = 8
-# the device program's stages after the SSSP: each works over every
-# prefix row, whatever the event changed
-ROW_SCOPES = ("unpack", "select", "nexthop", "lfa", "pack", "diff", "compact")
+# the device program's stages after the SSSP: each works over the prefix
+# rows the epoch looks at (every row, or the candidates `candidates` finds)
+ROW_SCOPES = (
+    "candidates", "unpack", "select", "nexthop", "lfa", "pack", "diff",
+    "compact",
+)
 
 
 @contextlib.contextmanager
@@ -326,7 +333,9 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want,
     peer = adj_dbs[-1].adjacencies[0].other_node_name
     base = adj_dbs[-1].adjacencies[0].metric
     solver = TpuSpfSolver(me, enable_lfa=True, incremental_spf=True)
-    tables, rounds, cone_passes, cones, changed = [], [], [], [], []
+    tables, rounds, cone_passes, cones, changed, looked = (
+        [], [], [], [], [], []
+    )
 
     def solve(step: int) -> dict:
         """The link's metric up (even steps) or back, then a solve."""
@@ -350,6 +359,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want,
             cone_passes.append(solver.last_timing.get("cone_passes"))
             cones.append((stats.get("cone"), bool(stats.get("fell_back"))))
             changed.append(stats.get("changed_rows"))
+            looked.append(stats.get("rows_looked"))
         by_scope = device_stats.profiler_stop()["by_scope"] or {}
         prefix_only = {"prefix_only": _prefix_only_ms(
             solver, me, states, ps, prefix_dbs[-1]
@@ -367,6 +377,7 @@ def _scope_ms(name: str, adj_dbs, prefix_dbs, me: str, width: int, want,
         "cone_passes": cone_passes,
         "cones": cones,
         "changed_rows": changed,
+        "rows_looked": looked,
         "prefix_rows": stats.get("prefix_rows"),
         "prefixes": stats.get("prefixes"),
         "tables_equal_first_width": same,
