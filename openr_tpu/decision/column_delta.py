@@ -46,6 +46,7 @@ from openr_tpu.decision.columnar_rib import (
     LazyUnicastRoutes,
     RibView,
     _lookup,
+    cols_changed_mask,
     unpack_words,
 )
 from openr_tpu.runtime.counters import counters
@@ -481,22 +482,6 @@ class ColumnDelta:
         return self._batch
 
 
-def _col_changed_mask(oc, nc, rows: np.ndarray) -> np.ndarray:
-    """Row-wise column compare between two bundles: entry construction
-    is a pure function of these columns (same matrix/links per crib), so
-    byte-equal rows are route-equal."""
-    m = (oc.met[rows] != nc.met[rows])
-    m |= (oc.s3w[rows] != nc.s3w[rows]).any(axis=1)
-    m |= (oc.nhw[rows] != nc.nhw[rows]).any(axis=1)
-    m |= oc.ok[rows] != nc.ok[rows]
-    if oc.lfa_slot is not None and nc.lfa_slot is not None:
-        m |= oc.lfa_slot[rows] != nc.lfa_slot[rows]
-        m |= oc.lfa_metric[rows] != nc.lfa_metric[rows]
-    elif (oc.lfa_slot is None) != (nc.lfa_slot is None):
-        m |= True
-    return m
-
-
 def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
     """Column-native unicast diff old -> new. Requires `new` to be a
     LazyUnicastRoutes whose segments are their cribs' live tips. Two
@@ -578,13 +563,19 @@ def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
             # is exactly the changed set — no host re-compare needed
             changed = jrows
         else:
-            mask = _col_changed_mask(oc, nc, jrows)
+            mask = cols_changed_mask(oc, nc, jrows)
             if len(forced):
                 mask |= np.isin(jrows, forced)
             changed = jrows[mask]
         plist = crib.matrix.prefix_list
         upd = changed[nc.ok[changed]]
         dels = changed[oc.ok[changed] & ~nc.ok[changed]]
+        if not candidates and not multi:
+            # no host-touched key and no other layer: every changed row
+            # stays on the column path (a full result changes thousands)
+            segments.append((sn, upd))
+            del_prefixes.extend(plist[r] for r in dels.tolist())
+            continue
         # rows the host also touched (or that another layer shadows)
         # leave the column path and join the entry-compare candidates
         keep = np.ones(len(upd), bool)

@@ -16,8 +16,9 @@ and builds entry objects only at consumption boundaries:
 Three cooperating pieces:
 
   `ColumnarRib`   one (area, vantage)'s live column store. Mutated in
-                  place by the solver (full scatter on cold rebuild,
-                  row patches on steady-state deltas). Copy-on-write:
+                  place by the solver (full scatter on a full result,
+                  journaled as a change where a table stands; row
+                  patches on steady-state deltas). Copy-on-write:
                   before a mutation, the column bundle is copied iff a
                   live `RibView` still references it, so snapshots stay
                   valid at ~2 MB/flap cost.
@@ -322,15 +323,53 @@ class _Cols:
             self._key_rows = np.flatnonzero(self.ok)
         return self._key_rows
 
+    def same_shape(self, other: "_Cols") -> bool:
+        """Row for row comparable: as many rows, words as wide, LFA
+        columns on both or on neither."""
+        return (
+            self.s3w.shape == other.s3w.shape
+            and self.nhw.shape == other.nhw.shape
+            and (self.lfa_slot is None) == (other.lfa_slot is None)
+        )
+
+
+def cols_changed_mask(oc: _Cols, nc: _Cols, rows) -> np.ndarray:
+    """Row-wise column compare between two bundles over `rows` (an index
+    array, or a slice for every row): entry construction is a pure
+    function of these columns (same matrix/links per crib), so
+    byte-equal rows are route-equal."""
+    m = (oc.met[rows] != nc.met[rows])
+    m |= (oc.s3w[rows] != nc.s3w[rows]).any(axis=1)
+    m |= (oc.nhw[rows] != nc.nhw[rows]).any(axis=1)
+    m |= oc.ok[rows] != nc.ok[rows]
+    if oc.lfa_slot is not None and nc.lfa_slot is not None:
+        m |= oc.lfa_slot[rows] != nc.lfa_slot[rows]
+        m |= oc.lfa_metric[rows] != nc.lfa_metric[rows]
+    elif (oc.lfa_slot is None) != (nc.lfa_slot is None):
+        m |= True
+    return m
+
 
 class ColumnarRib:
     """One (area, vantage)'s packed route columns + shared entry cache.
 
-    The solver mutates this in place: `set_full_packed` on a cold
-    rebuild (device-compacted ok rows scattered into fresh columns),
+    The solver mutates this in place: `set_full_packed` on a full
+    result (device-compacted ok rows scattered into fresh columns),
     `apply_rows` on steady-state deltas. Every mutation bumps `epoch`
     and journals the changed row set so two RibView snapshots of the
-    same crib can diff in O(changed)."""
+    same crib can diff in O(changed).
+
+    A full result is a journaled change where a table stands, a reset
+    where none does. Over a standing bundle of the same shape (a warm
+    vantage whose event moved more rows than a delta pull holds) the
+    rows that differ are one journal entry and the entry cache is
+    patched in bulk, so the epoch's diff, digest, provenance stamps and
+    Fib's pass stay on the column path
+    (`decision.crib.full_journaled`; the solver stamps the row count on
+    its `tpu.mat` span as `full_changed_rows`). With no bundle to
+    compare (the first RIB, a new crib) or one of another shape, journal
+    and cache start anew (`decision.crib.full_resets`) and the next
+    diff is the cold one or the entry-level compare."""
 
     def __init__(self, my_node_name: str, matrix, links, root_idx: int,
                  block_v4: bool, use_v4_allowed: bool, lfa: bool):
@@ -385,11 +424,19 @@ class ColumnarRib:
             c._key_rows = None
 
     def set_full_packed(self, rows: np.ndarray, met, s3w, nhw,
-                        lfa_slot=None, lfa_metric=None) -> None:
-        """Cold rebuild from the device-compacted full buffer: `rows` are
-        the ok matrix rows (ascending), the value arrays their gathered
-        packed outputs. Non-ok rows keep zero columns — nothing reads
-        them (ok=False removes them from every view)."""
+                        lfa_slot=None, lfa_metric=None) -> Optional[int]:
+        """A full result from the device-compacted full buffer: `rows`
+        are the ok matrix rows (ascending), the value arrays their
+        gathered packed outputs. Non-ok rows keep zero columns — nothing
+        reads them (ok=False removes them from every view).
+
+        Where a table of the same shape stands, the result lands as a
+        journaled change: the rows whose columns differ from the
+        standing bundle are one device-exact journal entry, the floor
+        and the forced rows stay, and the entry cache is patched as
+        `apply_rows` patches it; returns how many rows changed. Where
+        none stands (the first RIB, a new crib) or the columns' shapes
+        differ, the journal and the cache are reset; returns None."""
         p_n = self.p_n
         keep = rows < p_n
         rows = rows[keep]
@@ -407,13 +454,47 @@ class ColumnarRib:
             c.lfa_metric[rows] = lfa_metric[keep]
         c.ok = np.zeros(p_n, bool)
         c.ok[rows] = True
+        old = self.cols
         self.cols = c  # old bundle stays with whatever views hold it
         self.epoch += 1
-        self.journal_floor = self.epoch
-        self.journal = []
-        self.forced = []
-        self.routes = {}
-        self.materialized = False
+        if old is None or not old.same_shape(c):
+            counters.increment("decision.crib.full_resets")
+            self.journal_floor = self.epoch
+            self.journal = []
+            self.forced = []
+            self.routes = {}
+            self.materialized = False
+            return None
+        # a row that is a route on neither side is no change, whatever
+        # an earlier patch left in its columns
+        changed = np.flatnonzero(
+            cols_changed_mask(old, c, slice(None)) & (old.ok | c.ok)
+        )
+        counters.increment("decision.crib.full_journaled")
+        self.journal.append((self.epoch, changed, True))
+        self._trim_journal()
+        self._refresh_rows(changed)
+        return len(changed)
+
+    def _drop_rows(self, rows: np.ndarray) -> None:
+        """Forget what was built of these rows."""
+        plist = self.matrix.prefix_list
+        pop = self.routes.pop
+        for r in rows.tolist():
+            pop(plist[r], None)
+
+    def _refresh_rows(self, rows: np.ndarray) -> None:
+        """Keep the entry cache coherent over `rows` of the columns as
+        they stand: where it is complete, the rows that are routes built
+        anew in ONE call and the others dropped; where partial, what was
+        built of them dropped."""
+        if self.materialized:
+            ok = self.cols.ok[rows]
+            self._drop_rows(rows[~ok])
+            if ok.any():
+                self._build_rows_into(self.cols, rows[ok], self.routes)
+        elif self.routes:
+            self._drop_rows(rows)
 
     def touch_rows(self, rows, old_names=()) -> None:
         """The matrix changed these rows (`PrefixMatrix.apply_changes`:
@@ -427,17 +508,11 @@ class ColumnarRib:
         rows = np.asarray(rows, np.int64)
         if not len(rows):
             return
-        plist = self.matrix.prefix_list
-        for r in rows.tolist():
-            self.routes.pop(plist[r], None)
         for name in old_names:
             self.routes.pop(name, None)
-        if self.materialized:
-            # complete again from the columns as they stand; what the
-            # device changes of these rows, apply_rows patches after
-            live = rows[self.cols.ok[rows]]
-            if len(live):
-                self._build_rows_into(self.cols, live, self.routes)
+        # from the columns as they stand; what the device changes of
+        # these rows, apply_rows patches after
+        self._refresh_rows(rows)
         self.epoch += 1
         self.journal.append((self.epoch, rows, False))
         self.forced.append((self.epoch, rows))
@@ -451,16 +526,17 @@ class ColumnarRib:
                 self.forced.pop(0)
 
     def set_full_arrays(self, met, s3, nh, lfa_slot=None, lfa_metric=None,
-                        ok=None) -> None:
-        """Cold rebuild from UNPACKED arrays (the sharded fabric path,
-        whose kernel returns bool masks + a device-computed ok)."""
+                        ok=None) -> Optional[int]:
+        """A full result as UNPACKED arrays (the sharded fabric path,
+        whose kernel returns bool masks + a device-computed ok); lands
+        and returns as `set_full_packed`."""
         if ok is None:
             ok = route_ok_rows(
                 self.matrix, self.root_idx, slice(0, len(met)),
                 met, s3, nh, self.block_v4,
             )
         rows = np.flatnonzero(ok)
-        self.set_full_packed(
+        return self.set_full_packed(
             rows, met[rows].astype(np.int32),
             pack_words_host(s3[rows]), pack_words_host(nh[rows]),
             None if lfa_slot is None else lfa_slot[rows].astype(np.int32),
@@ -517,14 +593,11 @@ class ColumnarRib:
         # keep the route cache coherent: eager patch when complete
         # (preserves the seed's O(changed) steady-state cost), row-wise
         # invalidation when partial
-        plist = self.matrix.prefix_list
         if self.materialized:
             if s3 is None:
                 s3 = unpack_words(s3w, a_cap)
                 nhm = unpack_words(nhw, max(d_n, 1))
-            for i, r in enumerate(rows.tolist()):
-                if not ok[i]:
-                    self.routes.pop(plist[r], None)
+            self._drop_rows(rows[~np.asarray(ok, bool)])
             keep = np.flatnonzero(ok)
             if len(keep):
                 build_entries(
@@ -534,8 +607,7 @@ class ColumnarRib:
                     use_v4_allowed=self.use_v4_allowed,
                 )
         elif self.routes:
-            for r in rows.tolist():
-                self.routes.pop(plist[r], None)
+            self._drop_rows(rows)
 
     def apply_rows_packed(self, rows: np.ndarray, met, s3w, nhw, ok,
                           lfa_slot=None, lfa_metric=None) -> None:
@@ -700,6 +772,40 @@ class RibView:
             e = self._routes.get(prefix)
         return e
 
+    def get_many(self, prefixes: list) -> list:
+        """`get(p, bulk=False)` for each prefix, the rows not built yet
+        built in ONE call (a numpy call a row otherwise: thousands after
+        a full result)."""
+        crib = self.crib
+        idx = row_index(crib.matrix)
+        ok = self.cols.ok
+        rows = np.fromiter(
+            (idx.get(p, -1) for p in prefixes), np.int64, len(prefixes)
+        )
+        # a row past this generation's columns was taken after it
+        live = (rows >= 0) & (rows < len(ok))
+        live[live] = ok[rows[live]]
+        if self.current:
+            cache = crib.routes
+            complete = crib.materialized
+        else:
+            if self._routes is None:
+                self._routes = {}
+            cache = self._routes
+            complete = self._forced
+        if not complete:
+            lacking = np.asarray([
+                r for p, r in zip(prefixes, np.where(live, rows, -1).tolist())
+                if r >= 0 and p not in cache
+            ], np.int64)
+            if len(lacking):
+                crib._build_rows_into(self.cols, lacking, cache)
+        get = cache.get
+        return [
+            get(p) if is_route else None
+            for p, is_route in zip(prefixes, live.tolist())
+        ]
+
     def all_routes(self) -> dict:
         if self.current:
             return self.crib.materialize()
@@ -821,6 +927,25 @@ class LazyUnicastRoutes(MutableMapping):
             seg.n_rows() > sum(1 for k in dl if seg.has(k))
             for seg in self.segments
         )
+
+    def lookup_many(self, keys: list) -> list:
+        """`_lookup` of each key, in the same precedence, with no row
+        built singly: Fib's dirty-route pass reads thousands of keys
+        after a full result."""
+        if self._merged is not None:
+            return [self._merged.get(k) for k in keys]
+        out = [self.base.get(k) for k in keys]
+        for seg in self.segments:  # later wins
+            for i, e in enumerate(seg.get_many(keys)):
+                if e is not None:
+                    out[i] = e
+        if self.overrides or self.deleted:
+            for i, k in enumerate(keys):
+                if k in self.deleted:
+                    out[i] = None
+                elif k in self.overrides:
+                    out[i] = self.overrides[k]
+        return out
 
     def snapshot(self) -> "LazyUnicastRoutes":
         """Detached copy sharing the column bundles: fresh RibViews pin

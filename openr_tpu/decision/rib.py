@@ -217,12 +217,21 @@ class ProvenanceLedger:
     def _fold_oldest(self) -> None:
         layer = self._layers.pop(0)
         seq = layer[0]
+        # a newer layer no larger than a few of this one is named once
+        # (its own membership test is a Python call a prefix: thousands
+        # after a full result); a larger one is asked
+        named, asked = set(), []
+        for _, members, *_ in self._layers:
+            if len(members) <= 4 * len(layer[1]):
+                named.update(members)
+            else:
+                asked.append(members)
         for prefix in layer[1]:
+            if prefix in named or any(prefix in m for m in asked):
+                continue  # a newer layer answers for it anyway
             es, _ = self._explicit.get(prefix, (0, None))
             if es > seq:
                 continue
-            if any(prefix in nl[1] for nl in self._layers):
-                continue  # a newer layer answers for it anyway
             self._explicit[prefix] = (seq, self._build(layer, prefix))
 
 

@@ -3588,6 +3588,7 @@ class TpuSpfSolver:
             }
             if mc_info is not None:
                 stats["multichip"] = mc_info
+            full_changed = None
             if full_pull:
                 fbuf = np.asarray(full_buf)
                 t2 = _time.monotonic()
@@ -3602,7 +3603,9 @@ class TpuSpfSolver:
                 if lfa:
                     lfa_slot = fbuf[o:o + p_cap]; o += p_cap
                     lfa_metric = fbuf[o:o + p_cap]
-                crib.set_full_packed(
+                # rows journaled where a table stood, None where the
+                # result reset it (the first RIB, a new crib)
+                full_changed = crib.set_full_packed(
                     oidx[:okc], metric[:okc], s3w[:okc], nhw[:okc],
                     None if lfa_slot is None else lfa_slot[:okc],
                     None if lfa_metric is None else lfa_metric[:okc],
@@ -3752,6 +3755,9 @@ class TpuSpfSolver:
             # Decision loop's first touch O(1)
             stats["ok_rows"] = int(len(crib.cols.key_rows()))
             mat_attrs = {}
+            if full_changed is not None:
+                mat_attrs["full_changed_rows"] = full_changed
+                stats["full_changed_rows"] = full_changed
             if lfa and crib.cols.lfa_slot is not None:
                 # routes the table holds, and those of them that carry a
                 # loop-free alternate

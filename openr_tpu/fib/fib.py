@@ -105,6 +105,15 @@ class RouteState:
             return _lazy_lookup(ur, prefix)
         return ur.get(prefix)
 
+    def unicast_routes_of(self, prefixes: list) -> list:
+        """`unicast_route_of` for many (None where the prefix is no
+        route): a columnar table answers from its row index and builds
+        what it lacks of them in one call, not a row at a time."""
+        ur = self.unicast_routes
+        if isinstance(ur, LazyUnicastRoutes):
+            return ur.lookup_many(prefixes)
+        return [ur.get(p) for p in prefixes]
+
     def unicast_snapshot(self):
         """Publishable snapshot of the desired unicast table: O(1) for
         a columnar table (detached lazy clone), dict copy otherwise."""
@@ -532,16 +541,13 @@ class Fib(Actor):
             ctx, "platform.program.build", parent_id=parent
         )]
 
-        add_prefixes = [
-            p
-            for p, ts in rs.dirty_prefixes.items()
-            if ts <= now and p in rs.unicast_routes
-        ]
-        del_prefixes = [
-            p
-            for p, ts in rs.dirty_prefixes.items()
-            if ts <= now and p not in rs.unicast_routes
-        ]
+        # one read of the desired table per due prefix: its route, or
+        # None where it is to go (a full result dirties thousands)
+        due = [p for p, ts in rs.dirty_prefixes.items() if ts <= now]
+        due_routes = rs.unicast_routes_of(due)
+        add_prefixes = [p for p, e in zip(due, due_routes) if e is not None]
+        del_prefixes = [p for p, e in zip(due, due_routes) if e is None]
+        add_unicast = [e for e in due_routes if e is not None]
         add_labels = [
             l
             for l, ts in rs.dirty_labels.items()
@@ -552,7 +558,6 @@ class Fib(Actor):
             for l, ts in rs.dirty_labels.items()
             if ts <= now and l not in rs.mpls_routes
         ]
-        add_unicast = [rs.unicast_route_of(p) for p in add_prefixes]
         add_mpls = [rs.mpls_routes[l] for l in add_labels]
         programmed = DecisionRouteUpdate(
             type=RouteUpdateType.INCREMENTAL,
@@ -576,9 +581,10 @@ class Fib(Actor):
                 )
             for p in add_prefixes:
                 rs.dirty_prefixes.pop(p, None)
-                programmed.unicast_routes_to_update[p] = (
-                    rs.unicast_route_of(p)
-                )
+            # read again: the table may have moved while the write waited
+            programmed.unicast_routes_to_update.update(
+                zip(add_prefixes, rs.unicast_routes_of(add_prefixes))
+            )
         except FibUpdateError as e:
             ok = False
             for p in add_prefixes:

@@ -36,13 +36,15 @@ from openr_tpu.types import Adjacency, AdjacencyDatabase
 from tests.conftest import run_async
 
 
-def _flap(states, adj_dbs, node, metric):
+def _flap(states, adj_dbs, node, metric, but=()):
+    """Every link of `node` takes `metric`, but those to `but`."""
     victim = next(d for d in adj_dbs if d.this_node_name == node)
     states["0"].update_adjacency_database(
         AdjacencyDatabase(
             this_node_name=node,
             adjacencies=tuple(
-                Adjacency(**{**a.__dict__, "metric": metric})
+                a if a.other_node_name in but
+                else Adjacency(**{**a.__dict__, "metric": metric})
                 for a in victim.adjacencies
             ),
             area="0",
@@ -54,6 +56,12 @@ def _withdraw(states, node):
     states["0"].update_adjacency_database(
         AdjacencyDatabase(this_node_name=node, adjacencies=(), area="0")
     )
+
+
+def _counter(name):
+    from openr_tpu.runtime.counters import counters
+
+    return int(counters.get_counter(name) or 0)
 
 
 # -- diff parity -----------------------------------------------------------
@@ -114,6 +122,114 @@ def test_column_diff_matches_brute_force_through_churn(seed, kw):
     assert engaged >= 3, f"columnar diff engaged only {engaged}/5 steps"
 
 
+@pytest.mark.parametrize("kw", [{}, {"enable_lfa": True}],
+                         ids=["plain", "lfa"])
+@pytest.mark.parametrize("seed", [3, 21, 42])
+def test_full_result_on_a_warm_vantage_is_a_journaled_change(
+    monkeypatch, seed, kw
+):
+    """Property: a full result that lands on a standing table (more rows
+    changed than a delta pull holds: the budget is patched small before
+    the solver is built) is one journal entry, and `calculate_update`
+    over it is the columnar diff — update set, delete set and entries
+    those of the brute-force per-entry compare, the legacy journal diff
+    agreeing, and the epoch's RIB digest that of the same delta taken
+    through the entry path."""
+    import openr_tpu.decision.tpu_solver as ts
+    from openr_tpu.decision.columnar_rib import fast_unicast_diff
+    from openr_tpu.decision.rib import DecisionRouteUpdate
+    from openr_tpu.decision.rib_digest import delta_digest
+
+    monkeypatch.setattr(ts, "_DELTA_BUDGET", 2)
+    rng = np.random.default_rng(seed)
+    adj_dbs, prefix_dbs = topologies.random_mesh(26, seed=seed)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    me = "node-0"
+    by_name = {d.this_node_name: d for d in adj_dbs}
+    near = [a.other_node_name for a in by_name[me].adjacencies]
+    # behind a neighbour, and no neighbour itself: it may leave with the
+    # vantage's own links standing
+    beyond = next(
+        a.other_node_name
+        for n in near for a in by_name[n].adjacencies
+        if a.other_node_name not in (me, *near)
+    )
+    tpu = TpuSpfSolver(me, **kw)
+    resets = _counter("decision.crib.full_resets")
+    db_old = tpu.build_route_db(me, states, ps)
+    crib = db_old.unicast_routes.segments[0].crib
+    # the first RIB has no table to be a change of
+    assert _counter("decision.crib.full_resets") == resets + 1
+    assert crib.journal == [] and crib.journal_floor == crib.epoch
+    floor = crib.journal_floor
+
+    journaled = 0
+    for step in range(6):
+        # a neighbour's other links: the routes through it move, the
+        # vantage's own links stand and so does its crib
+        victim = near[step % len(near)]
+        if step == 4:
+            victim = beyond
+            _withdraw(states, victim)
+        else:
+            _flap(
+                states, adj_dbs, victim, int(rng.integers(2, 40)), but=(me,)
+            )
+        before = {
+            name: _counter(f"decision.crib.{name}")
+            for name in ("full_journaled", "full_resets")
+        }
+        db_new = tpu.build_route_db(me, states, ps)
+        stats = tpu.last_device_stats
+        assert db_new.unicast_routes.segments[0].crib is crib
+        if step == 2:
+            # a host-touched key beside the full result: it takes the
+            # entry path, the rows beside it stay in columns
+            pfx = next(iter(dict(db_new.unicast_routes)))
+            db_new.unicast_routes[pfx] = dataclasses.replace(
+                db_new.unicast_routes[pfx], igp_cost=777_777
+            )
+        upd = db_old.calculate_update(db_new)
+        old_mat = dict(db_old.unicast_routes)
+        new_mat = dict(db_new.unicast_routes)
+        brute_update = {
+            p: e for p, e in new_mat.items()
+            if p not in old_mat or old_mat[p] != e
+        }
+        brute_dels = sorted(p for p in old_mat if p not in new_mat)
+        ctx = f"seed={seed} step={step} victim={victim}"
+        assert upd.columns is not None and not upd.columns.full, ctx
+        assert upd.fast_diff, ctx
+        assert dict(upd.unicast_routes_to_update) == brute_update, ctx
+        assert sorted(upd.unicast_routes_to_delete) == brute_dels, ctx
+        assert fast_unicast_diff(
+            db_old.unicast_routes, db_new.unicast_routes
+        ) == (brute_update, brute_dels), ctx
+        assert delta_digest(upd) == delta_digest(DecisionRouteUpdate(
+            unicast_routes_to_update=brute_update,
+            unicast_routes_to_delete=brute_dels,
+        )), ctx
+        gained = {
+            name: _counter(f"decision.crib.{name}") - n
+            for name, n in before.items()
+        }
+        if stats["full_pull"]:
+            journaled += 1
+            assert gained == {"full_journaled": 1, "full_resets": 0}, ctx
+            assert stats["changed_rows"] > 2, ctx
+            # the rows journaled: those the device counted, but any
+            # that is a route on neither side
+            assert 2 < stats["full_changed_rows"] <= stats["changed_rows"]
+            j_epoch, j_rows, j_exact = crib.journal[-1]
+            assert j_epoch == crib.epoch and j_exact, ctx
+            assert len(j_rows) == stats["full_changed_rows"], ctx
+        else:
+            assert gained == {"full_journaled": 0, "full_resets": 0}, ctx
+        assert crib.journal_floor == floor, ctx
+        db_old = db_new
+    assert journaled >= 3, f"a full result landed in {journaled}/6 steps"
+
+
 def test_column_diff_snapshot_isolated_from_later_churn():
     """The new_mapping a delta carries must keep answering with its own
     generation even after the solver patches the live columns (Fib
@@ -134,15 +250,9 @@ def test_column_diff_snapshot_isolated_from_later_churn():
 # -- the key index stands still across warm epochs -------------------------
 
 
-def _counter(name):
-    from openr_tpu.runtime.counters import counters
-
-    return int(counters.get_counter(name) or 0)
-
-
 def test_warm_epochs_build_no_key_index_and_only_changed_entries():
     """After the first warm epoch, further apply_rows + calculate_update
-    + RouteState.update + Fib's four dirty-set scans build no O(rows)
+    + RouteState.update + Fib's read of its dirty set build no O(rows)
     key structure (decision.crib.key_index_builds stands still) and only
     the changed routes' entries (decision.rib.entries_built)."""
     from openr_tpu.fib.fib import RouteState
@@ -171,17 +281,14 @@ def test_warm_epochs_build_no_key_index_and_only_changed_entries():
         dirty = dict.fromkeys(upd.unicast_routes_to_update, 0.0)
         dirty.update(dict.fromkeys(upd.unicast_routes_to_delete, 0.0))
         now = 1.0
-        # Fib._program_dirty_routes' scans, as written there
-        add_prefixes = [
-            p for p, ts in dirty.items()
-            if ts <= now and p in rs.unicast_routes
-        ]
-        del_prefixes = [
-            p for p, ts in dirty.items()
-            if ts <= now and p not in rs.unicast_routes
-        ]
-        add_unicast = [rs.unicast_route_of(p) for p in add_prefixes]
-        assert all(e is not None for e in add_unicast)
+        # Fib._program_dirty_routes' read of the table, as written there
+        due = [p for p, ts in dirty.items() if ts <= now]
+        due_routes = rs.unicast_routes_of(due)
+        add_prefixes = [p for p, e in zip(due, due_routes) if e is not None]
+        del_prefixes = [p for p, e in zip(due, due_routes) if e is None]
+        assert [p in rs.unicast_routes for p in due] == [
+            e is not None for e in due_routes]
+        assert due_routes == [rs.unicast_route_of(p) for p in due]
         assert sorted(del_prefixes) == sorted(upd.unicast_routes_to_delete)
         assert len(rs.unicast_routes) == int(crib.cols.ok.sum())
         assert (

@@ -257,6 +257,10 @@ def test_lazy_table_arithmetic_matches_materialized(seed, n_seg, stale):
         assert truth == bool(mat)
         assert member == {k: k in mat for k in probe}
         assert found == {k: mat.get(k) for k in probe}
+        # and read in bulk (Fib's dirty-route pass), over a fresh copy:
+        # what it lacks it builds itself
+        assert table.snapshot().lookup_many(probe) == [
+            mat.get(k) for k in probe]
         assert set(table) == set(mat)  # the O(rows) dump agrees too
     assert not lz_empty and len(lz_empty) == 0
 
@@ -296,3 +300,175 @@ def test_stale_view_answers_from_its_own_generation(seed):
     assert (
         counters.get_counter("decision.crib.key_index_builds") or 0
     ) == builds
+
+
+# -- a full result: a journaled change where a table stands ----------------
+
+
+def _counter(name):
+    from openr_tpu.runtime.counters import counters
+
+    return int(counters.get_counter(name) or 0)
+
+
+def _land_full(crib, cols, lfa=True):
+    """`cols`' ok rows as the device's compacted full buffer hands them."""
+    rows = np.flatnonzero(cols.ok)
+    with_lfa = lfa and cols.lfa_slot is not None
+    return crib.set_full_packed(
+        rows, cols.met[rows], cols.s3w[rows], cols.nhw[rows],
+        cols.lfa_slot[rows] if with_lfa else None,
+        cols.lfa_metric[rows] if with_lfa else None,
+    )
+
+
+def _perturbed(cols, rng, n):
+    """A copy of `cols` with 3n rows moved: n withdrawn, n at another
+    metric, n given the next hops (and alternate) of another row. Returns
+    (copy, rows withdrawn, rows whose columns changed and still route)."""
+    c = cols.copy()
+    gone, dearer, swapped, donors = (
+        rng.choice(np.flatnonzero(cols.ok), 4 * n, replace=False)
+        .reshape(4, n)
+    )
+    c.ok[gone] = False
+    for col in (c.met, c.s3w, c.nhw):
+        col[gone] = 0
+    c.met[dearer] += 7
+    c.nhw[swapped] = cols.nhw[donors]
+    if c.lfa_slot is not None:
+        c.lfa_slot[gone] = -1
+        c.lfa_metric[gone] = 0
+        c.lfa_slot[swapped] = cols.lfa_slot[donors]
+        c.lfa_metric[swapped] = cols.lfa_metric[donors]
+    moved = np.concatenate([dearer, swapped])
+    differs = (c.met[moved] != cols.met[moved]) | (
+        c.nhw[moved] != cols.nhw[moved]).any(axis=1)
+    if c.lfa_slot is not None:
+        differs |= c.lfa_slot[moved] != cols.lfa_slot[moved]
+        differs |= c.lfa_metric[moved] != cols.lfa_metric[moved]
+    return c, np.sort(gone), np.sort(moved[differs])
+
+
+def _fresh(crib):
+    """The tip's bundle built row for row, beside every cache."""
+    routes = {}
+    crib._build_rows_into(crib.cols, crib.cols.key_rows(), routes)
+    return routes
+
+
+def _lfa_view(seed, lfa):
+    adj_dbs, prefix_dbs = topologies.random_mesh(20, seed=seed)
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    db = TpuSpfSolver("node-0", enable_lfa=lfa).build_route_db(
+        "node-0", states, ps
+    )
+    return db.unicast_routes.segments[0]
+
+
+@pytest.mark.parametrize("lfa", [False, True], ids=["plain", "lfa"])
+@pytest.mark.parametrize("warm", [False, True], ids=["lazy", "materialized"])
+@pytest.mark.parametrize("seed", [5, 23])
+def test_full_result_over_a_standing_table(seed, warm, lfa):
+    """The journal gets one exact entry of the rows that differ, floor
+    and forced rows stand, a view taken before reads its own bundle, and
+    the entry cache ends equal to a fresh build of the new bundle (all of
+    it where the crib was materialized, what it holds where not) - with
+    the changed rows built in one call."""
+    from openr_tpu.decision.column_delta import fast_unicast_column_diff
+
+    rng = np.random.default_rng(seed)
+    view0 = _lfa_view(seed, lfa)
+    crib = view0.crib
+    assert (crib.cols.lfa_slot is not None) == lfa
+    plist = crib.matrix.prefix_list
+    before = dict(_fresh(crib))
+    if warm:
+        crib.materialize()
+    else:
+        for r in view0.key_rows()[:6].tolist():  # a partial cache
+            crib.entry_for_row(r)
+    old = LazyUnicastRoutes({}, [view0])
+    new_cols, gone, moved = _perturbed(crib.cols, rng, 3)
+    # an advertisement changed on a row the result leaves as it was
+    still = next(
+        int(r) for r in view0.key_rows()
+        if r not in gone and r not in moved
+    )
+    crib.touch_rows([still])
+    floor, epoch = crib.journal_floor, crib.epoch
+    counts = (_counter("decision.crib.full_journaled"),
+              _counter("decision.crib.full_resets"))
+    built = []
+    real_build = crib._build_rows_into
+    crib._build_rows_into = lambda c, rows, out: (
+        built.append(len(rows)), real_build(c, rows, out))[1]
+    try:
+        assert _land_full(crib, new_cols) == len(gone) + len(moved)
+    finally:
+        del crib._build_rows_into
+    assert built == ([len(moved)] if warm else [])
+    assert (_counter("decision.crib.full_journaled"),
+            _counter("decision.crib.full_resets")) == (
+                counts[0] + 1, counts[1])
+    assert (crib.journal_floor, crib.epoch) == (floor, epoch + 1)
+    j_epoch, j_rows, j_exact = crib.journal[-1]
+    assert j_epoch == crib.epoch and j_exact
+    assert j_rows.tolist() == sorted([*gone.tolist(), *moved.tolist()])
+    assert crib.forced_rows_since(view0.epoch).tolist() == [still]
+    # the view from before: its own generation, entry for entry
+    assert not view0.current and view0.cols is not crib.cols
+    assert dict(view0.all_routes()) == before
+    # the cache: the new bundle's entries, no row of the old one left
+    fresh = _fresh(crib)
+    assert set(fresh) == set(before) - {plist[r] for r in gone.tolist()}
+    if warm:
+        assert crib.materialized and crib.routes == fresh
+    else:
+        assert not crib.materialized
+        assert crib.routes == {p: fresh[p] for p in crib.routes}
+        assert not {plist[r] for r in j_rows.tolist()} & set(crib.routes)
+    # the diff over it stays in columns and sends the forced row too
+    new = LazyUnicastRoutes({}, [crib.view()])
+    delta = fast_unicast_column_diff(old, new)
+    assert delta is not None and not delta.full
+    assert sorted(delta.segments[0][1].tolist()) == sorted(
+        [*moved.tolist(), still])
+    assert sorted(delta.deletes) == sorted(plist[r] for r in gone.tolist())
+    upd, dels = fast_unicast_diff(old, new)
+    assert dict(delta.lazy_map()) == upd and delta.deletes == dels
+    assert plist[still] in upd and upd[plist[still]] == before[plist[still]]
+
+
+@pytest.mark.parametrize("case", ["first_rib", "lfa_vanishes", "lfa_appears"])
+def test_full_result_with_no_table_to_compare_resets(case):
+    """The first RIB of a crib, and a bundle whose LFA columns appear or
+    vanish, reset the journal, the floor, the forced rows and the cache
+    as every full result did."""
+    resets = _counter("decision.crib.full_resets")
+    journaled = _counter("decision.crib.full_journaled")
+    view0 = _lfa_view(9, lfa=case != "lfa_appears")
+    crib = view0.crib
+    assert _counter("decision.crib.full_resets") == resets + 1
+    if case != "first_rib":
+        crib.materialize()
+        cols = crib.cols.copy()
+        if case == "lfa_appears":
+            cols.lfa_slot = np.full(crib.p_n, -1, np.int32)
+            cols.lfa_metric = np.zeros(crib.p_n, np.int32)
+        crib.touch_rows(view0.key_rows()[:2])
+        assert crib.journal and crib.forced
+        assert _land_full(crib, cols, lfa=case == "lfa_appears") is None
+        assert _counter("decision.crib.full_resets") == resets + 2
+        assert (crib.cols.lfa_slot is not None) == (case == "lfa_appears")
+    assert _counter("decision.crib.full_journaled") == journaled
+    assert crib.journal == [] and crib.forced == []
+    assert crib.journal_floor == crib.epoch
+    assert crib.routes == {} and not crib.materialized
+    assert not crib.covers(crib.epoch - 1)
+    if case != "first_rib":
+        assert not view0.current
+        assert fast_unicast_diff(
+            LazyUnicastRoutes({}, [view0]),
+            LazyUnicastRoutes({}, [crib.view()]),
+        ) is None

@@ -3,6 +3,8 @@
 
 import asyncio
 
+import pytest
+
 from openr_tpu.config import FibConfig
 from openr_tpu.decision.rib import (
     DecisionRouteUpdate,
@@ -206,3 +208,121 @@ class TestFibPerf:
             descrs = [e.event_descr for e in perf_db[0].events]
             assert "FIB_RECEIVED" in descrs
             assert "FIB_PROGRAMMED" in descrs
+
+
+class _ColumnsService(MockFibService):
+    """Takes the full sync as packed columns, so that no route object is
+    built for it and the crib behind the table stays unmaterialized."""
+
+    supports_columns = True
+
+    async def sync_fib_columns(self, client_id, batch) -> None:
+        self._note("sync_fib_columns", batch.route_count())
+        self.sync_count += 1
+        self.unicast = dict.fromkeys(batch.prefix_set())
+
+
+class TestFibFullResult:
+    """A full result's delta (thousands of routes, ISSUE 45) through
+    process_decision_route_update and _program_dirty_routes."""
+
+    @pytest.mark.parametrize("service", [MockFibService, _ColumnsService],
+                             ids=["entries", "columns"])
+    def test_programs_the_brute_force_set_and_builds_no_row_singly(
+        self, monkeypatch, service
+    ):
+        import numpy as np
+
+        import openr_tpu.decision.tpu_solver as ts
+        from openr_tpu.decision.columnar_rib import ColumnarRib
+        from openr_tpu.decision.rib import DecisionRouteDb
+        from openr_tpu.decision.tpu_solver import TpuSpfSolver
+        from openr_tpu.models import topologies
+        from openr_tpu.types import Adjacency, AdjacencyDatabase
+
+        monkeypatch.setattr(ts, "_DELTA_BUDGET", 64)
+        adj_dbs, prefix_dbs = topologies.grid(48, node_labels=False)
+        states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+        me = "node-24-24"
+        tpu = TpuSpfSolver(me)
+        db = tpu.build_route_db(me, states, ps)
+        crib = db.unicast_routes.segments[0].crib
+        built = []
+        real_build = ColumnarRib._build_rows_into
+
+        def counted(self, cols, rows, routes):
+            built.append(len(rows))
+            return real_build(self, cols, rows, routes)
+
+        monkeypatch.setattr(ColumnarRib, "_build_rows_into", counted)
+
+        @run_async
+        async def drive():
+            nonlocal db
+            h = FibHarness()
+            h.service = h.fib.service = service()
+            h.fib._retry_signal = asyncio.Event()
+            first = DecisionRouteDb().calculate_update(db)
+            first.type = RouteUpdateType.FULL_SYNC
+            await h.fib.process_decision_route_update(first)
+            assert h.fib.route_state.state == FibState.SYNCED
+            assert crib.materialized == (service is MockFibService)
+            del built[:]  # the first sync's own build, where it made one
+            # the vantage's neighbour to the north takes dear links, but
+            # the one to the vantage: half the grid's routes move
+            victim = next(d for d in adj_dbs if d.this_node_name == "node-23-24")
+            states["0"].update_adjacency_database(AdjacencyDatabase(
+                this_node_name=victim.this_node_name,
+                adjacencies=tuple(
+                    a if a.other_node_name == me
+                    else Adjacency(**{**a.__dict__, "metric": 9})
+                    for a in victim.adjacencies
+                ),
+                area="0",
+            ))
+            new_db = tpu.build_route_db(me, states, ps)
+            assert tpu.last_device_stats["full_pull"]
+            assert new_db.unicast_routes.segments[0].crib is crib
+            # the landing's bulk patch, where the crib held every entry
+            n_changed = tpu.last_device_stats["full_changed_rows"]
+            assert n_changed > 500
+            assert built == (
+                [n_changed] if service is MockFibService else [])
+            del built[:]
+            upd = db.calculate_update(new_db)
+            upd.type = RouteUpdateType.INCREMENTAL
+            assert upd.columns is not None
+            await h.fib.process_decision_route_update(upd)
+            await h.fib._program_dirty_routes()
+            by_fib = list(built)
+            # the oracle's tables last: they build every row
+            return (dict(db.unicast_routes), dict(new_db.unicast_routes),
+                    h, upd, by_fib)
+
+        old_mat, new_mat, h, upd, built = drive()
+        brute = {
+            p: e for p, e in new_mat.items()
+            if p not in old_mat or old_mat[p] != e
+        }
+        assert len(brute) > 500 and len(brute) == len(
+            upd.unicast_routes_to_update)
+        assert ("add_unicast", len(brute)) in h.service.call_log
+        assert not any(op == "del_unicast" for op, _ in h.service.call_log)
+        programmed = {
+            p: e for p, e in h.service.unicast.items() if e is not None
+        }
+        if service is MockFibService:
+            assert programmed == new_mat
+            assert built == []  # every entry found in the patched cache
+        else:
+            assert programmed == brute
+            assert built == [len(brute)]  # what it lacked, in one call
+        assert 1 not in built
+        assert not h.fib.route_state.dirty_prefixes
+        ack = None
+        while h.fib_reader.size():
+            item = h.fib_reader.try_get()[1]
+            if isinstance(item, DecisionRouteUpdate):
+                ack = item
+        assert ack is not None
+        assert ack.unicast_routes_to_update == brute

@@ -18,7 +18,10 @@ candidate rows — the prefix-only ones since PR 42, and since PR 44 the
 incremental solves that found their moved node columns to reach no more
 rows than a delta pull holds — and those rows; `wide_epochs`, the
 incremental solves that looked at every row, and `wide_epochs.<reason>`)
-and `decision.crib.key_index_builds` gained over that window. A
+and `decision.crib.key_index_builds` gained over that window, with how
+the window's full results landed (`decision.crib.full_journaled`,
+`.full_resets`) and how many of its diffs were warm and columnar or
+journal-bounded (`decision.fast_unicast_diffs`). A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
 """
@@ -36,12 +39,16 @@ sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 import run  # noqa: E402  (stamps T_PROCESS)
 
 # of `decision.tpu.*`, the counters (the rest are gauges of the mirror),
-# and the one that counts O(rows) key structures built
+# the one that counts O(rows) key structures built, and how full results
+# landed in the columnar RIB and were diffed
 WINDOW_COUNTERS = tuple(f"decision.tpu.{name}" for name in (
     "epochs", "cold_compactions", "cone_passes", "cone_skips",
     "prefix_rows_changed", "prefix_only_epochs", "prefix_matrix_rebuilds",
     "candidate_epochs", "candidate_rows", "wide_epochs",
-)) + ("decision.crib.key_index_builds",)
+)) + (
+    "decision.crib.key_index_builds", "decision.crib.full_journaled",
+    "decision.crib.full_resets", "decision.fast_unicast_diffs",
+)
 WIDE_REASONS = "decision.tpu.wide_epochs."
 
 
