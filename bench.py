@@ -553,14 +553,14 @@ def _budget_summary(rows: list) -> dict:
 
 def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
                     flap_victims=8, small_graph_nodes=0, **solver_kw):
-    """Sustained flap-storm churn lane (streaming pipeline, ISSUE 16):
-    paced single-victim metric flaps at rate_hz through a
-    streaming_pipeline=True solver, each epoch's RIB delta programmed
-    into the mock FibService — churn-to-FIB-ack is flap-apply ->
-    programming ack, per-epoch download is last_timing's
-    bytes_downloaded (proportional to changed rows, not n). The closing
-    idle epoch (no flap) pins the standstill property: zero changed
-    rows, download still exactly one within-budget streaming payload."""
+    """Sustained flap-storm churn lane (ISSUE 16): paced single-victim
+    metric flaps at rate_hz through an incremental_spf=True solver, each
+    epoch's RIB delta programmed into the mock FibService —
+    churn-to-FIB-ack is flap-apply -> programming ack, per-epoch
+    download is last_timing's bytes_downloaded (one delta payload, not
+    the table). The closing idle epoch (no flap) pins the standstill
+    property: zero changed rows, download still exactly one delta
+    payload."""
     import asyncio as _asyncio
 
     from openr_tpu.decision.tpu_solver import TpuSpfSolver
@@ -576,20 +576,19 @@ def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
         f"({time.perf_counter() - t0:.1f}s build)")
 
     tpu = TpuSpfSolver(me, small_graph_nodes=small_graph_nodes,
-                       streaming_pipeline=True, **solver_kw)
+                       incremental_spf=True, **solver_kw)
     db = tpu.build_route_db(me, states, ps)  # cold seed: full pull
     full_bytes = int(
         getattr(tpu, "last_timing", {}).get("bytes_downloaded") or 0
     )
-    # warm the streamed epoch executable before pacing starts — the
+    # warm the incremental executable before pacing starts — the
     # storm measures steady-state churn, not the one-time jit compile
     _flap(states, adj_dbs, [1], 7919, area)
     db = tpu.build_route_db(me, states, ps)
     from openr_tpu.runtime.counters import counters as _counters
 
-    # post-boot retraces over the storm (summed across namespaces, so
-    # the new "stream" namespace is covered): a warm steady state must
-    # report 0 — the smoke test gates on it
+    # post-boot retraces over the storm (summed across namespaces): a
+    # warm steady state must report 0 — the smoke test gates on it
     retrace0 = sum(_counters.get_counters("xla_cache.retraces.").values())
     svc = MockFibService()
     victims = list(range(1, flap_victims + 1))
@@ -615,7 +614,7 @@ def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
 
     async def _storm():
         nonlocal db
-        acks, dl_bytes, rows, engaged, overflows = [], [], [], 0, 0
+        acks, dl_bytes, rows = [], [], []
         budget_rows, dig_ms, depths = [], [], []
         rolling = GENESIS
         dispatch = getattr(tpu, "dispatch_route_db", None)
@@ -691,26 +690,19 @@ def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
             db = new_db
             tm = getattr(tpu, "last_timing", {})
             dl_bytes.append(int(tm.get("bytes_downloaded") or 0))
-            st = tm.get("stream") or {}
-            if st.get("epochs"):
-                engaged += 1
-                overflows += int(st.get("overflows") or 0)
-            rows.append(int(st.get("changed_rows") or 0))
+            rows.append(int(tpu.last_device_stats.get("changed_rows") or 0))
         wall_s = time.perf_counter() - start
-        return (
-            acks, dl_bytes, rows, engaged, overflows, wall_s,
-            budget_rows, dig_ms, depths,
-        )
+        return acks, dl_bytes, rows, wall_s, budget_rows, dig_ms, depths
 
-    (acks, dl_bytes, rows, engaged, overflows, wall_s, budget_rows,
-     dig_ms, depths) = _asyncio.run(_storm())
-    # idle epoch: nothing changed since the last solve — the streaming
+    (acks, dl_bytes, rows, wall_s, budget_rows, dig_ms,
+     depths) = _asyncio.run(_storm())
+    # idle epoch: nothing changed since the last solve — the delta
     # payload still ships (count=0), so the download stands still at
-    # exactly one within-budget payload
+    # exactly one payload
     tpu.build_route_db(me, states, ps)
     tm = getattr(tpu, "last_timing", {})
     idle_bytes = int(tm.get("bytes_downloaded") or 0)
-    idle_rows = int((tm.get("stream") or {}).get("changed_rows") or 0)
+    idle_rows = int(tpu.last_device_stats.get("changed_rows") or 0)
 
     sa, sb = sorted(acks), sorted(dl_bytes)
     res = {
@@ -726,8 +718,6 @@ def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
         "idle_bytes_downloaded": idle_bytes,
         "idle_changed_rows": idle_rows,
         "changed_rows_max": max(rows) if rows else 0,
-        "stream_engaged": engaged,
-        "stream_overflows": overflows,
         "fib_routes": len(svc.unicast),
         "retraces": int(
             sum(_counters.get_counters("xla_cache.retraces.").values())
@@ -757,8 +747,7 @@ def bench_flapstorm(name, gen, me, events=100, rate_hz=100.0,
     log(f"[{name}] flapstorm: ack p50 {res['ack_p50_ms']} / p99 "
         f"{res['ack_p99_ms']} ms at {res['achieved_rate_hz']} ev/s "
         f"(asked {rate_hz}) / dl {res['bytes_downloaded_per_epoch']} B "
-        f"per epoch (full {full_bytes} B) / idle {idle_bytes} B "
-        f"/ engaged {engaged}/{events}")
+        f"per epoch (full {full_bytes} B) / idle {idle_bytes} B")
     if dig_ms:
         log(f"[{name}] rib digest: p50 {res['rib_digest_p50_ms']} / p99 "
             f"{res['rib_digest_p99_ms']} ms "
@@ -1082,18 +1071,6 @@ def main() -> None:
             "node-16-16",
         )
 
-    # streaming churn lane at 1k (CI-friendly size, same code path as
-    # the 100k headline below): runs only when named — the quick CI
-    # gate calls `--only=flapstorm_tg1k` and perf_diffs the committed
-    # BENCH_FLAPSTORM baseline
-    if only == "flapstorm_tg1k":
-        configs["flapstorm_tg1k"] = bench_flapstorm(
-            "flapstorm_tg1k",
-            lambda: topologies.grid(32, node_labels=False),
-            "node-16-16", events=60, rate_hz=100.0,
-        )
-        _ledger_record("flapstorm_tg1k", configs["flapstorm_tg1k"])
-
     # cold-start lane: boot-to-first-RIB through the full node stack
     # (skipped in --only runs that name another config)
     if only in (None, "boot"):
@@ -1162,9 +1139,8 @@ def main() -> None:
         headline = ("full_rib_recompute_100k_ms", r5[1], r5[2])
 
     # 5a: sustained flap storm at the 100k headline scale — the
-    # streaming pipeline's churn-to-FIB-ack distribution and per-epoch
-    # download (ISSUE 16 acceptance: p99 < 25 ms on the TPU rig, bytes
-    # proportional to changed rows)
+    # incremental path's churn-to-FIB-ack distribution and per-epoch
+    # download
     if only in (None, "flapstorm100k"):
         configs["flapstorm100k"] = bench_flapstorm(
             "flapstorm100k",
@@ -1316,16 +1292,11 @@ def main() -> None:
             "boot_first_rib_ms_warmcache"
         ),
         "aot_hit_rate": configs.get("boot", {}).get("aot_hit_rate"),
-        # streaming-churn headline (ISSUE 16): flap-apply -> FIB ack
-        # p99 under a sustained 100-events/s storm at 100k, plus the
-        # changed-rows-proportional per-epoch download beside the full
-        # plane it replaces
+        # churn headline (ISSUE 16): flap-apply -> FIB ack p99 under a
+        # sustained 100-events/s storm at 100k
         "churn_to_fib_ack_p99_ms_100k": configs.get(
             "flapstorm100k", {}
         ).get("ack_p99_ms"),
-        "stream_bytes_per_epoch_100k": configs.get(
-            "flapstorm100k", {}
-        ).get("bytes_downloaded_per_epoch"),
         "rtt_note": "e2e = device_ms + host sync/mat + rig_rtt_ms (the machine's fixed host<->device round trip)",
         "configs": configs,
     }))

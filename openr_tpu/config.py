@@ -227,18 +227,6 @@ class DecisionConfig:
     # the guard is a triage lever, not a production setting
     # (docs/Operations.md).
     transfer_guard: str = "off"
-    # streaming churn pipeline (decision/tpu_solver.py + ops/stream.py):
-    # fuse incremental relax, best-route selection, and the column diff
-    # against the previous epoch's device-resident published planes into
-    # one dispatch that downloads only a compacted changed-rows payload,
-    # and let the dispatch fiber admit the next coalesced LSDB delta
-    # while the previous epoch's FIB program is still in flight (epoch
-    # fence keeps acks/provenance attributed to the right epoch). Falls
-    # back per dispatch to the full-materialization path on first solve,
-    # shape/matrix churn, or CPU failover. Off = exactly the PR 12 path
-    # — the first bisection step for a streaming regression
-    # (docs/Operations.md).
-    streaming_pipeline: bool = False
     # input black-box recorder (runtime/replay_log.py): always-on
     # bounded ring of every publication delta Decision consumes +
     # periodic LSDB snapshot anchors + the per-epoch RIB digest ledger,
@@ -813,11 +801,6 @@ class Config:
         if dc.transfer_guard not in ("off", "log", "disallow"):
             raise ConfigError(
                 f"unknown transfer_guard {dc.transfer_guard!r}"
-            )
-        if not isinstance(dc.streaming_pipeline, bool):
-            raise ConfigError(
-                f"decision streaming_pipeline must be a bool, got "
-                f"{dc.streaming_pipeline!r}"
             )
         if not isinstance(dc.replay_recorder, bool):
             raise ConfigError(

@@ -319,7 +319,7 @@ class CtrlServer(Actor):
         }
         # device-kernel rows for the LAST solve, whatever its shape —
         # solver.last_timing is refreshed by every device collect
-        # (full, incremental seed-from-previous, streamed epoch), so
+        # (full, incremental seed-from-previous, prefix-only), so
         # these render after an incremental solve too, where the
         # windowed stats above can have already aged out
         solver = (
@@ -336,9 +336,6 @@ class CtrlServer(Actor):
                           "bytes_uploaded", "bytes_downloaded")
                 if tm.get(k) is not None
             }
-            # streamed churn epochs: budget use + changed-rows download
-            if isinstance(tm.get("stream"), dict):
-                last["stream"] = tm["stream"]
             out["solver"]["last_solve"] = last
             # windowed decision.device.* stats age out during idle (the
             # sample ring only answers for the trailing windows) and the

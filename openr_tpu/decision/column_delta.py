@@ -558,9 +558,9 @@ def fast_unicast_column_diff(old, new) -> Optional[ColumnDelta]:
             segments.append((sn, np.zeros(0, np.int64)))
             continue
         if crib.exact_since(so.epoch):
-            # streaming steady state: the journal entry came from the
-            # on-device column diff (apply_rows_packed), so its row set
-            # is exactly the changed set — no host re-compare needed
+            # one journaled full result (set_full_packed compared the
+            # two bundles): its row set is exactly the changed set — no
+            # host re-compare needed
             changed = jrows
         else:
             mask = cols_changed_mask(oc, nc, jrows)

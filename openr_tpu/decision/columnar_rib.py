@@ -393,10 +393,10 @@ class ColumnarRib:
         # oldest epoch the journal can still diff against; reset by
         # set_full_packed and by journal trimming
         self.journal_floor = 0
-        # (epoch, rows, exact): `exact` marks a device-exact entry —
-        # the row set IS the set of rows whose columns differ from the
-        # previous epoch (the streaming pipeline's on-device diff), not
-        # a superset a consumer must re-compare
+        # (epoch, rows, exact): `exact` marks an entry whose row set IS
+        # the set of rows whose columns differ from the previous epoch
+        # (a journaled full result: set_full_packed compared the two
+        # bundles), not a superset a consumer must re-compare
         self.journal: list[tuple[int, np.ndarray, bool]] = []
         # (epoch, rows) whose advertisement changed in the matrix: an
         # update of the diff whatever the columns say (the entry's
@@ -544,15 +544,13 @@ class ColumnarRib:
         )
 
     def apply_rows(self, rows: np.ndarray, met, s3w, nhw,
-                   lfa_slot=None, lfa_metric=None, ok=None,
-                   exact: bool = False) -> None:
+                   lfa_slot=None, lfa_metric=None, ok=None) -> None:
         """Steady-state delta: patch the changed rows in place (after
         copy-on-write if a snapshot is watching). When `ok` is None
-        (classic delta payload) the route-level filter is recomputed
+        (the delta payload) the route-level filter is recomputed
         host-side, which costs an unpack of both word planes; a caller
-        holding the device route-ok bit (apply_rows_packed) passes it
-        in and the unpack only happens if the eager route cache needs
-        the masks."""
+        holding the rows' route-ok bits passes them in and the unpack
+        only happens if the eager route cache needs the masks."""
         rows = np.asarray(rows)
         live = rows < self.p_n
         if not live.all():
@@ -588,7 +586,7 @@ class ColumnarRib:
         c.ok[rows] = ok
         c._key_rows = None
         self.epoch += 1
-        self.journal.append((self.epoch, np.asarray(rows), exact))
+        self.journal.append((self.epoch, np.asarray(rows), False))
         self._trim_journal()
         # keep the route cache coherent: eager patch when complete
         # (preserves the seed's O(changed) steady-state cost), row-wise
@@ -609,19 +607,6 @@ class ColumnarRib:
         elif self.routes:
             self._drop_rows(rows)
 
-    def apply_rows_packed(self, rows: np.ndarray, met, s3w, nhw, ok,
-                          lfa_slot=None, lfa_metric=None) -> None:
-        """Streaming-epoch delta (ops/stream.py payload): the device
-        route-ok bit arrives with the rows, so the patch is pure column
-        writes — no host word-unpack, no route_ok_rows recompute — and
-        the journal entry is device-exact: the row set is EXACTLY the
-        rows whose columns differ from the previous epoch, which lets
-        fast_unicast_column_diff skip its re-compare (exact_since)."""
-        self.apply_rows(
-            rows, met, s3w, nhw, lfa_slot, lfa_metric,
-            ok=np.asarray(ok, bool), exact=True,
-        )
-
     # -- reads (view side) -------------------------------------------------
 
     def covers(self, epoch: int) -> bool:
@@ -640,14 +625,13 @@ class ColumnarRib:
         return np.unique(np.concatenate(parts))
 
     def exact_since(self, epoch: int) -> bool:
-        """True iff the journal from `epoch` to the tip is ONE
-        device-exact entry — the streaming steady state, one epoch per
-        solve. The on-device diff is exact against the IMMEDIATELY
-        preceding epoch only: across several epochs the union may hold
-        rows that changed and changed back, which only a host
-        re-compare filters out. When this holds,
-        fast_unicast_column_diff consumes changed_rows_since verbatim
-        instead of re-comparing the columns."""
+        """True iff the journal from `epoch` to the tip is ONE exact
+        entry — a journaled full result and nothing after it. Its row
+        set is exact against the IMMEDIATELY preceding epoch only:
+        across several epochs the union may hold rows that changed and
+        changed back, which only a host re-compare filters out. When
+        this holds, fast_unicast_column_diff consumes changed_rows_since
+        verbatim instead of re-comparing the columns."""
         entries = [x for e, _r, x in self.journal if e > epoch]
         return len(entries) == 1 and entries[0]
 

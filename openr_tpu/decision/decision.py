@@ -106,8 +106,8 @@ class PendingUpdates:
     # replay recorder (runtime/replay_log.py): the event-ring cursor at
     # this batch's solve-read and, when a snapshot anchor came due, the
     # pending anchor — both captured in _begin_rebuild and committed in
-    # _finish_rebuild, riding the batch so overlapped streaming epochs
-    # keep their own boundaries
+    # _finish_rebuild, riding the batch so an epoch whose solve leaves
+    # the loop (async_dispatch) keeps its own boundaries
     replay_cursor: int = 0
     replay_snapshot: Optional[dict] = None
     # time.monotonic() of the first rebuild trigger this batch raised
@@ -144,8 +144,7 @@ _DEVICE_SOLVER_KWARGS = (
     "xla_cache_dir", "enable_numerical_sentinels", "fuse_n_cap",
     "incremental_spf", "incremental_cone_frac",
     "multichip_n_cap_threshold", "multichip_batch", "spf_kernel",
-    "transfer_guard", "streaming_pipeline", "aot_cache_dir",
-    "aot_speculate",
+    "transfer_guard", "aot_cache_dir", "aot_speculate",
 )
 
 
@@ -231,9 +230,6 @@ class Decision(Actor):
             skw.setdefault("multichip_batch", config.multichip_batch)
             skw.setdefault("spf_kernel", config.spf_kernel)
             skw.setdefault("transfer_guard", config.transfer_guard)
-            skw.setdefault(
-                "streaming_pipeline", config.streaming_pipeline
-            )
             # "" -> opt-in via $OPENR_TPU_AOT_CACHE (ops/xla_cache.py)
             skw.setdefault("aot_cache_dir", config.aot_cache_dir or None)
             skw.setdefault("aot_speculate", config.aot_speculate)
@@ -329,19 +325,6 @@ class Decision(Actor):
         # past the watermark; the batch re-enqueues after the next
         # solve completes (work is folded, never dropped)
         self._shed_overflow: Optional[PendingUpdates] = None
-        # streaming-pipeline epoch overlap: with
-        # cfg.streaming_pipeline + async_dispatch, epoch N's finish
-        # (RIB diff, provenance stamp, FIB push) runs as a deferred
-        # loop task chained on the previous finish, so the dispatch
-        # fiber may admit epoch N+1's coalesced delta while N's
-        # netlink program is still in flight. _fence_gen is the epoch
-        # fence: bumped whenever the world a deferred finish solved
-        # against may no longer hold (dispatch-fiber crash, degraded
-        # failover) — a finish whose captured fence is stale discards
-        # itself instead of programming a stale batch.
-        self._fence_gen = 0
-        self._stream_finish: Optional[asyncio.Task] = None
-        self._finish_done_t = 0.0
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -376,20 +359,12 @@ class Decision(Actor):
         on the loop), but batched/queued pending updates may have been
         lost — force a full rebuild so the next debounce re-derives
         routes from scratch."""
-        if task_name.endswith(".dispatch"):
-            # the crash orphans any deferred streaming finish still
-            # chained on the loop: its solve predates whatever state
-            # the fiber lost, so fence it out — it must not program a
-            # batch over the full rebuild forced below
-            self._fence_gen += 1
         self.pending.needs_full_rebuild = True
         self._trigger_rebuild()
 
     async def on_stop(self) -> None:
         if self._rebuild_debounced is not None:
             self._rebuild_debounced.cancel()
-        if self._stream_finish is not None:
-            self._stream_finish.cancel()
         if self._degraded:
             # the device-probe timer dies with the actor's loop, so a
             # stopped Decision can never promote — don't leave the
@@ -857,15 +832,7 @@ class Decision(Actor):
     async def _rebuild_async(self, pending: PendingUpdates) -> None:
         """Dispatch-fiber rebuild: identical to _rebuild except the full
         solve's one blocking host sync runs off-loop (_solve_full_async),
-        so LSDB ingestion continues during the device round trip.
-
-        With the streaming pipeline on, the finish itself (RIB diff,
-        provenance, FIB push) also leaves the dispatch fiber: it defers
-        onto the loop chained behind the previous epoch's finish, so the
-        fiber loops back to admit the next coalesced LSDB delta while
-        the previous epoch's netlink program is still in flight. Only
-        finishes overlap — dispatch N+1 never starts before collect N
-        (the solver's vantage state is single-flight by construction)."""
+        so LSDB ingestion continues during the device round trip."""
         ctx, spf_sp, full, t0 = self._begin_rebuild(pending)
         if full or self._device_serves(pending, spf_sp):
             new_db = await self._solve_full_async(ctx, spf_sp)
@@ -874,94 +841,7 @@ class Decision(Actor):
             bud = latency_budget.of_trace(ctx)
             if bud is not None:
                 bud.advance("device_exec")
-        if (
-            self.cfg.streaming_pipeline
-            and full
-            and not self._degraded
-            and new_db is not None
-            # brownout rung: past brownout the epoch-finish overlap is
-            # surrendered — each finish lands before the next dispatch,
-            # trading throughput for a bounded in-flight footprint
-            and (self._overload is None or self._overload.streaming_allowed())
-        ):
-            self._defer_finish(pending, ctx, spf_sp, t0, new_db, full)
-            return
-        # non-overlapping finish: drain the chain first — the diff in
-        # _finish_rebuild runs against self.route_db, which a deferred
-        # predecessor still owns until it lands
-        if self._stream_finish is not None:
-            try:
-                await self._stream_finish
-            # lint: allow(broad-except) predecessor already logged it
-            except Exception:  # pragma: no cover - logged at source
-                pass
-            bud = latency_budget.of_trace(ctx)
-            if bud is not None:
-                bud.advance("fence_hold")
         self._finish_rebuild(pending, ctx, spf_sp, t0, new_db, full)
-
-    def _defer_finish(
-        self, pending: PendingUpdates, ctx, spf_sp, t0, new_db, full
-    ) -> None:
-        """Queue epoch N's finish as a loop task behind epoch N-1's.
-        Finishes stay strictly ordered (each awaits its predecessor), so
-        acks and provenance stamps attribute to the right epoch; the
-        captured fence generation lets a finish whose world moved on
-        (fiber restart, degraded flip) discard itself and requeue a
-        full rebuild instead of programming a stale batch."""
-        prev = self._stream_finish
-        fence = self._fence_gen
-
-        async def _finish() -> None:
-            if prev is not None:
-                try:
-                    await prev
-                # lint: allow(broad-except) predecessor logged it
-                except Exception:  # pragma: no cover - logged at source
-                    pass
-            try:
-                bud = latency_budget.of_trace(ctx)
-                if bud is not None:
-                    # time chained behind the previous finish (plus any
-                    # fence-discard detour) is fence_hold by definition
-                    bud.advance("fence_hold")
-                if self._fence_gen != fence:
-                    counters.increment("decision.stream.fenced")
-                    if spf_sp is not None:
-                        spf_sp.attributes["fenced"] = True
-                        tracer.end_span(spf_sp)
-                    tracer.end_trace(ctx, status="fenced")
-                    latency_budget.close(bud, status="requeued")
-                    self.pending.needs_full_rebuild = True
-                    self._trigger_rebuild()
-                    return
-                # overlap won: how far past this epoch's solve START the
-                # previous finish (and its FIB program) was still
-                # running — 0 when the pipeline had already drained
-                overlap_ms = max(0.0, (self._finish_done_t - t0) * 1e3)
-                if prev is not None and overlap_ms > 0:
-                    counters.add_stat_value(
-                        "decision.stream.overlap_ms", overlap_ms
-                    )
-                    if spf_sp is not None:
-                        spf_sp.attributes["overlap_ms"] = round(
-                            overlap_ms, 3
-                        )
-                self._finish_rebuild(pending, ctx, spf_sp, t0, new_db, full)
-            # lint: allow(broad-except) fiber-equivalent crash recovery
-            except Exception:
-                log.exception(
-                    "%s: deferred epoch finish failed; forcing a full "
-                    "rebuild", self.name,
-                )
-                counters.increment("decision.stream.finish_errors")
-                latency_budget.discard_trace(ctx)
-                self.pending.needs_full_rebuild = True
-                self._trigger_rebuild()
-            finally:
-                self._finish_done_t = time.perf_counter()
-
-        self._stream_finish = asyncio.ensure_future(_finish())
 
     def _finish_rebuild(
         self, pending: PendingUpdates, ctx, spf_sp, t0, new_db, full=True
@@ -1044,7 +924,6 @@ class Decision(Actor):
             spf_sp.attributes["rib_digest"] = digest
         tracer.annotate(ctx, rib_digest=digest)
         if self._replay is not None:
-            tm = getattr(self.solver, "last_timing", None)
             self._replay.record_epoch(
                 epoch=self._solve_epoch,
                 cursor=pending.replay_cursor,
@@ -1053,9 +932,6 @@ class Decision(Actor):
                 solver_kind=self._solver_kind(full),
                 spf_kernel=self.cfg.spf_kernel,
                 full=full,
-                stream=(
-                    tm.get("stream") if isinstance(tm, dict) else None
-                ),
                 snapshot=pending.replay_snapshot,
             )
         self._stamp_provenance(update, pending, full)
@@ -1183,7 +1059,6 @@ class Decision(Actor):
             },
             "solver_backend": backend,
             "spf_kernel": cfg.spf_kernel,
-            "streaming_pipeline": cfg.streaming_pipeline,
             "incremental_spf": cfg.incremental_spf,
         }
 
@@ -1439,9 +1314,6 @@ class Decision(Actor):
 
     def _enter_degraded(self, exc: Exception) -> None:
         self._degraded = True
-        # epoch fence: any deferred streaming finish solved on the
-        # now-suspect primary; discard rather than program its batch
-        self._fence_gen += 1
         counters.set_counter("decision.solver.degraded", 1)
         counters.increment("decision.solver.failovers")
         log.error(
@@ -1645,15 +1517,6 @@ class Decision(Actor):
             v = tm.get(key)
             if v:
                 spf_sp.attributes[key] = v
-        st = tm.get("stream")
-        if isinstance(st, dict):
-            # streamed churn epochs (changed-rows-only download): the
-            # span carries the per-solve totals; the running counters
-            # are decision.stream.{epochs,changed_rows,bytes_downloaded}
-            spf_sp.attributes["stream_epochs"] = st.get("epochs")
-            spf_sp.attributes["stream_changed_rows"] = st.get(
-                "changed_rows"
-            )
         # a parent stands before its children in the list
         ids: dict[tuple, int] = {}
         for name, parent, start, end, attrs in tm.get("spans") or ():

@@ -262,10 +262,9 @@ class DecisionRouteUpdate:
     columns: Optional[object] = None
     # epoch fence provenance: Decision's solve epoch that produced this
     # delta. Fib coalesces deltas, so its programmed/ack publications
-    # carry the NEWEST epoch folded into the pass — with the streaming
-    # pipeline overlapping epochs, this is what keeps FIB acks and
-    # convergence traces attributed to the right solve. None on static
-    # and synthetic updates.
+    # carry the NEWEST epoch folded into the pass — this is what keeps
+    # FIB acks and convergence traces attributed to the right solve.
+    # None on static and synthetic updates.
     solve_epoch: Optional[int] = None
 
     def empty(self) -> bool:

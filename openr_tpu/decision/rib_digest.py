@@ -6,8 +6,8 @@ sorted (prefix, igp cost, sorted {neighbor/iface} next-hop identity)
 rows plus sorted deletes — never backend representation (column
 packing, device dtypes, nexthop object identity). That is what makes
 the digest the cross-backend parity oracle the replay harness needs:
-the streaming-pipeline tests already assert that cpu/tpu and
-streamed/host deltas materialize to EQUAL entry dicts, so any two
+the solver tests already assert that cpu/tpu and column/host deltas
+materialize to EQUAL entry dicts, so any two
 correct builds of the same epoch hash identically, while a wrong row
 on either side flips the digest.
 

@@ -377,7 +377,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                    sentinels: bool = True, emit_dist: bool = False,
                    incr: bool = False, mesh=None,
                    kernel: str = "sync", delta_exp: int = 0,
-                   stream: int = 0, narrow: bool = False):
+                   narrow: bool = False):
     """The fused production pipeline (raw closure — _build_pipeline jits
     it under the options a PipelineVariant names, vmapped over a group
     of same-shape areas for a `fused` one). Outputs:
@@ -393,9 +393,9 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 Its count and rows are DEFINED ONLY in an epoch with
                 `want_full` or count > budget — the epochs the host
                 reads it in (_make_prepare's full_pull) — and zeros in
-                every other: the route-ok predicate (where no streaming
-                payload needs it), the compaction and the six gathers
-                over every row sit under one lax.cond on that predicate.
+                every other: the route-ok predicate, the compaction and
+                the six gathers over every row sit under one lax.cond on
+                that predicate.
                 trips and the scalar tail are appended outside it and
                 read as delta_buf's in every epoch.
       metric, s3w, nhw, lfa_slot, lfa_metric: resident arrays (the next
@@ -405,14 +405,13 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
 
     `want_full` (argument 9, a runtime int32 scalar) is the dispatcher's
     half of that predicate: _lane_args sets it to `not vs.valid` — the
-    vantage has no table to patch (first solve, reset planes, an
-    abandoned streaming prepare), so the host will read full_buf
-    whatever changed. The other half, count > budget, is the device's
-    own, so an overflowing epoch finds its full pull built in the same
-    dispatch. An argument, not a PipelineVariant field: one executable
-    serves both. Under vmap (a `fused` group) the cond lowers to a
-    select and both branches run, as they did before there was a cond;
-    under `mesh` the predicate is replicated.
+    vantage has no table to patch (first solve, reset planes), so the
+    host will read full_buf whatever changed. The other half, count >
+    budget, is the device's own, so an overflowing epoch finds its full
+    pull built in the same dispatch. An argument, not a PipelineVariant
+    field: one executable serves both. Under vmap (a `fused` group) the
+    cond lowers to a select and both branches run, as they did before
+    there was a cond; under `mesh` the predicate is replicated.
 
     With `incr=True` the pipeline takes six extra trailing args
     (prev_dist, s_dirty_idx, s_dirty_old, r_dirty_idx, r_dirty_old,
@@ -423,8 +422,8 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     selection / LFA / packing / delta tail below is shared verbatim
     between the two kernels — output parity by construction.
 
-    With `narrow` (an incremental solve on one chip outside the
-    streaming pipeline: what _variant asks for) the row stages run over
+    With `narrow` (an incremental solve on one chip: what _variant asks
+    for) the row stages run over
     CANDIDATE ROWS where they can, and the pipeline takes two more
     trailing args (host_rows int32 [budget], wide). A row's five outputs
     are computed from its own cells in mbuf, from column n of the plane
@@ -457,10 +456,8 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     tail of both pull buffers, before the cone's three: the rows the row
     stages looked at (the candidates' count, p_cap on the all-rows
     side). `fused` groups (under vmap the cond lowers to a select and
-    both sides run), `mesh` (a sharded gather axis), `stream` (its
-    payload carries the device's route-ok bit a row; off in every
-    deployment measured) and the full solve (every row is new there)
-    keep the all-rows text alone.
+    both sides run), `mesh` (a sharded gather axis) and the full solve
+    (every row is new there) keep the all-rows text alone.
 
     With `mesh` (the multichip capacity tier) the SSSP core swaps for
     parallel/sharding.py's shard_mapped twins — shift columns over
@@ -469,25 +466,15 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
     handles fine (it is only the SSSP's dynamic roll it miscompiles;
     see make_mc_sssp). Fixpoint uniqueness keeps the output
     bit-identical to the single-chip tier.
-
-    With `stream` nonzero (a STREAM_BUDGETS bucket) this is the
-    streaming-epoch kernel (jit-cache namespace "stream"): the delta
-    payload uses the small bucketed budget instead of the classic
-    _DELTA_BUDGET and carries the device route-ok bit per changed row
-    (ops/stream.py layout), so the host applies the rows without
-    unpacking words. The changed mask and compaction are the SAME
-    ops/stream.py stages the classic delta path runs — parity by
-    construction.
     """
     import jax
     import jax.numpy as jnp
 
-    from openr_tpu.ops.compact import route_ok_device
-    from openr_tpu.ops.incremental import incremental_sssp
-    from openr_tpu.ops.stream import (
-        column_diff, compact_changed_rows, first_true_rows, rows_any,
-        true_rows,
+    from openr_tpu.ops.compact import (
+        column_diff, compact_changed_rows, first_true_rows, route_ok_device,
+        rows_any, true_rows,
     )
+    from openr_tpu.ops.incremental import incremental_sssp
 
     wa = -(-a_cap // 16)
     wd = -(-d_cap // 16)
@@ -580,7 +567,6 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
             # partitioner never touches a sharded gather axis
             dist_res = dist_d
             dist_d = jax.lax.with_sharding_constraint(dist_d, mc_rep)
-        delta_rows = stream or budget
 
         def all_rows(cells):
             """The row stages over every row: (delta_buf's and full_buf's
@@ -593,38 +579,28 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 lfa_slot = prev_lfa_slot
                 lfa_metric = prev_lfa_metric
 
-            def route_ok():
-                # route-level ok computed on device: compacts the cold
-                # full pull to ok rows, and on the streaming path rides
-                # the delta payload per changed row (the host apply is
-                # then unpack-free)
-                return route_ok_device(
-                    metric, s3, nh_mask, cells.ann_node, cells.min_nh,
-                    cells.v4_blocked, root,
-                )
-
             with jax.named_scope("pack"):
                 s3w = _pack_words(s3)
                 nhw = _pack_words(nh_mask)
-                # a streaming epoch ships ok with every changed row; any
-                # other needs it for the cold pull alone, and computes it
-                # there
-                ok = route_ok() if stream else None
             with jax.named_scope("diff"):
                 changed = column_diff(
                     metric, s3w, nhw, lfa_slot, lfa_metric, *prev, lfa,
                 )
             with jax.named_scope("compact"):
                 count, delta_parts = compact_changed_rows(
-                    changed, trips, metric, s3w, nhw, ok,
-                    lfa_slot, lfa_metric, delta_rows, p_cap, lfa,
+                    changed, trips, metric, s3w, nhw,
+                    lfa_slot, lfa_metric, budget, p_cap, lfa,
                 )
 
                 def cold_rows():
                     # cold-rebuild compaction: only ok rows' outputs ship
                     # (gathered to the front — pad slots past okc carry
-                    # the last ok row's values and are ignored)
-                    row_ok = route_ok() if ok is None else ok
+                    # the last ok row's values and are ignored). The
+                    # route-level ok is computed on the device, here alone
+                    row_ok = route_ok_device(
+                        metric, s3, nh_mask, cells.ann_node, cells.min_nh,
+                        cells.v4_blocked, root,
+                    )
                     oidx = true_rows(row_ok, p_cap)
                     osafe = jnp.clip(oidx, 0, p_cap - 1)
                     rows = [
@@ -650,7 +626,7 @@ def _make_pipeline(n_cap: int, s_cap: int, r_cap: int, kr_cap: int,
                 # it: the host's rule (full_pull in _make_prepare) on the
                 # device
                 okc, full_rows = jax.lax.cond(
-                    (want_full != 0) | (count > delta_rows),
+                    (want_full != 0) | (count > budget),
                     cold_rows, no_rows,
                 )
                 full_parts = [
@@ -779,8 +755,8 @@ def _candidate_row_stages(mbuf, dist_d, root, root_w, prev, cand_rows,
               for word as compact_changed_rows lays it: the changed
               candidates' indices and columns to the front, pad slots as
               a clipped read of the last row gives them
-      new     `prev` with the candidate rows written in. They are not
-              donated (an abandoned prepare must still find them), so
+      new     `prev` with the candidate rows written in. `prev` is not
+              donated (an abandoned prepare must still find it), so
               each is copied: a few MB
       sent    the two sentinels (none without `sentinels`), which stay
               what they are, reductions over every row: of the resident
@@ -788,7 +764,7 @@ def _candidate_row_stages(mbuf, dist_d, root, root_w, prev, cand_rows,
     import jax
     import jax.numpy as jnp
 
-    from openr_tpu.ops.stream import column_diff, first_true_rows, rows_any
+    from openr_tpu.ops.compact import column_diff, first_true_rows, rows_any
 
     pa = p_cap * a_cap
     rows = cand_rows.shape[0]
@@ -904,7 +880,7 @@ class PipelineVariant(NamedTuple):
     d_cap: int
     p_cap: int
     a_cap: int
-    budget: int               # rows of a classic delta pull
+    budget: int               # rows of a delta pull
     lfa: bool = False
     block_v4: bool = False
     sentinels: bool = True
@@ -912,9 +888,7 @@ class PipelineVariant(NamedTuple):
     delta_exp: int = 0        # > 0: bucketed Δ-stepping at 2^delta_exp
     # what kind of executable
     dirty_cap: int = 0        # > 0: incremental, both dirty buffers' pad
-    stream: int = 0           # a STREAM_BUDGETS bucket: streaming epoch
     fused: int = 0            # g same-shape areas vmapped in one dispatch
-    donate: bool = False      # prev planes + warm seed donated (stream)
     rows_only: int = 0        # prefix-only: the candidate rows' bucket
     narrow: bool = False      # incremental: row stages over candidate rows
     mesh: object = None       # the multichip tier's ('batch','graph') mesh
@@ -925,14 +899,10 @@ class PipelineVariant(NamedTuple):
         path builds (and _make_pipeline was never run with)."""
         v = cls(*fields, **named)
         one_chip = v.mesh is None
-        if v.stream and not (v.incr and one_chip):
-            raise ValueError(f"stream: incremental, on one chip: {v}")
         if v.fused and (v.incr or not one_chip):
             raise ValueError(f"fused: a full solve on one chip: {v}")
         if v.incr and not v.emit_dist:
             raise ValueError(f"an incremental solve emits the plane: {v}")
-        if v.donate and not v.stream:
-            raise ValueError(f"only a stream epoch donates: {v}")
         if v.rows_only and (
             v.incr or v.fused or v.emit_dist or not one_chip
             or v.rows_only > v.budget
@@ -941,9 +911,9 @@ class PipelineVariant(NamedTuple):
                 f"rows_only: no solve, one area, one chip, the plane "
                 f"stays where it is, the rows fit a delta pull: {v}"
             )
-        if v.narrow and not (v.incr and one_chip and not v.stream):
+        if v.narrow and not (v.incr and one_chip):
             raise ValueError(
-                f"narrow: an incremental solve on one chip, no stream: {v}"
+                f"narrow: an incremental solve on one chip: {v}"
             )
         return v
 
@@ -967,13 +937,11 @@ class PipelineVariant(NamedTuple):
 
     @property
     def namespace(self) -> str:
-        """jit-cache namespace: sharded, streaming and incremental
-        executables each churn their own LRU (and count under their own
+        """jit-cache namespace: sharded and incremental executables
+        each churn their own LRU (and count under their own
         xla_cache.<ns>_factory_*), never evicting a full solve."""
         if self.mesh is not None:
             return "multichip"
-        if self.stream:
-            return "stream"
         # the prefix-only executables, one a row bucket, lie with the
         # class's dirty-cap buckets
         return "incr" if self.incr or self.rows_only else ""
@@ -987,7 +955,7 @@ class PipelineVariant(NamedTuple):
         kind = (
             ("_mc" if self.mesh is not None else "")
             + ("_fused" if self.fused else "")
-            + ("_stream" if self.stream else "_incr" if self.incr else "")
+            + ("_incr" if self.incr else "")
             + ("_rows" if self.rows_only else "")
         )
         parts = [
@@ -995,7 +963,6 @@ class PipelineVariant(NamedTuple):
             f"n={self.n_cap},s={self.s_cap},d={self.d_cap}",
             f"p={self.p_cap},a={self.a_cap}",
             f"dd={self.dirty_cap}" if self.incr else "",
-            f"sb={self.stream}" if self.stream else "",
             f"rr={self.rows_only}" if self.rows_only else "",
             f"mesh={_mesh_tag(self.mesh)}" if self.mesh is not None else "",
             "res" if self.has_res else "",
@@ -1068,9 +1035,6 @@ def _build_pipeline(*fields) -> tuple:
     positional ints. The wrapper AOT-compiles on first call, recording
     compile time + XLA cost_analysis into the kernel ledger
     (ops/xla_cache.ledger). The jit options follow from the record:
-      - `donate`: the previous epoch's published planes and warm seed
-        (args 10-15) update HBM in place — one plane set resident, not
-        two;
       - `mesh`: NamedSharding annotations, so GSPMD partitions the
         weight state — parity with one chip by construction (the int32
         min/add/compare algebra is partitioning-invariant, XLA argmin
@@ -1097,12 +1061,10 @@ def _build_pipeline(*fields) -> tuple:
         pipeline = _make_pipeline(
             *v.shape_key, v.budget, v.lfa, v.block_v4, v.sentinels,
             v.emit_dist, incr=v.incr, mesh=v.mesh, kernel=v.kernel,
-            delta_exp=v.delta_exp, stream=v.stream, narrow=v.narrow,
+            delta_exp=v.delta_exp, narrow=v.narrow,
         )
     kw = {}
-    if v.donate:
-        kw = {"donate_argnums": (10, 11, 12, 13, 14, 15)}
-    elif v.mesh is not None:
+    if v.mesh is not None:
         kw["in_shardings"], kw["out_shardings"] = _mc_shardings(
             v.mesh, v.n_cap, v.r_cap, v.d_cap, v.emit_dist, v.incr
         )
@@ -1124,7 +1086,7 @@ def _build_pipeline(*fields) -> tuple:
 # budget and its own xla_cache.<ns>_factory_* counters
 _PIPELINE_CACHES = {
     ns: bounded_jit_cache(namespace=ns)(_build_pipeline)
-    for ns in ("", "incr", "stream", "multichip")
+    for ns in ("", "incr", "multichip")
 }
 
 
@@ -1341,7 +1303,7 @@ class _VantageState:
     __slots__ = (
         "shape_key", "matrix_version", "prev", "crib",
         "links_tuple", "valid", "prev_dist", "dist_epoch", "root_sig",
-        "stream_budget", "rows_stamp", "shared_stamp",
+        "rows_stamp", "shared_stamp",
     )
 
     def __init__(self):
@@ -1363,12 +1325,6 @@ class _VantageState:
         self.crib: Optional[ColumnarRib] = None
         self.links_tuple: tuple = ()
         self.valid = False
-        # streaming-epoch changed-rows budget (ops/stream.py bucket):
-        # tracks this vantage's recent churn — grows on payload
-        # overflow, shrinks back toward the floor on quiet epochs.
-        # Floor literal mirrors STREAM_BUDGETS[0] (importing the ops
-        # module pulls in jax, which this module defers to solve time).
-        self.stream_budget = 64
         # incremental-solve seed state: the [D, N] distance plane of
         # the last single-area dispatch, the area drain epoch it
         # corresponds to, and the root out-link signature it was
@@ -1615,7 +1571,6 @@ class TpuSpfSolver:
         multichip_batch: int = 0,
         spf_kernel: str = "bucketed",
         transfer_guard: str = "off",
-        streaming_pipeline: bool = False,
         aot_cache_dir: str | None = None,
         aot_speculate: bool = False, **solver_kwargs
     ):
@@ -1663,22 +1618,7 @@ class TpuSpfSolver:
         # first solve, shape/root churn, journal gaps, zero-weight
         # edges, or when the cone exceeds incremental_cone_frac of the
         # fabric's node-lanes (decided on device, same dispatch).
-        # streaming churn pipeline (ops/stream.py): fuse the incremental
-        # relax, selection and the on-device column diff into one
-        # dispatch per epoch, download a bucketed changed-rows payload
-        # carrying the device route-ok bit, and DONATE the previous
-        # epoch's resident planes (in-place HBM double-buffer). Implies
-        # incremental_spf — the streaming epoch is the incremental solve
-        # with a different download contract; every incremental
-        # fallback rung (first solve, shape/root churn, journal gaps,
-        # payload overflow, CPU failover) drops to the classic path.
-        if not isinstance(streaming_pipeline, bool):
-            raise ValueError(
-                f"streaming_pipeline must be a bool, "
-                f"got {streaming_pipeline!r}"
-            )
-        self.streaming_pipeline = streaming_pipeline
-        self.incremental_spf = bool(incremental_spf) or streaming_pipeline
+        self.incremental_spf = bool(incremental_spf)
         self.incremental_cone_frac = float(incremental_cone_frac)
         # multichip capacity tier (parallel/sharding.py): an area whose
         # padded n_cap exceeds the threshold — with >1 device visible —
@@ -2082,9 +2022,6 @@ class TpuSpfSolver:
         halo_total = 0
         bucketed_engaged = False
         bytes_downloaded = 0
-        stream_epochs = 0
-        stream_changed = 0
-        stream_overflows = 0
         for area, fut in pending.futures:
             res = fut.result()
             views.append(res["view"])
@@ -2095,14 +2032,8 @@ class TpuSpfSolver:
             cone_passes_total += int(stats.get("cone_passes") or 0)
             bucket_epochs_total += int(stats.get("bucket_epochs") or 0)
             halo_total += int(stats.get("halo_exchanges") or 0)
-            # download ledger (ISSUE 16): every path reports its pulled
-            # bytes; streaming epochs additionally report budget use
+            # download ledger (ISSUE 16): every path reports its bytes
             bytes_downloaded += int(stats.get("bytes_downloaded") or 0)
-            if stats.get("stream"):
-                stream_epochs += 1
-                stream_changed += int(stats.get("changed_rows") or 0)
-                if stats["stream"].get("overflow"):
-                    stream_overflows += 1
             if stats.get("spf_kernel") == "bucketed":
                 bucketed_engaged = True
             if stats.get("incremental"):
@@ -2189,13 +2120,6 @@ class TpuSpfSolver:
             "spf_kernel": "bucketed" if bucketed_engaged else "sync",
             **pending.ksp2_timing,
         }
-        if stream_epochs:
-            self.last_timing["stream"] = {
-                "epochs": stream_epochs,
-                "changed_rows": stream_changed,
-                "bytes_downloaded": bytes_downloaded,
-                "overflows": stream_overflows,
-            }
         return route_db
 
     def _prime_ucmp(
@@ -3174,8 +3098,8 @@ class TpuSpfSolver:
         ad, vs = pv["ad"], pv["vs"]
         root_idx = np.int32(pv["root_idx"])
         root_nbr, root_w = pv["root_nbr"], pv["root_w"]
-        # a vantage with no table yet (first solve, reset, an abandoned
-        # streaming prepare) will read the cold pull whatever changed:
+        # a vantage with no table yet (first solve, reset) will read the
+        # cold pull whatever changed:
         # _make_prepare's `was_valid`, told to the device
         want_full = np.int32(not vs.valid)
         if self._transfer_guard_mode() is not None and pv.get("mc") is None:
@@ -3273,8 +3197,7 @@ class TpuSpfSolver:
 
         baker.submit(f"next:{variant.aot_key}", bake)
 
-    def _variant(self, pv: dict, dirty_cap: int = 0, stream: int = 0,
-                 fused: int = 0, donate: bool = False,
+    def _variant(self, pv: dict, dirty_cap: int = 0, fused: int = 0,
                  rows_only: int = 0) -> PipelineVariant:
         """The executable a prepared vantage dispatches: its shape
         class, flags and tier, the solver's knobs, and the kind the
@@ -3282,19 +3205,19 @@ class TpuSpfSolver:
         dispatch path reads _DELTA_BUDGET for an executable (_sync_area
         and _prep_vantage bound by it the rows they keep). The full
         solve emits the distance plane whenever incremental solves may
-        follow it; a fused group's areas never seed one. An incremental solve on one
-        chip, outside the streaming pipeline, is the `narrow` one: its
-        row stages may run over candidate rows. Unchecked here, once an
-        event: the factory checks what it builds."""
+        follow it; a fused group's areas never seed one. An incremental
+        solve on one chip is the `narrow` one: its row stages may run
+        over candidate rows. Unchecked here, once an event: the factory
+        checks what it builds."""
         return PipelineVariant(
             *pv["shape_key"], _DELTA_BUDGET, pv["lfa"], pv["block_v4"],
             self.enable_sentinels,
             emit_dist=not rows_only and (
                 dirty_cap > 0 or (self.incremental_spf and not fused)
             ),
-            delta_exp=pv["delta_exp"], dirty_cap=dirty_cap, stream=stream,
-            fused=fused, donate=donate, rows_only=rows_only,
-            narrow=dirty_cap > 0 and not stream and pv["mc"] is None,
+            delta_exp=pv["delta_exp"], dirty_cap=dirty_cap,
+            fused=fused, rows_only=rows_only,
+            narrow=dirty_cap > 0 and pv["mc"] is None,
             mesh=pv["mc"],
         )
 
@@ -3337,7 +3260,7 @@ class TpuSpfSolver:
         rows_only = 0
         if (
             incr is not None and incr["still"] and pv["mc"] is None
-            and not self.streaming_pipeline and cand is not None
+            and cand is not None
         ):
             # more rows than a bucket or a delta pull holds: not this way
             rows_only = _dirty_bucket(len(cand)) or 0
@@ -3350,22 +3273,15 @@ class TpuSpfSolver:
             # resident plane stands, and the row stages run over the
             # rows scattered since the resident outputs were computed
             # and over no other, with no relaxation and no cone (on one
-            # chip, outside the streaming pipeline, where those rows are
-            # known and fit; elsewhere the incremental solve below finds
-            # nothing dirty, converges at once and looks at every row)
+            # chip, where those rows are known and fit; elsewhere the
+            # incremental solve below finds nothing dirty, converges at
+            # once and looks at every row)
             variant = self._variant(pv, rows_only=rows_only)
             rows = np.full(rows_only, cand[-1] if len(cand) else 0, np.int32)
             rows[:len(cand)] = cand
             if self._transfer_guard_mode() is not None:
                 rows = self._put_counted(rows)
             args = self._lane_args(pv) + (pv["vs"].prev_dist, rows)
-        elif pv["mc"] is None and self.streaming_pipeline:
-            # streaming epoch: same eligibility ladder as the
-            # incremental solve (its rungs ARE the fallback ladder
-            # — first solve, shape/root churn, journal gaps all
-            # land in the full solve), different download contract +
-            # donated double-buffer
-            return self._dispatch_stream(pv)
         else:
             variant = self._variant(pv, dirty_cap=incr["cap"])
             args = self._incr_args(pv, variant)
@@ -3405,48 +3321,6 @@ class TpuSpfSolver:
             pv, variant, kernel_name, delta_buf, full_buf, new_prev
         )
 
-    def _dispatch_stream(self, pv: dict):
-        """Streaming-epoch dispatch (jit-cache namespace "stream"): ONE
-        fused executable chains the incremental relax, selection/LFA
-        and the on-device column diff, and the download is the bucketed
-        changed-rows payload carrying the device route-ok bit
-        (ops/stream.py). The previous epoch's published planes + warm
-        distance seed are DONATED into the dispatch — the epoch
-        double-buffer flips in place in HBM — so the vantage advances
-        to the new handles IMMEDIATELY after dispatch and stays invalid
-        until prepare() commits the columnar patch: an abandoned
-        prepare costs one clean full rebuild, never a crib that has
-        silently diverged from the resident planes. Donation also kills
-        the device-probe replay state (its stored prev handles), so
-        both probes are cleared."""
-        vs = pv["vs"]
-        variant = self._variant(
-            pv, dirty_cap=pv["incr"]["cap"],
-            stream=int(vs.stream_budget) or 64,
-            # the guarded-retry path in _run_exec replays the call
-            # after a finding — impossible once the inputs are donated
-            donate=self._transfer_guard_mode() is None,
-        )
-        kernel_name, run = pipeline_for(variant)
-        delta_buf, full_buf, *new_prev = self._run_exec(
-            variant.namespace, kernel_name, pv["shape_key"], run,
-            self._incr_args(pv, variant), pv["area"],
-        )
-        prepare = self._make_prepare(
-            pv, variant, kernel_name, delta_buf, full_buf, new_prev
-        )
-        # post-donation hygiene, on the dispatch thread before anything
-        # can observe the dead handles: advance the double-buffer,
-        # invalidate until the prepare lands, drop the replay probes
-        vs.prev = tuple(new_prev[:5])
-        vs.prev_dist = new_prev[5]
-        vs.dist_epoch = pv["dist_epoch"]
-        vs.root_sig = pv["root_sig"]
-        vs.valid = False
-        self._last_exec = None
-        self._last_exec_incr = None
-        return prepare
-
     def _dispatch_fused(self, group: list[dict]) -> list[tuple]:
         """ONE vmapped dispatch for a group of same-shape areas; returns
         (pv, prepare) pairs. Per-area inputs travel as g-tuples (a
@@ -3481,25 +3355,14 @@ class TpuSpfSolver:
         is the executable that wrote the buffers, so its fields say how
         to read them. Thread-safety: one worker thread, and the caller
         does not touch this vantage's state until it collects the
-        future.
-
-        With `variant.stream` (the epoch's changed-rows bucket) the
-        delta payload is the bucketed ops/stream.py layout: the device
-        route-ok bit rides per changed row, so the patch goes through
-        apply_rows_packed — no host word-unpack, and the crib journal
-        entry is marked device-exact (fast_unicast_column_diff then
-        skips its re-compare). An over-budget epoch falls back to the
-        device-compacted full pull and the budget grows for the next
-        epoch."""
+        future."""
         import time as _time
-
-        from openr_tpu.ops.stream import STREAM_BUDGETS, stream_budget
 
         # the jitted call has just returned: the device runs from here
         t_disp = _time.monotonic()
         plan, matrix, vs = pv["plan"], pv["matrix"], pv["vs"]
         lfa, sentinels = variant.lfa, variant.sentinels
-        fused, stream = variant.fused, variant.stream
+        fused = variant.fused
         emit, incr = variant.emit_dist, variant.incr
         rows_only = variant.rows_only
         spf_kernel = variant.kernel
@@ -3538,7 +3401,7 @@ class TpuSpfSolver:
                 vs.dist_epoch = pv["dist_epoch"]
             wa = -(-a_cap // 16)
             wd = -(-d_cap // 16)
-            b = stream or variant.budget
+            b = variant.budget
             crib = vs.crib
             count = None
             trips = 0
@@ -3617,32 +3480,17 @@ class TpuSpfSolver:
                 metric = dbuf[o:o + b]; o += b
                 s3w = dbuf[o:o + b * wa].reshape(b, wa); o += b * wa
                 nhw = dbuf[o:o + b * wd].reshape(b, wd); o += b * wd
-                okb = None
-                if stream:
-                    # streaming payload: device route-ok bit per row
-                    okb = dbuf[o:o + b]; o += b
                 lfa_slot = lfa_metric = None
                 if lfa:
                     lfa_slot = dbuf[o:o + b]; o += b
                     lfa_metric = dbuf[o:o + b]
                 live = cidx < p_cap
-                if stream:
-                    crib.apply_rows_packed(
-                        cidx[live][:count], metric[live][:count],
-                        s3w[live][:count], nhw[live][:count],
-                        okb[live][:count].astype(bool),
-                        None if lfa_slot is None
-                        else lfa_slot[live][:count],
-                        None if lfa_metric is None
-                        else lfa_metric[live][:count],
-                    )
-                else:
-                    crib.apply_rows(
-                        cidx[live][:count], metric[live][:count],
-                        s3w[live][:count], nhw[live][:count],
-                        None if lfa_slot is None else lfa_slot[live][:count],
-                        None if lfa_metric is None else lfa_metric[live][:count],
-                    )
+                crib.apply_rows(
+                    cidx[live][:count], metric[live][:count],
+                    s3w[live][:count], nhw[live][:count],
+                    None if lfa_slot is None else lfa_slot[live][:count],
+                    None if lfa_metric is None else lfa_metric[live][:count],
+                )
             # tail layout, back to front: [-1] is always the executed-
             # relaxation rounds scalar; the incremental kernel's
             # [cone_passes, cone, fell_back] sit at [-4]/[-3]/[-2]; the
@@ -3703,39 +3551,14 @@ class TpuSpfSolver:
                     "saturated_rows": int(sbuf[off - 1]),
                 }
             # device->host download accounting: every pulled buffer
-            # counts (an over-budget streaming epoch pays both the
-            # delta head-peek and the full pull)
+            # counts (an over-budget epoch pays both the delta
+            # head-peek and the full pull)
             bytes_dl = 0
             if was_valid:
                 bytes_dl += int(dbuf.nbytes)
             if full_pull:
                 bytes_dl += int(fbuf.nbytes)
             stats["bytes_downloaded"] = bytes_dl
-            if stream:
-                stats["stream"] = {
-                    "budget": b,
-                    "overflow": bool(full_pull),
-                }
-                # adapt next epoch's bucket to the observed churn: grow
-                # past an overflow, settle back toward the floor when
-                # the storm quiets (quantized — budget churn can't
-                # thrash the "stream" jit-cache namespace)
-                vs.stream_budget = (
-                    stream_budget(count or 0) or STREAM_BUDGETS[-1]
-                )
-                # donation left the vantage invalid across the dispatch
-                # window; the columnar patch above committed, so the
-                # resident planes and the crib agree again
-                vs.valid = True
-                counters.increment("decision.stream.epochs")
-                counters.add_stat_value(
-                    "decision.stream.changed_rows", count or 0
-                )
-                counters.add_stat_value(
-                    "decision.stream.bytes_downloaded", bytes_dl
-                )
-                if full_pull:
-                    counters.increment("decision.stream.overflows")
             stats["trips"] = trips
             # executed-relaxation work accounting (ISSUE 13): rounds is
             # the device-counted relaxation passes; under the bucketed

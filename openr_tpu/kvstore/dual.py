@@ -141,6 +141,17 @@ class Dual:
         # the parent (a lost topo_set would otherwise silently detach
         # this node's subtree from the flood tree).
         self.peers.add(peer)
+        self._introduce(peer)
+        # and ask the peer for the same. The two ends of a session come
+        # up one after the other: what the first says reaches an end
+        # that does not track it yet and is dropped (handle_message),
+        # and the second may know no root to say anything about — the
+        # first would never speak again, and a node whose every peer
+        # came up first would stay outside the tree for good, reached by
+        # nobody's flooding.
+        self._send(peer, {"type": "hello"})
+
+    def _introduce(self, peer: str) -> None:
         for root, rs in self.roots.items():
             self._send(peer, self._update_msg(root, peer))
             if rs.successor == peer:
@@ -173,7 +184,10 @@ class Dual:
             # peer_up re-introduces state on both sides. This covers
             # topo_set too: an in-flight child claim from a removed peer
             # would leak a ghost child forever (peer_up re-sends the
-            # claim, so dropping loses nothing).
+            # claim and asks for the peer's, so dropping loses nothing).
+            return
+        if mtype == "hello":
+            self._introduce(sender)
             return
         if mtype == "topo_set":
             rs = self._root_state(root)

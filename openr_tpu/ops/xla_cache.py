@@ -413,8 +413,8 @@ def bounded_jit_cache(max_buckets: int = 8, namespace: str = ""):
     _build_pipeline) is wrapped once per namespace its variants name
     (PipelineVariant.namespace): incremental solves use "incr" —
     dirty-set cap churn buckets under xla_cache.incr_* and cannot
-    evict the full-solve or sweep executables — streaming epochs
-    "stream", and the multichip capacity tier "multichip" for the
+    evict the full-solve or sweep executables — and the multichip
+    capacity tier "multichip" for the
     same reason: a sharded executable can never evict a single-chip
     one or vice versa, so a fabric that oscillates around the tier
     threshold keeps both resident. The non-int mesh object in a

@@ -42,7 +42,6 @@ from openr_tpu.runtime.counters import counters
 BUDGET_COMPONENTS: Tuple[str, ...] = (
     "ingest_wait",     # KvStore recv -> dispatch-fiber pickup
     "coalesce_hold",   # deliberate coalescing sleep + merge window
-    "fence_hold",      # waiting behind the stream fence / requeue hold
     "host_sync",       # LSDB delta read + host->device upload (dispatch)
     "dispatch_gap",    # solve enqueued -> device work actually starts
     "device_exec",     # device kernel execution

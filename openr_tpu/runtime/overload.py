@@ -3,7 +3,7 @@
 The platform survives *faults* (supervised restart, CPU failover,
 flight recorder, deterministic replay) but faults are discrete;
 *overload* is sustained. A pathological flapping adjacency or a churn
-storm past the streaming pipeline's capacity grows the dispatch queue
+storm past the solve pipeline's capacity grows the dispatch queue
 without bound, monopolizes solves, and burns the ack-p99 SLO with no
 mechanism to shed, damp, or degrade. This module is that mechanism —
 one controller per node, three cooperating pieces:
@@ -47,12 +47,11 @@ time). A clock that reads *backwards* (paused process, test reuse)
 decays nothing rather than inflating penalties: monotonicity is
 enforced, not assumed.
 
-Brownout rungs beyond admission control are enacted by the owners of
-the machinery: Decision consults ``streaming_allowed()`` before
-deferring an epoch finish behind the stream fence and
-``multichip_allowed()`` to pin the solver to the single-chip tier
-(decision/tpu_solver.py honors ``force_single_chip``). Each rung is a
-query, not a command, so a rung reverses the instant the ladder does.
+The rung beyond admission control is enacted by the owner of the
+machinery: Decision consults ``multichip_allowed()`` to pin the solver
+to the single-chip tier (decision/tpu_solver.py honors
+``force_single_chip``). A rung is a query, not a command, so it
+reverses the instant the ladder does.
 
 One controller per node, looked up by node name (``get_controller``)
 — same per-node registry idiom as the replay recorder: in-process
@@ -417,11 +416,6 @@ class OverloadController:
             self.level >= SHEDDING and queue_depth >= self.queue_watermark
         )
 
-    def streaming_allowed(self) -> bool:
-        """Brownout rung: drop the streaming overlap (epoch finishes
-        deferred behind the stream fence) back to the simple path."""
-        return self.level < BROWNOUT
-
     def multichip_allowed(self) -> bool:
         """Deepest rung before shedding-only: pin the solver to the
         single-chip tier, releasing the mesh's HBM."""
@@ -463,7 +457,6 @@ class OverloadController:
             "deferred_probes": self.deferred_probes,
             "coalesce_max_ms": self.coalesce_max_ms,
             "dwell_s": self.dwell_s,
-            "streaming_allowed": self.streaming_allowed(),
             "multichip_allowed": self.multichip_allowed(),
             "damper": self.damper.report(),
             "history": [

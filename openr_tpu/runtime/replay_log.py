@@ -14,12 +14,13 @@ incident re-executable offline (tools/replay.py). The recorder keeps:
   events older than the ring holds — re-anchored every
   `replay_snapshot_every_epochs` solves and on demand;
 - a per-epoch ledger: RIB digest + rolling digest, solver kind,
-  spf_kernel, stream budget, and the event-ring cursor captured at the
+  spf_kernel, and the event-ring cursor captured at the
   solve's LSDB read, which is what lets replay coalesce by recorded
   epoch boundaries instead of timers.
 
-Snapshot anchoring is two-phase because epochs overlap under the
-streaming pipeline: Decision captures the snapshot at `_begin_rebuild`
+Snapshot anchoring is two-phase because a solve may leave the loop
+(async_dispatch) while events keep arriving: Decision captures the
+snapshot at `_begin_rebuild`
 (the one point where LSDB state and cursor are exactly the solve's
 input) and the anchor only commits in `_finish_rebuild` once the epoch
 number it bases is known. A solve that dies before finishing re-arms
@@ -182,7 +183,6 @@ class ReplayRecorder:
         solver_kind: str,
         spf_kernel: str,
         full: bool,
-        stream: Optional[dict] = None,
         snapshot: Optional[dict] = None,
     ) -> None:
         """Phase 2, at the epoch's finish: ledger entry (+ anchor
@@ -205,7 +205,6 @@ class ReplayRecorder:
             "solver_kind": solver_kind,
             "spf_kernel": spf_kernel,
             "full": bool(full),
-            "stream": stream,
         })
         self._epochs_recorded += 1
         if (

@@ -15,15 +15,14 @@ production LSDB of that class hits, because capacities are pow2-rounded
 (ops/edgeplan.py). Classes whose real deployment uses KSP2 or LFA
 should prewarm those variants too — they are distinct programs.
 
-Beyond the default full-solve executables, the solver keeps four more
+Beyond the default full-solve executables, the solver keeps three more
 jit-cache namespaces (ops/xla_cache.py bounded_jit_cache; the pipeline's
 are chosen by tpu_solver.PipelineVariant.namespace): "incr"
-(seed-from-previous incremental SSSP), "stream" (the fused streaming
-churn epoch with the on-device column diff), "multichip" (the sharded
+(seed-from-previous incremental SSSP), "multichip" (the sharded
 capacity tier), and "whatif" (interactive sweep batches). Each is a
 distinct program set — a daemon that cold-starts straight into churn
 pays the incr compile on its first flap unless it was baked. --incr /
---stream / --multichip / --whatif prewarm those namespaces too, and each bake
+--multichip / --whatif prewarm those namespaces too, and each bake
 records a `prewarm[<namespace>:<nodes>]` entry (compile_ms) in the
 kernel ledger so `breeze tpu kernels` shows what the bake paid per
 workload class.
@@ -138,32 +137,6 @@ def prewarm_incr(nodes: int, verbose: bool = True) -> float:
         print(
             f"[prewarm] class {side}x{side} ({side * side} nodes)"
             f" +incr: {dt:.1f}s"
-        )
-    return dt
-
-
-def prewarm_stream(nodes: int, verbose: bool = True) -> float:
-    """Bake the "stream" namespace: the fused streaming-epoch kernel
-    (relax -> selection -> on-device column diff -> changed-rows
-    compaction, ops/stream.py) under both round-loop kernels. A cold
-    solve seeds the resident planes, then a metric flap re-solves
-    through the streaming pipeline — compiling the (dirty-cap,
-    stream-budget) shape class the production churn path hits first."""
-    from openr_tpu.decision.tpu_solver import TpuSpfSolver
-
-    side, adj_dbs, states, ps, me = _grid_inputs(nodes)
-    t0 = time.perf_counter()
-    for kern, metric in (("bucketed", 57), ("sync", 58)):
-        solver = TpuSpfSolver(me, streaming_pipeline=True, spf_kernel=kern)
-        solver.build_route_db(me, states, ps)  # cold seed
-        _flap_one(states, adj_dbs, metric=metric)
-        solver.build_route_db(me, states, ps)  # stream-namespace compile
-    dt = time.perf_counter() - t0
-    _record_prewarm("stream", side * side, dt)
-    if verbose:
-        print(
-            f"[prewarm] class {side}x{side} ({side * side} nodes)"
-            f" +stream: {dt:.1f}s"
         )
     return dt
 
@@ -305,10 +278,6 @@ def main(argv=None) -> int:
         help="also bake the incremental-SSSP (incr) namespace",
     )
     p.add_argument(
-        "--stream", action="store_true",
-        help="also bake the streaming churn-epoch (stream) namespace",
-    )
-    p.add_argument(
         "--multichip", action="store_true",
         help="also bake the sharded capacity-tier (multichip) namespace"
         " (needs >=2 devices)",
@@ -379,8 +348,6 @@ def main(argv=None) -> int:
             total += prewarm_class(n, enable_lfa=False, enable_ksp2=True)
         if args.incr:
             total += prewarm_incr(n)
-        if args.stream:
-            total += prewarm_stream(n)
         if args.multichip:
             total += prewarm_multichip(n)
         if args.whatif:

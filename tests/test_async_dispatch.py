@@ -84,9 +84,7 @@ class TestBoundedJitCache:
         from openr_tpu.decision import tpu_solver as ts
         from openr_tpu.ops import ksp2, ucmp
 
-        assert set(ts._PIPELINE_CACHES) == {
-            "", "incr", "stream", "multichip"
-        }
+        assert set(ts._PIPELINE_CACHES) == {"", "incr", "multichip"}
         for fn in (
             *ts._PIPELINE_CACHES.values(), ts._scatter_jit,
             ksp2._base_sssp_fn, ksp2._masked_rows_fn,

@@ -2,15 +2,15 @@
 is bit for bit what left it before, and the cold pull's half runs only in
 an epoch that reads it.
 
-  rows     `ops/stream.rows_any` equals the reshaped `any`, and
-           `ops/stream.first_true_rows` and `true_rows` equal
+  rows     `ops/compact.rows_any` equals the reshaped `any`, and
+           `ops/compact.first_true_rows` and `true_rows` equal
            `jnp.nonzero(mask, size=..., fill_value=...)[0]` for every
            mask: lengths from the smallest prefix plane a test builds
            (8 rows) to fabric10k_pfx's 524,288, every delta budget, masks
            empty, of one row, of a budget's worth less one, exactly, and
            plus one, full, and random from 1e-5 to 0.5;
   buffers  every dispatch of a solver under randomized churn — full,
-           incremental, streaming — is replayed through the frozen
+           incremental — is replayed through the frozen
            pipeline of the parent commit (`jnp.nonzero` in both halves,
            the cold half unconditional): `delta_buf` equal in every
            epoch, `full_buf` equal whenever the host reads it, and zeros
@@ -20,7 +20,12 @@ an epoch that reads it.
   predicate  `want_full | count > budget`: at budget + 1 changed rows
            exactly the cold half runs and at budget it does not, and a
            vantage with no table asks for the whole table though fewer
-           rows than the budget differ from the zeroed planes.
+           rows than the budget differ from the zeroed planes;
+  payload  what the incremental path downloads and what the host makes
+           of it, with and without the two alternate columns: the
+           device's changed set is the host column compare's under
+           withdrawals, an idle epoch is one delta payload of zero rows
+           and a busy one's bytes, and warm churn compiles nothing.
 """
 
 import functools
@@ -34,7 +39,9 @@ import pytest
 from openr_tpu.decision import tpu_solver as ts
 from openr_tpu.decision.spf_solver import SpfSolver
 from openr_tpu.decision.tpu_solver import TpuSpfSolver
-from openr_tpu.ops import stream
+from openr_tpu.decision.column_delta import cols_changed_mask
+from openr_tpu.ops import compact
+from openr_tpu.runtime.counters import counters
 from tests.test_incremental_spf import ME, _Churn, _cnt, _grid
 from tests.test_tpu_solver import assert_rib_equal
 
@@ -72,9 +79,9 @@ def _mask(kind: str, p: int, size: int) -> np.ndarray:
 @functools.lru_cache(maxsize=None)
 def _jitted(which: str, p: int, size: int):
     if which == "first":
-        return jax.jit(lambda m: stream.first_true_rows(m, size, p))
+        return jax.jit(lambda m: compact.first_true_rows(m, size, p))
     if which == "all":
-        return jax.jit(lambda m: stream.true_rows(m, p))
+        return jax.jit(lambda m: compact.true_rows(m, p))
     return jax.jit(lambda m: jnp.nonzero(m, size=size, fill_value=p)[0])
 
 
@@ -103,7 +110,7 @@ def test_first_true_rows_under_vmap():
     """A `fused` group's pipeline runs under vmap: one mask an area."""
     rng = np.random.default_rng(7)
     masks = jnp.asarray(rng.random((3, 512)) < 0.2)
-    got = jax.vmap(lambda m: stream.first_true_rows(m, 64, 512))(masks)
+    got = jax.vmap(lambda m: compact.first_true_rows(m, 64, 512))(masks)
     want = jnp.stack([
         jnp.nonzero(m, size=64, fill_value=512)[0] for m in masks
     ])
@@ -122,7 +129,7 @@ def test_rows_any_is_any_over_a_rows_cells(p, a):
     for density in (0.0, 0.02, 0.5, 1.0):
         cells = rng.random(p * a) < density
         got = np.asarray(jax.jit(
-            lambda c: stream.rows_any(c, p, a)
+            lambda c: compact.rows_any(c, p, a)
         )(jnp.asarray(cells.astype(np.int32))))
         assert got.dtype == bool and got.shape == (p,)
         np.testing.assert_array_equal(got, cells.reshape(p, a).any(axis=1))
@@ -131,11 +138,12 @@ def test_rows_any_is_any_over_a_rows_cells(p, a):
 # -- the pipeline's buffers against the parent's ----------------------------
 
 
-def _frozen_compact_changed_rows(changed, trips, metric, s3w, nhw, ok,
+def _frozen_compact_changed_rows(changed, trips, metric, s3w, nhw,
                                  lfa_slot, lfa_metric, budget: int,
                                  p_cap: int, lfa: bool):
-    """`ops/stream.compact_changed_rows` as the parent commit (8c7b8a0)
-    has it, verbatim: the oracle of the delta half."""
+    """`compact_changed_rows` as PR 37's parent commit (8c7b8a0) has
+    it, less the route-ok column that went with the streaming pipeline:
+    the oracle of the delta half."""
     count = changed.sum().astype(jnp.int32)
     cidx = jnp.nonzero(changed, size=budget, fill_value=p_cap)[0]
     safe = jnp.clip(cidx, 0, p_cap - 1).astype(jnp.int32)
@@ -147,8 +155,6 @@ def _frozen_compact_changed_rows(changed, trips, metric, s3w, nhw, ok,
         s3w[safe].ravel(),
         nhw[safe].ravel(),
     ]
-    if ok is not None:
-        parts.append(ok[safe].astype(jnp.int32))
     if lfa:
         parts += [lfa_slot[safe], lfa_metric[safe]]
     return count, parts
@@ -164,16 +170,16 @@ def parent_pipeline(monkeypatch, variant: ts.PipelineVariant):
     """The parent commit's pipeline for `variant`, as a jitted callable
     of today's arguments: both compactions by `jnp.nonzero`, and — called
     with want_full = 1, which `parent_buffers` does — the cold half in
-    every epoch. Nothing is donated, so a dispatch's arguments can go
-    through it before they go to the executable under test."""
+    every epoch."""
     with monkeypatch.context() as m:
-        m.setattr(stream, "compact_changed_rows", _frozen_compact_changed_rows)
-        m.setattr(stream, "true_rows", _frozen_true_rows)
+        m.setattr(compact, "compact_changed_rows",
+                  _frozen_compact_changed_rows)
+        m.setattr(compact, "true_rows", _frozen_true_rows)
         closure = ts._make_pipeline(
             *variant.shape_key, variant.budget, variant.lfa,
             variant.block_v4, variant.sentinels, variant.emit_dist,
             incr=variant.incr, mesh=None, kernel=variant.kernel,
-            delta_exp=variant.delta_exp, stream=variant.stream,
+            delta_exp=variant.delta_exp,
         )
     return jax.jit(closure)
 
@@ -224,8 +230,8 @@ def split_looked(buf: np.ndarray, variant: ts.PipelineVariant) -> tuple:
 
 class Recorder:
     """Wraps a solver's `_run_exec`: every dispatch's arguments go
-    through the parent's pipeline first (a streaming epoch donates
-    them), then to the executable under test, and the two pairs of
+    through the parent's pipeline, then to the executable under test
+    (neither donates them), and the two pairs of
     buffers are compared by the contract. `epochs` keeps (variant,
     want_full, count, cold) of each dispatch, and `looked` the rows its
     row stages looked at (None but for a `narrow` variant: whichever
@@ -288,7 +294,7 @@ class Recorder:
             return
         np.testing.assert_array_equal(got_d, want_d, err_msg=ctx)
         count = int(got_d[0])
-        cold = bool(want_full) or count > (variant.stream or variant.budget)
+        cold = bool(want_full) or count > variant.budget
         assert got_f.shape == want_f.shape, ctx
         # trips and the scalar tail read the same in every epoch
         assert got_f[1] == want_f[1], ctx
@@ -302,9 +308,9 @@ class Recorder:
 
 MODES = {
     "full": {"incremental_spf": False},
-    "incremental": {"incremental_spf": True, "streaming_pipeline": False},
-    "streaming": {"incremental_spf": True, "streaming_pipeline": True},
+    "incremental": {"incremental_spf": True},
 }
+LFA = pytest.mark.parametrize("lfa", [False, True], ids=["plain", "lfa"])
 
 
 def _cold_count() -> int:
@@ -376,14 +382,13 @@ def test_buffers_equal_the_parents_on_randomized_churn(
     assert first[1] == 1 and first[3]
     assert not any(cold for *_, cold in later), rec.epochs
     assert any(count for _, _, count, _ in later), rec.epochs
-    # the mode's own executable ran (an ineligible epoch of the other
-    # two modes falls back to the full solve)
-    kinds = {(v.incr, bool(v.stream)) for v, *_ in later}
-    assert {"full": (False, False), "incremental": (True, False),
-            "streaming": (True, True)}[mode] in kinds, kinds
+    # the mode's own executable ran (an ineligible epoch of the
+    # incremental mode falls back to the full solve)
+    kinds = {v.incr for v, *_ in later}
+    assert (mode == "incremental") in kinds, kinds
     assert mode != "full" or len(kinds) == 1, kinds
-    # an incremental solve outside the streaming pipeline is the narrow
-    # one (ISSUE 44), and on the grid some of its epochs look at fewer
+    # an incremental solve on one chip is the narrow one (ISSUE 44),
+    # and on the grid some of its epochs look at fewer
     # rows than all: what they leave is the parent's all the same
     looked = [n for (v, *_), n in zip(rec.epochs, rec.looked) if v.narrow]
     assert bool(looked) == (mode == "incremental"), rec.looked
@@ -403,13 +408,14 @@ def test_buffers_equal_the_parents_with_alternates(monkeypatch, mode):
     assert all(v.lfa for v, *_ in rec.epochs)
 
 
-def _captured_dispatch(monkeypatch, budget: int, kind: str):
+def _captured_dispatch(monkeypatch, budget: int, kind: str,
+                       lfa: bool = False):
     """(variant at `budget`, arguments, outputs' planes) of a warm
     dispatch of `kind` on the grid: the stuff to call pipeline closures
     with by hand."""
     adj_dbs, states, ps = _grid()
     churn = _Churn(adj_dbs, states)
-    tpu = TpuSpfSolver(ME, **MODES[kind])
+    tpu = TpuSpfSolver(ME, **MODES[kind], enable_lfa=lfa)
     seen = []
     variants = record_variants(monkeypatch)
     real_exec = tpu._run_exec
@@ -426,19 +432,22 @@ def _captured_dispatch(monkeypatch, budget: int, kind: str):
     churn.set_metric("node-0-1", "node-1-1", 40)
     tpu.build_route_db(ME, states, ps)
     variant, args, planes = seen[-1]
-    if variant.stream:
-        variant = variant._replace(stream=budget)
-    return variant._replace(budget=budget, donate=False), args, planes
+    assert variant.lfa == lfa
+    return variant._replace(budget=budget), args, planes
 
 
+@LFA
 @pytest.mark.parametrize("over", [-1, 0, 1])
 @pytest.mark.parametrize("kind", list(MODES))
-def test_cold_half_runs_from_budget_plus_one(monkeypatch, kind, over):
+def test_cold_half_runs_from_budget_plus_one(monkeypatch, kind, over, lfa):
     """With a table (want_full 0) and exactly budget - 1, budget and
     budget + 1 rows changed: only the last builds `full_buf`, and it is
-    the parent's; with want_full 1 all three do."""
+    the parent's; with want_full 1 all three do. With alternates the
+    cold half gathers two more columns a row."""
     budget = 16
-    variant, args, planes = _captured_dispatch(monkeypatch, budget, kind)
+    variant, args, planes = _captured_dispatch(
+        monkeypatch, budget, kind, lfa
+    )
     assert variant.p_cap > budget + 1
     # previous planes = this epoch's outputs, but for `budget + over`
     # rows of the metric plane: exactly that many rows read as changed
@@ -510,16 +519,18 @@ def test_the_narrow_variant_takes_the_host_s_word(monkeypatch, word):
         assert got_f[0] == 0 and not got_f[2:-tail].any()
 
 
+@LFA
 @pytest.mark.parametrize("mode", list(MODES))
-def test_a_vantage_with_no_table_gets_the_whole_table(monkeypatch, mode):
+def test_a_vantage_with_no_table_gets_the_whole_table(monkeypatch, mode,
+                                                      lfa):
     """vs.valid false with fewer routes than the budget: against zeroed
     planes fewer rows than the budget read as changed, so a predicate
     inferred from the count alone would ship no table. First solve, then
-    a reset (what an abandoned streaming prepare leaves behind)."""
+    a reset."""
     adj_dbs, states, ps = _grid()
     churn = _Churn(adj_dbs, states)
-    cpu = SpfSolver(ME)
-    tpu = TpuSpfSolver(ME, **MODES[mode])
+    cpu = SpfSolver(ME, enable_lfa=lfa)
+    tpu = TpuSpfSolver(ME, **MODES[mode], enable_lfa=lfa)
     rec = Recorder(monkeypatch, tpu)
 
     def solve(ctx, cold: bool):
@@ -547,3 +558,124 @@ def test_a_vantage_with_no_table_gets_the_whole_table(monkeypatch, mode):
     assert 0 < count < variant.budget
     churn.set_metric("node-0-1", "node-1-1", 9)
     solve("warm again", False)
+
+
+# -- what the incremental path downloads, and what the host makes of it -----
+
+
+def _retraces() -> float:
+    return sum(counters.get_counters("xla_cache.retraces.").values())
+
+
+def _warm_incremental(lfa: bool):
+    """(solver, churn, states, ps) of an incremental solver on the grid
+    after its cold full pull and one warm epoch: the incremental
+    executable is built and the vantage has a table."""
+    adj_dbs, states, ps = _grid()
+    churn = _Churn(adj_dbs, states)
+    tpu = TpuSpfSolver(ME, incremental_spf=True, enable_lfa=lfa)
+    tpu.build_route_db(ME, states, ps)
+    churn.set_metric("node-0-1", "node-1-1", 9)
+    tpu.build_route_db(ME, states, ps)
+    assert tpu.last_timing["incremental"]
+    return tpu, churn, states, ps
+
+
+@LFA
+def test_device_changed_set_is_the_host_column_compares(lfa):
+    """The rows an epoch's delta payload names are exactly the rows in
+    which the host's compare of the old and new column bundles
+    (`cols_changed_mask`, what `fast_unicast_column_diff` runs over the
+    journal) finds a difference, and the delta that comes of them is the
+    CPU oracle's — through a withdrawal (a corner cut off: its loopback
+    goes by the ok -> False lane) and its return."""
+    tpu, churn, states, ps = _warm_incremental(lfa)
+    cpu = SpfSolver(ME, enable_lfa=lfa)
+    (vs,) = tpu._vstates.values()
+    db = tpu.build_route_db(ME, states, ps)
+    want_db = dict(cpu.build_route_db(ME, states, ps).unicast_routes)
+
+    def step(ctx):
+        nonlocal db, want_db
+        old = vs.crib.view()
+        new_db = tpu.build_route_db(ME, states, ps)
+        assert not tpu.last_device_stats["full_pull"], ctx
+        new = vs.crib.view()
+        host = np.flatnonzero(
+            cols_changed_mask(old.cols, new.cols, slice(None))
+        )
+        device = np.sort(vs.crib.changed_rows_since(old.epoch))
+        np.testing.assert_array_equal(device, host, err_msg=ctx)
+        assert tpu.last_device_stats["changed_rows"] == len(host), ctx
+        upd = db.calculate_update(new_db)
+        assert upd.columns is not None, ctx
+        want_new = dict(cpu.build_route_db(ME, states, ps).unicast_routes)
+        assert dict(upd.unicast_routes_to_update) == {
+            p: e for p, e in want_new.items() if want_db.get(p) != e
+        }, ctx
+        assert sorted(upd.unicast_routes_to_delete) == sorted(
+            p for p in want_db if p not in want_new
+        ), ctx
+        db, want_db = new_db, want_new
+        return upd
+
+    churn.set_metric("node-0-1", "node-1-1", 1)
+    assert step("a metric comes down").unicast_routes_to_update
+    corner = "node-0-0"
+    saved = [churn.dbs[n] for n in (corner, "node-0-1", "node-1-0")]
+    churn.link_down(corner, "node-0-1")
+    churn.link_down(corner, "node-1-0")
+    assert step("a corner is cut off").unicast_routes_to_delete
+    for adj_db in saved:
+        churn._put(adj_db)
+    assert step("and comes back").unicast_routes_to_update
+
+
+@LFA
+def test_an_idle_epoch_is_one_delta_payload_of_zero_rows(lfa):
+    """An epoch in which nothing changed still downloads the delta
+    payload and nothing else: zero rows, and a busy epoch's bytes less
+    the four words a solve puts in the tail (the rows looked at and the
+    cone's three: nothing is dirty, so the row stages alone run, over no
+    row) — the payload has the budget's shape, two columns a row wider
+    with alternates, not the changed rows'."""
+    tpu, churn, states, ps = _warm_incremental(lfa)
+    churn.set_metric("node-0-1", "node-1-1", 1)
+    tpu.build_route_db(ME, states, ps)
+    busy = tpu.last_device_stats
+    assert busy["changed_rows"] > 0 and not busy["full_pull"]
+    (vs,) = tpu._vstates.values()
+    d_cap, a_cap = vs.shape_key[5], vs.shape_key[7]
+    # count and trips, the rows, the sentinels and the rounds
+    words = 2 + ts._DELTA_BUDGET * (
+        2 + -(-a_cap // 16) + -(-d_cap // 16) + 2 * lfa
+    ) + 2 + 1
+    assert busy["bytes_downloaded"] == 4 * (words + 4)
+    for i in range(2):
+        tpu.build_route_db(ME, states, ps)
+        idle = tpu.last_device_stats
+        assert idle["prefix_only"] and not idle["full_pull"], i
+        assert idle["changed_rows"] == 0, i
+        assert idle["bytes_downloaded"] == 4 * words, i
+        assert tpu.last_timing["bytes_downloaded"] == 4 * words, i
+
+
+@LFA
+def test_warm_churn_compiles_nothing(lfa):
+    """Once the incremental executable is built, churn of the same dirty
+    bucket builds and traces no other, in any namespace."""
+    tpu, churn, states, ps = _warm_incremental(lfa)
+    retraces = _retraces()
+    built = {
+        key: value for key, value in counters.get_counters(
+            "xla_cache."
+        ).items() if key.endswith("factory_misses")
+    }
+    for metric in (12, 19, 4, 88, 2):
+        churn.set_metric("node-0-1", "node-1-1", metric)
+        tpu.build_route_db(ME, states, ps)
+        assert tpu.last_timing["incremental"], metric
+    assert _retraces() == retraces
+    assert {
+        key: counters.get_counter(key) for key in built
+    } == built

@@ -85,3 +85,17 @@ def test_json_roundtrip():
 def test_bad_json():
     with pytest.raises(ConfigError):
         Config.from_json("{not json")
+
+
+def test_a_file_that_names_a_deleted_knob_loads():
+    """`decision_config.streaming_pipeline` went with its path (PR 46):
+    a deployed file that still names it loads — the loader drops the key
+    — and runs the inline path, which is what "off" always meant."""
+    import json
+
+    plain = json.loads(Config(_base()).dump_json())
+    plain["decision_config"]["streaming_pipeline"] = True
+    cfg = Config.from_json(json.dumps(plain))
+    assert cfg.node_name == "node1"
+    assert not hasattr(cfg.raw.decision_config, "streaming_pipeline")
+    assert cfg.raw.decision_config == Config(_base()).raw.decision_config

@@ -288,7 +288,10 @@ class TestMonitorEventLogs:
         q = ReplicateQueue("logSamples-dp")
         mon = Monitor(
             "node1",
-            MonitorConfig(max_event_log_entries=3),
+            # no SLO tracks: the stats they read are the process's, and a
+            # slow convergence of an earlier test in this worker would put
+            # its SLO_BURN_ALERT into this ring of three
+            MonitorConfig(max_event_log_entries=3, slos={}),
             q.get_reader(),
             interval_s=0.05,
         )

@@ -81,6 +81,27 @@ class TestDualUnit:
         for d in net.nodes.values():
             assert d.roots["a"].state is DualState.PASSIVE
 
+    def test_the_end_that_comes_up_second_still_hears_of_the_root(self):
+        """A session's two ends come up one after the other (each after
+        its own full sync): what the first says is dropped by an end that
+        does not track it yet, and the second, knowing no root, has
+        nothing to say — so it asks. Every peer of `c` comes up first."""
+        net = Net()
+        net.add("a", is_root=True)
+        net.add("b")
+        net.add("c")
+        net.connect("a", "b")
+        for first in ("a", "b"):
+            net.nodes[first].peer_up("c")
+            net.pump()
+        assert "a" not in net.nodes["c"].roots
+        for first in ("a", "b"):
+            net.nodes["c"].peer_up(first)
+            net.pump()
+        assert tree_of(net, "a") == {"a": None, "b": "a", "c": "a"}
+        assert net.nodes["a"].roots["a"].children == {"b", "c"}
+        assert net.nodes["c"].flood_peers() == {"a"}
+
     def test_diamond_reconverges_through_active(self):
         #   a (root)
         #  / \

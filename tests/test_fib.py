@@ -326,3 +326,89 @@ class TestFibFullResult:
                 ack = item
         assert ack is not None
         assert ack.unicast_routes_to_update == brute
+
+
+@pytest.mark.parametrize("lfa", [False, True], ids=["plain", "lfa"])
+def test_make_before_break_ledger_is_the_cpu_oracles(lfa):
+    """Each epoch's delta programmed into two scripted netlink
+    dataplanes — one fed by the incremental device solve's column delta,
+    one by the CPU oracle's per-entry delta — with failures injected on
+    the old-metric cleanups of make-before-break and a withdrawal:
+    `_metric`, the `_stale` ledger, the failed sets and every prefix's
+    kernel op sequence stay the same throughout."""
+    import errno
+
+    from openr_tpu.decision.rib import DecisionRouteDb
+    from openr_tpu.decision.spf_solver import SpfSolver
+    from openr_tpu.decision.tpu_solver import TpuSpfSolver
+    from openr_tpu.serde import to_plain
+    from tests.test_column_spine import (
+        _per_prefix_ops,
+        _scripted_dataplane,
+        _ScriptedNetlink,
+    )
+    from tests.test_incremental_spf import ME, _Churn, _grid
+
+    adj_dbs, states, ps = _grid()
+    churn = _Churn(adj_dbs, states)
+    solvers = (
+        TpuSpfSolver(ME, incremental_spf=True, enable_lfa=lfa),
+        SpfSolver(ME, enable_lfa=lfa),
+    )
+    fakes = (_ScriptedNetlink(), _ScriptedNetlink())
+    planes = tuple(_scripted_dataplane(fake) for fake in fakes)
+    dbs = [DecisionRouteDb(), DecisionRouteDb()]
+
+    async def program(dp, fake, upd, fail):
+        fake.fail = dict(fail)
+        if upd.columns is not None:
+            failed = await dp.add_unicast_columns(upd.columns.to_batch())
+        else:
+            failed = await dp.add_unicast({
+                p: to_plain(e)
+                for p, e in dict(upd.unicast_routes_to_update).items()
+            })
+        if upd.unicast_routes_to_delete:
+            failed += await dp.delete_unicast(
+                list(upd.unicast_routes_to_delete)
+            )
+        return sorted(set(failed))
+
+    def step(ctx, fail=()):
+        failed, columns = [], []
+        for i, solver in enumerate(solvers):
+            new = solver.build_route_db(ME, states, ps)
+            upd = dbs[i].calculate_update(new)
+            dbs[i] = new
+            columns.append(upd.columns is not None)
+            failed.append(asyncio.run(program(planes[i], fakes[i], upd, fail)))
+        assert failed[0] == failed[1], ctx
+        assert planes[0]._metric == planes[1]._metric, ctx
+        assert planes[0]._stale == planes[1]._stale, ctx
+        assert _per_prefix_ops(fakes[0]) == _per_prefix_ops(fakes[1]), ctx
+        return columns
+
+    step("the first table")
+    # the one short way to the grid's east edge: every step of its
+    # metric moves routes, and each is a make-before-break transition
+    edge = ("node-2-3", "node-2-4")
+    churn.set_metric(*edge, 2)
+    assert step("metrics move") == [True, False]
+    assert solvers[0].last_timing["incremental"]
+    # the cleanups of the old metric fail: the prefixes wait in _stale
+    cleanups = {
+        ("del", p, m): errno.EBUSY for p, m in planes[1]._metric.items()
+    }
+    churn.set_metric(*edge, 3)
+    step("cleanups fail", cleanups)
+    assert planes[0]._stale
+    churn.set_metric(*edge, 1)
+    step("a clean round clears them")
+    assert not planes[0]._stale
+    saved = [churn.dbs[n] for n in ("node-0-0", "node-0-1", "node-1-0")]
+    churn.link_down("node-0-0", "node-0-1")
+    churn.link_down("node-0-0", "node-1-0")
+    step("a corner is cut off")
+    for adj_db in saved:
+        churn._put(adj_db)
+    step("and comes back")

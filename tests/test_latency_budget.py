@@ -130,7 +130,7 @@ class TestLedgerLifecycle:
 
     def test_requeued_status_counts_separately(self, ledger):
         bud = ledger.begin("k", start=0.0)
-        bud.advance("fence_hold", now=0.003)
+        bud.advance("coalesce_hold", now=0.003)
         row = ledger.close(bud, status="requeued", now=0.003)
         assert row["status"] == "requeued"
         assert counters.get_counter("budget.requeued_epochs") == 1

@@ -826,43 +826,37 @@ def test_chaos_sentinel_catches_cross_thread_solver_dispatch(affinity_on):
     assert db2 is not None
 
 
-def test_purity_and_donation_trace_stream_epoch_roots():
-    """ISSUE 16: the fused streaming-epoch kernel is device code end to
-    end. ops/stream.py rides the ops/ traced prefix (its column-diff +
-    compaction stages are purity-analyzed), the solver module's
-    `pipeline` jit root — which _build_pipeline jits for the fused
-    epoch — is discovered, and the stream stages' function-local
+def test_purity_and_donation_trace_the_pipeline_s_roots():
+    """The pipeline is device code end to end. ops/compact.py rides the
+    ops/ traced prefix (its column-diff + compaction stages are
+    purity-analyzed), the solver module's `pipeline` jit root — which
+    _build_pipeline jits — is discovered, and the stages' function-local
     imports resolve to the traced module, so a host impurity seeded in
-    either stage would flow to the root's findings. The donation
-    checker must index the stream executable's conditional kwargs-dict
-    donation (the epoch double-buffer's donated planes + warm seed),
-    and the shipped modules must run clean."""
+    either stage would flow to the root's findings. The donation checker
+    must index the delta scatter's donated resident array, and the
+    shipped modules must run clean."""
     project = Project(REPO_ROOT, ["openr_tpu"])
-    sf = project.file("openr_tpu/ops/stream.py")
+    sf = project.file("openr_tpu/ops/compact.py")
     assert sf is not None
     assert purity_check._is_traced_file(sf.rel)
     solver = project.file("openr_tpu/decision/tpu_solver.py")
     g = purity_check._ModuleGraph(solver)
     assert "pipeline" in g.traced, g.traced
     assert g.imports.get("column_diff") == (
-        "openr_tpu.ops.stream", "column_diff"
+        "openr_tpu.ops.compact", "column_diff"
     )
     assert g.imports.get("compact_changed_rows") == (
-        "openr_tpu.ops.stream", "compact_changed_rows"
+        "openr_tpu.ops.compact", "compact_changed_rows"
     )
-    # the streaming executable donates the prev planes + distance seed
-    # (positions 10-15, after the want_full scalar) through the
-    # conditional dict form — the read-after-donate rule must see every
-    # position
-    donated = donation_check._factory_donations(
-        g.defs["_build_pipeline"]
-    )
-    assert {10, 11, 12, 13, 14, 15} <= donated, donated
+    # the delta scatter updates the resident array in place: the
+    # read-after-donate rule must see its position
+    donated = donation_check._factory_donations(g.defs["_scatter_jit"])
+    assert donated == {0}, donated
     findings = [
         f
         for f in purity_check.run(project) + donation_check.run(project)
         if f.path in (
-            "openr_tpu/ops/stream.py",
+            "openr_tpu/ops/compact.py",
             "openr_tpu/decision/tpu_solver.py",
         )
     ]

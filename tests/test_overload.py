@@ -308,9 +308,9 @@ class TestAdmissionPriorities:
     def test_brownout_rungs_and_counter_export(self):
         clk = Clock()
         c = _ctl(clk)
-        assert c.streaming_allowed() and c.multichip_allowed()
+        assert c.admit("whatif") and c.multichip_allowed()
         c.observe(queue_depth=8)
-        assert not c.streaming_allowed()
+        assert not c.admit("whatif")
         assert c.multichip_allowed()
         c.observe(queue_depth=16)
         assert not c.multichip_allowed()
@@ -466,7 +466,7 @@ class TestHbmBrownoutDrill:
     @run_async
     async def test_injected_hbm_pressure_downshifts_and_recovers(self):
         """Injected HBM-pressure brownout: the ladder walks up under
-        memory pressure (what-if rejected, streaming surrendered,
+        memory pressure (what-if rejected, the mesh surrendered,
         transition history populated) and back down rung by rung after
         the signal clears — while live convergence keeps working the
         whole way through (no stale-route window)."""
@@ -480,7 +480,6 @@ class TestHbmBrownoutDrill:
             # the Monitor's feed, compressed: worst-device HBM fraction
             # over the high watermark
             assert ctl.observe(hbm_frac=0.95) == BROWNOUT
-            assert not ctl.streaming_allowed()
             assert ctl.admit("whatif") is False
             assert counters.get_counter("overload.brownout") == 1
             # escalate: memory high AND queue at watermark -> shedding
@@ -510,7 +509,7 @@ class TestHbmBrownoutDrill:
             await asyncio.wait_for(drained(), 10.0)
             assert seen[0] > OK and seen[-1] == OK, seen
             assert all(a - b == 1 for a, b in zip(seen, seen[1:])), seen
-            assert ctl.streaming_allowed() and ctl.multichip_allowed()
+            assert ctl.admit("whatif") and ctl.multichip_allowed()
             rep = await h.decision.overload_report()
             assert [t["to"] for t in rep["history"]][-3:] == [
                 "brownout", "backpressure", "ok"

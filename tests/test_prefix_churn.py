@@ -598,7 +598,7 @@ def test_an_epoch_the_candidate_rows_cannot_serve(monkeypatch, case):
         for k in range(4):
             w.advertise(nodes[k], entry_of(w.fresh_prefix()))
     elif case == "vantage_not_valid":
-        vs.valid = False  # as an abandoned streaming prepare leaves it
+        vs.valid = False  # as a reset of the planes leaves it
     elif case == "a_link_event_in_the_same_epoch":
         u, v = next(e for e in w.churn.edges() if ME not in e)
         w.churn.link_down(u, v)

@@ -179,7 +179,7 @@ def test_many_prefixes_a_node_with_alternates_match_the_oracle(seed, mode):
     assert seen["backups"] * 2 >= (n - 1) * per_node, seen
 
 
-@pytest.mark.parametrize("mode", ["full", "incremental", "streaming"])
+@pytest.mark.parametrize("mode", ["full", "incremental"])
 def test_32_a_node_ships_what_the_parent_shipped(monkeypatch, mode):
     """2,048 rows over 64 node columns, LFA on: every dispatch of seeded
     link downs and ups replayed through the parent commit's pipeline

@@ -166,6 +166,20 @@ class TestRecordReplay:
         assert report["epochs_compared"] >= 4, report
 
     @run_async
+    async def test_a_bundle_of_an_older_build_still_replays(self):
+        """A bundle recorded before PR 46 names the streaming pipeline in
+        its meta and a `stream` word in every epoch: both are ignored."""
+        async with DecisionHarness() as h:
+            annex = await _churned_session(h)
+        bundle = json.loads(json.dumps({"node": "1", "inputs": annex}))
+        bundle["inputs"]["meta"]["streaming_pipeline"] = True
+        for ep in bundle["inputs"]["epochs"]:
+            ep["stream"] = {"epochs": 1, "changed_rows": 3, "overflows": 0}
+        report = replay_bundle(bundle)
+        assert report["status"] == "identical", report
+        assert report["epochs_compared"] >= 4, report
+
+    @run_async
     async def test_injected_divergence_bisects_to_tampered_epoch(self):
         async with DecisionHarness() as h:
             annex = await _churned_session(h)

@@ -174,8 +174,9 @@ def run_scenarios(side: int, ksp2_every: int, mc_threshold: int) -> Capture:
     with Capture() as cap:
         # Decision's default: full solve, then the incremental kernel
         solve_then_churn(side, incremental_spf=True)
-        # streaming epoch (donates the resident planes), with LFA
-        solve_then_churn(side, streaming_pipeline=True, enable_lfa=True)
+        # the same with LFA: two more columns a row, in both bodies of
+        # the narrow program's row stages
+        solve_then_churn(side, incremental_spf=True, enable_lfa=True)
         # KSP2: base field, masked batch, then the delta batch
         solve_then_churn(side, ksp2_every, churn=2, incremental_spf=True)
         # the multichip tier, full and incremental
@@ -253,22 +254,26 @@ def captured(topo, cache_off):
 
 
 VARIANTS = {
-    # variant -> (label prefix, must the text alias a donated input?)
-    "full": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,bk1]", False),
-    "incremental": ("pipeline_incr[n=4096,", False),
-    "full_lfa": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,lfa,bk1]", False),
-    "streaming_lfa_donating": ("pipeline_stream[n=4096,", True),
-    "delta_scatter_donating": ("_scatter_jit", True),
-    "ksp2_base": ("_base_sssp_fn", False),
-    "ksp2_masked_batch": ("_masked_rows_fn", False),
-    "ksp2_delta_batch": ("_masked_rows_delta_fn", False),
+    # variant -> (label prefix, with LFA?, must the text alias a
+    # donated input?)
+    "full": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,bk1]", False, False),
+    "incremental": ("pipeline_incr[n=4096,", False, False),
+    "full_lfa": ("pipeline[n=4096,s=4,d=4,p=4096,a=2,lfa,bk1]", True, False),
+    "incremental_lfa": ("pipeline_incr[n=4096,", True, False),
+    "delta_scatter_donating": ("_scatter_jit", False, True),
+    "ksp2_base": ("_base_sssp_fn", False, False),
+    "ksp2_masked_batch": ("_masked_rows_fn", False, False),
+    "ksp2_delta_batch": ("_masked_rows_delta_fn", False, False),
 }
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 def test_program_compiles_for_a_described_v5e(captured, one_chip, variant):
-    prefix, donating = VARIANTS[variant]
-    labels = [k for k in captured.programs if k.startswith(prefix)]
+    prefix, lfa, donating = VARIANTS[variant]
+    labels = [
+        k for k in captured.programs
+        if k.startswith(prefix) and (",lfa" in k) == lfa
+    ]
     assert labels, (variant, sorted(captured.programs))
     for label in labels:
         compiled = compile_single(one_chip, *captured.programs[label])

@@ -2,8 +2,7 @@
 `inputs` annex through the real Decision ingest path and bit-compare
 per-epoch RIB digests against the recording.
 
-    python -m tools.replay <bundle-dir | bundle.json> [--solver cpu|tpu]
-                           [--streaming on|off] [-v]
+    python -m tools.replay <bundle-dir | bundle.json> [--solver cpu|tpu] [-v]
     python -m tools.replay --selftest --out <dir>
 
 A RIB is a deterministic function of the ordered LSDB event stream
@@ -21,11 +20,10 @@ The verdict is a bisection: the first epoch whose replayed digest
 differs from the recording is printed with its recorded solver
 kind/kernel and the event window that fed it — from there the
 subsystem runbook takes over (docs/Operations.md § Incident replay).
-`--solver cpu|tpu` and `--streaming on|off` turn the same bundle into
-an A/B parity test: a recording made by the streaming device pipeline
-must replay bit-identically on the CPU oracle, so a digest mismatch
-localizes WHICH side (and which epoch) diverged over real incident
-data.
+`--solver cpu|tpu` turns the same bundle into an A/B parity test: a
+recording made by the device pipeline must replay bit-identically on
+the CPU oracle, so a digest mismatch localizes WHICH side (and which
+epoch) diverged over real incident data.
 
 Exit status: 0 bit-identical, 1 diverged (first divergent epoch
 printed), 2 not replayable (no annex, or the event ring had a gap).
@@ -64,8 +62,7 @@ def load_bundle(path: str) -> dict:
     return bundle
 
 
-def _headless_decision(node: str, solver: str, streaming: bool,
-                       spf_kernel: str):
+def _headless_decision(node: str, solver: str, spf_kernel: str):
     """A real Decision, driven synchronously: no event loop, no
     debounce, readerless route-updates queue, recorder off (replay
     must not re-record itself)."""
@@ -76,7 +73,6 @@ def _headless_decision(node: str, solver: str, streaming: bool,
     cfg = DecisionConfig(
         solver_backend=solver,
         spf_kernel=spf_kernel,
-        streaming_pipeline=streaming,
         async_dispatch=False,
         replay_recorder=False,
     )
@@ -130,7 +126,6 @@ def _solve(d, full: bool) -> str:
 def replay_bundle(
     bundle: dict,
     solver: str = "cpu",
-    streaming: bool = False,
     verbose: bool = False,
     out=sys.stdout,
 ) -> dict:
@@ -168,10 +163,9 @@ def replay_bundle(
     node = inputs.get("node", bundle.get("node", ""))
     spf_kernel = meta.get("spf_kernel", "bucketed")
 
-    d = _headless_decision(node, solver, streaming, spf_kernel)
+    d = _headless_decision(node, solver, spf_kernel)
     say(
-        f"replaying node={node!r} solver={solver} "
-        f"streaming={'on' if streaming else 'off'}: "
+        f"replaying node={node!r} solver={solver}: "
         f"snapshot@cursor={snapshot['cursor']} "
         f"base_epoch={snapshot['base_epoch']}, "
         f"{len(events)} events, {len(epochs)} epochs"
@@ -228,7 +222,6 @@ def replay_bundle(
                 "replayed": replayed,
                 "solver_kind": ep.get("solver_kind"),
                 "spf_kernel": ep.get("spf_kernel"),
-                "stream": ep.get("stream"),
                 "event_keys": [ev["key"] for ev in window],
             }
 
@@ -236,7 +229,6 @@ def replay_bundle(
         "status": "diverged" if first_divergent else "identical",
         "node": node,
         "solver": solver,
-        "streaming": streaming,
         "recorded_meta": meta,
         "epochs_compared": len(compared),
         "epochs": compared,
@@ -253,8 +245,7 @@ def _print_verdict(report: dict, out=sys.stdout) -> None:
     if report["status"] == "identical":
         print(
             f"IDENTICAL: {n} epoch digests replayed bit-identically "
-            f"(solver={report['solver']}, "
-            f"streaming={'on' if report['streaming'] else 'off'})",
+            f"(solver={report['solver']})",
             file=out,
         )
         return
@@ -264,7 +255,7 @@ def _print_verdict(report: dict, out=sys.stdout) -> None:
         f"(first of {n} compared): recorded {fd['recorded']} != "
         f"replayed {fd['replayed']}\n"
         f"  recorded solver_kind={fd['solver_kind']} "
-        f"spf_kernel={fd['spf_kernel']} stream={fd['stream']}\n"
+        f"spf_kernel={fd['spf_kernel']}\n"
         f"  epoch's event window ({len(fd['event_keys'])} keys): "
         f"{', '.join(fd['event_keys'][:8])}"
         f"{' ...' if len(fd['event_keys']) > 8 else ''}\n"
@@ -428,10 +419,6 @@ def main(argv: Optional[list[str]] = None) -> int:
         "--solver", choices=("cpu", "tpu"), default="cpu",
         help="solver backend to replay on (default cpu)",
     )
-    ap.add_argument(
-        "--streaming", choices=("on", "off"), default="off",
-        help="streaming pipeline for the replay solver (tpu only)",
-    )
     ap.add_argument("-v", "--verbose", action="store_true")
     ap.add_argument("--json", action="store_true",
                     help="print the full report as JSON")
@@ -450,7 +437,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     report = replay_bundle(
         bundle,
         solver=args.solver,
-        streaming=args.streaming == "on",
         verbose=args.verbose,
     )
     if args.json:
