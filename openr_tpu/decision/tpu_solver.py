@@ -2927,10 +2927,17 @@ class TpuSpfSolver:
                 d_cap = d_pad
         # one lane of the [d_cap, n_cap] distance plane a link of the
         # vantage; the rest of d_cap is padding every pass still pays for
-        lanes = {"spf_sources": len(links), "spf_lanes": d_cap}
+        # and what a row holds: every row stage pays for a_cap announcer
+        # slots a row, whatever `announcer_cells` of them hold an
+        # advertiser; `select` has a choice to make in the rows with two
+        p_cap, a_cap = matrix.ann_node.shape
+        lanes = {
+            "spf_sources": len(links), "spf_lanes": d_cap,
+            "announcer_slots": a_cap, "announcer_cells": matrix.n_cells,
+            "multi_announcer_rows": matrix.n_multi,
+        }
         for key, value in lanes.items():
             counters.set_counter(f"decision.tpu.{key}", value)
-        p_cap, a_cap = matrix.ann_node.shape
         r_cap, kr_cap = plan.res_nbr.shape
         has_res = plan.k_res > 0
         shape_key = (
@@ -3581,6 +3588,7 @@ class TpuSpfSolver:
             if full_changed is not None:
                 mat_attrs["full_changed_rows"] = full_changed
                 stats["full_changed_rows"] = full_changed
+                self._count("decision.tpu.full_changed_rows", full_changed)
             if lfa and crib.cols.lfa_slot is not None:
                 # routes the table holds, and those of them that carry a
                 # loop-free alternate

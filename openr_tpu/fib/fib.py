@@ -44,7 +44,7 @@ from openr_tpu.runtime.actor import Actor
 from openr_tpu.runtime.counters import counters
 from openr_tpu.runtime.faults import maybe_fail
 from openr_tpu.runtime.latency_budget import latency_budget
-from openr_tpu.runtime.lifecycle import boot_tracer
+from openr_tpu.runtime.lifecycle import boot_tracer, freeze_boot_heap
 from openr_tpu.runtime.throttle import ExponentialBackoff
 from openr_tpu.runtime.tracing import TraceContext, tracer
 from openr_tpu.types import (
@@ -486,6 +486,7 @@ class Fib(Actor):
                 ),
             )
             boot_tracer.complete(node=self.node_name)
+            freeze_boot_heap()
             self._fib_updates_q.push(InitializationEvent.FIB_SYNCED)
 
     # -- dirty-route retry (ref retryRoutes Fib.cpp:345-430) ---------------

@@ -106,6 +106,10 @@ class PrefixMatrix:
     # _AreaDevs that hold this matrix (the PrefixState memo shares it
     # between solvers): only a sole holder changes it in place
     holders: int = 0
+    # valid announcer cells, and rows with two or more of them: counted
+    # where the matrix is built, kept where a row is cleared and filled
+    n_cells: int = 0
+    n_multi: int = 0
 
     @property
     def n_prefixes(self) -> int:
@@ -221,6 +225,8 @@ class PrefixMatrix:
                 )
             self.node_areas[r] = nas
             self.entry_refs[r] = refs
+            self.n_cells += len(refs)
+            self.n_multi += len(refs) >= 2
             rows.append(r)
         rows = list(dict.fromkeys(rows))
         if rows:
@@ -231,6 +237,9 @@ class PrefixMatrix:
     def _clear_row(self, r: int) -> None:
         """No announcer in the row's cells. Its name and its entry refs
         stay: an earlier generation's view may still read them."""
+        cells = int(np.count_nonzero(self.ann_valid[r]))
+        self.n_cells -= cells
+        self.n_multi -= cells >= 2
         self.ann_node[r] = -1
         self.ann_valid[r] = False
         self.path_pref[r] = _NEG32
@@ -304,6 +313,7 @@ def build_prefix_matrix(
     # in one shot (per-cell numpy scalar stores are ~10x slower at the
     # 100k-prefix scale)
     cells: list[tuple] = []
+    n_multi = 0
     cell_append = cells.append
     pl_append = prefix_list.append
     na_append = node_areas.append
@@ -332,6 +342,7 @@ def build_prefix_matrix(
             ))
             row_nas.append(na)
             row_entries.append(entry)
+        n_multi += len(row_nas) >= 2
         na_append(row_nas)
         er_append(row_entries)
     if p:
@@ -361,4 +372,6 @@ def build_prefix_matrix(
         min_nexthop=min_nexthop,
         is_v4=is_v4,
         entry_refs=entry_refs,
+        n_cells=len(cells),
+        n_multi=n_multi,
     )

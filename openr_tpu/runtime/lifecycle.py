@@ -44,6 +44,7 @@ multi-node test processes only the node that ``begin``-ed records.
 from __future__ import annotations
 
 import contextlib
+import gc
 import threading
 import time
 from typing import Optional
@@ -218,3 +219,45 @@ class BootTracer:
 
 
 boot_tracer = BootTracer()
+
+
+_heap_frozen = False
+
+
+def freeze_boot_heap() -> None:
+    """Once a process: take what the boot has built, the LSDB, the first
+    table and Fib's copy of it, out of the cyclic collector's sight
+    (`gc.freeze`, after one full collection so that no garbage is kept).
+    Those objects live as long as the process, or die by reference count
+    when an update replaces them; none needs the collector. Left in its
+    oldest generation they are walked whole by every collection of that
+    generation, and an event that builds a table's worth of routes sets
+    one off (PERF.md section 6, PR 47: at 200,000 prefix entries and
+    50,000 routes, 0.34-0.44 s in every second event). After the freeze
+    such a collection walks what events have made since and no more.
+
+    The freeze takes the collector's brake with it, so the oldest
+    generation's threshold is raised in the same call: CPython runs a
+    full collection only once a quarter as many objects have come of age
+    as survived the last one, frozen objects do not count as survivors,
+    and without the brake the oldest generation runs every tenth time the
+    middle one does. Measured with the freeze alone (PERF.md section 4,
+    call 2, PR 47): about one collection an event, short where a table is
+    rebuilt whole, but 135-293 ms in nearly every plane drain of
+    fabric10k.plane, whose median then carries them (+5 and +31 % in two
+    pairs). A tenth as often, the median event carries none in the three
+    cells measured (call 3).
+
+    The first Fib of a process to program its first table calls it, the
+    last step of the initialization sequence wherever the stack was built
+    (`main.run_daemon`, a harness that wires the actors itself); later
+    ones (a test process runs many stacks) leave the heap alone."""
+    global _heap_frozen
+    if _heap_frozen:
+        return
+    _heap_frozen = True
+    gc.collect()
+    gc.freeze()
+    young, middle, oldest = gc.get_threshold()
+    gc.set_threshold(young, middle, oldest * 10)
+    counters.set_counter("runtime.gc.frozen_objects", gc.get_freeze_count())

@@ -381,3 +381,37 @@ def test_the_prefix_only_solve_compiles_with_no_loop(one_chip, cache_off):
         S((cells,), np.int32),
     ))
     assert scatter.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_four_advertiser_class_compiles(one_chip, cache_off):
+    """wan50k_region's incremental executable (ISSUE 47: 65,536 prefix
+    rows of FOUR announcer slots over a 1,024-column node plane, LFA, the
+    `narrow` variant the dispatcher asks for) from its variant record
+    alone. Every cell before it ran `a_cap` 2 with one slot in use; here
+    `select`, `nexthop` and `lfa` reduce and gather over four live cells a
+    row, the flat candidate mask is [262144] long, and an uplink's step or
+    a border router's drain takes the all-rows side and the cold pull in
+    one epoch. The compiler plans ~0.4 GB of temporaries for it."""
+    S = jax.ShapeDtypeStruct
+    key = (1024, 4, 2048, 4, True, 4, 65536, 4)
+    record = ts.PipelineVariant.checked(
+        *key, ts._DELTA_BUDGET, True, True, True, emit_dist=True,
+        dirty_cap=64, narrow=True,
+    )
+    assert "a=4" in record.name
+    avals = ts._pipeline_avals(key) + (
+        S((4, 1024), np.int32),
+        *(S((64,), np.int32) for _ in range(4)),
+        S((), np.int32),
+        S((ts._DELTA_BUDGET,), np.int32), S((), np.int32),
+    )
+    _name, run = ts._build_pipeline(*record)
+    compiled = compile_single(one_chip, run.jitted, avals)
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    mask = [
+        line for line in text.splitlines()
+        if 'op_name="jit(pipeline)/candidates/' in line
+    ]
+    assert mask and not any("[65536,4]" in line for line in mask)
