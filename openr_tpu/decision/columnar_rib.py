@@ -32,14 +32,23 @@ Three cooperating pieces:
                   with `overrides`/`deleted` capturing post-build
                   mutations (statics, RibPolicy edits) without forcing.
 
-Entry identity is preserved exactly: `build_entries` below is the
-former `tpu_solver._build_entries` loop, moved verbatim so columnar and
-eager materialization are byte-identical (asserted by the property test
-in tests/test_columnar_rib.py).
+Entry identity is preserved exactly: `build_entries` below builds the
+entries the former eager loop (`tpu_solver._build_entries`) built, field
+for field, so columnar and eager materialization are byte-identical
+(asserted by the property test in tests/test_columnar_rib.py, and case
+by case against `RibUnicastEntry(...)`'s own `__init__`). It builds them
+BY GROUP (PR 48): what a row's packed columns decide — next hops,
+alternate, cost — is built once per distinct value of those columns and
+copied into each entry, the announcer an entry names comes from numpy,
+and the loop over the rows does only what is the row's own (prefix,
+advertisement, one dict, one object, one insert). One path for one row
+and for 300,000; `decision.rib.entry_groups` beside
+`decision.rib.entries_built` says how many values the rows took.
 """
 
 from __future__ import annotations
 
+import struct
 import weakref
 from collections.abc import MutableMapping
 from typing import Optional
@@ -53,6 +62,7 @@ from openr_tpu.runtime.counters import counters
 
 INF_E = int(INF32E)
 _entry_new = object.__new__
+_entry_set = object.__setattr__
 
 # journal records retained per crib; an older snapshot falls back to the
 # full per-entry compare (bounded memory, not bounded correctness)
@@ -72,21 +82,27 @@ def _entry_defaults() -> tuple[dict, list]:
     """(plain defaults, per-entry default factories) of RibUnicastEntry,
     derived from the dataclass itself so the fast constructor below
     cannot silently desynchronize when a defaulted field is added to the
-    schema. Factory-defaulted fields the loop does not overwrite are
-    CALLED PER ENTRY — sharing one factory product across all entries
-    would alias a future mutable default."""
+    schema. The dict holds every field in the schema's order, the ones
+    the loop sets as placeholders. Factory-defaulted fields the loop does
+    not overwrite are CALLED PER ENTRY — sharing one factory product
+    across all entries would alias a future mutable default."""
     import dataclasses
 
     plain = {}
     factories = []
     for f in dataclasses.fields(RibUnicastEntry):
+        plain[f.name] = None  # placeholder where set per group or entry
+        if f.name in _ENTRY_SET_FIELDS:
+            continue
         if f.default is not dataclasses.MISSING:
             plain[f.name] = f.default
         elif f.default_factory is not dataclasses.MISSING:  # type: ignore[misc]
-            if f.name in _ENTRY_SET_FIELDS:
-                plain[f.name] = None  # placeholder; always overwritten
-            else:
-                factories.append((f.name, f.default_factory))  # type: ignore[misc]
+            factories.append((f.name, f.default_factory))  # type: ignore[misc]
+        else:
+            raise TypeError(
+                f"RibUnicastEntry.{f.name} has no default and "
+                "build_entries does not set it"
+            )
     return plain, factories
 
 
@@ -149,114 +165,181 @@ def route_ok_rows(matrix, root_idx: int, rows, met, s3, nh,
     return ok
 
 
+def _next_hop(link, my_node_name: str, use_v4: bool, metric: int) -> NextHop:
+    """The next hop over `link` as seen from this node (family-aware
+    address, ref createNextHop: a v4 prefix takes the link's v4 address
+    unless v4-over-v6 is on)."""
+    return NextHop(
+        address=link.nh_from_node(my_node_name, use_v4),
+        if_name=link.iface_from_node(my_node_name),
+        metric=metric,
+        area=link.area,
+        neighbor_node_name=link.other_node(my_node_name),
+    )
+
+
+_NO_LFA = frozenset()
+
+
+def _group_template(key: bytes, nh_stride: int, lfa: bool, nh_cache: dict,
+                    my_node_name: str, links) -> dict:
+    """The entry dict of one group, everything its columns decide filled
+    in: `key` is one row of build_entries' key plane (next-hop bits, then
+    metric, family, and where the table has them the alternate's slot and
+    metric as int32), decoded here so a group needs no row to stand for
+    it. Next hops go through `nh_cache` under the keys they always had:
+    the frozensets are shared by a group's entries and across events."""
+    n_links = len(links)
+    tail = struct.unpack_from("<4i" if lfa else "<2i", key, nh_stride)
+    m, use_v4 = tail[0], bool(tail[1])
+    nh_key = (key[:nh_stride], m, use_v4)
+    nexthops = nh_cache.get(nh_key)
+    if nexthops is None:
+        # np.packbits' order: link d is bit 7 - d % 8 of byte d // 8
+        nexthops = nh_cache[nh_key] = frozenset(
+            _next_hop(links[d], my_node_name, use_v4, m)
+            for d in range(n_links)
+            if key[d >> 3] & (0x80 >> (d & 7))
+        )
+    lfa_nexthops = _NO_LFA
+    if lfa and 0 <= tail[2] < n_links:
+        slot, alt_m = tail[2], tail[3]
+        lkey = ("lfa", slot, alt_m, use_v4)
+        lfa_nexthops = nh_cache.get(lkey)
+        if lfa_nexthops is None:
+            lfa_nexthops = nh_cache[lkey] = frozenset(
+                {_next_hop(links[slot], my_node_name, use_v4, alt_m)}
+            )
+    d = dict(_ENTRY_DEFAULTS)
+    d["nexthops"] = nexthops
+    d["igp_cost"] = m
+    d["lfa_nexthops"] = lfa_nexthops
+    return d
+
+
+def _best_announcers(s3v: np.ndarray, n_sel: np.ndarray, rows_l: list,
+                     node_areas: list, my_node_name: str) -> list:
+    """Per row the index of the announcer whose advertisement the route
+    carries: the one selected, or among several `select_best_node_area`'s
+    choice (self first, else the minimum) - run once per distinct case,
+    a case being the selected bits and the row's announcers by name.
+    An index past the row's names (`s3v.shape[1]`) where none of them is
+    selected."""
+    best = s3v.argmax(axis=1).tolist()
+    multi = np.flatnonzero(n_sel > 1)
+    if not len(multi):
+        return best
+    bits = np.packbits(s3v[multi], axis=1)
+    stride = bits.shape[1]
+    bits_b = bits.tobytes()
+    none = s3v.shape[1]
+    cases: dict = {}
+    for j, i in enumerate(multi.tolist()):
+        nas = node_areas[rows_l[i]]
+        case = (bits_b[j * stride:(j + 1) * stride], tuple(nas))
+        ba = cases.get(case)
+        if ba is None:
+            row = s3v[i].tolist()
+            sel = {na: a for a, na in enumerate(nas) if row[a]}
+            ba = cases[case] = (
+                sel[select_best_node_area(set(sel), my_node_name)]
+                if sel else none
+            )
+        best[i] = ba
+    return best
+
+
 def build_entries(
     routes: dict, nh_cache: dict, my_node_name: str, matrix, links, rows,
     met, s3, nh, lfa_slot=None, lfa_metric=None, value_rows=None,
     use_v4_allowed: bool = True,
-) -> None:
+) -> tuple[int, int]:
     """Construct RibUnicastEntry for the given matrix rows into `routes`.
     met/s3/nh (and lfa arrays) are indexed by value_rows (delta path) or
-    by matrix row (full)."""
+    by matrix row (full). -> (entries built, groups resolved).
+
+    Built by group: what a row's columns decide - next hops, alternate,
+    cost - is one template dict per distinct value of the columns
+    (`_group_template`), and the announcer an entry names comes from
+    numpy (`_best_announcers`). The loop over the rows does what is the
+    row's own: a copy of its group's template, the prefix, the
+    announcer's advertisement, one RibUnicastEntry, one insert. A drain
+    or an exit's shift moves tens of thousands of rows onto a handful of
+    values; rows that each differ make a group a row, and the loop then
+    costs what building their next hops costs."""
+    vi = rows if value_rows is None else value_rows
+    s3v = s3[vi]
+    n_sel = s3v.sum(axis=1)
+    if not n_sel.all():
+        # no selected announcer, no route
+        live = n_sel > 0
+        rows, vi, s3v, n_sel = rows[live], vi[live], s3v[live], n_sel[live]
+    n = len(rows)
+    if not n:
+        return 0, 0
     node_areas = matrix.node_areas
-    entry_refs = matrix.entry_refs
-    prefix_list = matrix.prefix_list
-    # row data as Python lists / flat bytes: the loop below runs for
-    # every changed route (all ~100k on a cold rebuild) and per-row
-    # numpy scalar indexing costs ~10x a list index
-    nh_bytes = np.packbits(nh, axis=1).tobytes()
-    nh_stride = -(-nh.shape[1] // 8) if len(rows) else 1
     rows_l = rows.tolist()
-    vi_l = value_rows.tolist() if value_rows is not None else rows_l
-    met_l = met.tolist()
-    s3_l = s3.tolist()
-    nh_l = nh.tolist()
-    lfa_slot_l = lfa_slot.tolist() if lfa_slot is not None else None
-    lfa_metric_l = lfa_metric.tolist() if lfa_metric is not None else None
-    no_lfa = frozenset()
-    n_links = len(links)
-    # family-aware next-hop addresses (ref createNextHop): v4
-    # prefixes take the link's v4 address unless v4-over-v6 is on.
-    # Sliced by row — the delta path calls this for a handful of
-    # rows and must not pay an O(P) conversion.
-    v4_rows_l = matrix.is_v4[rows].tolist()
-    built = 0
-    for i, p in enumerate(rows_l):
-        vi = vi_l[i]
-        row = s3_l[vi]
-        nas = node_areas[p]
-        sel = [(a, na) for a, na in enumerate(nas) if row[a]]
-        if not sel:
+    best_l = _best_announcers(s3v, n_sel, rows_l, node_areas, my_node_name)
+    # the key plane: a row's bytes are its group's name (next-hop bits,
+    # then metric, family, alternate as int32)
+    nh_bits = np.packbits(nh[vi], axis=1)
+    nh_stride = nh_bits.shape[1]
+    lfa = lfa_slot is not None
+    plane = np.empty((n, nh_stride + (16 if lfa else 8)), np.uint8)
+    plane[:, :nh_stride] = nh_bits
+    tail = plane[:, nh_stride:].view("<i4")
+    tail[:, 0] = met[vi]
+    # a v4 prefix takes the link's v4 address unless v4-over-v6 is on
+    tail[:, 1] = matrix.is_v4[rows] if use_v4_allowed else 0
+    if lfa:
+        tail[:, 2] = lfa_slot[vi]
+        tail[:, 3] = lfa_metric[vi]
+    # flat bytes and Python lists: per-row numpy indexing costs ~10x a
+    # list's
+    keys = plane.view(np.dtype((np.void, plane.shape[1]))).ravel().tolist()
+    templates = {
+        key: _group_template(
+            key, nh_stride, lfa, nh_cache, my_node_name, links
+        )
+        for key in set(keys)
+    }
+    skipped = 0
+    for prefix, refs, nas, ba, template in zip(
+        map(matrix.prefix_list.__getitem__, rows_l),
+        map(matrix.entry_refs.__getitem__, rows_l),
+        map(node_areas.__getitem__, rows_l),
+        best_l,
+        map(templates.__getitem__, keys),
+    ):
+        try:
+            ref = refs[ba]
+        except IndexError:
+            # the selected bits lie past the row's names (a generation
+            # older than the row's advertisement): no route
+            skipped += 1
             continue
-        m = met_l[vi]
-        use_v4 = use_v4_allowed and v4_rows_l[i]
-        key = (nh_bytes[vi * nh_stride:(vi + 1) * nh_stride], m, use_v4)
-        nexthops = nh_cache.get(key)
-        if nexthops is None:
-            nh_row = nh_l[vi]
-            nexthops = frozenset(
-                NextHop(
-                    address=links[d].nh_from_node(my_node_name, use_v4),
-                    if_name=links[d].iface_from_node(my_node_name),
-                    metric=m,
-                    area=links[d].area,
-                    neighbor_node_name=links[d].other_node(my_node_name),
-                )
-                for d in range(n_links)
-                if nh_row[d]
-            )
-            nh_cache[key] = nexthops
-        lfa_nexthops = no_lfa
-        if lfa_slot_l is not None:
-            d = lfa_slot_l[vi]
-            if 0 <= d < n_links:
-                alt_m = lfa_metric_l[vi]
-                lkey = ("lfa", d, alt_m, use_v4)
-                lfa_nexthops = nh_cache.get(lkey)
-                if lfa_nexthops is None:
-                    lfa_nexthops = frozenset({
-                        NextHop(
-                            address=links[d].nh_from_node(
-                                my_node_name, use_v4
-                            ),
-                            if_name=links[d].iface_from_node(my_node_name),
-                            metric=alt_m,
-                            area=links[d].area,
-                            neighbor_node_name=links[d].other_node(
-                                my_node_name
-                            ),
-                        )
-                    })
-                    nh_cache[lkey] = lfa_nexthops
-        if len(sel) == 1:
-            ba, best = sel[0]
-        else:
-            best = select_best_node_area(
-                {na for _, na in sel}, my_node_name
-            )
-            ba = next(a for a, na in sel if na == best)
-        prefix = prefix_list[p]
         # bypass the dataclass __init__ (per-field object.__setattr__
-        # x9) — this loop constructs one entry per route on a cold
-        # 100k rebuild; equality/hash read the same attributes either
-        # way, and unset fields come from the schema-derived defaults
-        entry = _entry_new(RibUnicastEntry)
-        d = dict(_ENTRY_DEFAULTS)
+        # x9): equality/hash read the same attributes either way, and
+        # the template holds the schema-derived defaults
+        d = template.copy()
         for fname, factory in _ENTRY_FACTORIES:
             d[fname] = factory()
         d["prefix"] = prefix
-        d["nexthops"] = nexthops
-        d["best_prefix_entry"] = entry_refs[p][ba]
-        d["best_node_area"] = best
-        d["igp_cost"] = m
-        d["lfa_nexthops"] = lfa_nexthops
-        entry.__dict__.update(d)
+        d["best_prefix_entry"] = ref
+        d["best_node_area"] = nas[ba]
+        entry = _entry_new(RibUnicastEntry)
+        _entry_set(entry, "__dict__", d)  # the dict itself, no copy
         routes[prefix] = entry
-        built += 1
+    built = n - skipped
     if built:
         # the zero-objects gate for the columnar spine: any hot path
         # that claims to stay in packed-array land is asserted against
         # this counter standing still
         counters.increment("decision.rib.entries_built", built)
+        # how often the grouping engages: groups / built near 0 is rows
+        # that read alike, near 1 traffic that bypasses it
+        counters.increment("decision.rib.entry_groups", len(templates))
+    return built, len(templates)
 
 
 def row_index(matrix) -> dict:
@@ -407,6 +490,11 @@ class ColumnarRib:
         # per-row cache (invalidated row-wise by apply_rows)
         self.materialized = False
         self.nh_cache: dict = {}
+        # entries built from this crib's columns over its life, and the
+        # groups they were built by (`build_entries`): the solver stamps
+        # what an epoch's materialization gained on its `tpu.mat` span
+        self.entries_built = 0
+        self.entry_groups = 0
         self._views: "weakref.WeakSet[RibView]" = weakref.WeakSet()
 
     # -- mutation (solver side) -------------------------------------------
@@ -598,12 +686,12 @@ class ColumnarRib:
             self._drop_rows(rows[~np.asarray(ok, bool)])
             keep = np.flatnonzero(ok)
             if len(keep):
-                build_entries(
+                self._count_built(build_entries(
                     self.routes, self.nh_cache, self.my_node_name,
                     self.matrix, self.links, rows[keep], met, s3, nhm,
                     lfa_slot, lfa_metric, value_rows=keep,
                     use_v4_allowed=self.use_v4_allowed,
-                )
+                ))
         elif self.routes:
             self._drop_rows(rows)
 
@@ -635,11 +723,15 @@ class ColumnarRib:
         entries = [x for e, _r, x in self.journal if e > epoch]
         return len(entries) == 1 and entries[0]
 
+    def _count_built(self, built_groups: tuple[int, int]) -> None:
+        self.entries_built += built_groups[0]
+        self.entry_groups += built_groups[1]
+
     def _build_rows_into(self, cols: _Cols, rows: np.ndarray,
                          routes: dict) -> None:
         a_cap = self.matrix.ann_node.shape[1]
         d_n = len(self.links)
-        build_entries(
+        self._count_built(build_entries(
             routes, self.nh_cache, self.my_node_name, self.matrix,
             self.links, rows,
             cols.met[rows],
@@ -649,7 +741,7 @@ class ColumnarRib:
             None if cols.lfa_metric is None else cols.lfa_metric[rows],
             value_rows=np.arange(len(rows)),
             use_v4_allowed=self.use_v4_allowed,
-        )
+        ))
 
     def materialize(self) -> dict:
         """Bulk-build every ok row (the consumption-boundary path)."""

@@ -3446,6 +3446,7 @@ class TpuSpfSolver:
             counters.increment("decision.tpu.epochs")
             if full_pull:
                 counters.increment("decision.tpu.cold_compactions")
+            built0, groups0 = crib.entries_built, crib.entry_groups
             stats = {
                 "n_cap": plan.n_cap,
                 "s_cap": plan.s_cap,
@@ -3589,6 +3590,17 @@ class TpuSpfSolver:
                 mat_attrs["full_changed_rows"] = full_changed
                 stats["full_changed_rows"] = full_changed
                 self._count("decision.tpu.full_changed_rows", full_changed)
+            if crib.entries_built > built0:
+                # what this epoch's patch of the entry cache built, and
+                # by how many groups (columnar_rib.build_entries): groups
+                # / built near 0 is rows that read alike, near 1 rows
+                # that each differ
+                mat_attrs["entries_built"] = stats["entries_built"] = (
+                    crib.entries_built - built0
+                )
+                mat_attrs["entry_groups"] = stats["entry_groups"] = (
+                    crib.entry_groups - groups0
+                )
             if lfa and crib.cols.lfa_slot is not None:
                 # routes the table holds, and those of them that carry a
                 # loop-free alternate

@@ -310,6 +310,64 @@ def test_warm_epochs_build_no_key_index_and_only_changed_entries():
     assert builds >= 1  # the counter does fire: the index was built once
 
 
+@pytest.mark.parametrize("distinct", [False, True],
+                         ids=["one_value", "every_row_distinct"])
+def test_entry_groups_count_what_the_rows_columns_tell_apart(distinct):
+    """decision.rib.entry_groups beside decision.rib.entries_built: N
+    leaves behind one hub, their N routes moved by one event of the
+    vantage's own link. Leaves at one distance read alike (one group),
+    leaves at N distances do not (N groups); N entries are built either
+    way, and the epoch's tpu.mat span and last_device_stats carry both
+    counts."""
+    from openr_tpu.types import PrefixForwardingAlgorithm
+
+    n = 12
+    me, hub = "node-me", "node-hub"
+    leaves = [f"node-leaf{i:02d}" for i in range(n)]
+    adj = topologies._adj
+    nodes = {me: [adj(me, hub)], hub: [adj(hub, me)]}
+    for i, leaf in enumerate(leaves):
+        far = 1 + i if distinct else 1
+        nodes[hub].append(adj(hub, leaf, metric=far))
+        nodes[leaf] = [adj(leaf, hub, metric=far)]
+    adj_dbs, prefix_dbs = topologies._mk_dbs(
+        nodes, "0", PrefixForwardingAlgorithm.SP_ECMP, False)
+    prefix_dbs = [db for db in prefix_dbs if db.this_node_name in leaves]
+    states, ps = topologies.build_states(adj_dbs, prefix_dbs)
+    tpu = TpuSpfSolver(me)
+    db = tpu.build_route_db(me, states, ps)
+    built, groups = (_counter("decision.rib.entries_built"),
+                     _counter("decision.rib.entry_groups"))
+    assert len(dict(db.unicast_routes)) == n  # the table, materialized
+    want_groups = n if distinct else 1
+    assert _counter("decision.rib.entries_built") - built == n
+    assert _counter("decision.rib.entry_groups") - groups == want_groups
+    for step, metric in enumerate((9, 4)):
+        built, groups = (_counter("decision.rib.entries_built"),
+                         _counter("decision.rib.entry_groups"))
+        _flap(states, adj_dbs, me, metric)
+        new_db = tpu.build_route_db(me, states, ps)
+        assert _counter("decision.rib.entries_built") - built == n, step
+        assert (
+            _counter("decision.rib.entry_groups") - groups == want_groups
+        ), step
+        mat = {name: attrs for name, _, _, _, attrs
+               in tpu.last_timing["spans"]}["tpu.mat"]
+        assert mat["entries_built"] == n
+        assert mat["entry_groups"] == want_groups
+        stats = tpu.last_device_stats
+        assert (stats["entries_built"], stats["entry_groups"]) == (
+            n, want_groups)
+        assert {e.igp_cost for e in new_db.unicast_routes.values()} == (
+            {metric + 1 + i for i in range(n)} if distinct else {metric + 1}
+        )
+    # an epoch that moves no route builds none, and its span says nothing
+    tpu.build_route_db(me, states, ps)
+    mat = {name: attrs for name, _, _, _, attrs
+           in tpu.last_timing["spans"]}["tpu.mat"]
+    assert "entries_built" not in mat and "entry_groups" not in mat
+
+
 def test_rehearsed_benchmark_stamps_key_index_builds_on_rib_diff(capsys):
     """In a rehearsal of the benchmark's flap cell (benchmark/rehearsal,
     CPU) every decision.rib_diff span carries `key_index_builds`, and

@@ -472,3 +472,357 @@ def test_full_result_with_no_table_to_compare_resets(case):
             LazyUnicastRoutes({}, [view0]),
             LazyUnicastRoutes({}, [crib.view()]),
         ) is None
+
+
+# -- entries are built by group: held to RibUnicastEntry's own __init__ ----
+
+_ME = "n-me"
+_D = 5  # links of the vantage
+
+
+def _group_links():
+    """`_D` links of the vantage, each with a v4 and a v6 address."""
+    from openr_tpu.decision.link_state import Link
+
+    return [
+        Link(
+            "0",
+            _ME, Adjacency(f"nbr-{d}", f"if-{d}", metric=1,
+                           next_hop_v4=f"10.0.{d}.2",
+                           next_hop_v6=f"fe80::{d}:2"),
+            f"nbr-{d}", Adjacency(_ME, f"if-{d}-back", metric=1,
+                                  next_hop_v4=f"10.0.{d}.1",
+                                  next_hop_v6=f"fe80::{d}:1"),
+        )
+        for d in range(_D)
+    ]
+
+
+def _group_matrix(announcers, v4=()):
+    """A matrix's host side for rows whose announcers are `announcers[r]`
+    (node names, in the order of their cells); rows in `v4` carry a v4
+    prefix."""
+    from types import SimpleNamespace
+
+    from openr_tpu.types import PrefixEntry
+
+    n = len(announcers)
+    plist = [
+        f"10.9.{r}.0/24" if r in v4 else f"2001:db8:{r:x}::/64"
+        for r in range(n)
+    ]
+    return SimpleNamespace(
+        prefix_list=plist,
+        node_areas=[[(name, "0") for name in row] for row in announcers],
+        entry_refs=[
+            [PrefixEntry(prefix=plist[r], weight=100 * r + a)
+             for a in range(len(row))]
+            for r, row in enumerate(announcers)
+        ],
+        is_v4=np.asarray([r in v4 for r in range(n)], bool),
+    )
+
+
+def _bits(n, width, *cells):
+    m = np.zeros((n, width), bool)
+    for r, c in cells:
+        m[r, c] = True
+    return m
+
+
+def _entries_by_init(links, matrix, rows, met, s3, nh, lfa_slot, lfa_metric,
+                     value_rows, use_v4_allowed):
+    """What build_entries has to build, through RibUnicastEntry(...) and
+    NextHop(...)'s own __init__ and select_best_node_area, a row at a
+    time."""
+    from openr_tpu.decision.rib import NextHop, RibUnicastEntry
+    from openr_tpu.decision.spf_solver import select_best_node_area
+
+    def hop(d, use_v4, metric):
+        return NextHop(
+            address=links[d].nh_from_node(_ME, use_v4),
+            if_name=links[d].iface_from_node(_ME),
+            metric=metric,
+            area=links[d].area,
+            neighbor_node_name=links[d].other_node(_ME),
+        )
+
+    out = {}
+    for i, p in enumerate(rows.tolist()):
+        v = p if value_rows is None else int(value_rows[i])
+        nas = matrix.node_areas[p]
+        selected = {na for a, na in enumerate(nas) if s3[v, a]}
+        if not selected:
+            continue
+        best = select_best_node_area(selected, _ME)
+        use_v4 = use_v4_allowed and bool(matrix.is_v4[p])
+        alternate = frozenset()
+        if lfa_slot is not None and 0 <= lfa_slot[v] < len(links):
+            alternate = frozenset(
+                {hop(int(lfa_slot[v]), use_v4, int(lfa_metric[v]))}
+            )
+        out[matrix.prefix_list[p]] = RibUnicastEntry(
+            prefix=matrix.prefix_list[p],
+            nexthops=frozenset(
+                hop(d, use_v4, int(met[v]))
+                for d in range(len(links)) if nh[v, d]
+            ),
+            best_prefix_entry=matrix.entry_refs[p][nas.index(best)],
+            best_node_area=best,
+            igp_cost=int(met[v]),
+            lfa_nexthops=alternate,
+        )
+    return out
+
+
+def _grouped_case(case):
+    """-> (matrix, rows, met, s3, nh, lfa_slot, lfa_metric, value_rows,
+    use_v4_allowed, groups expected or None)."""
+    a_cap = 4
+    four = ["n-a", "n-b", "n-c", "n-d"]
+    lfa_slot = lfa_metric = value_rows = None
+    use_v4_allowed = True
+    groups = None
+    if case == "one_row":
+        n = 1
+        matrix = _group_matrix([four])
+        s3 = _bits(n, a_cap, (0, 2))
+        nh = _bits(n, _D, (0, 1), (0, 3))
+        met = np.asarray([40], np.int32)
+        groups = 1
+    elif case in ("one_value", "every_row_distinct"):
+        n = 24
+        matrix = _group_matrix([four] * n)
+        s3 = _bits(n, a_cap, *((r, r % a_cap) for r in range(n)))
+        nh = _bits(n, _D, *((r, 1) for r in range(n)),
+                   *((r, 4) for r in range(n)))
+        met = np.full(n, 70, np.int32)
+        lfa_slot = np.full(n, 2, np.int32)
+        lfa_metric = np.full(n, 95, np.int32)
+        groups = 1
+        if case == "every_row_distinct":
+            # the metric, the next hops or the alternate alone tell rows
+            # apart
+            met[:8] += 1 + np.arange(8, dtype=np.int32)
+            nh[8:16] = [
+                [(i + 1) >> d & 1 for d in range(_D)] for i in range(8)
+            ]
+            lfa_metric[16:] += 1 + np.arange(8, dtype=np.int32)
+            lfa_slot[20:] = 3
+            groups = n
+    elif case.startswith(("two_selected", "three_selected")):
+        k = 2 if case.startswith("two") else 3
+        me_in = case.endswith("with_vantage")
+        n = 12
+        # the announcers differ from row to row, in name and in order,
+        # and the same bits select another best among other names
+        names = []
+        for r in range(n):
+            row = [f"n-{chr(ord('a') + (r + j) % 6)}" for j in range(a_cap)]
+            if me_in:
+                row[(r + 1) % a_cap] = _ME
+            names.append(row if r % 2 else row[::-1])
+        matrix = _group_matrix(names)
+        s3 = _bits(n, a_cap, *(
+            (r, (r + 1 + j) % a_cap) for r in range(n) for j in range(k)
+        ))
+        nh = _bits(n, _D, *((r, r % 2) for r in range(n)))
+        met = np.full(n, 30, np.int32)
+        groups = 2
+    elif case in ("v4_allowed", "v4_over_v6"):
+        n = 6
+        matrix = _group_matrix([four] * n, v4={1, 2, 5})
+        s3 = _bits(n, a_cap, *((r, 0) for r in range(n)))
+        nh = _bits(n, _D, *((r, 2) for r in range(n)))
+        met = np.full(n, 10, np.int32)
+        lfa_slot = np.full(n, 0, np.int32)
+        lfa_metric = np.full(n, 20, np.int32)
+        use_v4_allowed = case == "v4_allowed"
+        groups = 2 if use_v4_allowed else 1
+    elif case in ("lfa_present", "lfa_slot_out_of_range"):
+        n = 8
+        matrix = _group_matrix([four] * n)
+        s3 = _bits(n, a_cap, *((r, 3) for r in range(n)))
+        nh = _bits(n, _D, *((r, 0) for r in range(n)))
+        met = np.full(n, 10, np.int32)
+        lfa_slot = np.asarray([0, 1, 1, 4, 4, 2, 2, 2], np.int32)
+        lfa_metric = np.asarray([15, 15, 16, 15, 15, 15, 15, 15], np.int32)
+        groups = 5
+        if case == "lfa_slot_out_of_range":
+            # none, past the links, and a negative that is not -1: no
+            # alternate (the device writes -1 and 0 there; other bytes
+            # name other groups of equal entries)
+            lfa_slot[[1, 3, 5, 6]] = [-1, _D, _D + 3, -7]
+            groups = 8
+    elif case == "value_rows":
+        # the delta path: six matrix rows out of twenty, their values at
+        # positions of a payload that carries other rows' values too
+        n = 20
+        matrix = _group_matrix([four] * n)
+        rows = np.asarray([17, 3, 11, 4, 19, 0])
+        value_rows = np.asarray([5, 0, 7, 2, 3, 6])
+        s3 = _bits(8, a_cap, *((v, v % a_cap) for v in range(8)),
+                   (7, 0), (3, 2))
+        nh = _bits(8, _D, *((v, v % _D) for v in range(8)), (2, 4))
+        met = (100 + np.arange(8) // 2).astype(np.int32)
+        lfa_slot = (np.arange(8) % 3 - 1).astype(np.int32)
+        lfa_metric = (met + 9).astype(np.int32)
+        return (matrix, rows, met, s3, nh, lfa_slot, lfa_metric,
+                value_rows, True, 6)
+    elif case == "no_selected_bit":
+        n = 9
+        matrix = _group_matrix([four] * n)
+        s3 = _bits(n, a_cap, (1, 0), (4, 2), (4, 3), (8, 1))
+        nh = _bits(n, _D, *((r, 1) for r in range(n)))
+        met = np.full(n, 12, np.int32)
+        groups = 1
+    else:
+        raise AssertionError(case)
+    return (matrix, np.arange(n), met, s3, nh, lfa_slot, lfa_metric,
+            value_rows, use_v4_allowed, groups)
+
+
+@pytest.mark.parametrize("case", [
+    "one_row", "one_value", "every_row_distinct",
+    "two_selected_with_vantage", "two_selected_without_vantage",
+    "three_selected_with_vantage", "three_selected_without_vantage",
+    "v4_allowed", "v4_over_v6",
+    "lfa_absent", "lfa_present", "lfa_slot_out_of_range",
+    "value_rows", "no_selected_bit",
+])
+def test_grouped_build_matches_entries_built_by_init(case):
+    """build_entries builds by group (one template a distinct value of
+    the columns, the announcer from numpy): every entry equals, field for
+    field and by == and hash, the one RibUnicastEntry(...) builds from
+    the same row, and the two counters count entries and groups."""
+    import dataclasses
+
+    from openr_tpu.decision.columnar_rib import build_entries
+    from openr_tpu.decision.rib import RibUnicastEntry
+
+    (matrix, rows, met, s3, nh, lfa_slot, lfa_metric, value_rows,
+     use_v4_allowed, groups) = _grouped_case(
+        "one_value" if case == "lfa_absent" else case)
+    if case == "lfa_absent":
+        lfa_slot = lfa_metric = None
+    links = _group_links()
+    want = _entries_by_init(
+        links, matrix, rows, met, s3, nh, lfa_slot, lfa_metric, value_rows,
+        use_v4_allowed,
+    )
+    assert want, case
+    built0 = _counter("decision.rib.entries_built")
+    groups0 = _counter("decision.rib.entry_groups")
+    got, nh_cache = {"stays": None}, {}
+    counted = build_entries(
+        got, nh_cache, _ME, matrix, links, rows, met, s3, nh, lfa_slot,
+        lfa_metric, value_rows=value_rows, use_v4_allowed=use_v4_allowed,
+    )
+    assert got.pop("stays", 0) is None  # built INTO the table handed in
+    assert got == want
+    names = [f.name for f in dataclasses.fields(RibUnicastEntry)]
+    for prefix, entry in got.items():
+        ref = want[prefix]
+        assert type(entry) is RibUnicastEntry
+        assert list(entry.__dict__) == names == list(ref.__dict__), prefix
+        for name in names:
+            assert getattr(entry, name) == getattr(ref, name), (prefix, name)
+        # the advertisement is the row's own object, not an equal one
+        assert entry.best_prefix_entry is ref.best_prefix_entry, prefix
+        assert hash(entry) == hash(ref) and entry == ref, prefix
+        assert repr(entry) == repr(ref), prefix
+        for hop in entry.nexthops:
+            assert hop.metric == entry.igp_cost
+    if case == "no_selected_bit":
+        assert len(want) == 3 < len(rows)  # skipped, and not counted
+    if case.endswith("with_vantage"):
+        assert {e.best_node_area for e in got.values()} == {(_ME, "0")}
+    if case.endswith("without_vantage"):
+        assert len({e.best_node_area for e in got.values()}) > 2
+    if case == "v4_allowed":
+        assert {
+            hop.address for p in (1, 2, 5)
+            for e in [got[matrix.prefix_list[p]]]
+            for hop in e.nexthops | e.lfa_nexthops
+        } == {"10.0.2.2", "10.0.0.2"}
+    if case == "lfa_slot_out_of_range":
+        assert [bool(got[p].lfa_nexthops) for p in matrix.prefix_list] == [
+            True, False, True, False, True, False, False, True]
+    assert counted[0] == len(want)
+    assert _counter("decision.rib.entries_built") - built0 == len(want)
+    assert counted[1] == _counter("decision.rib.entry_groups") - groups0
+    if groups is not None:
+        assert counted[1] == groups, case
+    # again over a warm nh_cache: the same table, the next hops shared
+    again = {}
+    build_entries(
+        again, nh_cache, _ME, matrix, links, rows, met, s3, nh, lfa_slot,
+        lfa_metric, value_rows=value_rows, use_v4_allowed=use_v4_allowed,
+    )
+    assert again == want
+    for prefix, entry in again.items():
+        assert entry.nexthops is got[prefix].nexthops
+        assert entry.lfa_nexthops is got[prefix].lfa_nexthops or not (
+            entry.lfa_nexthops)
+
+
+def test_entries_of_one_group_share_no_dict():
+    """A group's entries are copies of one template: each has a dict of
+    its own (a store into one is not seen in another), the immutable
+    next hops are the one frozenset of nh_cache, and a factory-defaulted
+    field the loop does not set is called for every entry."""
+    from openr_tpu.decision import columnar_rib
+
+    (matrix, rows, met, s3, nh, lfa_slot, lfa_metric, value_rows,
+     use_v4_allowed, _groups) = _grouped_case("one_value")
+    links = _group_links()
+
+    def build():
+        routes = {}
+        assert columnar_rib.build_entries(
+            routes, {}, _ME, matrix, links, rows, met, s3, nh, lfa_slot,
+            lfa_metric,
+        ) == (len(rows), 1)
+        return list(routes.values())
+
+    entries = build()
+    dicts = {id(e.__dict__) for e in entries}
+    assert len(dicts) == len(entries)
+    first, second = entries[:2]
+    assert first.nexthops is second.nexthops
+    assert first.lfa_nexthops is second.lfa_nexthops
+    object.__setattr__(first, "counter_id", "only-mine")
+    assert second.counter_id is None
+    assert all(e.counter_id is None for e in entries[1:])
+    saved = columnar_rib._ENTRY_FACTORIES
+    columnar_rib._ENTRY_FACTORIES = [("counter_id", list)]
+    try:
+        entries = build()
+    finally:
+        columnar_rib._ENTRY_FACTORIES = saved
+    assert all(e.counter_id == [] for e in entries)
+    assert len({id(e.counter_id) for e in entries}) == len(entries)
+
+
+def test_selected_bits_past_a_rows_names_build_no_route():
+    """A generation older than a row's advertisement may select a cell
+    the row no longer has: such a row is no route (and not counted), as
+    a row with no bit; a bit among the names beside one past them is
+    read alone."""
+    from openr_tpu.decision.columnar_rib import build_entries
+
+    matrix = _group_matrix([["n-a", "n-b"], ["n-a", "n-b"], ["n-a"]])
+    links = _group_links()
+    s3 = _bits(3, 4, (0, 3), (1, 1), (1, 2), (2, 0))
+    nh = _bits(3, _D, (0, 0), (1, 0), (2, 0))
+    met = np.full(3, 5, np.int32)
+    routes = {}
+    built0 = _counter("decision.rib.entries_built")
+    assert build_entries(
+        routes, {}, _ME, matrix, links, np.arange(3), met, s3, nh,
+    ) == (2, 1)
+    assert _counter("decision.rib.entries_built") - built0 == 2
+    assert sorted(routes) == sorted(matrix.prefix_list[1:])
+    assert routes[matrix.prefix_list[1]].best_node_area == ("n-b", "0")
+    assert routes == _entries_by_init(
+        links, matrix, np.arange(3), met, s3, nh, None, None, None, True)

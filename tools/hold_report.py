@@ -20,8 +20,10 @@ rows than a delta pull holds — and those rows; `wide_epochs`, the
 incremental solves that looked at every row, and `wide_epochs.<reason>`)
 and `decision.crib.key_index_builds` gained over that window, with how
 the window's full results landed (`decision.crib.full_journaled`,
-`.full_resets`) and how many of its diffs were warm and columnar or
-journal-bounded (`decision.fast_unicast_diffs`). A
+`.full_resets`), how many of its diffs were warm and columnar or
+journal-bounded (`decision.fast_unicast_diffs`), and the route entries it
+built with the groups they were built by (`decision.rib.entries_built`,
+`.entry_groups`: groups / built near 0 is rows that read alike). A
 builder's tool: it edits nothing of the benchmark and the program has no
 such exporter.
 """
@@ -48,6 +50,7 @@ WINDOW_COUNTERS = tuple(f"decision.tpu.{name}" for name in (
 )) + (
     "decision.crib.key_index_builds", "decision.crib.full_journaled",
     "decision.crib.full_resets", "decision.fast_unicast_diffs",
+    "decision.rib.entries_built", "decision.rib.entry_groups",
 )
 WIDE_REASONS = "decision.tpu.wide_epochs."
 
